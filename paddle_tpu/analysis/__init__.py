@@ -12,7 +12,7 @@ anything executes.
   mutation). Wired into ``Executor.run`` as verify-on-first-compile.
 - :mod:`.donation` — donation-safety analyzer (host-owned / view /
   zero-copy-host-backed buffers donated; unused donations; alias
-  escapes — the PR 6 SIGSEGV taxonomy). Wired into ``Trainer`` at
+  escapes — the PR 6 SIGSEGV classification). Wired into ``Trainer`` at
   compile time.
 - :mod:`.shardcheck` — static Plan audit (would-reshard, dropped
   specs, big-leaf-replicated). Rendered by ``Plan.describe`` and

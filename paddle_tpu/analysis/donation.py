@@ -10,7 +10,7 @@ the runtime reused memory numpy still owned) and its snapshot-side twin
 source). This module flags those classes *before the step runs*, as
 typed :class:`..diagnostics.Diagnostic` errors.
 
-Buffer-provenance taxonomy (the PR 6 classes):
+Buffer-provenance classes (from PR 6):
 
 - ``"numpy"``        — a host ``np.ndarray`` owning its data. Donating
   it is flagged: on the CPU backend the implicit ``device_put`` may
@@ -128,7 +128,7 @@ def _is_cpu(x) -> bool:
 
 
 def classify_provenance(leaf) -> str:
-    """Classify one leaf into the taxonomy above (module docstring)."""
+    """Classify one leaf into the classes above (module docstring)."""
     import jax
 
     if isinstance(leaf, np.ndarray):

@@ -190,7 +190,7 @@ def export_decoder(decoder, directory: str, *,
     _require(not (decoder.paged and decoder.prefix_cache), AotError,
              "aot export does not cover the paged prefix cache (suffix/"
              "restep executables are not serialized)")
-    exp_mod = _compat.jax_export()
+    exp_mod = jax.export
     gens = jnp.asarray(decoder._slot_gen.astype(np.uint32))
 
     blobs: Dict[str, bytes] = {}
@@ -395,7 +395,7 @@ def load_programs(directory: str, manifest: Dict[str, Any]):
     callable is ``jax.jit(exported.call)`` — jit-wrapped ONCE so the
     serving loop's per-tick dispatch hits the jit cache instead of
     re-staging the call primitive."""
-    exp_mod = _compat.jax_export()
+    exp_mod = jax.export
     checks = manifest.get("checksums", {})
 
     def _one(fname):

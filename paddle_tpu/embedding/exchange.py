@@ -51,7 +51,7 @@ from ..core.mesh import get_mesh
 from ..optimizer.sparse import apply_rows, find_sparse_embeddings, merge_rows
 from ..quant.collectives import MIN_COMPRESS_SIZE, record_payload_bytes
 from ..quant.ops import absmax_decode, absmax_encode
-from ..utils.compat import shard_map
+from jax import shard_map
 
 PyTree = Any
 

@@ -21,8 +21,11 @@ def run_check(verbose: bool = True) -> bool:
             print(msg)
 
     devs = jax.devices()
-    log(f"paddle_tpu {pt.__version__} — {len(devs)} device(s): "
-        f"{devs[0].platform}")
+    log(f"paddle_tpu {pt.__version__} — platform {devs[0].platform}, "
+        f"device_kind {devs[0].device_kind!r}, {len(devs)} device(s)")
+    if devs[0].platform == "cpu":
+        log("NOTE: this check ran on the CPU — no accelerator was "
+            "exercised (python chip_smoke.py is the on-chip check)")
 
     pt.seed(0)
     model = pt.nn.Sequential(pt.nn.Linear(4, 8, act="relu"),

@@ -20,7 +20,6 @@ from .core.enforce import enforce
 from .nn.layer import Layer
 from .utils import compat as _compat
 
-_compat.jax_export()  # jax<0.5: jax.export is lazy; attribute access needs one import
 
 
 def save(layer: Layer, dirname: str, example_args: Sequence,

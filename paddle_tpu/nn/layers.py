@@ -455,8 +455,7 @@ class _MHADecodeMixin:
             q = rotary_embedding(q, q_positions,
                                  theta=self.rotary_theta)
         if (decode_t is not None and tq == 1 and self.use_flash
-                and decode_flash_ok(k.shape[1], self.head_dim)
-                and _get_flash_decode() is not None):
+                and decode_flash_ok(k.shape[1], self.head_dim)):
             out = _get_flash_decode()(q, k, v, decode_t, window=window)
         else:
             out = scaled_dot_product_attention(

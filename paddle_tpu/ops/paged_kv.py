@@ -230,9 +230,8 @@ def attend(q, kpool, vpool, table, t_rows,
     t_rows = jnp.broadcast_to(jnp.asarray(t_rows, jnp.int32),
                               (q.shape[0],))
     quantized = isinstance(kpool, QuantizedPool)
-    if (A.decode_flash_ok(page_size * n_log, d,
-                          "int8" if quantized else "f32", page_size)
-            and A._get_flash_decode() is not None):
+    if A.decode_flash_ok(page_size * n_log, d,
+                         "int8" if quantized else "f32", page_size):
         from .pallas.flash_decode import flash_decode_paged
 
         if quantized:

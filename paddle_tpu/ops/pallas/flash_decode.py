@@ -45,12 +45,6 @@ from jax.experimental import pallas as pl
 from ...core.enforce import enforce
 from .flash_attention import _NEG_INF, _scratch, _use_interpret, pltpu
 
-if pltpu is None:  # pragma: no cover
-    # unlike the sibling training kernel, this one NEEDS pltpu
-    # (PrefetchScalarGridSpec for the cursor); failing the import here
-    # lets ops.attention's guarded importers fall back to the XLA path
-    raise ImportError("flash_decode requires jax.experimental.pallas.tpu")
-
 DEFAULT_DECODE_BLOCK_K = 256
 
 

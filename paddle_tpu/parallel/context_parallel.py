@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax import lax
 from jax.sharding import PartitionSpec as P
 

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.enforce import enforce
-from ..utils import compat
 from ..optimizer.optimizers import Momentum, Optimizer, tree_map
 
 
@@ -99,7 +98,7 @@ def quantized_allreduce(x, axis_name: str = "dp", bits: int = 8):
 
     Call inside shard_map with ``axis_name`` live. x must have a leading
     dim divisible by the axis size (pad first if needed)."""
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     qmax = float(2 ** (bits - 1) - 1)
     orig_shape = x.shape
     flat = x.reshape(-1)
@@ -137,7 +136,7 @@ def dgc_allreduce(grads, axis_name: str = "dp", sparsity: float = 0.999,
     summed (dense) gradients."""
     def reduce_leaf(g):
         kept, _ = top_k_sparsify(g, sparsity)
-        if quantize and kept.size % compat.axis_size(axis_name) == 0:
+        if quantize and kept.size % lax.axis_size(axis_name) == 0:
             return quantized_allreduce(kept, axis_name)
         return lax.psum(kept, axis_name)
 

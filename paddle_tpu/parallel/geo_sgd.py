@@ -34,7 +34,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.enforce import enforce
-from ..utils.compat import shard_map
+from jax import shard_map
 
 
 class GeoSGDTrainer:

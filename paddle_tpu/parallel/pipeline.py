@@ -53,7 +53,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.enforce import enforce
 from ..core.mesh import get_mesh
-from ..utils.compat import shard_map
+from jax import shard_map
 
 
 def _stack_to_stages(stacked_params, n_stages: int):

@@ -486,7 +486,7 @@ def compile_step(plan: Optional[Plan], fn: Callable, *,
       per-shard on the batch argument (``batch_argnum``) with all other
       arguments replicated, and MUST be collective-aware: reduce its
       loss/grads over ``jax.lax`` collectives on the batch axes (the
-      Trainer threads ``pmean_axes`` for this). ``check_rep=False``
+      Trainer threads ``pmean_axes`` for this). ``check_vma=False``
       because the post-``pmean`` replication is real but not statically
       inferable.
 
@@ -513,7 +513,7 @@ def compile_step(plan: Optional[Plan], fn: Callable, *,
         return compiled
 
     # pure-DP fallback: shard_map keeps map-style collective ergonomics
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     enforce(not static_argnums,
             "static_argnums is not supported on the shard_map fallback "
@@ -527,7 +527,7 @@ def compile_step(plan: Optional[Plan], fn: Callable, *,
         b = batch_argnum % n
         in_specs = tuple(batch_spec if i == b else P() for i in range(n))
         return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), check_rep=False)(*args)
+                         out_specs=P(), check_vma=False)(*args)
 
     compiled = jax.jit(wrapped, donate_argnums=donate)
     compiled.compiled_via = "shard_map"

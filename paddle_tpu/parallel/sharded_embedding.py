@@ -32,7 +32,7 @@ from ..core.enforce import InvalidArgumentError, enforce
 from ..core.mesh import get_mesh
 from ..nn.layer import Layer
 from .. import initializer as I
-from ..utils.compat import shard_map
+from jax import shard_map
 
 
 def _lookup_inner(ids, table, *, axis, rows_per_shard):

@@ -285,8 +285,6 @@ def _partitioned_psum(axis_name: str, group: int, has_key: bool):
     from jax.experimental.custom_partitioning import custom_partitioning
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    compat.fix_custom_partitioning_static_args()
-
     def ref(x, *maybe_key):
         # global semantics (abstract eval + the no-mesh eager fallback):
         # the exact fp32 sum over the stacked partials. The partitioned
@@ -334,9 +332,21 @@ def _partitioned_psum(axis_name: str, group: int, has_key: bool):
         msh = getattr(a_sh, "mesh", None) or mesh
         return NamedSharding(msh, P())
 
-    compat.def_partition(
-        wrapped, partition=partition,
-        infer_sharding_from_operands=infer_sharding_from_operands)
+    def sharding_rule(mesh, value_types, result_types):
+        # Shardy's view of the call: the stacked dim ``n`` is summed
+        # away, every other dim of x passes through; the key (when
+        # given) shares no factor with the result
+        def dims(prefix, rank):
+            return " ".join(f"{prefix}{i}" for i in range(rank))
+
+        rest = dims("d", len(value_types[0].shape) - 1)
+        key = ", " + dims("k", len(value_types[1].shape)) if has_key else ""
+        return f"n {rest}{key} -> {rest}"
+
+    wrapped.def_partition(
+        partition=partition,
+        infer_sharding_from_operands=infer_sharding_from_operands,
+        sharding_rule=sharding_rule)
     return wrapped
 
 

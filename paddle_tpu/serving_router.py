@@ -2779,8 +2779,9 @@ def spawn_replicas(spec: Optional[str], n: int, role: str = "decode",
             cmd += ["--spec-kw", json.dumps(spec_kw)]
         if not warm:
             cmd += ["--no-warm"]
+        # workers inherit the caller's platform: the chip in production,
+        # JAX_PLATFORMS=cpu where the caller (the test suite) set it
         wenv = dict(os.environ if env is None else env)
-        wenv.setdefault("JAX_PLATFORMS", "cpu")
         procs.append((i, subprocess.Popen(
             cmd, env=wenv, stdout=log, stderr=subprocess.STDOUT), pf,
             log))

@@ -23,11 +23,8 @@ import numpy as np
 
 from ..core.enforce import enforce
 from ..utils.atomic import atomic_write_text
-from ..utils import compat as _compat
 from .executor import Executor, Scope, _exec_opnodes, _exec_program
 from .program import Program, Var, _GradNode, _OpNode
-
-_compat.jax_export()  # jax<0.5: jax.export is lazy; attribute access needs one import
 
 
 def _prune(program: Program, fetch_names: Sequence[str]):

@@ -20,7 +20,7 @@ at with its profiler (SURVEY §5.1). The pieces:
   ``serving.BatchedDecoder.run(debug_port=)``, or ``server.start()``.
 - ``costs``: program cost ledger — XLA cost/memory analysis per cached
   executable, MFU + arithmetic intensity + roofline verdict derivation
-  (per-backend peak table with a nominal CPU fallback row).
+  (peaks from ``utils.flops.DEVICE_PEAKS``; the CPU has none).
 - ``profiling``: goodput ledger (step-time bucket decomposition,
   active-slot-tokens vs capacity), bounded on-demand device capture
   (``POST /profilez``, 404→409→200), and the ``PT-PERF-80x``

@@ -735,9 +735,7 @@ class TrainLoop:
                     dt = time.perf_counter() - t0
                     # performance attribution: register the step
                     # program's cost once, split this step into goodput
-                    # buckets, and feed the regression sentinel (a
-                    # device-init-timeout CPU fallback is a degraded
-                    # row — it must never poison a chip baseline)
+                    # buckets, and feed the regression sentinel
                     if not self._cost_registered:
                         self._cost_registered = True
                         self._register_step_cost(batch)
@@ -746,9 +744,7 @@ class TrainLoop:
                         input_wait=input_wait, dispatch=disp,
                         device_compute=max(0.0, dt - disp))
                     _profiling.sentinel().observe(
-                        "train.step", self._backend(), dt,
-                        degraded=bool(os.environ.get(
-                            "PT_BENCH_CPU_FALLBACK")))
+                        "train.step", self._backend(), dt)
                     _costs.observe_step("train.step", dt)
                     tmet = _train_metrics()
                     tmet["steps"].inc()

@@ -340,7 +340,7 @@ class TestExecutorWiring:
 
 
 class TestDonation:
-    def test_provenance_taxonomy(self):
+    def test_provenance_classes(self):
         owned_np = np.ones((4, 4), np.float32)
         assert classify_provenance(owned_np) == "numpy"
         assert classify_provenance(owned_np[1:]) == "host-view"

@@ -648,8 +648,11 @@ def test_scaler_spawn_retire_e2e_real_replicas():
         # idle: the scaler retires the spawned replica
         _until(lambda: sc._live_count() == 1, timeout=30,
                msg="drained back to min")
-        assert any(e["event"] == "scale_down"
-                   for e in sc.scale_events())
+        # the event is recorded AFTER the victim left the fleet and was
+        # closed (Scaler._drain_bg), so wait for it as well
+        _until(lambda: any(e["event"] == "scale_down"
+                           for e in sc.scale_events()), timeout=30,
+               msg="scale_down recorded")
         sc.stop()
         assert max(n for _, n in sc.timeline) == 2
         assert sc.timeline[-1][1] == 1

@@ -16,8 +16,7 @@ order), not compiler noise.
 import jax
 import pytest
 
-from conftest import load_tool, requires_partial_manual
-from paddle_tpu.utils import compat
+from conftest import load_tool
 
 pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
                                 reason="needs 8 devices")
@@ -32,10 +31,6 @@ def _kinds(rep):
     return set(rep["collectives"])
 
 
-@pytest.mark.skipif(
-    not compat.supports_partial_manual_shard_map(),
-    reason="golden collective structure pinned on the r5 toolchain's GSPMD; "
-           "this older jax partitions dp4tp2 with extra gathers/all-to-alls")
 def test_dp_only_configs_reduce_gradients_only(cr):
     """Pure/2D data+tensor parallel BERT: every byte moves through
     all-reduce (grad buckets + tp activation reductions) — a gather or
@@ -46,7 +41,6 @@ def test_dp_only_configs_reduce_gradients_only(cr):
         assert rep["bytes_per_flop"] < bpf_budget, (name, rep)
 
 
-@requires_partial_manual
 def test_hybrid_pp_config_structure_and_budget(cr):
     """dp x tp x pp: neighbour permutes for the pipeline, all-reduce for
     dp/tp, and NO all-to-all — the r4 interleaved weight-shuffle bug
@@ -57,7 +51,6 @@ def test_hybrid_pp_config_structure_and_budget(cr):
     assert rep["bytes_per_flop"] < 0.06, rep
 
 
-@requires_partial_manual
 def test_interleaved_traffic_equals_gpipe(cr):
     """Ring-order weight storage keeps the interleaved schedule's
     traffic EQUAL to GPipe's (the r4 regression this gate exists for)."""
@@ -88,7 +81,6 @@ def test_deepfm_ep_dispatch_budget(cr):
     assert rep["comm_mbytes_total"] < 0.2, rep
 
 
-@requires_partial_manual
 def test_bert_moe_ep_pp_structure(cr):
     """The r5 dp x pp x ep MoE composition: expert cross-layout movement
     (all-gather/all-to-all), the pp ring, and dp grad all-reduce in ONE
@@ -100,7 +92,6 @@ def test_bert_moe_ep_pp_structure(cr):
     assert rep["bytes_per_flop"] < 0.03, rep
 
 
-@requires_partial_manual
 def test_gpt_hybrid_structure(cr):
     """The GPT 3D flagship shows the same collective structure as the
     BERT hybrid: all-reduce (dp grads + tp activations) and the

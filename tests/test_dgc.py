@@ -9,7 +9,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as pt
-from paddle_tpu.utils.compat import shard_map
+from jax import shard_map
 from paddle_tpu.parallel import (DGCMomentum, dgc_allreduce,
                                  quantized_allreduce, top_k_sparsify)
 

@@ -21,7 +21,6 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import paddle_tpu as pt
-from conftest import requires_partial_manual
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
 pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
@@ -38,12 +37,6 @@ def partitioner(request):
     callbacks). Both params set the flag EXPLICITLY (with save/restore)
     so the matrix holds even if the ambient default changes or another
     test leaks the config (VERDICT r4 weak #5 / next #9)."""
-    from paddle_tpu.utils import compat
-
-    if (request.param == "shardy"
-            and not compat.supports_shardy_sharding_rule()):
-        pytest.skip("this jax's custom_partitioning takes no sdy "
-                    "sharding_rule — shardy-mode would gather, not shard")
     old = jax.config.jax_use_shardy_partitioner
     jax.config.update("jax_use_shardy_partitioner",
                       request.param == "shardy")
@@ -238,7 +231,6 @@ def test_partitioned_feature_combos_match_unsharded(causal, window, mask,
                                rtol=2e-6, atol=2e-6)
 
 
-@requires_partial_manual
 def test_hybrid_bert_flagship_rides_flash(monkeypatch):
     """VERDICT r3 #3 done-criterion: the FLAGSHIP build_bert_hybrid_step
     (real BertForPretraining under dp x tp x pp) takes the flash kernel
@@ -362,3 +354,45 @@ def test_banded_window_partitions_without_gather(partitioner):
     for gg, rr, name in zip(got_g, ref_g, "qkv"):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(rr),
                                    rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("gqa", [False, True], ids=["mha", "gqa"])
+def test_shard_map_route_matches_unsharded(monkeypatch, gqa):
+    """The multi-chip TPU route (libtpu has no custom_partitioning): the
+    same per-shard kernel bodies under jax.shard_map over the ambient
+    mesh — batch over dp, (kv-)heads over tp. Steered onto the CPU sim
+    here (the route is chosen from the backend): no all-gather, values
+    and gradients equal the unsharded run."""
+    import importlib
+
+    from paddle_tpu.core.mesh import mesh_scope
+
+    FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    mesh = pt.build_mesh(dp=2, tp=2, pp=2)
+    q, k, v = _qkv(seed=5)
+    if gqa:
+        k, v = k[:, :, :2], v[:, :, :2]     # 4 q heads over 2 kv heads
+    keep = jnp.asarray(np.arange(256)[None, :]
+                       < RNG.integers(128, 256, size=(4, 1)))
+    ct = jnp.asarray(RNG.normal(size=q.shape).astype(np.float32))
+
+    def loss(q, k, v, m):
+        return (flash_attention(q, k, v, causal=True, kv_mask=m,
+                                interpret=True) * ct).sum()
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    ref = grad(q, k, v, keep)                 # custom_partitioning route
+    monkeypatch.setattr(FA, "_shard_map_mesh", lambda: mesh)
+    qs, ks, vs = _put(mesh, P("dp", None, "tp", None), q, k, v)
+    keep_s, = _put(mesh, P("dp", None), keep)
+    with mesh_scope(mesh):
+        gfn = jax.jit(grad)
+        txt = gfn.lower(qs, ks, vs, keep_s).compile().as_text()
+        got = gfn(qs, ks, vs, keep_s)
+    assert "all-gather" not in txt
+    assert "CustomSPMDPartitioning" not in txt
+    for g, r, name in zip(got, ref, "qkv"):
+        assert _spec4(g.sharding) == ("dp", None, "tp", None), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-5, atol=2e-5,
+                                   err_msg=f"d{name}")

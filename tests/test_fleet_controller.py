@@ -648,15 +648,22 @@ _ELASTIC_STUB = textwrap.dedent("""
     base = sys.argv[1]
     rank = os.environ["PADDLE_TRAINER_ID"]
     run_id = os.environ["PT_FLEET_RUN_ID"]
+    flag = []
+    # handler BEFORE the seen-file: rank 1 dies as soon as it sees the
+    # file, and the launcher's SIGTERM must find the handler installed
+    signal.signal(signal.SIGTERM, lambda *a: flag.append(1))
     with open(os.path.join(base, f"seen.{rank}.{run_id}"), "w") as f:
         f.write("1")
     if run_id.endswith("a1"):
         sys.exit(0)  # the restarted attempt completes
-    if rank == "1":
-        sys.exit(5)  # first attempt: rank 1 dies
-    flag = []
-    signal.signal(signal.SIGTERM, lambda *a: flag.append(1))
     t0 = time.time()
+    if rank == "1":
+        # first attempt: rank 1 dies — once rank 0 is demonstrably up
+        # (on a loaded host it could otherwise be torn down mid-start)
+        peer = os.path.join(base, f"seen.0.{run_id}")
+        while not os.path.exists(peer) and time.time() - t0 < 30:
+            time.sleep(0.02)
+        sys.exit(5)
     while not flag and time.time() - t0 < 60:
         time.sleep(0.02)
     sys.exit(0)  # clean coordinated-style exit within grace

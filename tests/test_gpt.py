@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from conftest import requires_partial_manual
 from paddle_tpu.models import gpt as G
 
 
@@ -167,7 +166,6 @@ def test_ring_sp_matches_plain():
                                atol=3e-5, rtol=3e-5)
 
 
-@requires_partial_manual
 def test_blocks_compose_with_pipeline():
     """GPT blocks are uniform h -> h: the stacked-params pipeline over
     'pp' matches the sequential fold (same contract as BERT's hybrid)."""

@@ -8,9 +8,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from conftest import requires_partial_manual
-
-pytestmark = requires_partial_manual
 
 
 def _mesh():

@@ -16,16 +16,9 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.parallel import build_bert_hybrid_step, pipeline_apply
 from paddle_tpu.models.bert import BertConfig
-from paddle_tpu.utils import compat
 
-pytestmark = [
-    pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 devices"),
-    pytest.mark.skipif(
-        not compat.supports_partial_manual_shard_map(),
-        reason="pp pipeline ring compiles via partial-auto shard_map, which "
-               "faults this jax's SPMD partitioner (needs jax.shard_map-era "
-               "jax)"),
-]
+pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
+                                reason="needs 8 devices")
 
 
 def _moe_cfg(layers=4):

@@ -23,13 +23,6 @@ import pytest
 
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.quant_matmul import quant_matmul
-from paddle_tpu.utils import compat
-
-# jax<0.5 ships jax.export as a LAZY package attribute — a plain
-# jax.export.export raises AttributeError until the submodule is
-# imported once; the compat funnel materializes it (the same shim every
-# production jax.export caller rides)
-compat.jax_export()
 
 # (b, t, h, d): BERT-base pretrain block and the 2k long-context shape
 ATTN_SHAPES = [(8, 512, 12, 64), (2, 2048, 16, 128)]

@@ -12,7 +12,6 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.parallel.pipeline import GPipe, pipeline_apply
-from conftest import requires_partial_manual
 
 
 L, D, B = 8, 16, 12
@@ -44,7 +43,6 @@ def _sequential(params, x):
     return h
 
 
-@requires_partial_manual
 def test_pipeline_forward_matches_sequential(pp_mesh):
     params = _params()
     x = jnp.asarray(np.random.default_rng(1).normal(
@@ -56,7 +54,6 @@ def test_pipeline_forward_matches_sequential(pp_mesh):
                                atol=1e-5, rtol=1e-5)
 
 
-@requires_partial_manual
 def test_pipeline_grads_match_sequential(pp_mesh):
     params = _params(2)
     x = jnp.asarray(np.random.default_rng(3).normal(
@@ -76,7 +73,6 @@ def test_pipeline_grads_match_sequential(pp_mesh):
                                    atol=5e-5, rtol=5e-5)
 
 
-@requires_partial_manual
 def test_pipeline_jit_with_stage_placed_params(pp_mesh):
     """jit + params physically placed per stage (the production memory
     layout: each chip holds L/n layers)."""
@@ -99,7 +95,6 @@ def test_pipeline_jit_with_stage_placed_params(pp_mesh):
     assert not placed["w"].sharding.is_fully_replicated
 
 
-@requires_partial_manual
 def test_gpipe_layer_wrapper(pp_mesh):
     import paddle_tpu.nn as nn
 

@@ -12,9 +12,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from conftest import requires_partial_manual
-
-pytestmark = requires_partial_manual
 from paddle_tpu.parallel.pipeline import (bubble_fraction, gpipe_ticks,
                                           interleaved_ticks,
                                           pipeline_apply)

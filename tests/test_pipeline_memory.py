@@ -17,9 +17,9 @@ Measured facts these tests pin (8-device CPU mesh, fwd+bwd compiled):
 
 2. The interleaved schedule pays ~v x GPipe's temp bytes: ~v x as many
    ring ticks, each saving a same-size carry for backward. Lower bubble
-   costs v x activation banking — the schedule-choice tradeoff
-   documented in BASELINE.md (use interleaved when bubble-bound, i.e.
-   m/n small; prefer GPipe when HBM-bound and m/n is already large).
+   costs v x activation banking — the schedule-choice tradeoff (use
+   interleaved when bubble-bound, i.e. m/n small; prefer GPipe when
+   HBM-bound and m/n is already large).
 """
 
 import jax
@@ -28,15 +28,11 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from conftest import requires_partial_manual
 from paddle_tpu.parallel import pipeline_apply
 from paddle_tpu.utils.memory import memory_usage
 
-pytestmark = [
-    pytest.mark.skipif(len(jax.devices()) < 8,
-                                    reason="needs 8 devices"),
-    requires_partial_manual,
-]
+pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
+                                reason="needs 8 devices")
 
 L, D, B = 8, 256, 32
 

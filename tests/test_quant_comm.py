@@ -10,7 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import paddle_tpu as pt
@@ -96,7 +96,7 @@ def _ring_psum(x_rows, devs, **kw):
     n = len(devs)
     f = shard_map(lambda v: QC.quantized_psum(v[0], "dp", n, **kw),
                   mesh=_dp_mesh(devs), in_specs=P("dp"), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     return np.asarray(jax.jit(f)(x_rows))
 
 
@@ -120,7 +120,7 @@ class TestQuantizedPsum:
         f = shard_map(
             lambda v: QC.quantized_psum(v[0], "dp", n)[None],
             mesh=_dp_mesh(eight_devices), in_specs=P("dp"),
-            out_specs=P("dp"), check_rep=False)
+            out_specs=P("dp"), check_vma=False)
         rows = np.asarray(jax.jit(f)(x))
         for d in range(1, n):
             np.testing.assert_array_equal(rows[0], rows[d])
@@ -163,7 +163,7 @@ class TestQuantizedPsum:
 
         f = shard_map(body, mesh=_dp_mesh(eight_devices),
                       in_specs=(P("dp"), P("dp"), P("dp")),
-                      out_specs=P(), check_rep=False)
+                      out_specs=P(), check_vma=False)
         out = jax.jit(f)(jnp.asarray(big), jnp.asarray(small),
                          jnp.asarray(cnt))
         # tiny float leaf: EXACT pmean

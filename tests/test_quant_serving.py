@@ -3,8 +3,7 @@ artifact exported through tools/export_serving.py --quantize runs
 through the same serving paths as the fp32 one — the Python predictor
 executes it with a bounded accuracy delta vs fp32, and the C++ native
 reader parses it — so quantized serving is in the test loop before any
-chip window (the on-chip ptserve p50/p99 items stay queued in
-tools/tpu_fill.sh). Reference role:
+chip run (on-chip ptserve p50/p99 has not been measured). Reference role:
 paddle/fluid/inference/api/mkldnn_quantizer.cc (PTQ for serving) +
 inference/tests/api (per-model serving tests)."""
 

@@ -17,7 +17,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.utils import compat
 from paddle_tpu import nn, optimizer
 from paddle_tpu.optimizer.sparse import (apply_rows, merge_rows,
                                          sparse_minimize_fn)
@@ -115,7 +114,7 @@ def test_flops_flat_in_vocab():
         ids = jnp.zeros((8, 16), jnp.int32)
         y = jnp.zeros((8,), jnp.float32)
         c = jax.jit(step_fn).lower(params, state, ids, y).compile()
-        ca = compat.cost_analysis(c)
+        ca = c.cost_analysis()
         if not ca or "flops" not in ca:
             pytest.skip("backend reports no cost analysis")
         return ca["flops"]

@@ -219,14 +219,11 @@ class TestBenchDiff:
             '{"no_metric": 1}\n')
         assert rows == {"tp": {"metric": "tp", "value": 20.0}}
 
-    def test_exclusion_taxonomy(self):
+    def test_exclusion_reasons(self):
         bd = self._bd()
         assert bd.exclude_reason({"value": 1.0}) is None
         assert bd.exclude_reason(
             {"value": 1.0, "backend_degraded": True}) \
-            == "backend_degraded"
-        assert bd.exclude_reason(
-            {"value": 1.0, "backend": "cpu_fallback"}) \
             == "backend_degraded"
         assert bd.exclude_reason(
             {"skipped": True, "cause": "no_chip"}) == "skipped:no_chip"

@@ -1,0 +1,242 @@
+"""The main path's Pallas kernels compile for a DESCRIBED TPU v5e.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip
+that is described, not attached: what Mosaic refuses on the chip it
+refuses here, at no chip time (interpret mode and the Mosaic-MLIR
+lowering of tests/test_pallas_mosaic_lowering.py both stop short of the
+layout/VMEM checks that refused quant_matmul's 1-D scale operand).
+Shapes are GPT-small's real ones (12 q / 4 kv heads, head dim 64,
+seq 1024, decode capacity 2048, page 64).
+
+Nothing runs, so these say nothing about results or times.
+
+The topology is described inside a module-scoped fixture — never at
+import, in a skipif, or in parametrize: only ONE process at a time may
+load libtpu, so under pytest-xdist only the worker that is handed this
+file may load it. For the same reason every compile runs in the test's
+own process, and all of them live in this one file.
+"""
+
+import importlib
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu as pt
+from paddle_tpu.core.mesh import mesh_scope
+from paddle_tpu.ops.pallas.flash_attention import flash_attention
+from paddle_tpu.ops.pallas.flash_decode import (flash_decode,
+                                                flash_decode_paged)
+from paddle_tpu.ops.pallas.quant_matmul import quant_matmul
+
+# GPT-small training shape (GPTConfig.small, batch 8 x seq 1024)
+B, T, H, H_KV, D = 8, 1024, 12, 4, 64
+SLOTS, CAPACITY, PAGE = 8, 2048, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # another process may hold libtpu for a few seconds (the ptserve
+    # child of tests/test_native_predictor.py, on another xdist worker):
+    # its lock frees when it exits, so wait a bounded time before
+    # giving up
+    deadline = time.monotonic() + 60.0
+    while True:
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure means skip
+            if "lockfile" in str(e) and time.monotonic() < deadline:
+                time.sleep(2.0)
+                continue
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chips(topo):
+    """The four described chips, with the persistent compile cache off:
+    a compile for a described chip is written to the cache but cannot
+    be read back without a chip (the next run would warn and compile
+    again)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield list(topo.devices)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(chips):
+    return SingleDeviceSharding(chips[0])
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _attn_shapes(one_chip):
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, T, H_KV, D), jnp.bfloat16,
+                              sharding=one_chip)
+    return q, kv
+
+
+def test_described_chip_is_a_v5e(topo):
+    assert topo.devices[0].platform == "tpu"
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+
+
+def test_flash_forward_compiles(one_chip):
+    q, kv = _attn_shapes(one_chip)
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False), q, kv, kv)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_backward_compiles(one_chip):
+    q, kv = _attn_shapes(one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    # forward (recomputed) + the dq and dk/dv backward kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_flash_key_padding_mask_compiles(one_chip):
+    q, kv = _attn_shapes(one_chip)
+    mask = jax.ShapeDtypeStruct((B, T), jnp.bool_, sharding=one_chip)
+
+    def loss(q, k, v, m):
+        return flash_attention(q, k, v, kv_mask=m,
+                               interpret=False).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+                          mask)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_decode_compiles(one_chip, dtype):
+    q = jax.ShapeDtypeStruct((SLOTS, 1, H, D), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((SLOTS, CAPACITY, H_KV, D), dtype,
+                              sharding=one_chip)
+    t = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    text = _compiled_text(
+        lambda q, k, v, t: flash_decode(q, k, v, t, interpret=False),
+        q, kv, kv, t)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_flash_decode_paged_compiles(one_chip, quantized):
+    n_log = CAPACITY // PAGE
+    pages = SLOTS * n_log
+    q = jax.ShapeDtypeStruct((SLOTS, 1, H, D), jnp.float32,
+                             sharding=one_chip)
+    pool = jax.ShapeDtypeStruct(
+        (pages, PAGE, H_KV, D), jnp.int8 if quantized else jnp.float32,
+        sharding=one_chip)
+    table = jax.ShapeDtypeStruct((SLOTS, n_log), jnp.int32,
+                                 sharding=one_chip)
+    t = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    if quantized:
+        scale = jax.ShapeDtypeStruct((pages, PAGE, H_KV), jnp.float32,
+                                     sharding=one_chip)
+        text = _compiled_text(
+            lambda q, kp, vp, ks, vs, tb, t: flash_decode_paged(
+                q, kp, vp, tb, t, k_scale=ks, v_scale=vs,
+                interpret=False),
+            q, pool, pool, scale, scale, table, t)
+    else:
+        text = _compiled_text(
+            lambda q, kp, vp, tb, t: flash_decode_paged(
+                q, kp, vp, tb, t, interpret=False),
+            q, pool, pool, table, t)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n", [2048, 768], ids=["ffn_up", "ffn_down"])
+def test_quant_matmul_compiles(one_chip, n):
+    """int8 GEMM at GPT-small's FFN widths with per-channel weight
+    scales — the operand whose 1-D layout Mosaic used to refuse."""
+    k = 768 if n == 2048 else 2048
+    a = jax.ShapeDtypeStruct((512, k), jnp.int8, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((k, n), jnp.int8, sharding=one_chip)
+    sa = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    sb = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    text = _compiled_text(
+        lambda a, b, sa, sb: quant_matmul(a, b, sa, sb, use_pallas=True),
+        a, b, sa, sb)
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# four chips: the data-parallel mesh of chip_smoke.py --chips 4
+# ---------------------------------------------------------------------------
+
+def test_tpu_compiler_refuses_custom_partitioning(chips):
+    """libtpu does not implement custom_partitioning: on more than one
+    chip the call survives to the compiler, which has no emitter for it.
+    This is WHY the flash kernel rides jax.shard_map on a multi-chip TPU
+    mesh; the day this test fails, that second route can retire."""
+    from jax.experimental.custom_partitioning import custom_partitioning
+
+    @custom_partitioning
+    def double(x):
+        return x * 2
+
+    double.def_partition(
+        partition=lambda mesh, args, res: (
+            mesh, lambda x: x * 2, args[0].sharding, (args[0].sharding,)),
+        infer_sharding_from_operands=lambda mesh, args, res:
+            args[0].sharding,
+        sharding_rule="i j -> i j")
+    mesh = pt.build_mesh(dp=4, devices=chips)
+    x = jax.ShapeDtypeStruct((8, 128), jnp.float32,
+                             sharding=NamedSharding(mesh, P("dp")))
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="CustomSPMDPartitioning"):
+        jax.jit(double).lower(x).compile()
+
+
+def test_flash_dp4_compiles_via_shard_map(chips, monkeypatch):
+    """Flash forward + backward at the GPT-small training shape, batch
+    sharded over a dp=4 mesh of the described chips: compiles, keeps the
+    kernel, gathers nothing. The route is chosen from the backend, which
+    is the CPU here, so the test steers that one seam."""
+    FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(FA, "_use_interpret", lambda: False)
+    mesh = pt.build_mesh(dp=4, devices=chips)
+    sh = NamedSharding(mesh, P("dp"))
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((B, T, H_KV, D), jnp.bfloat16, sharding=sh)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v,
+                               causal=True).astype(jnp.float32).sum()
+
+    with mesh_scope(mesh):
+        text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3
+    assert "all-gather" not in text
+    # each chip works on its quarter of the batch
+    assert f"bf16[{B // 4},{T},{H_KV}," in text

@@ -1,19 +1,18 @@
 #!/usr/bin/env python
 """Diff a bench session against the recorded trajectory.
 
-``tools/bench_all.sh`` leaves a session log of one-JSON-line-per-bench
-rows; ``BENCH_HISTORY.json`` holds the best recorded accelerator number
+A bench session log holds one JSON line per ``bench.py`` run;
+``BENCH_HISTORY.json`` holds the best recorded accelerator number
 per metric. This tool answers the question every post-session review
 asks — *which metrics moved, and which rows are even comparable* — in
 one pass:
 
 - the NEWEST row per metric wins (a session that re-runs bert_base
   after pallas_tune diffs the tuned number);
-- degraded rows are EXCLUDED, never diffed: ``backend_degraded`` /
-  ``backend: cpu_fallback`` (device-init-timeout fallbacks) and
-  skipped rows (``skipped`` / ``cause``) — the BENCH_r05 hazard class
-  (CPU numbers silently polluting on-chip deltas) as a tool invariant,
-  matching the exclusion the regression sentinel applies;
+- degraded rows are EXCLUDED, never diffed: ``backend_degraded`` and
+  skipped rows (``skipped`` / ``cause``) — a row that measured nothing
+  must never pollute on-chip deltas — matching the exclusion the
+  regression sentinel applies;
 - per-metric delta vs the history baseline (``metric`` key, then the
   ``metric@...`` variant tiers evaluate_against_history records under),
   higher-is-better (history keeps the max);
@@ -25,7 +24,7 @@ Usage::
     python tools/bench_diff.py [session.log|-] [--history PATH]
         [--threshold 0.10] [--format text|json]
 
-The positional default is ``bench_all.log`` in the repo root; ``-``
+The positional default is ``bench_session.log`` in the repo root; ``-``
 reads stdin. Non-JSON log lines are skipped.
 """
 
@@ -59,7 +58,7 @@ def parse_lines(text: str) -> Dict[str, Dict[str, Any]]:
 
 def exclude_reason(row: Dict[str, Any]) -> Optional[str]:
     """Why this row must not be diffed (None = comparable)."""
-    if row.get("backend_degraded") or row.get("backend") == "cpu_fallback":
+    if row.get("backend_degraded"):
         return "backend_degraded"
     if row.get("skipped"):
         return f"skipped:{row.get('cause', 'unknown')}"
@@ -140,9 +139,9 @@ def render(report: Dict[str, Any]) -> str:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("session", nargs="?",
-                    default=os.path.join(REPO, "bench_all.log"),
+                    default=os.path.join(REPO, "bench_session.log"),
                     help="bench session log of JSON lines, or - for "
-                         "stdin (default: bench_all.log)")
+                         "stdin (default: bench_session.log)")
     ap.add_argument("--history",
                     default=os.path.join(REPO, "BENCH_HISTORY.json"))
     ap.add_argument("--threshold", type=float, default=0.10,

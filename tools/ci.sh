@@ -62,8 +62,8 @@ dtype keys)"
       -q -k "dtype_key" || exit $?
     # bench diff smoke: the session-vs-history comparator on a crafted
     # 3-row session — a clean row compares, a >10% drop sets exit 1,
-    # and a cpu_fallback row is EXCLUDED (the BENCH_r05 pollution
-    # class must fail loudly here before it misreads a real session)
+    # and a degraded row is EXCLUDED (it must fail loudly here before it
+    # misreads a real session)
     stage "bench diff smoke (tools/bench_diff.py on crafted rows)"
     JAX_PLATFORMS=cpu python -c "
 import json, subprocess, sys, tempfile, os
@@ -73,7 +73,7 @@ rows = '\n'.join(json.dumps(r) for r in [
     {'metric': 'a_tp', 'value': 99.0, 'unit': 'x/s', 'backend': 'tpu'},
     {'metric': 'b_tp', 'value': 50.0, 'unit': 'x/s', 'backend': 'tpu'},
     {'metric': 'c_tp', 'value': 40.0, 'unit': 'x/s',
-     'backend': 'cpu_fallback', 'backend_degraded': True}])
+     'backend': 'cpu', 'backend_degraded': True}])
 with tempfile.TemporaryDirectory() as d:
     hp, sp = os.path.join(d, 'h.json'), os.path.join(d, 's.log')
     open(hp, 'w').write(json.dumps(hist))
