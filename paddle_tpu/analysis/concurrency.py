@@ -227,6 +227,10 @@ class _ModuleModel:
         self.thread_entries: Set[str] = set()
         # qualnames with .join() called on their thread binding
         self.joined_bindings: Set[str] = set()
+        # "Class.method" -> "Class.attr": @contextmanager methods that
+        # hold ``self.attr`` across their yield (``with self.m(...):``
+        # then acquires that lock like ``with self.attr:`` does)
+        self.lock_cms: Dict[str, str] = {}
 
 
 # ---------------------------------------------------------------------------
@@ -275,6 +279,24 @@ class _SymbolCollector(ast.NodeVisitor):
             self._record(node.target, node.value)
         self.generic_visit(node)
 
+    def visit_FunctionDef(self, node):
+        # a @contextmanager method that calls self.<attr>.acquire() is
+        # a lock-holding context manager (whether <attr> is a lock is
+        # resolved at the ``with`` site, once every symbol is known)
+        if self._cls and any(_terminal(d) == "contextmanager"
+                             for d in node.decorator_list):
+            for sub in ast.walk(node):
+                if (isinstance(sub, ast.Call)
+                        and isinstance(sub.func, ast.Attribute)
+                        and sub.func.attr == "acquire"
+                        and isinstance(sub.func.value, ast.Attribute)
+                        and isinstance(sub.func.value.value, ast.Name)
+                        and sub.func.value.value.id == "self"):
+                    self.model.lock_cms[f"{self._cls}.{node.name}"] = \
+                        f"{self._cls}.{sub.func.value.attr}"
+                    break
+        self.generic_visit(node)
+
 
 # ---------------------------------------------------------------------------
 # pass 2: per-function analysis with lexical lock-hold tracking
@@ -301,6 +323,19 @@ class _FnAnalyzer:
             key = f"{self.model.modname}.{node.id}"
             return key if key in self.model.symbols else None
         return None
+
+    def _with_sym(self, expr: ast.AST) -> Optional[str]:
+        """The symbol a ``with`` item acquires: the lock itself, or the
+        lock a ``self.<method>(...)`` lock-holding context manager of
+        this class holds (``_ModuleModel.lock_cms``)."""
+        if (isinstance(expr, ast.Call)
+                and isinstance(expr.func, ast.Attribute)
+                and isinstance(expr.func.value, ast.Name)
+                and expr.func.value.id == "self" and self.info.cls):
+            sym = self.model.lock_cms.get(
+                f"{self.info.cls}.{expr.func.attr}")
+            return sym if sym in self.model.symbols else None
+        return self._sym_id(expr)
 
     def _kind_of(self, sym: Optional[str]) -> Optional[str]:
         return self.model.symbols.get(sym) if sym else None
@@ -336,7 +371,7 @@ class _FnAnalyzer:
         if isinstance(node, ast.With):
             acquired: List[str] = []
             for item in node.items:
-                sym = self._sym_id(item.context_expr)
+                sym = self._with_sym(item.context_expr)
                 kind = self._kind_of(sym)
                 if kind in (_KIND_LOCK, _KIND_COND):
                     self.info.acquires.append((sym, node.lineno))

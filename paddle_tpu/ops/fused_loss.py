@@ -55,6 +55,7 @@ def linear_cross_entropy(hidden, weight, bias, labels, chunk: int = 4096,
     return loss
 
 
+@jax.named_scope("linear_ce")
 def _lce_fwd_impl(hidden, weight, bias, labels, chunk, ignore_index):
     n, d = hidden.shape
     d2, v = weight.shape
@@ -96,6 +97,7 @@ def _lce_fwd_impl(hidden, weight, bias, labels, chunk, ignore_index):
     return loss, (hidden, weight, bias, labels, lse)
 
 
+@jax.named_scope("linear_ce")
 def _lce_bwd(chunk, ignore_index, res, g):
     hidden, weight, bias, labels, lse = res
     n, d = hidden.shape

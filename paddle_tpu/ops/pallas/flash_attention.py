@@ -52,6 +52,22 @@ def _scratch(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
+def _named_call(name, kernel, **kw):
+    """``pl.pallas_call`` under a stable device name. ``name=`` names the
+    Mosaic kernel; the scope directly around the call names the
+    ``custom-call`` instruction, which takes the innermost scope of its
+    ``op_name`` — without one a profiler's ``XLA Ops`` event is named
+    after the JAX transform around the kernel (``%checkpoint.N``,
+    ``%jvp__.N``). Metadata only: the compiled kernel is the same."""
+    call = pl.pallas_call(kernel, name=name, **kw)
+
+    def run(*operands):
+        with jax.named_scope(name):
+            return call(*operands)
+
+    return run
+
+
 def _dropout_keep(seed, row0, col0, bq, bk, dropout_p):
     """Deterministic keep-mask for attention-probability dropout, from a
     counter-based integer hash of (per-(b,h) seed, global row, global
@@ -379,7 +395,8 @@ def _fwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, causal,
     if dropout_p > 0.0:
         in_specs.append(_seed_spec(q.shape[0]))
         inputs += (seed,)
-    o, lse = pl.pallas_call(
+    o, lse = _named_call(
+        "pt_flash_fwd",
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -618,7 +635,8 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
     if dropout_p > 0.0:
         dq_in_specs.append(_seed_spec(q.shape[0]))
         dq_inputs += (seed,)
-    dq = pl.pallas_call(
+    dq = _named_call(
+        "pt_flash_dq",
         functools.partial(
             _dq_kernel, scale=scale, causal=causal, window=window,
             has_mask=has_mask, has_segs=has_segs, dropout_p=dropout_p,
@@ -668,7 +686,8 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
     if dropout_p > 0.0:
         dkv_in_specs.append(_seed_spec(q.shape[0]))
         dkv_inputs += (seed,)
-    dk, dv = pl.pallas_call(
+    dk, dv = _named_call(
+        "pt_flash_dkdv",
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, window=window,
             has_mask=has_mask, has_segs=has_segs, dropout_p=dropout_p,

@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...core.enforce import enforce
-from .flash_attention import _NEG_INF, _scratch, _use_interpret, pltpu
+from .flash_attention import (_NEG_INF, _named_call, _scratch,
+                              _use_interpret, pltpu)
 
 DEFAULT_DECODE_BLOCK_K = 256
 
@@ -234,7 +235,8 @@ def flash_decode_paged(q, kpool, vpool, table, t, *,
         kernel = functools.partial(_paged_kernel, **kw)
         in_specs = [qo_spec, kv_spec, kv_spec]
         operands = (t_arr, table, qh, kpool, vpool)
-    out = pl.pallas_call(
+    out = _named_call(
+        "pt_flash_decode_paged",
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -313,7 +315,8 @@ def flash_decode(q, k, v, t, *, window: Optional[int] = None,
         _decode_kernel, scale=scale, window=window, block_k=block_k,
         n_j=n_j, nheads=h, kv_heads=kv_h)
     qo_spec = pl.BlockSpec((1, h, d), lambda b_, j, t_: (b_, 0, 0))
-    out = pl.pallas_call(
+    out = _named_call(
+        "pt_flash_decode",
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,

@@ -33,6 +33,7 @@ from ..core.enforce import enforce
 from ..core.mesh import get_mesh, mesh_scope
 from ..nn.layer import Layer
 from ..optimizer.optimizers import Optimizer
+from ..telemetry.trace import RecordEvent
 from .plan import Plan, compile_step, pmean_axes
 
 
@@ -170,13 +171,15 @@ class Trainer:
             donate = (0, 1, 2, 3, 4) if self.strategy.donate_inputs else ()
             self._jit_step = compile_step(
                 plan, self._accum_step, donate_argnums=donate,
+                name="pt_train_accum_step",
                 **self._step_shardings(accum=True))
         else:
             donate = (0, 1, 2) if self.strategy.donate_inputs else ()
             self._jit_step = compile_step(
                 plan, self._step, donate_argnums=donate,
-                **self._step_shardings())
+                name="pt_train_step", **self._step_shardings())
         self._jit_eval = compile_step(plan, self._eval_step,
+                                      name="pt_eval_step",
                                       **self._eval_shardings())
         self._multi_cache = {}
         self._check_donation_safety(donate)
@@ -400,12 +403,11 @@ class Trainer:
             return fn(*args)
 
     def train_step(self, batch) -> Tuple[Any, Dict[str, Any]]:
-        from ..core.profiler import RecordEvent
-
         # op-level span parity (reference: RecordEvent pushed around every
-        # op run, platform/profiler.h:81) — here one span per compiled
-        # step, doubling as the dispatch-time histogram when telemetry
-        # is on (async dispatch: the fenced step time is train_loop's)
+        # op run, platform/profiler.h:81) — here one program span per
+        # compiled step, in every profiler session, doubling as the
+        # dispatch-time histogram when telemetry is on (async dispatch:
+        # the fenced step time is train_loop's)
         hist = (_trainer_metrics()["dispatch"]
                 if telemetry.enabled() else None)
         with RecordEvent("train_step", histogram=hist):
@@ -447,8 +449,6 @@ class Trainer:
         Cuts host→device round trips by n. The batch is reused for each
         inner step; feed-per-step loops should call train_step instead. Returns the
         last step's (loss, metrics)."""
-        from ..core.profiler import RecordEvent
-
         fn = self.steps_jit(n)
         with RecordEvent(f"train_steps[{n}]"):
             self._rng, sub = jax.random.split(self._rng)
@@ -493,6 +493,7 @@ class Trainer:
             # single step: pjit shardings / shard_map wrap carry over
             # (the scan body calls _step, which is collective-aware)
             fn = compile_step(self.plan, many, donate_argnums=donate,
+                              name=f"pt_train_steps_{n}",
                               **self._step_shardings())
             self._multi_cache[key] = fn
         return fn

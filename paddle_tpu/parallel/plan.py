@@ -54,6 +54,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
 from ..core.enforce import enforce
+from ..telemetry.trace import named
 
 PLAN_AXES = ("dp", "fsdp", "tp")
 # the opt-in table axis (present in the plan mesh only when ep > 1 so
@@ -473,7 +474,8 @@ def compile_step(plan: Optional[Plan], fn: Callable, *,
                  in_shardings=None, out_shardings=None,
                  donate_argnums: Sequence[int] = (),
                  batch_argnum: int = -1,
-                 static_argnums: Sequence[int] = ()):
+                 static_argnums: Sequence[int] = (),
+                 name: Optional[str] = None):
     """Compile ``fn`` for the plan. Three regimes, one entry point:
 
     - ``plan`` is ``None`` (or a 1-device plan): plain
@@ -492,9 +494,13 @@ def compile_step(plan: Optional[Plan], fn: Callable, *,
 
     The returned callable carries ``compiled_via`` in
     ``("jit", "pjit", "shard_map")`` so callers (and tests) can pin the
-    selection.
+    selection. ``name`` is the program's stable name: the HLO module
+    and a profile's ``XLA Modules`` event are ``jit_<name>`` in every
+    regime (default: the function's own name).
     """
     donate = tuple(donate_argnums)
+    if name:
+        fn = named(fn, name)
     if plan is None or plan.num_devices == 1:
         compiled = jax.jit(fn, donate_argnums=donate,
                            static_argnums=tuple(static_argnums))
@@ -529,7 +535,8 @@ def compile_step(plan: Optional[Plan], fn: Callable, *,
         return shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=P(), check_vma=False)(*args)
 
-    compiled = jax.jit(wrapped, donate_argnums=donate)
+    compiled = jax.jit(named(wrapped, name) if name else wrapped,
+                       donate_argnums=donate)
     compiled.compiled_via = "shard_map"
     return compiled
 

@@ -62,6 +62,7 @@ from .telemetry import profiling as _profiling
 from .telemetry import recompile as _recompile
 from .telemetry import server as _dbg_server
 from .telemetry import tracing as _tracing
+from .telemetry.trace import Span, named as _named
 
 # reusable inert context manager: span call-sites gate on
 # telemetry.enabled() (the zero-cost contract — a disabled run must
@@ -1007,10 +1008,10 @@ class BatchedDecoder:
                         m["kv_pool_bytes"].set(pool_b)
                         m["kv_pool_live_bytes"].set(occ * pool_b)
                     t_tick = time.perf_counter()
-                if not self.preempted:
-                    self._admit()
-                self._prefill_tick()
-                self._step()
+                with Span("serve.tick",
+                          n_active=int(self.active.sum()),
+                          queued=len(self.queue)):
+                    self._tick(admit=not self.preempted)
                 if telem:
                     tick += 1
                     # stamp OUR server when we own one (owner-scoped
@@ -1040,6 +1041,20 @@ class BatchedDecoder:
         out = {rid: r.result for rid, r in self.done.items()}
         self.done = {}
         return out
+
+    def _tick(self, admit: bool = True) -> None:
+        """The phases of one serving tick — admit, one prefill chunk,
+        one decode step — each under its program span. The caller
+        opens ``serve.tick`` around this and whatever else its tick
+        holds (``run``'s loop; ``LocalReplica._tick_locked`` with its
+        harvest), so the children tile a busy tick."""
+        if admit:
+            with Span("serve.admit"):
+                self._admit()
+        if self._pf_order:
+            with Span("serve.prefill_tick"):
+                self._prefill_tick()
+        self._step()
 
     def _statusz(self) -> Dict[str, Any]:
         """Arena view for /statusz (host-side fields only — reading it
@@ -1290,7 +1305,8 @@ class BatchedDecoder:
                 pools.append((paged_ops.import_pages(kp, ids, pk),
                               paged_ops.import_pages(vp, ids, pv)))
             self.pools = pools
-            self._activate(s, r, jnp.asarray(h.logits), plen)
+            self._activate(s, r, self._first_token(
+                s, jnp.asarray(h.logits), plen), plen)
 
     # ----- internals -------------------------------------------------------
 
@@ -1330,7 +1346,7 @@ class BatchedDecoder:
                 logits, new = _row_apply(caches, s, body)
             return new, logits[0]
 
-        fn = jax.jit(prefill)
+        fn = jax.jit(_named(prefill, f"pt_prefill_{lb}"))
         self._prefill_cache[lb] = fn
         return fn
 
@@ -1354,7 +1370,7 @@ class BatchedDecoder:
                     jnp.full((1,), plen - 1, jnp.int32))
             return pools, logits[0]
 
-        fn = jax.jit(prefill)
+        fn = jax.jit(_named(prefill, f"pt_prefill_paged_{lb}"))
         self._prefill_cache[("paged", lb)] = fn
         return fn
 
@@ -1372,7 +1388,7 @@ class BatchedDecoder:
                         padded[None], pools, table_row, t0, head=False)
                 return pools
 
-            chunk_fn = jax.jit(chunk)
+            chunk_fn = jax.jit(_named(chunk, f"pt_prefill_suffix_{lb}"))
             self._prefill_cache[("suffix", lb)] = chunk_fn
         restep_fn = self._prefill_cache.get(("restep",))
         if restep_fn is None:
@@ -1383,7 +1399,7 @@ class BatchedDecoder:
                         jnp.full((1,), pos, jnp.int32))
                 return pools, logits[0]
 
-            restep_fn = jax.jit(restep)
+            restep_fn = jax.jit(_named(restep, "pt_prefill_restep"))
             self._prefill_cache[("restep",)] = restep_fn
         return chunk_fn, restep_fn
 
@@ -1403,7 +1419,7 @@ class BatchedDecoder:
                         toks[None], row, t0, head=False))
             return new
 
-        fn = jax.jit(chunk)
+        fn = jax.jit(_named(chunk, f"pt_prefill_chunk_{c}"))
         self._prefill_cache[("cchunk", c)] = fn
         return fn
 
@@ -1422,7 +1438,7 @@ class BatchedDecoder:
                     lambda row: model._step_logits(tok[None], row, pos))
             return new, logits[0]
 
-        fn = jax.jit(restep)
+        fn = jax.jit(_named(restep, "pt_prefill_restep"))
         self._prefill_cache[("crestep",)] = fn
         return fn
 
@@ -1475,7 +1491,7 @@ class BatchedDecoder:
                 jnp.asarray(s, jnp.int32))
         self._pf[s] = None
         self._pf_order.pop(0)
-        self._activate(s, r, logits, plen)
+        self._activate(s, r, self._first_token(s, logits, plen), plen)
 
     def _prefix_key(self, prompt: np.ndarray, n: int) -> bytes:
         return np.ascontiguousarray(prompt[:n], np.int32).tobytes()
@@ -1562,16 +1578,21 @@ class BatchedDecoder:
                         padded[None], row, 0, head=False))
             return new
 
-        fn = jax.jit(prefill)
+        fn = jax.jit(_named(prefill, f"pt_draft_prefill_{lb}"))
         self._prefill_cache[("draft", lb)] = fn
         return fn
 
-    def _activate(self, s: int, r: Request, logits, plen: int):
-        """Shared admission epilogue: first-token pick + slot live."""
+    def _first_token(self, s: int, logits, plen: int) -> int:
+        """The first token of slot ``s``, picked and fetched: where the
+        host has waited out the prefill's device work."""
+        return int(self._pick(logits[None], s, plen)[0])
+
+    def _activate(self, s: int, r: Request, tok: int, plen: int):
+        """Shared admission epilogue: the slot goes live on its first
+        token."""
         self.active[s] = True
         self._slot_trace[s] = r.trace
-        tok = self._pick(logits[None], s, plen)[0]
-        self.emitted[s] = [int(tok)]
+        self.emitted[s] = [tok]
         r.t_first = time.perf_counter()
         r.t_tokens.append(r.t_first)
         if telemetry.enabled():
@@ -1589,7 +1610,7 @@ class BatchedDecoder:
                                rid=r.rid, slot=s)
             m["tokens"].inc()
         self.budget[s] = r.max_new - 1
-        self.tok = self.tok.at[s].set(int(tok))
+        self.tok = self.tok.at[s].set(tok)
         self.t = self.t.at[s].set(plen)
         if r.stream is not None:
             # the first token leaves the arena at activation, not at
@@ -1676,52 +1697,59 @@ class BatchedDecoder:
                                    plen=plen, slot=s, cached=cached)
                      if telem else _NULL_CM)
             with pf_cm:
-                if self.paged:
-                    row = self.table[s]
-                    if cached == 0:
-                        pf = self._prefill_fn_paged(lb)
-                        self.pools, logits = pf(
-                            self._mstate, self.pools, jnp.asarray(row),
-                            jnp.asarray(padded), plen)
+                # the program span ends where the host holds the first
+                # token: what every decoding row waits out when a
+                # prefill falls into its tick
+                with Span("serve.prefill", rid=r.rid, plen=plen,
+                          bucket=lb, queued_us=int(
+                              (time.perf_counter() - r.t_submit) * 1e6)):
+                    if self.paged:
+                        row = self.table[s]
+                        if cached == 0:
+                            pf = self._prefill_fn_paged(lb)
+                            self.pools, logits = pf(
+                                self._mstate, self.pools, jnp.asarray(row),
+                                jnp.asarray(padded), plen)
+                            if telem:
+                                _costs.ensure_program(
+                                    f"serving.prefill[paged,{lb}]", pf,
+                                    (self._mstate, self.pools,
+                                     jnp.asarray(row), jnp.asarray(padded),
+                                     plen), origin="serving")
+                        else:
+                            # prefill only the uncached suffix (page-aligned
+                            # t0), then the usual last-token re-step for the
+                            # next-token logits — handles a fully-cached
+                            # prompt (empty suffix) too
+                            suf = r.prompt[cached:]
+                            if len(suf):
+                                slb = self._bucket_len(len(suf))
+                                spad = np.zeros((slb,), np.int32)
+                                spad[:len(suf)] = suf
+                                chunk_fn, restep_fn = self._suffix_fns(slb)
+                                self.pools = chunk_fn(
+                                    self._mstate, self.pools,
+                                    jnp.asarray(row),
+                                    jnp.asarray(spad), cached)
+                            else:
+                                _, restep_fn = self._suffix_fns(self.bucket)
+                            self.pools, logits = restep_fn(
+                                self._mstate, self.pools, jnp.asarray(row),
+                                jnp.asarray(r.prompt[plen - 1], jnp.int32),
+                                plen - 1)
+                    else:
+                        pf = self._prefill_fn(lb)
+                        self.caches, logits = pf(
+                            self._mstate, self.caches, jnp.asarray(padded),
+                            plen, s)
                         if telem:
                             _costs.ensure_program(
-                                f"serving.prefill[paged,{lb}]", pf,
-                                (self._mstate, self.pools,
-                                 jnp.asarray(row), jnp.asarray(padded),
-                                 plen), origin="serving")
-                    else:
-                        # prefill only the uncached suffix (page-aligned
-                        # t0), then the usual last-token re-step for the
-                        # next-token logits — handles a fully-cached
-                        # prompt (empty suffix) too
-                        suf = r.prompt[cached:]
-                        if len(suf):
-                            slb = self._bucket_len(len(suf))
-                            spad = np.zeros((slb,), np.int32)
-                            spad[:len(suf)] = suf
-                            chunk_fn, restep_fn = self._suffix_fns(slb)
-                            self.pools = chunk_fn(
-                                self._mstate, self.pools,
-                                jnp.asarray(row),
-                                jnp.asarray(spad), cached)
-                        else:
-                            _, restep_fn = self._suffix_fns(self.bucket)
-                        self.pools, logits = restep_fn(
-                            self._mstate, self.pools, jnp.asarray(row),
-                            jnp.asarray(r.prompt[plen - 1], jnp.int32),
-                            plen - 1)
-                else:
-                    pf = self._prefill_fn(lb)
-                    self.caches, logits = pf(
-                        self._mstate, self.caches, jnp.asarray(padded),
-                        plen, s)
-                    if telem:
-                        _costs.ensure_program(
-                            f"serving.prefill[{lb}]", pf,
-                            (self._mstate, self.caches,
-                             jnp.asarray(padded), plen, s),
-                            origin="serving")
-                self._activate(s, r, logits, int(plen))
+                                f"serving.prefill[{lb}]", pf,
+                                (self._mstate, self.caches,
+                                 jnp.asarray(padded), plen, s),
+                                origin="serving")
+                    tok = self._first_token(s, logits, plen)
+                self._activate(s, r, tok, plen)
 
     def _pick(self, logits, s: int, pos: int):
         """Admission-time single-row pick (the steady-state loop picks
@@ -1785,7 +1813,8 @@ class BatchedDecoder:
                         body, (caches, tok, t), None, length=kd)
                 return caches, jnp.swapaxes(toks, 0, 1)
 
-        return jax.jit(step)
+        return jax.jit(_named(
+            step, "pt_decode_step" if kd == 1 else f"pt_decode_step_k{kd}"))
 
     def _step_multi(self):
         """decode_steps host side: append each row's k tokens in order
@@ -1796,7 +1825,6 @@ class BatchedDecoder:
         if not self.active.any():
             return
         kd = 1 if self.degraded else self.decode_steps
-        step_fn, args = self._step_call()
         was_active = self.active.copy()
         telem = telemetry.enabled()
         if telem:
@@ -1819,11 +1847,15 @@ class BatchedDecoder:
                                  n_active=int(was_active.sum()))
                    if telem and tick_ctx is not None else _NULL_CM)
         with tick_cm:
-            if self.paged:
-                self.pools, toks = step_fn(*args)
-            else:
-                self.caches, toks = step_fn(*args)
-            toks = np.asarray(jax.device_get(toks)).astype(np.int32)
+            with Span("serve.step.dispatch"):
+                step_fn, args = self._step_call()
+                if self.paged:
+                    self.pools, toks = step_fn(*args)
+                else:
+                    self.caches, toks = step_fn(*args)
+            # the host blocked on the device
+            with Span("serve.step.fetch"):
+                toks = np.asarray(jax.device_get(toks)).astype(np.int32)
         self._warmed = True
         if telem:
             # cost-ledger registration, once per step variant (set
@@ -1833,22 +1865,23 @@ class BatchedDecoder:
                                   origin="serving")
         now = time.perf_counter()
         n_emitted = 0
-        for s in range(self.slots):
-            if not was_active[s]:
-                continue
-            r = self.owner[s]
-            for j in range(kd):
-                self.emitted[s].append(int(toks[s, j]))
-                r.t_tokens.append(now)
-                n_emitted += 1
-                self.budget[s] -= 1
-                self._maybe_finish(s)
-                if not self.active[s]:
-                    break
-            if r.stream is not None and r.result is None:
-                # per-tick streaming: this tick's tokens leave NOW
-                # (completion already streamed via finish above)
-                r.stream.offer(self.emitted[s], now)
+        with Span("serve.step.emit"):
+            for s in range(self.slots):
+                if not was_active[s]:
+                    continue
+                r = self.owner[s]
+                for j in range(kd):
+                    self.emitted[s].append(int(toks[s, j]))
+                    r.t_tokens.append(now)
+                    n_emitted += 1
+                    self.budget[s] -= 1
+                    self._maybe_finish(s)
+                    if not self.active[s]:
+                        break
+                if r.stream is not None and r.result is None:
+                    # per-tick streaming: this tick's tokens leave NOW
+                    # (completion already streamed via finish above)
+                    r.stream.offer(self.emitted[s], now)
         # tick accounting (plain ints — the bench harness reads these
         # without enabling telemetry)
         self.tick_count += 1
@@ -1870,13 +1903,15 @@ class BatchedDecoder:
                 f"serving.step[k={kd}]", self._backend(), itl,
                 kind="itl",
                 degraded=self.degraded)
-        # retired rows keep what _maybe_finish left (paged parking)
-        keep = was_active & self.active
-        cur_t = np.asarray(self.t)
-        self.tok = jnp.asarray(np.where(
-            keep, toks[:, -1], np.asarray(self.tok)).astype(np.int32))
-        self.t = jnp.asarray(np.where(
-            keep, cur_t + kd, cur_t).astype(np.int32))
+        # retired rows keep what _maybe_finish left (paged parking);
+        # np.asarray of self.t and self.tok are two more device fetches
+        with Span("serve.step.cursor"):
+            keep = was_active & self.active
+            cur_t = np.asarray(self.t)
+            self.tok = jnp.asarray(np.where(
+                keep, toks[:, -1], np.asarray(self.tok)).astype(np.int32))
+            self.t = jnp.asarray(np.where(
+                keep, cur_t + kd, cur_t).astype(np.int32))
 
     def _build_spec_step(self):
         """One speculative ROUND over the whole arena, jitted: gamma
@@ -1995,7 +2030,7 @@ class BatchedDecoder:
             with inject_state((model, *mstate), (draft, *dstate)):
                 return spec(tstate, table, caches_d, tok, t, gens)
 
-        return jax.jit(spec_injected)
+        return jax.jit(_named(spec_injected, "pt_spec_step"))
 
     def _step_spec(self):
         """One speculative round (host side): run the jitted round,
@@ -2012,44 +2047,47 @@ class BatchedDecoder:
             _recompile.record("serving.spec_step", self.tok, self.t,
                               weights=self._weights_fp)
             t_dispatch = time.perf_counter()
-        gens = jnp.asarray(self._slot_gen.astype(np.uint32))
-        if self.paged:
-            (self.pools, self.caches_d, emitted, n, new_tok,
-             new_t) = self._spec_fn(self._mstate, self._dstate,
-                                    self.pools,
-                                    jnp.asarray(self.table),
-                                    self.caches_d, self.tok, self.t,
-                                    gens)
-        else:
-            (self.caches, self.caches_d, emitted, n, new_tok,
-             new_t) = self._spec_fn(self._mstate, self._dstate,
-                                    self.caches, None, self.caches_d,
-                                    self.tok, self.t, gens)
+        with Span("serve.step.dispatch"):
+            gens = jnp.asarray(self._slot_gen.astype(np.uint32))
+            if self.paged:
+                (self.pools, self.caches_d, emitted, n, new_tok,
+                 new_t) = self._spec_fn(self._mstate, self._dstate,
+                                        self.pools,
+                                        jnp.asarray(self.table),
+                                        self.caches_d, self.tok, self.t,
+                                        gens)
+            else:
+                (self.caches, self.caches_d, emitted, n, new_tok,
+                 new_t) = self._spec_fn(self._mstate, self._dstate,
+                                        self.caches, None, self.caches_d,
+                                        self.tok, self.t, gens)
         # ONE batched transfer for the round's four host-side scalars
         # (per-array device_get would pay four sync round trips in the
         # serving hot loop)
-        emitted, n_np, new_tok, new_t = jax.device_get(
-            (emitted, n, new_tok, new_t))
+        with Span("serve.step.fetch"):
+            emitted, n_np, new_tok, new_t = jax.device_get(
+                (emitted, n, new_tok, new_t))
         self._warmed = True
         now = time.perf_counter()
         self.spec_rounds += 1
         self.spec_row_rounds += int(was_active.sum())
         self.spec_accepted += int(n_np[was_active].sum())
         n_emitted = 0
-        for s in range(self.slots):
-            if not was_active[s]:
-                continue
-            r = self.owner[s]
-            for j in range(int(n_np[s]) + 1):
-                self.emitted[s].append(int(emitted[s, j]))
-                r.t_tokens.append(now)
-                n_emitted += 1
-                self.budget[s] -= 1
-                self._maybe_finish(s)
-                if not self.active[s]:
-                    break
-            if r.stream is not None and r.result is None:
-                r.stream.offer(self.emitted[s], now)
+        with Span("serve.step.emit"):
+            for s in range(self.slots):
+                if not was_active[s]:
+                    continue
+                r = self.owner[s]
+                for j in range(int(n_np[s]) + 1):
+                    self.emitted[s].append(int(emitted[s, j]))
+                    r.t_tokens.append(now)
+                    n_emitted += 1
+                    self.budget[s] -= 1
+                    self._maybe_finish(s)
+                    if not self.active[s]:
+                        break
+                if r.stream is not None and r.result is None:
+                    r.stream.offer(self.emitted[s], now)
         if telem:
             m = _serving_metrics()
             m["spec_rounds"].inc(int(was_active.sum()))
@@ -2068,11 +2106,13 @@ class BatchedDecoder:
                               if spec_ctx is not None else None))
         # retired rows keep what _maybe_finish left (paged parking);
         # live rows advance by their accepted count + 1
-        keep = was_active & self.active
-        self.tok = jnp.asarray(
-            np.where(keep, new_tok, np.asarray(self.tok)))
-        self.t = jnp.asarray(
-            np.where(keep, new_t, np.asarray(self.t)).astype(np.int32))
+        with Span("serve.step.cursor"):
+            keep = was_active & self.active
+            self.tok = jnp.asarray(
+                np.where(keep, new_tok, np.asarray(self.tok)))
+            self.t = jnp.asarray(
+                np.where(keep, new_t,
+                         np.asarray(self.t)).astype(np.int32))
 
     def _step(self):
         if self._dl_active:

@@ -95,6 +95,7 @@ from .resilience import reliability as _reliability
 from .serving import BatchedDecoder, KVHandoff, TokenStream, reject_cause
 from .telemetry import server as _dbg_server
 from .telemetry import tracing as _tracing
+from .telemetry.trace import Span
 
 _NULL_CM = contextlib.nullcontext()
 
@@ -406,6 +407,20 @@ class LocalReplica:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
+    @contextlib.contextmanager
+    def _locked(self, who: str):
+        """Hold ``_mu``; the program span ``replica.lock_wait.<who>``
+        covers the wait for it on the caller's thread, not the hold.
+        For callers only: ``_loop`` takes the lock with a plain
+        ``with``, because anything between its release and its next
+        acquire changes how often a waiting caller gets in."""
+        with Span("replica.lock_wait." + who):
+            self._mu.acquire()
+        try:
+            yield
+        finally:
+            self._mu.release()
+
     # -- lifecycle ----------------------------------------------------------
 
     def start(self) -> "LocalReplica":
@@ -441,13 +456,13 @@ class LocalReplica:
             if rid in self.drain_results(keep=True):
                 break
             if self._thread is None:  # not started: tick inline
-                with self._mu:
+                with self._locked("other"):
                     self._tick_locked()
             else:
                 time.sleep(0.005)
         else:
             raise EnforceError(f"replica {self.name} warmup timed out")
-        with self._mu:
+        with self._locked("other"):
             self.decoder.warm_step()
 
     # -- serving API (router-facing) ----------------------------------------
@@ -475,7 +490,7 @@ class LocalReplica:
     def submit(self, prompt, max_new: int,
                session: Optional[str] = None,
                stream: bool = False) -> int:
-        with self._mu:
+        with self._locked("submit"):
             if not stream:
                 return self.decoder.submit(prompt, max_new)
             ts = TokenStream()
@@ -486,7 +501,7 @@ class LocalReplica:
     def inject(self, handoff: KVHandoff, max_new: int,
                session: Optional[str] = None,
                stream: bool = False) -> int:
-        with self._mu:
+        with self._locked("submit"):
             if not stream:
                 return self.decoder.inject_prefilled(handoff, max_new)
             ts = TokenStream()
@@ -499,7 +514,7 @@ class LocalReplica:
         """Claim the replica-side token stream for ``rid`` (one
         consumer per stream) — an iterator of token/control records.
         Typed error when no stream was registered for the rid."""
-        with self._mu:
+        with self._locked("other"):
             ts = self._streams.pop(rid, None)
         enforce(ts is not None,
                 "no token stream registered for rid %s on replica %s",
@@ -507,14 +522,14 @@ class LocalReplica:
         return iter(ts)
 
     def prefill(self, prompt) -> KVHandoff:
-        with self._mu:
+        with self._locked("other"):
             return self.decoder.prefill_export(prompt)
 
     def drain_results(self, keep: bool = False) -> Dict[int, Dict]:
         """Completed requests since the last drain:
         ``{rid: {tokens, ttft_s, itl_p99_s, t_first, t_done}}``.
         ``keep=True`` peeks without consuming (warmup)."""
-        with self._mu:
+        with self._locked("drain"):
             out = dict(self._done)
             if not keep:
                 self._done.clear()
@@ -526,7 +541,7 @@ class LocalReplica:
         — an admitted request runs to completion and its result is
         simply discarded (greedy decode is bounded by max_new, so the
         waste is bounded too). Returns True when dequeued."""
-        with self._mu:
+        with self._locked("other"):
             q = self.decoder.queue
             for i, r in enumerate(q):
                 if r.rid == rid:
@@ -535,7 +550,7 @@ class LocalReplica:
         return False
 
     def set_degraded(self, on: bool) -> None:
-        with self._mu:
+        with self._locked("other"):
             self.decoder.set_degraded(on)
 
     def healthz(self) -> Dict[str, Any]:
@@ -544,7 +559,7 @@ class LocalReplica:
 
     def load(self) -> Dict[str, Any]:
         d = self.decoder
-        with self._mu:
+        with self._locked("other"):
             out = {"queue_depth": len(d.queue),
                    "active_slots": int(d.active.sum()),
                    "prefilling": len(d._pf_order),
@@ -562,47 +577,56 @@ class LocalReplica:
 
     def _tick_locked(self) -> bool:
         """One serving tick (caller holds the lock). Returns True when
-        any work happened (idle loops back off otherwise)."""
+        any work happened (idle loops back off otherwise). A busy tick
+        is the program span ``serve.tick``, tiled by the arena's phase
+        spans and ``replica.harvest``."""
         d = self.decoder
         busy = bool(d.queue or d._pf_order or d.active.any())
         if not busy:
             return False
-        from .resilience import faults as _faults
-        inj = _faults.active()
-        if inj is not None:
-            # chaos point replica.wedge: a delay_s rule freezes THIS
-            # serve tick — the in-process stand-in for SIGSTOP (only
-            # fired while busy, so the idle loop doesn't burn the
-            # schedule clock)
-            inj.fire("replica.wedge", path=self.name)
-        d._admit()
-        d._prefill_tick()
-        d._step()
-        if d.done:
-            for rid, r in d.done.items():
-                if getattr(r, "deadline_exceeded", False) \
-                        or r.result is None:
-                    # expired in the arena (queue/prefill/decode sweep):
-                    # the record carries the typed cause, never a fake
-                    # token list
-                    self._done[rid] = {
-                        "tokens": None, "ttft_s": None,
-                        "itl_p99_s": None, "t_first": r.t_first,
-                        "t_done": r.t_done, "n_tokens": 0,
-                        "deadline_exceeded": True,
-                    }
-                    continue
-                ts = r.t_tokens
-                itl = np.diff(ts) if len(ts) > 1 else np.asarray([0.0])
-                self._done[rid] = {
-                    "tokens": r.result,
-                    "ttft_s": r.t_first - r.t_submit,
-                    "itl_p99_s": float(np.quantile(itl, 0.99)),
-                    "t_first": r.t_first, "t_done": r.t_done,
-                    "n_tokens": len(r.result),
-                }
-            d.done.clear()
+        with Span("serve.tick", n_active=int(d.active.sum()),
+                  queued=len(d.queue)):
+            from .resilience import faults as _faults
+            inj = _faults.active()
+            if inj is not None:
+                # chaos point replica.wedge: a delay_s rule freezes
+                # THIS serve tick — the in-process stand-in for SIGSTOP
+                # (only fired while busy, so the idle loop doesn't burn
+                # the schedule clock)
+                inj.fire("replica.wedge", path=self.name)
+            d._tick()
+            if d.done:
+                with Span("replica.harvest"):
+                    self._harvest_locked()
         return True
+
+    def _harvest_locked(self) -> None:
+        """Move the arena's finished requests into ``_done`` records
+        (caller holds the lock)."""
+        d = self.decoder
+        for rid, r in d.done.items():
+            if getattr(r, "deadline_exceeded", False) \
+                    or r.result is None:
+                # expired in the arena (queue/prefill/decode sweep):
+                # the record carries the typed cause, never a fake
+                # token list
+                self._done[rid] = {
+                    "tokens": None, "ttft_s": None,
+                    "itl_p99_s": None, "t_first": r.t_first,
+                    "t_done": r.t_done, "n_tokens": 0,
+                    "deadline_exceeded": True,
+                }
+                continue
+            ts = r.t_tokens
+            itl = np.diff(ts) if len(ts) > 1 else np.asarray([0.0])
+            self._done[rid] = {
+                "tokens": r.result,
+                "ttft_s": r.t_first - r.t_submit,
+                "itl_p99_s": float(np.quantile(itl, 0.99)),
+                "t_first": r.t_first, "t_done": r.t_done,
+                "n_tokens": len(r.result),
+            }
+        d.done.clear()
 
     def _loop(self) -> None:
         while not self._stop.is_set():

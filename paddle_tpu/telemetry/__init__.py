@@ -8,9 +8,18 @@ at with its profiler (SURVEY §5.1). The pieces:
 - ``metrics``: process-global :class:`MetricsRegistry` with typed
   :class:`Counter` / :class:`Gauge` / :class:`Histogram` (fixed
   log-spaced buckets, lock-free snapshot reads).
-- ``trace``: nestable spans unifying (and superseding)
-  ``core/profiler.py``'s RecordEvent — chrome-trace JSON export
-  preserved, plus a structured JSONL event log.
+- ``trace``: program spans. A :class:`Span` is one
+  ``jax.profiler.TraceAnnotation`` per phase (a serving tick and its
+  phases, a wait for the replica's lock, a train step), entered whether
+  or not telemetry is enabled, so every profiler session — a
+  ``POST /profilez`` capture, a benchmark's tracer, ``start_profiler``
+  — holds the program's spans on the clock of the device's lines. With
+  no session running the annotation is inert (about a microsecond of
+  Python per span; spans sit at phase boundaries, never per token).
+  The host-side event list (chrome-trace JSON, JSONL; superseding
+  ``core/profiler.py``'s RecordEvent) is kept only while
+  ``start_profiler()`` collects. ``named`` gives a jitted program its
+  stable ``pt_*`` name.
 - ``recompile``: jitted-call signature fingerprinting — counts trace
   cache misses per call-site (the #1 silent TPU perf killer).
 - ``export``: Prometheus text format + ``summary()`` human table +
@@ -33,10 +42,11 @@ at with its profiler (SURVEY §5.1). The pieces:
   inversions with witness stack pairs, and validates the static
   ``analysis/concurrency.py`` lock graph against observed reality.
 
-Everything is OFF by default and zero-cost when off: instrumented
-call-sites check :func:`enabled` (one module-global bool) before any
-dict work, and instrumentation only ever records host-side scalars
-outside jit — tracers never reach an instrument.
+Everything but ``trace.Span`` is OFF by default and zero-cost when off:
+instrumented call-sites check :func:`enabled` (one module-global bool)
+before any dict work, and instrumentation only ever records host-side
+scalars outside jit — tracers never reach an instrument. A ``Span`` is
+one inert annotation per phase, always entered.
 
 Usage::
 

@@ -206,6 +206,12 @@ def capture_device_trace(out_dir: str,
                          duration_ms: float = 500) -> Dict[str, Any]:
     """Run ONE bounded ``jax.profiler`` trace into ``out_dir``.
 
+    The capture holds the program's own spans beside the runtime's and
+    the device's lines: every ``telemetry.trace.Span`` annotates
+    whichever profiler session is running (``serve.tick`` and its
+    phases, ``replica.lock_wait.*``, ``train_step``), and the programs
+    and kernels run under their ``pt_*`` names.
+
     Raises :class:`CaptureBusyError` (-> 409) if a capture is already
     running in this process. ``duration_ms`` is clamped to
     ``PT_PROFILEZ_CAP_MS``; the trace lands in a ``.tmp-<pid>`` dir and
@@ -261,8 +267,11 @@ def artifact_base_dir() -> str:
 
 
 def _default_artifact_dir() -> str:
-    return os.path.join(artifact_base_dir(),
-                        f"capture-{os.getpid()}-{int(time.time())}")
+    # milliseconds: two captures of one process within a second must
+    # not land on one directory (the second rename would fail)
+    return os.path.join(
+        artifact_base_dir(),
+        f"capture-{os.getpid()}-{time.time_ns() // 1_000_000}")
 
 
 def artifact_tar(artifact_id: Optional[str]) -> tuple:

@@ -1,9 +1,12 @@
-"""Nestable span tracing — supersedes ``core/profiler.py``'s RecordEvent.
+"""Program spans — supersedes ``core/profiler.py``'s RecordEvent.
 
 One span machinery for the whole framework: RAII/context-manager spans
-(reference: paddle/fluid/platform/profiler.h:81 RecordEvent) collected
-host-side with monotonic timestamps and a thread-local nesting stack,
-exported as
+(reference: paddle/fluid/platform/profiler.h:81 RecordEvent). A span is
+first of all a ``jax.profiler.TraceAnnotation``, entered ALWAYS, so it
+lands in every profiler session on the clock of the device's lines (the
+rule, the cost and the sites are on :class:`Span`). While
+``start_profiler()`` collects, spans are also kept host-side with
+monotonic timestamps and a thread-local nesting stack, exported as
 
 - chrome-trace JSON (``export_chrome_trace`` — the historical
   tools/timeline.py contract, preserved verbatim), and
@@ -12,9 +15,9 @@ exported as
   depth and parent span; greppable/streamable where chrome-trace is
   load-the-whole-file).
 
-Device-side tracing still delegates to ``jax.profiler`` (XPlane /
-TensorBoard — the TPU analog of CUPTI); jax is imported lazily so the
-telemetry package stays import-light.
+Device-side tracing delegates to ``jax.profiler`` (XPlane /
+TensorBoard — the TPU analog of CUPTI); jax is imported lazily (at the
+first span) so the telemetry package stays import-light.
 
 ``core/profiler.py`` and ``fluid/profiler.py`` are thin shims over this
 module. Compat invariant: ``_events`` is only ever mutated IN PLACE
@@ -28,6 +31,7 @@ duration when telemetry is enabled — one timer, both sinks.
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import threading
@@ -57,19 +61,33 @@ def _tid() -> int:
 
 
 class Span:
-    """Context-manager span; nests via a thread-local stack and also
-    annotates device traces (``jax.profiler.TraceAnnotation``) so spans
-    appear in XPlane timelines when a device trace is running."""
+    """Context-manager program span.
+
+    Always enters a ``jax.profiler.TraceAnnotation`` of its name, so the
+    span shows in EVERY profiler session on the profiler's own clock —
+    ``start_profiler`` here, ``POST /profilez``, a benchmark's tracer —
+    whoever started it. With no session running the annotation is inert
+    (one atomic load in the runtime). Keyword arguments ride the
+    annotation and come back as the event's stats
+    (``Span("serve.prefill", rid=7, plen=300)``); keep them to counts
+    and ids, and keep spans at phase boundaries — per tick, per prefill,
+    per train step, never per token or per layer.
+
+    The host-side event list (nesting depth, parent, chrome-trace
+    export) is kept only while ``start_profiler()`` collects."""
 
     __slots__ = ("name", "cat", "histogram", "_t0", "_ann", "_depth",
                  "_parent", "_pushed")
 
-    def __init__(self, name: str, cat: str = "host", histogram=None):
+    def __init__(self, name: str, cat: str = "host", histogram=None,
+                 **args):
+        import jax  # lazy: keeps `import paddle_tpu.telemetry` light
+
         self.name = name
         self.cat = cat
         self.histogram = histogram
         self._t0 = 0.0
-        self._ann = None
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
         self._depth = 0
         self._parent = None
         self._pushed = False
@@ -81,18 +99,13 @@ class Span:
             self._parent = stack[-1].name if stack else None
             stack.append(self)
             self._pushed = True
-            import jax  # lazy: only on an enabled trace path
-
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
+        self._ann.__exit__(*exc)
         if self._pushed:
             # pop by identity, and even when collection was stopped
             # mid-span — an `if _enabled` guard here would leak the
@@ -126,6 +139,19 @@ class Span:
         if self.histogram is not None and _metrics.enabled():
             self.histogram.observe((t1 - self._t0) / 1e9)
         return False
+
+
+def named(fn, name: str):
+    """``fn`` under the ``__name__`` ``name``: ``jax.jit`` calls the
+    program ``jit_<name>``, which is what a profile's ``XLA Modules``
+    line and the HLO module carry. A stable name chosen by the program
+    survives a rename of the Python function."""
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    run.__name__ = run.__qualname__ = name
+    return run
 
 
 # historical names, kept as the same objects (API.spec / shim compat)

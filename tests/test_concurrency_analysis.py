@@ -154,6 +154,42 @@ class TestRace401:
         """
         assert _codes(src) == []
 
+    def test_lock_holding_context_manager_counts_as_the_lock(self):
+        # LocalReplica._locked: a @contextmanager method that acquires
+        # self._mu (under a span that times the wait), yields, and
+        # releases — ``with self._locked(...)`` holds _mu like
+        # ``with self._mu`` does
+        src = """
+            import contextlib
+            import threading
+            class C:
+                def __init__(self):
+                    self._mu = threading.RLock()
+                    self.count = 0
+                @contextlib.contextmanager
+                def _locked(self, who):
+                    self._mu.acquire()
+                    try:
+                        yield
+                    finally:
+                        self._mu.release()
+                def start(self):
+                    threading.Thread(target=self._loop, daemon=True,
+                                     name="pt-x").start()
+                def _loop(self):
+                    with self._locked("loop"):
+                        self._tick_locked()
+                def _tick_locked(self):
+                    self.count += 1
+                def snapshot(self):
+                    with self._locked("other"):
+                        return self.count
+        """
+        assert _codes(src) == []
+        # the same shape without the acquire guards nothing
+        assert "PT-RACE-401" in _codes(src.replace(
+            "self._mu.acquire()", "pass"))
+
     def test_two_thread_entries_racing_each_other_flagged(self):
         # the peer write can live in ANOTHER thread entry — two worker
         # loops racing is the classic write/write form

@@ -118,6 +118,10 @@ def test_flash_backward_compiles(one_chip):
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
     # forward (recomputed) + the dq and dk/dv backward kernels
     assert text.count("tpu_custom_call") >= 3
+    # each custom-call instruction carries its kernel's own name: what a
+    # profile's XLA Ops line calls the event
+    for name in ("%pt_flash_fwd", "%pt_flash_dq", "%pt_flash_dkdv"):
+        assert name in text, name
 
 
 def test_flash_key_padding_mask_compiles(one_chip):
@@ -144,6 +148,7 @@ def test_flash_decode_compiles(one_chip, dtype):
         lambda q, k, v, t: flash_decode(q, k, v, t, interpret=False),
         q, kv, kv, t)
     assert "tpu_custom_call" in text
+    assert "%pt_flash_decode" in text
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
@@ -172,6 +177,7 @@ def test_flash_decode_paged_compiles(one_chip, quantized):
                 q, kp, vp, tb, t, interpret=False),
             q, pool, pool, table, t)
     assert "tpu_custom_call" in text
+    assert "%pt_flash_decode_paged" in text
 
 
 @pytest.mark.parametrize("n", [2048, 768], ids=["ffn_up", "ffn_down"])
