@@ -244,20 +244,42 @@ def decode_flash_ok(capacity: int, d: int,
     return tuned.get("use_flash", True)
 
 
+def flash_operand_dtype(dtype):
+    """The type q/k/v of ``dtype`` reach the flash kernels in under the
+    active policy: its compute type where that is bfloat16 and narrower
+    (mixed_bf16: f32 activations, bf16 matmuls, as every ``Linear``
+    multiplies), else ``dtype`` itself. Read from what is observed — the
+    ``float32`` and ``bfloat16`` policies narrow nothing, and neither
+    does ``mixed_fp16``: Mosaic refuses float16 operands (compiled for a
+    described v5e), so those kernels stay f32. ``flash_attention()``
+    casts by it; the dispatch gate and the block table are keyed by it."""
+    from ..core.dtypes import get_policy, to_dtype
+
+    dtype = jnp.dtype(dtype)
+    compute = to_dtype(get_policy().compute_dtype)
+    if (compute == jnp.bfloat16 and jnp.issubdtype(dtype, jnp.floating)
+            and compute.itemsize < dtype.itemsize):
+        return compute
+    return dtype
+
+
 def _flash_ok(q, k, causal: bool = False, window=None) -> bool:
     """Flash kernel constraints for (B, T, H, D) operands — see
     flash_shape_ok for the actual gate."""
     return flash_shape_ok(q.shape[1], k.shape[1], q.shape[-1],
-                          causal=causal, window=window)
+                          causal=causal, window=window,
+                          dtype=flash_operand_dtype(q.dtype))
 
 
-def flash_shape_ok(tq, tk, d, causal: bool = False, window=None) -> bool:
+def flash_shape_ok(tq, tk, d, causal: bool = False, window=None,
+                   dtype=jnp.float32) -> bool:
     """Flash kernel constraints: TPU backend, block-divisible seq lens,
     supported head dim — and the autotuner's measured verdict when one
     exists (tools/pallas_tune.py records use_flash=False for shape
     buckets where the XLA fallback won on-chip). Shape-level so the
     ring-attention dispatch (parallel/context_parallel.py) can gate on
-    its PER-SHARD (t/sp) block shape."""
+    its PER-SHARD (t/sp) block shape. ``dtype``: the type q/k/v reach
+    the kernel in (the table is keyed by it)."""
     if not _FORCE_FLASH and jax.default_backend() != "tpu":
         return False
     # 64-divisible seqs use block=64 (the tuner measures that shape too:
@@ -273,7 +295,7 @@ def flash_shape_ok(tq, tk, d, causal: bool = False, window=None) -> bool:
         return True
     from .pallas.tuning import attention_key, get_tuned
 
-    tuned = get_tuned(attention_key(tq, tk, d, causal))
+    tuned = get_tuned(attention_key(tq, tk, d, causal, dtype=dtype))
     if tuned is not None and not tuned.get("use_flash", True):
         return False
     return True

@@ -136,6 +136,17 @@ def _banded_imap(lo_fn, n, row_fn=lambda b: b, zeros=1):
     return imap
 
 
+def _causal_j_hi(i, *, block_q, block_k, offset, n_j):
+    # last k-block _block_should_run lets causal q-block i touch
+    return jnp.clip((i * block_q + block_q - 1 + offset) // block_k,
+                    0, n_j - 1)
+
+
+def _causal_i_lo(j, *, block_q, block_k, offset, n_i):
+    # first q-block _block_should_run lets touch causal k-block j
+    return jnp.clip((j * block_k - offset) // block_q, 0, n_i - 1)
+
+
 def _block_should_run(i, j, *, causal, window, offset, block_q, block_k):
     """Block-level skip predicate shared by fwd/dq/dkv: a causal block
     runs iff its lowest row can see its first column; a window adds
@@ -219,11 +230,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
 
     @pl.when(should_run)
     def _body():
-        # matmul inputs stay in their native dtype (bf16 in production):
-        # bf16 x bf16 -> f32 via preferred_element_type runs at full MXU
-        # rate, while a pre-cast to f32 would drop to the fp32 matmul
-        # rate (4-8x slower on v5e) for zero accuracy gain in the
-        # accumulator
+        # matmul inputs stay in the type they arrive in, and the caller
+        # decides it: flash_attention() narrows q/k/v to the policy's
+        # compute type before the custom_vjp, so under mixed_bf16 these
+        # are bf16 x bf16 -> f32 at the full MXU rate. f32 operands
+        # (float32 policy) run at the fp32 matmul rate: measured on the
+        # v5e at d128 that costs this kernel 1.1 to 1.25 times the bf16
+        # time at equal blocks (PERF.md section 7), the score block's
+        # vector work being most of it. Accumulators are f32 either way
         q = q_ref[0]                      # (bq, d)
         k = k_ref[0]                      # (bk, d)
         v = v_ref[0]                      # (bk, d)
@@ -296,15 +310,30 @@ def _kv_row_fold(bh, nheads, kv_heads):
     return (bh // nheads) * kv_heads + (bh % nheads) // group
 
 
-def _kv_spec(block_k, d, nheads, kv_heads, kv_arg_pos=2):
+def _kv_spec(block_k, d, nheads, kv_heads, kv_arg_pos=2, j_hi=None):
     """K/V block spec; ``kv_arg_pos`` names which grid arg is the
     kv-block index (2 for the fwd/dq (b, i, j) grids, 1 for the dkv
-    swapped (b, j, i) grid)."""
+    swapped (b, j, i) grid). ``j_hi`` (fwd/dq, causal): q-block -> last
+    k-block that runs; a later step is skipped by ``pl.when``, and
+    clamped to it re-names the block already in VMEM, so nothing is
+    fetched for it (the band's ``_banded_imap`` does the same)."""
 
     def imap(*args, _h=nheads, _kv=kv_heads, _p=kv_arg_pos):
-        return (_kv_row_fold(args[0], _h, _kv), args[_p], 0)
+        j = args[_p]
+        if j_hi is not None:
+            j = jnp.minimum(j, j_hi(args[1]))
+        return (_kv_row_fold(args[0], _h, _kv), j, 0)
 
     return _vmem_spec((1, block_k, d), imap)
+
+
+def _causal_kv_spec(block_q, block_k, d, nheads, kv_heads, offset, n_j,
+                    causal):
+    """Non-banded K/V spec of the fwd/dq (b, i, j) grids."""
+    j_hi = (functools.partial(_causal_j_hi, block_q=block_q,
+                              block_k=block_k, offset=offset, n_j=n_j)
+            if causal else None)
+    return _kv_spec(block_k, d, nheads, kv_heads, j_hi=j_hi)
 
 
 def _mask_block_spec(nheads, block_k, j_pos=2, banded_lo=None,
@@ -375,7 +404,8 @@ def _fwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, causal,
         kv_spec = _vmem_spec((1, block_k, d), _banded_imap(
             j_lo, n_j, lambda b: _kv_row_fold(b, nheads, kv_heads)))
     else:
-        kv_spec = _kv_spec(block_k, d, nheads, kv_heads)
+        kv_spec = _causal_kv_spec(block_q, block_k, d, nheads, kv_heads,
+                                  offset, n_j, causal)
     mask_spec = _mask_block_spec(
         nheads, block_k, j_pos=2,
         banded_lo=j_lo if banded else None, n_j=n_j)
@@ -607,7 +637,9 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
     q_imap_banded = _banded_imap(i_lo, n_i)
 
     dq_kv_spec = (_vmem_spec((1, block_k, d), kv_imap_banded)
-                  if banded_j else _kv_spec(block_k, d, nheads, kv_heads))
+                  if banded_j else _causal_kv_spec(
+                      block_q, block_k, d, nheads, kv_heads, offset, n_j,
+                      causal))
     dq_in_specs = [
         _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         dq_kv_spec,
@@ -651,12 +683,20 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
         interpret=interpret,
     )(*dq_inputs)
 
-    dkv_q_spec = (_vmem_spec((1, block_q, d), q_imap_banded) if banded_i
-                  else _vmem_spec((1, block_q, d),
-                                  lambda b, j, i: (b, i, 0)))
-    dkv_q1_spec = (_vmem_spec((1, block_q, 1), q_imap_banded) if banded_i
-                   else _vmem_spec((1, block_q, 1),
-                                   lambda b, j, i: (b, i, 0)))
+    if causal:
+        # dkv's skipped steps come FIRST (q-blocks above the diagonal):
+        # clamped up to the first block that runs, they prefetch it
+        # once and fetch nothing else (see _kv_spec's j_hi)
+        i_first = functools.partial(_causal_i_lo, block_q=block_q,
+                                    block_k=block_k, offset=offset,
+                                    n_i=n_i)
+        q_imap = lambda b, j, i: (b, jnp.maximum(i, i_first(j)), 0)
+    else:
+        q_imap = lambda b, j, i: (b, i, 0)
+    dkv_q_spec = _vmem_spec(
+        (1, block_q, d), q_imap_banded if banded_i else q_imap)
+    dkv_q1_spec = _vmem_spec(
+        (1, block_q, 1), q_imap_banded if banded_i else q_imap)
     dkv_in_specs = [
         dkv_q_spec,
         _kv_spec(block_k, d, nheads, kv_heads, kv_arg_pos=1),
@@ -815,17 +855,21 @@ def _bwd4(q, k, v, kvm, seg, seed, o, lse, do, *, causal, window,
 
 
 def resolve_block_sizes(tq, tk, d, causal, block_q=None, block_k=None,
-                        block_q_bwd=None, block_k_bwd=None):
+                        block_q_bwd=None, block_k_bwd=None,
+                        dtype=jnp.float32):
     """Resolve the four kernel block sizes from the autotuned table
     (ops/pallas/tuning.py), falling back pow2-wise to sizes that divide
     the sequence lengths. Shared by flash_attention and the
     ring-attention per-step calls (parallel/context_parallel.py), which
-    see t/sp-sized blocks and must resolve against THOSE shapes."""
+    see t/sp-sized blocks and must resolve against THOSE shapes.
+    ``dtype`` is the type q/k/v reach the kernel in: the table is keyed
+    by it, so an entry measured at bf16 never sizes an f32 call."""
     tuned = {}
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
         from .tuning import attention_key, get_tuned
 
-        tuned = get_tuned(attention_key(tq, tk, d, causal)) or {}
+        tuned = get_tuned(attention_key(tq, tk, d, causal,
+                                        dtype=dtype)) or {}
 
     def _resolve(given, key, seq, default):
         # pow2 buckets can hold shapes the tuned block doesn't divide
@@ -1233,9 +1277,19 @@ def flash_attention(q, k, v, causal: bool = False,
 
     Sequence lengths must divide the block sizes (shrunk automatically for
     short sequences). Differentiable (custom VJP, recompute backward).
-    Block sizes default to the autotuned table (ops/pallas/tuning.py,
-    written by tools/pallas_tune.py on real hardware) and fall back to
-    128x128.
+
+    Operand type: q, k and v are narrowed HERE, before the custom VJP,
+    to the active policy's compute type where that is narrower than
+    their own (``ops.attention.flash_operand_dtype``; f32 -> bf16 under
+    ``mixed_bf16``), and the result is cast back to q's type. The
+    residuals, the incoming cotangent and the layout transposes are then
+    that type too; lse, delta and every accumulator stay f32. Under the
+    ``float32`` and ``bfloat16`` policies nothing is cast.
+
+    Block sizes come from the table measured on the chip
+    (ops/pallas/tuned_blocks.json, written by tools/pallas_tune.py,
+    keyed by device kind, shape bucket and operand type) and fall back
+    to 128x128 where it has no entry.
 
     ``kv_mask``: optional (batch, tk) keep-mask (True/nonzero = attend) —
     the key-padding form every ragged-batch model needs (the LoD
@@ -1258,6 +1312,10 @@ def flash_attention(q, k, v, causal: bool = False,
     b, tq, h, d = q.shape
     tk = k.shape[1]
     h_kv = k.shape[2]
+    from ..attention import flash_operand_dtype
+
+    out_dtype = q.dtype
+    q, k, v = (x.astype(flash_operand_dtype(x.dtype)) for x in (q, k, v))
     if h_kv != h:
         # GQA/MQA: fewer K/V heads than Q heads; the kernel reads the
         # shared block via its index map (no head-repeat in HBM)
@@ -1268,7 +1326,8 @@ def flash_attention(q, k, v, causal: bool = False,
     if scale is None:
         scale = d ** -0.5
     block_q, block_k, block_q_bwd, block_k_bwd = resolve_block_sizes(
-        tq, tk, d, causal, block_q, block_k, block_q_bwd, block_k_bwd)
+        tq, tk, d, causal, block_q, block_k, block_q_bwd, block_k_bwd,
+        dtype=q.dtype)
     if tq % block_q or tk % block_k or tq % block_q_bwd or tk % block_k_bwd:
         raise ValueError(
             f"seq lens ({tq},{tk}) must be divisible by blocks "
@@ -1312,4 +1371,4 @@ def flash_attention(q, k, v, causal: bool = False,
     return _flash(q, k, v, kvm, seg, seed, causal,
                   None if window is None else int(window), float(scale),
                   float(dropout_p), block_q, block_k, block_q_bwd,
-                  block_k_bwd, interpret)
+                  block_k_bwd, interpret).astype(out_dtype)

@@ -7,8 +7,9 @@ operators/conv_cudnn_op.cu.cc workspace search).
 Here the tunables are Pallas grid/block sizes (and the flash-vs-XLA
 dispatch choice). ``tools/pallas_tune.py`` sweeps candidates ON THE REAL
 CHIP and persists winners to ``tuned_blocks.json`` next to this file,
-keyed by (kernel, device_kind, shape bucket); kernels consult the table
-at call time and fall back to the static defaults when no entry exists.
+keyed by (kernel, device_kind, shape bucket, operand type); kernels
+consult the table at call time and fall back to the static defaults
+when no entry exists.
 Entries tuned on one chip generation never apply to another (device_kind
 is in the key).
 """
@@ -65,11 +66,27 @@ def _pow2_bucket(n: int) -> int:
     return b
 
 
+_DTYPE_TAGS = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}
+
+
+def dtype_tag(dtype) -> str:
+    """Short name of an operand type in a table key (``f32``, ``bf16``)."""
+    import numpy as np
+
+    name = np.dtype(dtype).name
+    return _DTYPE_TAGS.get(name, name)
+
+
 def attention_key(tq: int, tk: int, d: int, causal: bool,
-                  kind: Optional[str] = None) -> str:
+                  kind: Optional[str] = None, dtype="float32") -> str:
+    """Flash-attention bucket: pow2 sequence lengths x head_dim x mask
+    x OPERAND TYPE (as :func:`decode_key` keys by ``pool_dtype``). The
+    type of q/k/v decides the MXU rate and how much VMEM a score block
+    takes, so blocks measured at bf16 never size an f32 call: a
+    1024 x 1024 f32 score block is 4 MB a buffer."""
     return (f"flash_attention|{kind or _device_kind()}|"
             f"tq{_pow2_bucket(tq)}|tk{_pow2_bucket(tk)}|d{d}|"
-            f"{'causal' if causal else 'full'}")
+            f"{'causal' if causal else 'full'}|{dtype_tag(dtype)}")
 
 
 def decode_key(capacity: int, d: int, kind: Optional[str] = None,

@@ -470,11 +470,12 @@ def ring_attention(q, k, v, *, causal: bool = False,
     from ..ops.attention import flash_shape_ok
 
     if use_flash and window is None and flash_shape_ok(
-            t_local, t_local, d, causal=causal):
+            t_local, t_local, d, causal=causal, dtype=q.dtype):
         from ..ops.pallas.flash_attention import (_use_interpret,
                                                   resolve_block_sizes)
 
-        blocks = resolve_block_sizes(t_local, t_local, d, causal)
+        blocks = resolve_block_sizes(t_local, t_local, d, causal,
+                                     dtype=q.dtype)
         inner = functools.partial(
             _ring_flash_inner, axis=axis, causal=causal,
             scale=float(scale), n=n, blocks=blocks,

@@ -244,3 +244,120 @@ def test_tune_attention_sweeps_fwd_and_bwd_independently(monkeypatch):
     assert entry["use_flash"] is True
     assert entry["flash_ms"] == pytest.approx(7000.0)
     assert entry["xla_ms"] == pytest.approx(10000.0)
+
+
+# ---------------------------------------------------------------------------
+# the committed table (paddle_tpu/ops/pallas/tuned_blocks.json) and the
+# operand type in its keys
+# ---------------------------------------------------------------------------
+
+V5E = "tpu_v5_lite"  # what _device_kind() makes of the chip's "TPU v5 lite"
+
+
+@pytest.fixture
+def committed(monkeypatch):
+    """The table as committed, looked up as the v5e would."""
+    tuning.reset_cache()
+    monkeypatch.setattr(tuning, "_device_kind", lambda: V5E)
+    with open(tuning._TABLE_PATH) as f:
+        yield json.load(f)
+    tuning.reset_cache()
+
+
+def test_attention_key_carries_operand_type():
+    k32 = tuning.attention_key(2048, 2048, 128, True, kind=V5E)
+    k16 = tuning.attention_key(2048, 2048, 128, True, kind=V5E,
+                               dtype=jnp.bfloat16)
+    assert k32 == "flash_attention|tpu_v5_lite|tq2048|tk2048|d128|causal|f32"
+    assert k16 == "flash_attention|tpu_v5_lite|tq2048|tk2048|d128|causal|bf16"
+    assert tuning.attention_key(2048, 2048, 128, True, kind=V5E,
+                                dtype="bfloat16") == k16
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_committed_entry_sizes_the_train_cell(committed, dtype):
+    """(2048, 2048, 128, causal) on a v5e resolves to the committed
+    entry of ITS operand type, which carries the chip's kind in its key
+    and the timings it was chosen from."""
+    from paddle_tpu.ops.pallas.flash_attention import resolve_block_sizes
+
+    key = tuning.attention_key(2048, 2048, 128, True, dtype=dtype)
+    entry = committed[key]
+    assert key.split("|")[1] == V5E
+    got = resolve_block_sizes(2048, 2048, 128, True, dtype=dtype)
+    assert got == (entry["block_q"], entry["block_k"],
+                   entry["block_q_bwd"], entry["block_k_bwd"])
+    assert got != (128,) * 4
+    # the winners are the fastest pairs of the sweep the entry records
+    fwd, grad = entry["sweep_fwd_ms"], entry["sweep_grad_ms"]
+    assert min(fwd, key=fwd.get) == f"{got[0]}x{got[1]}"
+    assert min(grad, key=grad.get) == f"{got[2]}x{got[3]}"
+    assert entry["shape"] == [8, 2048, 16, 8, 128]
+    assert entry["fwd_ms"] == fwd[f"{got[0]}x{got[1]}"]
+    assert entry["xla_ms"] > 0 and entry["fwd_spread_pct"] >= 0
+
+
+def test_committed_table_leaves_other_shapes_at_128(committed):
+    from paddle_tpu.ops.pallas.flash_attention import resolve_block_sizes
+
+    for shape in ((512, 512, 64, True), (2048, 2048, 64, True),
+                  (2048, 2048, 128, False), (1024, 1024, 128, True)):
+        for dtype in (jnp.bfloat16, jnp.float32):
+            assert resolve_block_sizes(*shape, dtype=dtype) == (128,) * 4
+    # another chip generation never reads the v5e's entry
+    assert tuning.attention_key(2048, 2048, 128, True, kind="tpu_v4",
+                                dtype=jnp.bfloat16) not in committed
+
+
+def test_f32_call_never_gets_blocks_measured_at_bf16(table, monkeypatch):
+    """Only a bf16 entry in the table: the bf16 call takes it, the f32
+    call at the same shape keeps 128 x 128, through flash_attention()
+    too — under mixed_bf16 the f32 caller IS a bf16 call."""
+    import importlib
+
+    from paddle_tpu.core.dtypes import policy_scope
+
+    FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(tuning, "_device_kind", lambda: V5E)
+    tuning.set_tuned(
+        tuning.attention_key(256, 256, 64, True, dtype=jnp.bfloat16),
+        {"block_q": 256, "block_k": 64, "block_q_bwd": 64,
+         "block_k_bwd": 256}, persist=False)
+    assert FA.resolve_block_sizes(256, 256, 64, True,
+                                  dtype=jnp.bfloat16) == (256, 64, 64, 256)
+    assert FA.resolve_block_sizes(256, 256, 64, True,
+                                  dtype=jnp.float32) == (128,) * 4
+    assert FA.resolve_block_sizes(256, 256, 64, True) == (128,) * 4
+
+    calls = []
+    real = FA._flash
+    monkeypatch.setattr(FA, "_flash", lambda q, k, v, *rest: (
+        calls.append((q.dtype, rest[7:11])), real(q, k, v, *rest))[1])
+    q = jnp.zeros((1, 256, 2, 64), jnp.float32)
+    FA.flash_attention(q, q, q, causal=True)
+    assert calls[-1] == (jnp.float32, (128,) * 4)
+    with policy_scope("mixed_bf16"):
+        FA.flash_attention(q, q, q, causal=True)
+    assert calls[-1] == (jnp.bfloat16, (256, 64, 64, 256))
+
+
+def test_table_lookups_are_counted(table, monkeypatch):
+    """Engagement is read from the hit counter: a lookup the table
+    serves counts as a hit, one it lacks as a miss."""
+    from paddle_tpu import telemetry
+
+    monkeypatch.setattr(telemetry, "enabled", lambda: True)
+    counts = lambda: {
+        n: telemetry.registry().counter(f"pt_tuning_cache_{n}_total",
+                                        "").value
+        for n in ("hits", "misses")}
+    key = tuning.attention_key(2048, 2048, 128, True, kind=V5E,
+                               dtype=jnp.bfloat16)
+    tuning.set_tuned(key, {"block_q": 512, "block_k": 512}, persist=False)
+    before = counts()
+    assert tuning.get_tuned(key)["block_q"] == 512
+    assert tuning.get_tuned(key.replace("bf16", "f32")) is None
+    after = counts()
+    assert after["hits"] == before["hits"] + 1
+    assert after["misses"] == before["misses"] + 1

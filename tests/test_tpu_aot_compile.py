@@ -6,7 +6,10 @@ refuses here, at no chip time (interpret mode and the Mosaic-MLIR
 lowering of tests/test_pallas_mosaic_lowering.py both stop short of the
 layout/VMEM checks that refused quant_matmul's 1-D scale operand).
 Shapes are GPT-small's real ones (12 q / 4 kv heads, head dim 64,
-seq 1024, decode capacity 2048, page 64).
+seq 1024, decode capacity 2048, page 64), and for the flash forward and
+backward also the benchmark's train cell (8 x 2048, 16 q / 8 kv heads of
+128) at the blocks the committed table holds for the v5e, with bf16 and
+with f32 operands.
 
 Nothing runs, so these say nothing about results or times.
 
@@ -36,6 +39,14 @@ from paddle_tpu.ops.pallas.quant_matmul import quant_matmul
 # GPT-small training shape (GPTConfig.small, batch 8 x seq 1024)
 B, T, H, H_KV, D = 8, 1024, 12, 4, 64
 SLOTS, CAPACITY, PAGE = 8, 2048, 64
+# (b, t, h, h_kv, d, operand type): GPT-small as above, and
+# internlm2-1.8b.pretrain_2k's attention as mixed_bf16 / float32 hand it
+# to the kernels
+ATTN_CASES = {
+    "gpt_small": (B, T, H, H_KV, D, jnp.bfloat16),
+    "train_cell_bf16": (8, 2048, 16, 8, 128, jnp.bfloat16),
+    "train_cell_f32": (8, 2048, 16, 8, 128, jnp.float32),
+}
 
 
 @pytest.fixture(scope="module")
@@ -88,11 +99,37 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-def _attn_shapes(one_chip):
-    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((B, T, H_KV, D), jnp.bfloat16,
-                              sharding=one_chip)
+def _attn_shapes(one_chip, case="gpt_small"):
+    b, t, h, h_kv, d, dtype = ATTN_CASES[case]
+    q = jax.ShapeDtypeStruct((b, t, h, d), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, h_kv, d), dtype, sharding=one_chip)
     return q, kv
+
+
+@pytest.fixture
+def table_of_described_chip(topo, monkeypatch):
+    """Block sizes are looked up under the kind of the device the process
+    runs on, which is the CPU here: steer that one seam to the described
+    chip, so that the compile runs at the blocks the committed table
+    (ops/pallas/tuned_blocks.json) holds for it."""
+    from paddle_tpu.ops.pallas import tuning
+
+    kind = topo.devices[0].device_kind.lower().replace(" ", "_")
+    monkeypatch.setattr(tuning, "_device_kind", lambda: kind)
+    tuning.reset_cache()
+    yield
+    tuning.reset_cache()
+
+
+def _assert_committed_blocks(case):
+    from paddle_tpu.ops.pallas.flash_attention import resolve_block_sizes
+
+    _, t, _, _, d, dtype = ATTN_CASES[case]
+    blocks = resolve_block_sizes(t, t, d, True, dtype=dtype)
+    if case == "gpt_small":
+        assert blocks == (128,) * 4  # no entry at d64: the defaults
+    else:
+        assert min(blocks) > 128, blocks
 
 
 def test_described_chip_is_a_v5e(topo):
@@ -100,16 +137,21 @@ def test_described_chip_is_a_v5e(topo):
     assert topo.devices[0].device_kind == "TPU v5 lite"
 
 
-def test_flash_forward_compiles(one_chip):
-    q, kv = _attn_shapes(one_chip)
+@pytest.mark.parametrize("case", sorted(ATTN_CASES))
+def test_flash_forward_compiles(one_chip, table_of_described_chip, case):
+    _assert_committed_blocks(case)
+    q, kv = _attn_shapes(one_chip, case)
     text = _compiled_text(
         lambda q, k, v: flash_attention(q, k, v, causal=True,
                                         interpret=False), q, kv, kv)
     assert "tpu_custom_call" in text
+    assert "%pt_flash_fwd" in text
 
 
-def test_flash_backward_compiles(one_chip):
-    q, kv = _attn_shapes(one_chip)
+@pytest.mark.parametrize("case", sorted(ATTN_CASES))
+def test_flash_backward_compiles(one_chip, table_of_described_chip, case):
+    _assert_committed_blocks(case)
+    q, kv = _attn_shapes(one_chip, case)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True,

@@ -7,6 +7,8 @@ benchmark candidate kernels per shape, cache the winner).
 Usage (on real TPU; refuses to record from CPU/interpret timings):
   python tools/pallas_tune.py                      # default shape set
   python tools/pallas_tune.py --attention 32,128,12,64 --causal
+  python tools/pallas_tune.py --attention 8,2048,16,8,128 --causal \
+      --dtype bf16 --dtype f32                     # GQA 16 q / 8 kv heads
   python tools/pallas_tune.py --matmul 1024,1024,1024
   python tools/pallas_tune.py --dry-run            # print, don't persist
 
@@ -20,6 +22,7 @@ from __future__ import annotations
 import argparse
 import itertools
 import os
+import statistics
 import sys
 import time
 
@@ -27,13 +30,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-ATTN_BLOCKS = [128, 256, 512]
+ATTN_BLOCKS = [128, 256, 512, 1024]
+ATTN_DTYPES = {"bf16": "bfloat16", "f32": "float32"}
 GEMM_TILES = [128, 256, 512]
-# default shape set: BERT-base pretrain, long-context (bert_long's real
-# shape is d=64/h=12 — the table is keyed on (tq, tk, d, causal), so a
-# d=128 tune would never match it), a d=128 long-context variant, NMT
+# default shape set, (b, t, h, d) or (b, t, h, kv_heads, d): BERT-base
+# pretrain, long-context (bert_long's real shape is d=64/h=12 — the table
+# is keyed on (tq, tk, d, causal, operand type), so a d=128 tune would
+# never match it), the benchmark's train cell (internlm2-1.8b.pretrain_2k:
+# 8 x 2048, 16 q / 8 kv heads of 128), NMT
 DEFAULT_ATTN = [(32, 128, 12, 64), (8, 512, 12, 64), (4, 2048, 12, 64),
-                (2, 2048, 16, 128), (64, 64, 8, 64)]
+                (8, 2048, 16, 8, 128), (64, 64, 8, 64)]
 DEFAULT_GEMM = [(512, 768, 768), (2048, 3072, 768), (4096, 30528, 768)]
 # decode: GPT-small serving cache (cap 2048, GQA 12q/4kv d64) + the NMT
 # decode cache (cap 64)
@@ -64,7 +70,22 @@ def _time(fn, *args, warmup=2, iters=10):
     return (time.perf_counter() - t0) / iters
 
 
-def tune_attention(b, t, h, d, causal, dry_run=False):
+def _time_reps(fn, *args, reps=5):
+    """Median of ``reps`` timed batches and their spread (max - min over
+    the median): the number a winner is chosen from, and how far to
+    trust the gap to the runner-up."""
+    ts = [_time(fn, *args) for _ in range(reps)]
+    med = statistics.median(ts)
+    return med, (max(ts) - min(ts)) / med
+
+
+def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
+                   dtype="bf16"):
+    """Sweep the flash blocks for one shape at one OPERAND TYPE (the
+    table is keyed by it: bf16 is what the kernels see under mixed_bf16
+    and bfloat16 policies, f32 under the float32 policy). ``kv_heads``
+    < ``h`` runs the GQA form: the dk/dv kernel then runs per q head
+    and the groups are summed after it, as in training."""
     import jax
     import jax.numpy as jnp
 
@@ -72,10 +93,12 @@ def tune_attention(b, t, h, d, causal, dry_run=False):
     from paddle_tpu.ops.pallas import tuning
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
+    kv_heads = kv_heads or h
+    jdtype = jnp.dtype(ATTN_DTYPES[dtype])
     rng = np.random.default_rng(0)
-    mk = lambda: jnp.asarray(rng.normal(size=(b, t, h, d))
-                             .astype(np.float32)).astype(jnp.bfloat16)
-    q, k, v = mk(), mk(), mk()
+    mk = lambda heads=h: jnp.asarray(rng.normal(size=(b, t, heads, d))
+                                     .astype(np.float32)).astype(jdtype)
+    q, k, v = mk(), mk(kv_heads), mk(kv_heads)
     # a RANDOM cotangent keeps the comparison honest: grad of a plain
     # .sum() hands XLA a constant all-ones dO it can fold through its
     # transparent backward, while the opaque Pallas kernel sees a real
@@ -93,62 +116,61 @@ def tune_attention(b, t, h, d, causal, dry_run=False):
     # an empty sweep that would persist use_flash=False unmeasured
     cand = [blk for blk in ATTN_BLOCKS if blk <= t] or [t]
 
+    def sweep(what, build):
+        results = []
+        for bq, bk in itertools.product(cand, cand):
+            try:
+                ms, spread = _time_reps(build(bq, bk), q, k, v)
+                results.append((ms, bq, bk, spread))
+                print(f"  flash {what} bq={bq} bk={bk}: {ms*1e3:.3f}ms "
+                      f"(spread {spread*100:.1f}%)", flush=True)
+            except Exception as e:
+                print(f"  flash {what} bq={bq} bk={bk}: FAILED "
+                      f"({type(e).__name__}: {str(e)[:120]})", flush=True)
+        return results
+
     # forward and backward are tuned INDEPENDENTLY: the dq/dkv kernels
     # have a different arithmetic-intensity sweet spot than the fwd
     # kernel, and coupling them to one (bq, bk) pair leaves bwd time on
     # the table (observed on-chip: best fwd pair != best bwd pair)
-    fwd_results = []
-    for bq, bk in itertools.product(cand, cand):
-        try:
-            f = jax.jit(lambda q, k, v, _bq=bq, _bk=bk: flash_attention(
-                q, k, v, causal=causal, block_q=_bq, block_k=_bk,
-                interpret=False))
-            fwd = _time(f, q, k, v)
-            fwd_results.append((fwd, bq, bk))
-            print(f"  flash fwd bq={bq} bk={bk}: {fwd*1e3:.3f}ms")
-        except Exception as e:
-            print(f"  flash fwd bq={bq} bk={bk}: FAILED "
-                  f"({type(e).__name__}: {str(e)[:120]})")
+    fwd_results = sweep("fwd", lambda bq, bk: jax.jit(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=bq, block_k=bk,
+            interpret=False)))
     best_fwd = min(fwd_results) if fwd_results else None
 
     bwd_results = []
     if best_fwd is not None:
         fq, fk = best_fwd[1], best_fwd[2]
-        for bq, bk in itertools.product(cand, cand):
-            try:
-                bfn = grad_of(
-                    lambda q, k, v, _bq=bq, _bk=bk: flash_attention(
-                        q, k, v, causal=causal, block_q=fq, block_k=fk,
-                        block_q_bwd=_bq, block_k_bwd=_bk,
-                        interpret=False))
-                bwd = _time(bfn, q, k, v)  # grad pass = fwd + bwd cost
-                bwd_results.append((bwd, bq, bk))
-                print(f"  flash bwd bq={bq} bk={bk}: {bwd*1e3:.3f}ms")
-            except Exception as e:
-                print(f"  flash bwd bq={bq} bk={bk}: FAILED "
-                      f"({type(e).__name__}: {str(e)[:120]})")
+        # grad pass = fwd + bwd cost
+        bwd_results = sweep("bwd", lambda bq, bk: grad_of(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, block_q=fq, block_k=fk,
+                block_q_bwd=bq, block_k_bwd=bk, interpret=False)))
     best_bwd = min(bwd_results) if bwd_results else None
 
     xf = jax.jit(lambda q, k, v: xla_attention(q, k, v, causal=causal))
-    x_fwd = _time(xf, q, k, v)
-    x_bwd = _time(grad_of(lambda q, k, v: xla_attention(q, k, v,
-                                                        causal=causal)),
-                  q, k, v)
+    x_fwd, _ = _time_reps(xf, q, k, v)
+    x_bwd, _ = _time_reps(grad_of(lambda q, k, v: xla_attention(
+        q, k, v, causal=causal)), q, k, v)
     x_total = x_fwd + x_bwd
     print(f"  xla fallback: fwd {x_fwd*1e3:.3f}ms grad {x_bwd*1e3:.3f}ms")
 
-    key = tuning.attention_key(t, t, d, causal)
+    key = tuning.attention_key(t, t, d, causal, dtype=jdtype)
+    ms = lambda x: round(x * 1e3, 4)
+    table = lambda rs: {f"{bq}x{bk}": ms(dt) for dt, bq, bk, _ in rs}
+    measured = {"shape": [b, t, h, kv_heads, d],
+                "xla_ms": ms(x_total), "xla_fwd_ms": ms(x_fwd),
+                "xla_grad_ms": ms(x_bwd)}
     if best_fwd is None:
-        entry = {"use_flash": False, "xla_ms": round(x_total * 1e3, 4),
-                 "note": "no flash config compiled"}
+        entry = {"use_flash": False, "note": "no flash config compiled"}
     elif best_bwd is None:
         # fwd compiled (keep its measured winner for inference-style
         # callers) but no bwd config did — training dispatch must fall
         # back, and the note must not claim fwd failed too
         entry = {"block_q": best_fwd[1], "block_k": best_fwd[2],
                  "use_flash": False,
-                 "fwd_ms": round(best_fwd[0] * 1e3, 4),
-                 "xla_ms": round(x_total * 1e3, 4),
+                 "fwd_ms": ms(best_fwd[0]),
                  "note": "fwd compiled; no bwd config compiled"}
     else:
         # same convention both sides: total = fwd-only time + grad time
@@ -158,8 +180,13 @@ def tune_attention(b, t, h, d, causal, dry_run=False):
         entry = {"block_q": best_fwd[1], "block_k": best_fwd[2],
                  "block_q_bwd": best_bwd[1], "block_k_bwd": best_bwd[2],
                  "use_flash": bool(flash_total < x_total),
-                 "flash_ms": round(flash_total * 1e3, 4),
-                 "xla_ms": round(x_total * 1e3, 4)}
+                 "flash_ms": ms(flash_total),
+                 "fwd_ms": ms(best_fwd[0]), "grad_ms": ms(best_bwd[0]),
+                 "fwd_spread_pct": round(best_fwd[3] * 100, 2),
+                 "grad_spread_pct": round(best_bwd[3] * 100, 2)}
+    # what the winner was chosen from: every pair that compiled, in ms
+    entry.update(measured, sweep_fwd_ms=table(fwd_results),
+                 sweep_grad_ms=table(bwd_results))
     print(f"  -> {key}: {entry}")
     if not dry_run:
         tuning.set_tuned(key, entry)
@@ -355,7 +382,12 @@ def tune_matmul(m, n, k, dry_run=False):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--attention", action="append", default=None,
-                    metavar="B,T,H,D", help="attention shape to tune")
+                    metavar="B,T,H[,KV],D",
+                    help="attention shape to tune (KV: GQA kv heads)")
+    ap.add_argument("--dtype", action="append", default=None,
+                    choices=sorted(ATTN_DTYPES),
+                    help="attention operand type(s) to sweep; the table "
+                    "is keyed by it (default: bf16)")
     ap.add_argument("--matmul", action="append", default=None,
                     metavar="M,N,K", help="int8 GEMM shape to tune")
     ap.add_argument("--decode", action="append", default=None,
@@ -394,11 +426,15 @@ def main():
            if args.decode else ([] if explicit else DEFAULT_DECODE))
     causal_set = [args.causal] if args.attention else [False, True]
 
-    for (b, t, h, d) in attn:
-        for causal in causal_set:
-            print(f"tuning attention b={b} t={t} h={h} d={d} "
-                  f"causal={causal} on {backend}")
-            tune_attention(b, t, h, d, causal, dry_run=args.dry_run)
+    for shape in attn:
+        b, t, h, d = shape[0], shape[1], shape[2], shape[-1]
+        kv = shape[3] if len(shape) == 5 else h
+        for causal, dtype in itertools.product(causal_set,
+                                               args.dtype or ["bf16"]):
+            print(f"tuning attention b={b} t={t} h={h} kv={kv} d={d} "
+                  f"causal={causal} {dtype} on {backend}")
+            tune_attention(b, t, h, d, causal, dry_run=args.dry_run,
+                           kv_heads=kv, dtype=dtype)
     for (m, n, k) in gemm:
         print(f"tuning int8 gemm m={m} n={n} k={k} on {backend}")
         tune_matmul(m, n, k, dry_run=args.dry_run)
