@@ -2,16 +2,16 @@
 
 The program's side is read from the timed path itself (the job hands in
 what its own steps or its own served requests produced); the reference
-side is computed here from ``benchmark/reference/decoder_f32.py`` and
-weights regenerated from the seed. Every number compared is printed
-beside its limit; the limits and the readings they were set from live
-in ``benchmark/limits/<cell>.json``.
+side is computed here from the plain reference the cell's family names
+(``manifest.load_family``) and weights regenerated from the seed. Every
+number compared is printed beside its limit; the limits and the readings
+they were set from live in ``benchmark/limits/<cell>.json``.
 """
 
 from __future__ import annotations
 
+import functools
 import json
-import os
 import statistics
 import sys
 from typing import Dict, List, Sequence, Tuple
@@ -20,15 +20,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..reference import decoder_f32 as R
 from . import weights as W
-from .manifest import BENCH_DIR
 
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
 
 
-def load_limits(cell: str) -> Dict[str, float]:
-    with open(os.path.join(BENCH_DIR, "limits", cell + ".json")) as f:
+def load_limits(cell) -> Dict[str, float]:
+    with open(cell.path("limits", cell.name + ".json")) as f:
         return {k: float(v) for k, v in json.load(f)["limits"].items()}
 
 
@@ -61,34 +59,48 @@ def _norms(tree):
             for k, v in tree.items()}
 
 
-def delta_norms(params: Dict[str, jax.Array], seed: int) -> Dict[str, float]:
-    """Per leaf, the norm of ``params`` minus the seeded start (which is
-    regenerated inside the program, never stored)."""
-    out = _delta(params, W.seed_arg(seed))
+def delta_norms(params: Dict[str, jax.Array], seed: int,
+                rule) -> Dict[str, float]:
+    """Per leaf, the norm of ``params`` minus the start its family's
+    ``rule`` gives it (regenerated inside the program, never stored)."""
+    shapes = {k: v.shape for k, v in params.items()}
+    out = _delta(params, W.seed_arg(seed), W.rules_of(rule, shapes))
     return {k: float(v) for k, v in jax.device_get(out).items()}
 
 
-@jax.jit
-def _delta(params, seed_u32):
+@functools.partial(jax.jit, static_argnums=2)
+def _delta(params, seed_u32, rules):
     return {k: jnp.sqrt(jnp.sum(jnp.square(
-        v.astype(jnp.float32)
-        - W.leaf(seed_u32, k, v.shape, jnp.float32))))
-        for k, v in params.items()}
+        params[k].astype(jnp.float32)
+        - W.leaf(seed_u32, k, params[k].shape, jnp.float32, r))))
+        for k, r in zip(sorted(params), rules)}
 
 
-def train_reference(seed: int, dims: R.Dims, batches: np.ndarray,
+def adam(w, g, m, v, step: int, lr: float, b1: float = ADAM_B1,
+         b2: float = ADAM_B2, eps: float = ADAM_EPS):
+    """One Adam update (Kingma & Ba, bias-corrected); ``step`` from 1."""
+    m = b1 * m + (1 - b1) * g
+    v = b2 * v + (1 - b2) * jnp.square(g)
+    mhat = m / (1 - b1 ** step)
+    vhat = v / (1 - b2 ** step)
+    return w - lr * mhat / (jnp.sqrt(vhat) + eps), m, v
+
+
+def train_reference(seed: int, fam, dims, batches: np.ndarray,
                     lr: float, mode: str = "f32") -> dict:
     """Follow the first ``len(batches)`` (1 or 2) Adam steps in float32,
-    one row of the batch at a time so that it fits beside nothing else.
+    one row of the batch at a time so that it fits beside nothing else:
+    the family's loss is a mean over rows (``manifest.load_family``).
     Adam's moments after step one are functions of the first gradient,
     so that gradient is kept in their place: one array, not two."""
     if not 1 <= len(batches) <= 2:
         raise ValueError("the reference follows one or two steps")
     rows = batches.shape[1]
+    loss = fam.reference.loss
 
     def row_step(acc, w, row):
         l, g = jax.value_and_grad(
-            lambda p: R.loss(p, row[None], dims, mode, remat=True))(w)
+            lambda p: loss(p, row[None], dims, mode, remat=True))(w)
         return l, jax.tree_util.tree_map(
             lambda a, b: a + b / rows, acc, g)
 
@@ -105,18 +117,16 @@ def train_reference(seed: int, dims: R.Dims, batches: np.ndarray,
     @jax.jit
     def first(w, g):
         return jax.tree_util.tree_map(
-            lambda p, a: R.adam(p, a, 0.0, 0.0, 1, lr, ADAM_B1, ADAM_B2,
-                                ADAM_EPS)[0], w, g)
+            lambda p, a: adam(p, a, 0.0, 0.0, 1, lr)[0], w, g)
 
     @jax.jit
     def second(w, g1, g2):
         return jax.tree_util.tree_map(
-            lambda p, a, b: R.adam(p, b, (1 - ADAM_B1) * a,
-                                   (1 - ADAM_B2) * jnp.square(a), 2, lr,
-                                   ADAM_B1, ADAM_B2, ADAM_EPS)[0],
+            lambda p, a, b: adam(p, b, (1 - ADAM_B1) * a,
+                                 (1 - ADAM_B2) * jnp.square(a), 2, lr)[0],
             w, g1, g2)
 
-    w = W.make_all(seed, dims, jnp.float32)
+    w = W.make_all(seed, fam, dims, jnp.float32)
     l1, g1 = grads_of(w, batches[0])
     losses, gnorm = [l1], leaf_norms(g1)
     w = first(w, g1)
@@ -127,7 +137,7 @@ def train_reference(seed: int, dims: R.Dims, batches: np.ndarray,
         del g2
     del g1
     return {"losses": losses, "grad_norms": gnorm,
-            "delta_norms": delta_norms(w, seed)}
+            "delta_norms": delta_norms(w, seed, fam.leaf_rule)}
 
 
 def worst_leaf_gap(got: Dict[str, float], ref: Dict[str, float]) -> float:
@@ -167,7 +177,7 @@ def pick_sample(finished: Sequence[Tuple[np.ndarray, np.ndarray]],
     return [longest] + [rest[i] for i in take]
 
 
-def serve_reference(seed: int, dims: R.Dims, dtype,
+def serve_reference(seed: int, fam, dims, dtype,
                     sample: Sequence[Tuple[np.ndarray, np.ndarray]],
                     rows: int, capacity: int, max_out: int,
                     mode: str = "f32"):
@@ -188,13 +198,12 @@ def serve_reference(seed: int, dims: R.Dims, dtype,
         pos[r, :n] = len(prompt) - 1 + np.arange(n)
         served[r, :n] = out[:n]
         mask[r, :n] = True
-    top = {k: s for k, s in W.leaf_shapes(dims).items()
-           if not k.startswith("blocks.")}
-    lg = R.layerwise_logits(
+    lg = fam.reference.layerwise_logits(
         jnp.asarray(toks), jnp.asarray(pos), dims, mode,
-        get=lambda shapes: W.make_leaves(seed, shapes, dtype),
-        shapes_of_layer=lambda i: W.layer_shapes(dims, i),
-        top_shapes=top)
+        get=lambda shapes: W.make_leaves(seed, shapes, dtype,
+                                         fam.leaf_rule),
+        shapes_of_layer=lambda i: fam.layer_shapes(dims, i),
+        top_shapes=fam.top_shapes(dims))
     return lg, served, mask
 
 

@@ -1,9 +1,11 @@
-"""The only place the benchmark touches the program: build the system
-under test the way its users do, with the benchmark's seeded weights.
+"""Where the harness touches the program: build the system under test
+the way its users do, with the benchmark's seeded weights.
 
-``chip_smoke.py``'s constructors are the pattern: ``parallel.Trainer``
-with ``amp="mixed_bf16"`` for training, ``BatchedDecoder`` behind an
-in-process ``Router`` + ``LocalReplica`` for serving.
+The model is the cell's family's (``manifest.load_family``); what runs
+it is common to every family and no family's choice: ``parallel.Trainer``
+over ``forward_loss`` with the configuration's ``amp`` for training,
+``BatchedDecoder`` behind an in-process ``Router`` + ``LocalReplica``
+for serving (``chip_smoke.py``'s constructors are the pattern).
 """
 
 from __future__ import annotations
@@ -13,39 +15,32 @@ import jax
 from . import weights as W
 
 
-def build_model(config: dict, dims, seed: int, dtype: str,
+def build_model(fam, config: dict, dims, seed: int, dtype: str,
                 max_position: int, remat: bool):
-    """``GPTForCausalLM`` at the configuration's sizes holding the
-    benchmark's weights. The constructor runs under ``jax.eval_shape``
-    (nothing is allocated by the program's own initialisers, no 7 GB
-    made leaf by leaf and thrown away); the weights then come from one
-    jitted call, on the device, in ``dtype``."""
+    """The family's model at the configuration's sizes holding the
+    benchmark's weights. The family's constructor runs under
+    ``jax.eval_shape`` (nothing is allocated by the program's own
+    initialisers, no 7 GB made leaf by leaf and thrown away); the
+    weights then come from one jitted call, on the device, in
+    ``dtype``."""
     import paddle_tpu as pt
     from paddle_tpu.core.config import FLAGS
-    from paddle_tpu.models import gpt as G
 
     FLAGS.set("default_dtype", dtype)
-    cfg = G.GPTConfig(
-        vocab_size=dims.vocab, hidden_size=dims.hidden,
-        num_layers=dims.layers, num_heads=dims.heads,
-        num_kv_heads=dims.kv_heads, intermediate_size=dims.ffn,
-        max_position=max_position, rope_theta=dims.theta, remat=remat,
-        attn_window=dims.window, tie_embeddings=dims.tied)
-    if dims.hidden // dims.heads != dims.head_dim:
-        raise ValueError("GPTConfig derives head_dim as hidden / heads")
     box = {}
 
     def construct():
-        box["model"] = G.GPTForCausalLM(cfg)
+        box["model"] = fam.build_model(config, dims, dtype, max_position,
+                                       remat)
         return dict(box["model"].named_parameters())
 
     pt.seed(0)
     shapes = jax.eval_shape(construct)
     pt.seed(0)      # the global key held a tracer: make it concrete again
-    W.check_names(W.leaf_shapes(dims),
+    W.check_names(W.leaf_shapes(fam, dims),
                   ((k, v.shape) for k, v in shapes.items()))
     model = box["model"]
-    model.set_parameters(W.make_all(seed, dims, dtype))
+    model.set_parameters(W.make_all(seed, fam, dims, dtype))
     return model
 
 

@@ -21,7 +21,6 @@ import time
 import jax
 import numpy as np
 
-from ..reference.decoder_f32 import Dims
 from . import check, loadgen, program, runtime, stats
 
 TRACE_SECONDS = 3.0
@@ -110,10 +109,10 @@ def warm_up(mix, dims, serve, router, seed) -> None:
 
 def run(cell, seed: int, seconds: float, trace: bool, device: dict,
         t_start: float, control: bool = False, break_decoder=None) -> dict:
-    cfg, mix = cell.config, cell.traffic
-    dims = Dims.from_config(cfg)
+    cfg, mix, fam = cell.config, cell.traffic, cell.family
+    dims = fam.Dims.from_config(cfg)
     serve = cfg["serve"]
-    model = program.build_model(cfg, dims, seed, cfg["dtype"],
+    model = program.build_model(fam, cfg, dims, seed, cfg["dtype"],
                                 serve["capacity"], remat=False)
     dec, replica, router = program.build_serving(model, serve)
     if break_decoder is not None:
@@ -194,14 +193,14 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
     t_ref = time.perf_counter()
     if sample:
         lg, served, mask = check.serve_reference(
-            seed, dims, cfg["dtype"], sample, n_check, serve["capacity"],
-            max_out, "f32")
+            seed, fam, dims, cfg["dtype"], sample, n_check,
+            serve["capacity"], max_out, "f32")
         gap, same, n = check.serve_gap(lg, served, mask)
         numbers["served_gap_max"] = gap
         log(f"reference over {len(sample)} requests, {n} served tokens, "
             f"{same} equal to the reference's best, widest gap {gap:.4g} "
             f"sd, in {time.perf_counter() - t_ref:.1f} s")
-    ok = check.judge(numbers, check.load_limits(cell.name), "check")
+    ok = check.judge(numbers, check.load_limits(cell), "check")
     ok &= check.judge({"compiles_in_window": compiles.count},
                       {"compiles_in_window": 0})
     ok &= len(done) > 0
@@ -214,7 +213,7 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
                            if gaps else None),
             "setup_s": setup_s},
         "memory_peak_bytes": mem, "xplane": xplane,
-        "run": {"kind": "serve", "dims": dims, "config": cfg,
+        "run": {"kind": "serve", "family": fam, "dims": dims, "config": cfg,
                 "traffic": mix, "window_s": window, "ticks": d_ticks,
                 "tick_tokens": d_tok, "tick_capacity": d_cap,
                 "router_wait_s": waits, "ttft_s": ttft, "gaps_s": gaps,
@@ -224,8 +223,8 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
     }
     if control and sample:
         lc, _, _ = check.serve_reference(
-            seed, dims, cfg["dtype"], sample, n_check, serve["capacity"],
-            max_out, "fp8")
+            seed, fam, dims, cfg["dtype"], sample, n_check,
+            serve["capacity"], max_out, "fp8")
         ctl_tokens = np.asarray(jax.device_get(lc.argmax(-1)), np.int32)
         out["control_numbers"] = {
             "served_gap_max": check.serve_gap(lg, ctl_tokens, mask)[0]}

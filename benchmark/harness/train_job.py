@@ -19,7 +19,6 @@ import time
 import jax
 import numpy as np
 
-from ..reference.decoder_f32 import Dims
 from . import check, loadgen, program, runtime
 
 TRACE_SECONDS = 3.0
@@ -34,13 +33,13 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
     """Run the cell and return the job's result (see ``run.py``).
     ``control`` also computes the lower-precision control's readings;
     ``break_step`` (tests only) wraps the trainer's step call."""
-    cfg, mix = cell.config, cell.traffic
-    dims = Dims.from_config(cfg)
+    cfg, mix, fam = cell.config, cell.traffic, cell.family
+    dims = fam.Dims.from_config(cfg)
     rows, seq, lr = int(mix["rows"]), int(mix["seq"]), float(mix["lr"])
     k_check = int(mix["check_steps"])
     warm = max(int(mix["warmup_steps"]), k_check)
 
-    model = program.build_model(cfg, dims, seed, cfg["dtype"], seq,
+    model = program.build_model(fam, cfg, dims, seed, cfg["dtype"], seq,
                                 remat=bool(cfg["train"]["remat"]))
     trainer = program.build_trainer(model, lr, cfg["train"]["amp"])
     step_call = trainer.train_step
@@ -62,7 +61,8 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
                     {n: s["m"] for n, s in
                      zip(names, trainer.opt_state["leaf"])}).items()}
         if i == k_check - 1:
-            got["delta_norms"] = check.delta_norms(trainer.params, seed)
+            got["delta_norms"] = check.delta_norms(trainer.params, seed,
+                                                   fam.leaf_rule)
     jax.block_until_ready(loss)
     log(f"first losses {got['losses']}")
 
@@ -118,11 +118,11 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
     del trainer, model, step_call, feed, one_step, loss
     gc.collect()
     t_ref = time.perf_counter()
-    ref = check.train_reference(seed, dims, ring[:k_check], lr, "f32")
+    ref = check.train_reference(seed, fam, dims, ring[:k_check], lr, "f32")
     numbers = check.compare_train(got, ref)
     log(f"reference losses {ref['losses']} in "
         f"{time.perf_counter() - t_ref:.1f} s")
-    ok = check.judge(numbers, check.load_limits(cell.name), "check")
+    ok = check.judge(numbers, check.load_limits(cell), "check")
     ok &= check.judge({"compiles_in_window": compiles.count},
                       {"compiles_in_window": 0})
     ok &= bool(np.isfinite(last)) and steps > 0
@@ -132,13 +132,14 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
         "end_to_end": {"train_tokens_per_s": tokens / window,
                        "setup_s": setup_s},
         "memory_peak_bytes": mem, "xplane": xplane,
-        "run": {"kind": "train", "dims": dims, "config": cfg,
+        "run": {"kind": "train", "family": fam, "dims": dims, "config": cfg,
                 "traffic": mix, "window_s": window, "steps": steps,
                 "step_s": step_s, "dispatch_s": dispatch_s,
                 "tokens_per_s": tokens / window,
                 "memory_peak_bytes": mem, "device": device},
     }
     if control:
-        ctl = check.train_reference(seed, dims, ring[:k_check], lr, "fp8")
+        ctl = check.train_reference(seed, fam, dims, ring[:k_check], lr,
+                                    "fp8")
         out["control_numbers"] = check.compare_train(ctl, ref)
     return out

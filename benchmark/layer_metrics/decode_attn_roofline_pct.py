@@ -1,6 +1,6 @@
 """Kernels (`ops/pallas/flash_decode.py`): the least time the chip could
-take to read the live keys and values of a tick (bytes from
-`benchmark/harness/flops.py`, bf16 KV; memory-bound) over the time of
+take to read the live keys and values of a tick (bytes from the run's
+family, `benchmark/families/`, bf16 KV; memory-bound) over the time of
 the decode kernel in the device trace. On a v5e trace the kernel is the
 `XLA Ops` event `%closed_call.N`, a `custom-call` to `tpu_custom_call`,
 once per layer per tick; serving's prefill programs hold no Pallas
@@ -23,8 +23,8 @@ def read(run):
         return None
     live = run["tick_tokens"] / run["ticks"] * ctx
     s, bound = flops.roofline_seconds(
-        flops.decode_attention_flops(run["dims"], live),
-        flops.decode_attention_bytes(run["dims"], live, 2),
+        run["family"].decode_attention_flops(run["dims"], live),
+        run["family"].decode_attention_bytes(run["dims"], live, 2),
         run["device"]["peaks"])
     spent = sum(e[2] for e in kernels) / 1e9 / len(kernels)
     print(f"[decode_attn_roofline_pct] {bound}-bound; {len(kernels)} "

@@ -1,9 +1,9 @@
 """Kernels (`ops/pallas/flash_attention.py`, forward + backward): the
 least time the chip could take for the causal attention a training step
-needs (operations and bytes from `benchmark/harness/flops.py`: lower
-triangle, forward once, backward's four matmuls, q/k/v/o moved once, at
-the bf16 the configuration states) over the time of the step's Pallas
-kernels in the device trace. On a v5e trace those are the `XLA Ops`
+needs (operations and bytes from the run's family, `benchmark/families/`:
+lower triangle, forward once, backward's four matmuls, q/k/v/o moved
+once, at the bf16 the configuration states) over the time of the step's
+Pallas kernels in the device trace. On a v5e trace those are the `XLA Ops`
 events whose instruction is a `custom-call` to `tpu_custom_call`
 (`%jvp__.N` forward, `%rematted_computation.N` remat's forward again,
 `%checkpoint.N` dq and dk/dv); the train step has no other. Remat's
@@ -22,12 +22,12 @@ def read(run):
     steps = trace_reduce.whole_runs(t["modules"])
     if not kernels or not steps:
         return None
-    dims, seq = run["dims"], run["traffic"]["seq"]
+    fam, dims, seq = run["family"], run["dims"], run["traffic"]["seq"]
     need, bound = 0.0, None
     for backward in (False, True):
         s, bound = flops.roofline_seconds(
-            flops.attention_flops(dims, seq, backward),
-            flops.attention_bytes(dims, seq, 2, backward),
+            fam.attention_flops(dims, seq, backward),
+            fam.attention_bytes(dims, seq, 2, backward),
             run["device"]["peaks"])
         need += s
     need *= dims.layers * run["traffic"]["rows"] * steps

@@ -161,16 +161,6 @@ def loss(w, batch, dims: Dims, mode: str = "f32", remat: bool = False):
     return total / (batch.shape[0] * (batch.shape[1] - 1))
 
 
-def adam(w, g, m, v, step: int, lr: float, b1: float = 0.9,
-         b2: float = 0.999, eps: float = 1e-8):
-    """One Adam update (Kingma & Ba, bias-corrected); ``step`` from 1."""
-    m = b1 * m + (1 - b1) * g
-    v = b2 * v + (1 - b2) * jnp.square(g)
-    mhat = m / (1 - b1 ** step)
-    vhat = v / (1 - b2 ** step)
-    return w - lr * mhat / (jnp.sqrt(vhat) + eps), m, v
-
-
 def layerwise_logits(tokens, positions, dims: Dims, mode: str,
                      get: Callable[[Dict[str, tuple]], Dict[str, jax.Array]],
                      shapes_of_layer: Callable[[int], Dict[str, tuple]],

@@ -1,13 +1,15 @@
 #!/usr/bin/env python3
 """Rehearsal 3: compile each cell's programs at the real sizes for a
 described TPU v5e (``v5e:2x2`` topology, one described chip), with no
-chip attached. Prints ``memory_analysis()`` and the ``tpu_custom_call``
-count of each. A compile that passes is not a chip run: nothing here is
-a time or a result.
+chip attached. Prints ``memory_analysis()``, the ``tpu_custom_call``
+count and a hash of the lowered program's text of each (equal hashes on
+two commits: the same program goes to the compiler). A compile that
+passes is not a chip run: nothing here is a time or a result.
 
     JAX_PLATFORMS=cpu python3 benchmark/rehearse_compile.py [cell ...]
 """
 
+import hashlib
 import importlib
 import os
 import sys
@@ -32,7 +34,9 @@ def report(name, lowered):
           f"{gb(m.output_size_in_bytes)}, aliased "
           f"{gb(m.alias_size_in_bytes)}, temporaries "
           f"{gb(m.temp_size_in_bytes)}; "
-          f"{compiled.as_text().count('tpu_custom_call')} tpu_custom_call",
+          f"{compiled.as_text().count('tpu_custom_call')} tpu_custom_call; "
+          f"lowered text sha256 "
+          f"{hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]}",
           flush=True)
 
 
@@ -40,7 +44,6 @@ def main(argv):
     from jax.experimental import topologies
 
     from benchmark.harness import loadgen, manifest, program
-    from benchmark.reference.decoder_f32 import Dims
     import paddle_tpu.ops.attention as attn
     from paddle_tpu.core.mesh import mesh_scope
 
@@ -60,12 +63,12 @@ def main(argv):
     names = argv or [w["name"] for w in man["workloads"]]
     for name in names:
         cell = manifest.Cell(man, name)
-        cfg, mix = cell.config, cell.traffic
-        dims = Dims.from_config(cfg)
+        cfg, mix, fam = cell.config, cell.traffic, cell.family
+        dims = fam.Dims.from_config(cfg)
         with attn.force_flash():
             if cell.kind == "train":
-                model = _abstract_model(program, cfg, dims, mix["seq"],
-                                        cfg["train"]["remat"])
+                model = _abstract_model(program, fam, cfg, dims,
+                                        mix["seq"], cfg["train"]["remat"])
                 import paddle_tpu as pt
                 from paddle_tpu import optimizer, parallel
 
@@ -95,7 +98,7 @@ def main(argv):
                 from paddle_tpu.serving import BatchedDecoder
 
                 serve = cfg["serve"]
-                model = _abstract_model(program, cfg, dims,
+                model = _abstract_model(program, fam, cfg, dims,
                                         serve["capacity"], False).eval()
                 dec = BatchedDecoder(
                     model, slots=serve["slots"],
@@ -119,7 +122,7 @@ def main(argv):
     return 0
 
 
-def _abstract_model(program, cfg, dims, max_position, remat):
+def _abstract_model(program, fam, cfg, dims, max_position, remat):
     """The model object with no weights behind it, and the shapes of
     its parameters on ``.shapes``."""
     import paddle_tpu.nn.layer as L
@@ -128,14 +131,14 @@ def _abstract_model(program, cfg, dims, max_position, remat):
 
     keep = L.Layer.set_parameters, W.make_all
     L.Layer.set_parameters = lambda self, flat: None
-    W.make_all = lambda seed, d, dtype: {}
+    W.make_all = lambda seed, fam, d, dtype: {}
     try:
-        model = program.build_model(cfg, dims, 0, cfg["dtype"],
+        model = program.build_model(fam, cfg, dims, 0, cfg["dtype"],
                                     max_position, remat)
     finally:
         L.Layer.set_parameters, W.make_all = keep
     model.shapes = {k: jax.ShapeDtypeStruct(s, jnp.dtype(cfg["dtype"]))
-                    for k, s in W.leaf_shapes(dims).items()}
+                    for k, s in W.leaf_shapes(fam, dims).items()}
     return model
 
 

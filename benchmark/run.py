@@ -43,7 +43,8 @@ def result_line(cell, job: dict, device: dict, trace: bool) -> dict:
             reduced = trace_reduce.reduce(
                 trace_reduce.read_xplane(job["xplane"]), cell.chips)
         job["run"]["trace"] = reduced
-        values = manifest.read_layer_metrics(cell.per_layer, job["run"])
+        values = manifest.read_layer_metrics(cell.per_layer, job["run"],
+                                             cell.root)
         if reduced is not None:
             dev["busy_s"] = reduced["busy_s"]
             dev["window_s"] = reduced["span_s"]

@@ -12,20 +12,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.harness import flops, program, weights as W
+from benchmark.families import decoder as F
+from benchmark.harness import program, weights as W
 from benchmark.reference import decoder_f32 as R
 from benchmark.tests import tiny
 
 TOL = 2e-4
+flops = F       # the shape formulas live with the family
 
 
 @pytest.fixture(scope="module")
 def setup():
     cell = tiny.cell(tiny.TRAIN)
     dims = R.Dims.from_config(cell.config)
-    model = program.build_model(cell.config, dims, 11, "float32", 64,
+    model = program.build_model(F, cell.config, dims, 11, "float32", 64,
                                 remat=False)
-    w = W.make_all(11, dims, jnp.float32)
+    w = W.make_all(11, F, dims, jnp.float32)
     ids = np.random.default_rng(0).integers(0, dims.vocab, (2, 48))
     return model, w, dims, jnp.asarray(ids, jnp.int32)
 
@@ -37,14 +39,17 @@ def rel(a, b):
 
 def test_weights_are_a_function_of_seed_name_and_index(setup):
     _, w, dims, _ = setup
-    again = W.make_leaves(11, W.layer_shapes(dims, 1), jnp.float32)
+    again = W.make_leaves(11, F.layer_shapes(dims, 1), jnp.float32,
+                          F.leaf_rule)
     for k, v in again.items():
         np.testing.assert_array_equal(np.asarray(v), np.asarray(w[k]))
-    other = W.make_leaves(12, W.layer_shapes(dims, 1), jnp.float32)
+    other = W.make_leaves(12, F.layer_shapes(dims, 1), jnp.float32,
+                          F.leaf_rule)
     k = "blocks.1.ffn.up.weight"
     assert not np.array_equal(np.asarray(other[k]), np.asarray(w[k]))
     assert abs(float(jnp.std(w[k])) - W.INIT_STD) < 0.002
-    big = W.make_leaves(2**31 + 12345, {k: (8, 8)}, jnp.bfloat16)
+    big = W.make_leaves(2**31 + 12345, {k: (8, 8)}, jnp.bfloat16,
+                        F.leaf_rule)
     assert big[k].dtype == jnp.bfloat16
 
 
@@ -58,12 +63,12 @@ def test_forward_logits(setup):
 def test_layerwise_logits_match_whole_forward(setup):
     _, w, dims, ids = setup
     pos = jnp.asarray([[5, 9, 47], [0, 1, 2]], jnp.int32)
-    top = {k: s for k, s in W.leaf_shapes(dims).items()
-           if not k.startswith("blocks.")}
     lw = R.layerwise_logits(
         ids, pos, dims, "f32",
-        get=lambda shapes: W.make_leaves(11, shapes, jnp.float32),
-        shapes_of_layer=lambda i: W.layer_shapes(dims, i), top_shapes=top)
+        get=lambda shapes: W.make_leaves(11, shapes, jnp.float32,
+                                         F.leaf_rule),
+        shapes_of_layer=lambda i: F.layer_shapes(dims, i),
+        top_shapes=F.top_shapes(dims))
     ref = jnp.stack([R.logits(r, w, dims)[p] for r, p in zip(ids, pos)])
     assert rel(lw, ref) < TOL
 

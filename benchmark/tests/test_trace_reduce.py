@@ -8,7 +8,6 @@ import os
 import pytest
 
 from benchmark.harness import manifest, trace_reduce as T
-from benchmark.reference.decoder_f32 import Dims
 from benchmark.tests import tiny
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
@@ -99,7 +98,8 @@ def test_recorded_train_trace():
     cell = tiny.cell(tiny.TRAIN)
     full = manifest.Cell(manifest.load_manifest(), tiny.TRAIN)
     run = {"kind": "train", "trace": r, "traffic": full.traffic,
-           "dims": Dims.from_config(full.config),
+           "family": full.family,
+           "dims": full.family.Dims.from_config(full.config),
            "device": {"peaks": {"bf16_flops_per_s": 197e12,
                                 "hbm_bytes_per_s": 819e9}}}
     share = manifest.load_reader("flash_roofline_pct")(run)
@@ -120,8 +120,8 @@ def test_recorded_serve_trace():
         m[0].startswith("jit_prefill(") for m in r["modules"])
     full = manifest.Cell(manifest.load_manifest(), tiny.CHAT)
     run = {"kind": "serve", "trace": r, "ticks": 100, "tick_tokens": 1400,
-           "mean_context_tokens": 450.0,
-           "dims": Dims.from_config(full.config),
+           "mean_context_tokens": 450.0, "family": full.family,
+           "dims": full.family.Dims.from_config(full.config),
            "device": {"peaks": {"bf16_flops_per_s": 197e12,
                                 "hbm_bytes_per_s": 819e9}}}
     share = manifest.load_reader("decode_attn_roofline_pct")(run)
