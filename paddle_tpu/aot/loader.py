@@ -100,6 +100,10 @@ class ModelStub:
             attns = [_StubAttn(None, None, spec[i]) for i in range(n)]
         self.blocks = [_StubBlock(a) for a in attns]
 
+    def init_cache(self, batch: int, capacity: int, dtype=None):
+        return [blk.self_attn.init_cache(batch, capacity, dtype)
+                for blk in self.blocks]
+
     def named_parameters(self) -> Dict[str, Any]:
         return dict(self._params)
 

@@ -175,6 +175,13 @@ class GPTForCausalLM(Layer):
             h.reshape(b * t, d), w, None, labels.reshape(-1),
             chunk=vocab_chunk, ignore_index=ignore_index)
 
+    def init_cache(self, batch: int, capacity: int, dtype=None):
+        """The decode cache ``serving.BatchedDecoder`` holds as its
+        arena: one (K, V) pair a block, (batch, capacity, kv_heads,
+        head_dim) each."""
+        return [blk.self_attn.init_cache(batch, capacity, dtype)
+                for blk in self.blocks]
+
     def _cached_blocks(self, x, caches, attn_step, head: bool = True):
         """ONE definition of the cached-decode block composition
         (norm1 -> attn -> residual -> ffn -> norm_f@head) shared by the
@@ -194,11 +201,14 @@ class GPTForCausalLM(Layer):
         return self.norm_f(x) @ self._head_weight(), new_caches
 
     def _chunk_logits(self, toks, caches, t0, head: bool = True,
-                      decode_kernel: bool = False):
+                      decode_kernel: bool = False, valid_len=None):
         """S KV-cached positions in one pass: embed ``toks`` (B, S), run
         every block's forward_chunk at cache indices [t0, t0+S), return
         ((B, S, V) logits, new caches). The speculative-decoding target
-        scores its gamma+1 candidates with one call."""
+        scores its gamma+1 candidates with one call. ``valid_len`` (how
+        many of the S tokens are the sequence, the rest padding) is
+        taken and not read: keys and values written past it sit above
+        the cursor, which masks them."""
         return self._cached_blocks(
             self.embed(toks), caches,
             lambda sa, h, ck, cv: sa.forward_chunk(

@@ -9,7 +9,7 @@ from .layers import (GELU, RNN, BatchNorm, BilinearTensorProduct, Conv2D,
                      SpectralNorm, Tanh)
 from .lora import (LoRALinear, apply_lora, lora_parameters,
                    merge_lora)
-from .moe import SwitchFFN
+from .moe import DroplessMoE, SwitchFFN
 from .rnn_layers import GRU, LSTM
 from .sampling_layers import NCE, HSigmoid
 from .transformer import (FeedForward, LearnedPositionalEmbedding,
@@ -24,7 +24,7 @@ __all__ = [
     "GRUCell", "LayerNorm", "Linear", "LSTMCell", "MultiHeadAttention",
     "Pool2D", "PRelu", "ReLU", "RMSNorm", "Sigmoid", "Softmax",
     "SpectralNorm", "Tanh",
-    "GRU", "LSTM", "NCE", "HSigmoid", "SwitchFFN",
+    "GRU", "LSTM", "NCE", "HSigmoid", "SwitchFFN", "DroplessMoE",
     "LoRALinear", "apply_lora", "lora_parameters", "merge_lora",
     "FeedForward", "LearnedPositionalEmbedding", "PositionalEncoding",
     "TransformerDecoder", "TransformerDecoderLayer", "TransformerEncoder",

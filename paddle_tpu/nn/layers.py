@@ -456,10 +456,12 @@ class _MHADecodeMixin:
                                  theta=self.rotary_theta)
         if (decode_t is not None and tq == 1 and self.use_flash
                 and decode_flash_ok(k.shape[1], self.head_dim)):
-            out = _get_flash_decode()(q, k, v, decode_t, window=window)
+            out = _get_flash_decode()(q, k, v, decode_t, window=window,
+                                      scale=self.scale)
         else:
             out = scaled_dot_product_attention(
-                q, k, v, mask=attn_mask, use_flash=self.use_flash)
+                q, k, v, mask=attn_mask, use_flash=self.use_flash,
+                scale=self.scale)
         return self.out_proj(out.reshape(b, tq, d))
 
     def forward_chunk(self, x_chunk, cache_k, cache_v, t0, window=None,
@@ -524,6 +526,8 @@ class _MHADecodeMixin:
         ``x_t``: (B, 1, D); returns (out, kpool, vpool)."""
         from ..ops import paged_kv
 
+        enforce(self.scale is None, "the paged decode step has no "
+                "score scale argument (scale=%s)", self.scale)
         pos_rows = t_rows.astype(jnp.int32)[:, None]          # (B, 1)
         k_t, v_t = self._project_kv_t(x_t, pos_rows)
         kpool, vpool = paged_kv.write_rows(
@@ -562,7 +566,7 @@ class _MHADecodeMixin:
             keep &= pos[None, :] > pos_chunk[:, None] - window
         out = scaled_dot_product_attention(
             self._rotated_q(x_chunk, pos_chunk), k, v,
-            mask=keep[None, None], use_flash=False)
+            mask=keep[None, None], use_flash=False, scale=self.scale)
         return (self.out_proj(out.reshape(b, s, d)), kpool, vpool)
 
     def _rotated_q(self, query, positions):
@@ -680,7 +684,7 @@ class _MHADecodeMixin:
             keep &= pos[None, None, :] > pos_chunk[:, :, None] - window
         out = scaled_dot_product_attention(
             self._rotated_q(x_chunk, pos_chunk), k, v,
-            mask=keep[:, None], use_flash=False)
+            mask=keep[:, None], use_flash=False, scale=self.scale)
         return (self.out_proj(out.reshape(b, s, d)), kpool, vpool)
 
 
@@ -693,7 +697,8 @@ class MultiHeadAttention(_MHADecodeMixin, Layer):
                  bias: bool = True, use_flash: bool = True,
                  seq_parallel: Optional[str] = None, dtype=None,
                  num_kv_heads: Optional[int] = None,
-                 rotary: bool = False, rotary_theta: float = 10000.0):
+                 rotary: bool = False, rotary_theta: float = 10000.0,
+                 scale: Optional[float] = None):
         super().__init__()
         enforce(embed_dim % num_heads == 0,
                 "embed_dim %s not divisible by heads %s", embed_dim, num_heads)
@@ -702,6 +707,11 @@ class MultiHeadAttention(_MHADecodeMixin, Layer):
         # Ulysses see position-correct rotations
         self.rotary = rotary
         self.rotary_theta = float(rotary_theta)
+        # what the scores are multiplied by; None = 1/sqrt(head_dim)
+        self.scale = None if scale is None else float(scale)
+        enforce(scale is None or seq_parallel is None,
+                "scale= is not carried through seq_parallel=%s",
+                seq_parallel)
         self.num_heads = num_heads
         self.head_dim = embed_dim // num_heads
         # GQA/MQA: fewer K/V heads than Q heads (the flash kernel reads
@@ -782,7 +792,7 @@ class MultiHeadAttention(_MHADecodeMixin, Layer):
                 dropout_p=self.dropout_p if self.training else 0.0,
                 dropout_key=self.rng("attn_dropout") if (self.training and self.dropout_p > 0) else None,
                 use_flash=self.use_flash, segment_ids=segment_ids,
-                window=window)
+                window=window, scale=self.scale)
         out = out.reshape(b, tq, d)
         return self.out_proj(out)
 

@@ -33,8 +33,9 @@ import jax.numpy as jnp
 from ..core.enforce import enforce
 from .. import initializer as I
 from .layer import Layer
+from .layers import Linear
 
-__all__ = ["SwitchFFN", "switch_moe"]
+__all__ = ["DroplessMoE", "SwitchFFN", "dropless_moe", "switch_moe"]
 
 
 def switch_moe(x, router_w, w1, b1, w2, b2, *, capacity: int,
@@ -164,6 +165,113 @@ class SwitchFFN(Layer):
         self.update_buffer("router_z_loss", z_loss)
         self.update_buffer("kept_fraction", kept)
         return y.reshape(b, t, d)
+
+
+def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
+                 experts_held=None):
+    """Dropless top-k gated experts over tokens, for the experts held
+    here.
+
+    x: (S, D) tokens; router_w: (D, E), the router over ALL ``E``
+    experts; w_gate, w_up: (held, D, F); w_down: (held, F, D), the
+    weights of experts ``first .. first + held - 1`` where
+    ``experts_held = (first, held)`` (default: all of them). Routing
+    rule ``"topk_softmax"``: the ``top_k`` largest router logits a token
+    (float32; ties broken as ``lax.top_k`` breaks them, lowest index
+    first), gates = softmax over those ``top_k`` logits. No capacity:
+    every (token, pick) pair that falls on a held expert is computed.
+    The pairs are sorted by expert and the gated product
+    ``(silu(x Wg) * (x Wu)) Wd`` runs as grouped matmuls
+    (``lax.ragged_dot``) over the held experts; a pair on an expert
+    that is not held adds nothing (its part of the result belongs to
+    the chip that holds it; nothing stands in for that chip here).
+
+    Returns (y (S, D) in x's dtype, tokens (held,) int32: the pairs
+    each held expert got)."""
+    s = x.shape[0]
+    e = router_w.shape[1]
+    first, held = (0, e) if experts_held is None else experts_held
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+        top_l, top_i = jax.lax.top_k(logits, top_k)        # (S, k)
+        gates = jax.nn.softmax(top_l, axis=-1)
+        local = top_i - first
+        here = (local >= 0) & (local < held)
+        # pairs on absent experts sort last, into a group no weight has
+        group = jnp.where(here, local, held).reshape(-1)   # (S k,)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+            jnp.int32)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(s * top_k, dtype=order.dtype))
+    with jax.named_scope("moe_experts"):
+        # the grouped product runs in the WEIGHTS' type with float32
+        # sums: the tokens are cast to it, never the experts (a float32
+        # copy of bfloat16 experts would be written out whole a call)
+        f32 = jnp.float32
+        xs = x[order // top_k].astype(w_gate.dtype)        # (S k, D)
+        h = (jax.nn.silu(jax.lax.ragged_dot(
+            xs, w_gate, sizes, preferred_element_type=f32))
+            * jax.lax.ragged_dot(xs, w_up, sizes,
+                                 preferred_element_type=f32))
+        out = jax.lax.ragged_dot(h.astype(w_down.dtype), w_down, sizes,
+                                 preferred_element_type=f32)
+        # rows past the last group belong to no held expert
+        out = jnp.where((jnp.arange(s * top_k) < jnp.sum(sizes))[:, None],
+                        out, 0)
+        picked = out[back].reshape(s, top_k, -1)
+        y = jnp.einsum("skd,sk->sd", picked, jnp.where(here, gates, 0.0))
+    return y.astype(x.dtype), sizes
+
+
+class DroplessMoE(Layer):
+    """Top-k routed gated experts with no capacity and no dropped token,
+    told which experts it holds (``experts_held = (first, count)`` of
+    ``num_experts``): the layer of an expert-parallel deployment as one
+    chip runs it. It routes over all ``num_experts``, computes the part
+    of the result its own experts give (:func:`dropless_moe`), and
+    leaves the rest to the chips that hold the others: summed over a
+    partition of the experts the parts are the whole layer
+    (``tests/test_hybrid.py``). A shared (always-on) MLP is the caller's.
+
+    ``forward(x (..., D)) -> (..., D)``; ``forward_counted`` also
+    returns the (count,) int32 pairs each held expert got."""
+
+    ROUTING = ("topk_softmax",)
+
+    def __init__(self, d_model: int, d_ff: int, num_experts: int,
+                 top_k: int, experts_held=None,
+                 routing: str = "topk_softmax", dtype=None):
+        super().__init__()
+        first, count = experts_held or (0, num_experts)
+        enforce(routing in self.ROUTING,
+                "routing rule %r is not one of %s", routing, self.ROUTING)
+        enforce(1 <= top_k <= num_experts,
+                "top_k %s must lie in 1..num_experts %s", top_k,
+                num_experts)
+        enforce(0 <= first and count >= 1
+                and first + count <= num_experts,
+                "experts_held %s is not a range of the %s experts",
+                (first, count), num_experts)
+        self.num_experts, self.top_k = num_experts, top_k
+        self.experts_held = (int(first), int(count))
+        self.router = Linear(d_model, num_experts, bias_attr=False,
+                             dtype=dtype)
+        init = I.XavierUniform()
+        self.create_parameter("w_gate", (count, d_model, d_ff), dtype, init)
+        self.create_parameter("w_up", (count, d_model, d_ff), dtype, init)
+        self.create_parameter("w_down", (count, d_ff, d_model), dtype, init)
+
+    def forward_counted(self, x):
+        lead = x.shape[:-1]
+        y, tokens = dropless_moe(
+            x.reshape(-1, x.shape[-1]), self.router.weight, self.w_gate,
+            self.w_up, self.w_down, top_k=self.top_k,
+            experts_held=self.experts_held)
+        return y.reshape(*lead, -1), tokens
+
+    def forward(self, x):
+        return self.forward_counted(x)[0]
 
 
 def expert_param_spec(axis: str = "ep"):

@@ -51,8 +51,8 @@ from jax import lax
 from . import telemetry
 from .core.enforce import enforce
 
-__all__ = ["BatchedDecoder", "PagedKVPool", "Request", "KVHandoff",
-           "TokenStream", "reject_cause"]
+__all__ = ["ArenaCounters", "BatchedDecoder", "PagedKVPool", "Request",
+           "KVHandoff", "TokenStream", "reject_cause"]
 from .nn.layer import inject_state
 from .resilience import reliability as _reliability
 from .ops import paged_kv as paged_ops
@@ -261,23 +261,50 @@ class PagedKVPool:
 
 
 def _row_apply(caches, s, fn):
-    """Slice slot ``s`` of each layer's (slots, ...) K/V cache pair as
-    a batch-1 row, run ``fn(row) -> (result, new_row)``, write the row
-    back (dtype-cast) — the ONE definition of the per-slot
-    slice/run/write-back boilerplate every contiguous prefill piece
-    (full, chunk, restep, draft) shares. jit-safe: callers close over
-    it inside their traced functions."""
-    row = [(lax.dynamic_slice_in_dim(ck, s, 1, axis=0),
-            lax.dynamic_slice_in_dim(cv, s, 1, axis=0))
-           for ck, cv in caches]
+    """Slice slot ``s`` of every leaf of the arena (one pytree a block,
+    each leaf (slots, ...): an attention block's K/V pair, a state-space
+    block's convolution tail and state) as a batch-1 row, run
+    ``fn(row) -> (result, new_row)``, write the row back (dtype-cast) —
+    the ONE definition of the per-slot slice/run/write-back boilerplate
+    every contiguous prefill piece (full, chunk, restep, draft) shares.
+    jit-safe: callers close over it inside their traced functions."""
+    row = jax.tree_util.tree_map(
+        lambda c: lax.dynamic_slice_in_dim(c, s, 1, axis=0), caches)
     out, row = fn(row)
-    new = []
-    for (ck, cv), (rk, rv) in zip(caches, row):
-        new.append((lax.dynamic_update_slice_in_dim(
-            ck, rk.astype(ck.dtype), s, axis=0),
-            lax.dynamic_update_slice_in_dim(
-                cv, rv.astype(cv.dtype), s, axis=0)))
-    return out, new
+    return out, jax.tree_util.tree_map(
+        lambda c, r: lax.dynamic_update_slice_in_dim(
+            c, r.astype(c.dtype), s, axis=0), caches, row)
+
+
+class ArenaCounters:
+    """Plain counters of one arena, kept apart from it so that they
+    outlive it (``last_counters`` is the newest arena's: a benchmark
+    frees the decoder before it reads). ``state_bytes``: device bytes of
+    the arena by kind of state, ``kv`` (addressed by position) and
+    ``recurrent`` (a fixed size a slot). ``sums``: the running sum of
+    whatever the model's decode step counts (``step_counters``), over
+    ``steps`` steps; ``expert_tokens`` is the one a model with routed
+    experts gives, the (held,) (token, pick) pairs each held expert
+    got, every row of the step counted (an idle slot's junk row too),
+    and None for any other model."""
+
+    def __init__(self, state_bytes: Dict[str, int]):
+        self.state_bytes = state_bytes
+        self.sums: Dict[str, np.ndarray] = {}
+        self.steps = 0
+
+    def add(self, counted: Dict[str, Any]) -> None:
+        for name, value in counted.items():
+            value = np.asarray(value, np.int64)
+            self.sums[name] = self.sums.get(name, 0) + value
+        self.steps += 1
+
+    @property
+    def expert_tokens(self) -> Optional[np.ndarray]:
+        return self.sums.get("expert_tokens")
+
+
+last_counters: Optional[ArenaCounters] = None
 
 
 def reject_cause(cause: str) -> None:
@@ -618,9 +645,16 @@ class Request:
 
 
 class BatchedDecoder:
-    """Slot-based continuous batching over a causal LM (GPT-family:
-    anything exposing ``_step_logits``/``_chunk_logits`` and
-    ``blocks[*].self_attn.init_cache``).
+    """Slot-based continuous batching over a causal LM: anything
+    exposing ``_step_logits``/``_chunk_logits``/``_step_logits_rows``
+    and ``init_cache(slots, capacity)``, the arena as a list with one
+    pytree a block whose leaves all lead with the slot axis. A model
+    may declare ``cache_kinds`` (a block: ``"kv"``, addressed by
+    position, or ``"recurrent"``, a state of fixed size). With
+    recurrent state a prefill starts the slot's state from zeros and
+    advances it over exactly the prompt, and every mode that addresses
+    the cache by position (pages, prefix reuse, handoff, speculative
+    verify, chunked prefill) is refused.
 
     ``submit()`` enqueues; ``run()`` drives to completion and returns
     {request_id: np.ndarray of generated ids (prompt excluded)}.
@@ -644,6 +678,23 @@ class BatchedDecoder:
                 "capacity %s < prompt bucket %s", capacity,
                 prompt_bucket)
         self.model = model
+        kinds = getattr(model, "cache_kinds", None)
+        self._recurrent = bool(kinds) and "recurrent" in kinds
+        if self._recurrent:
+            # a recurrent state is one value a slot, not a value a
+            # position: nothing below can page it, share a prefix of
+            # it, hand it over as pages, roll it back after a rejected
+            # draft, or resume it mid-prompt without a snapshot
+            for what, on in (("pages=", pages is not None),
+                             ("prefix_cache", prefix_cache),
+                             ("kv_dtype", kv_dtype is not None),
+                             ("draft= (speculative verify)",
+                              draft is not None),
+                             ("prefill_chunk", prefill_chunk is not None)):
+                enforce(not on, "%s is refused for a model with "
+                        "recurrent state: the state is not addressable "
+                        "by position, and snapshots of it are not "
+                        "kept", what)
         # CHUNKED PREFILL (opt-in): admission only ALLOCATES; the
         # prompt then prefills prefill_chunk tokens per serving-loop
         # tick (one chunk per tick across all admitting slots), so
@@ -715,6 +766,9 @@ class BatchedDecoder:
                     "temperature > 0 samples and needs a PRNG key")
         self.key = key if key is not None else jax.random.key(0)
         self.bucket = prompt_bucket
+        # what one tick may prefill whole, in padded prompt tokens: two
+        # buckets, or one prompt however long (``_admit``)
+        self.prefill_budget = 2 * prompt_bucket
         # PAGED mode (pages=N): K/V live in per-block SHARED page pools
         # + one page table — memory scales with live tokens (pages
         # actually allocated), not slots x capacity; admission
@@ -764,11 +818,16 @@ class BatchedDecoder:
             enforce(kv_dtype is None,
                     "kv_dtype requires paged mode (pages=N) — the "
                     "contiguous arena has no quantized form")
-            self.caches = [blk.self_attn.init_cache(slots, capacity)
-                           for blk in model.blocks]
+            self.caches = model.init_cache(slots, capacity)
         if draft is not None:
-            self.caches_d = [blk.self_attn.init_cache(slots, capacity)
-                             for blk in draft.blocks]
+            self.caches_d = draft.init_cache(slots, capacity)
+        self._kinds = (list(kinds) if kinds and not self.paged
+                       else ["kv"] * len(model.blocks))
+        self._counted = (hasattr(model, "step_counters")
+                         and not self.paged)
+        global last_counters
+        self.counters = last_counters = ArenaCounters(
+            self._state_bytes())
         self.tok = jnp.zeros((slots,), jnp.int32)      # last token/slot
         # cursors: paged mode parks EVERY not-yet-admitted slot past
         # capacity — an idle slot's table row is zeros, and a cursor of
@@ -1124,12 +1183,14 @@ class BatchedDecoder:
         past capacity so the junk writes DROP (write_rows' OOB
         semantics); contiguous junk lands at positions a later prefill
         fully overwrites and no attention ever reads (nothing is
-        active, and prefill rewrites [0, bucket) wholesale)."""
+        active, and prefill rewrites [0, bucket) wholesale); a
+        recurrent state advanced by junk is zeroed by the slot's next
+        prefill."""
         step_fn, args = self._step_call()
         if self.paged:
             self.pools, toks = step_fn(*args)
         else:
-            self.caches, toks = step_fn(*args)
+            self.caches, toks, *_ = step_fn(*args)
         jax.block_until_ready(toks)
         if self.draft is not None and not self.degraded:
             # spec arenas serve through the spec round: warm that
@@ -1171,6 +1232,9 @@ class BatchedDecoder:
         and freed again, so a prefill worker's pool only ever holds
         in-flight prompts. Requires paged mode (the page payload IS the
         wire format; contiguous arenas chunk-prefill locally instead)."""
+        enforce(not self._recurrent, "prefill_export is refused for a "
+                "model with recurrent state: a KVHandoff carries pages "
+                "of keys and values, and the state is neither")
         enforce(self.paged, "prefill_export requires paged mode "
                 "(pages=N) — the handoff payload is KV pages")
         # deadline check BEFORE the prefill compute: an expired request
@@ -1234,6 +1298,9 @@ class BatchedDecoder:
         this replica's prefill, so whole-prompt admission can't stall a
         decode tick. Queues like :meth:`submit` (paged backpressure
         applies); returns the request id."""
+        enforce(not self._recurrent, "inject_prefilled is refused for a "
+                "model with recurrent state: a KVHandoff carries pages "
+                "of keys and values, and the state is neither")
         enforce(self.paged, "inject_prefilled requires paged mode "
                 "(pages=N) on the decode replica")
         enforce(isinstance(handoff, KVHandoff),
@@ -1310,6 +1377,24 @@ class BatchedDecoder:
 
     # ----- internals -------------------------------------------------------
 
+    def _state_bytes(self) -> Dict[str, int]:
+        """Device bytes of the arena by kind of state."""
+        out = {"kv": 0, "recurrent": 0}
+        arena = self.pools if self.paged else self.caches
+        for kind, block in zip(self._kinds, arena):
+            out[kind] += sum(int(leaf.nbytes) for leaf in
+                             jax.tree_util.tree_leaves(block))
+        return out
+
+    def _fresh_row(self, row):
+        """The sliced row with every recurrent block's state zeroed:
+        whatever the slot's last request (or an idle slot's junk steps)
+        left there, a new sequence starts from nothing. Keys and values
+        stay: the cursor masks them."""
+        return [jax.tree_util.tree_map(jnp.zeros_like, r)
+                if kind == "recurrent" else r
+                for kind, r in zip(self._kinds, row)]
+
     def _bucket_len(self, n: int) -> int:
         b = self.bucket
         # clamp to capacity: bucket rounding past the arena would hand
@@ -1328,16 +1413,22 @@ class BatchedDecoder:
         model = self.model
 
         def prefill(mstate, caches, padded, plen, s):
-            # chunk-run the FULL bucket (static shape) CACHE-ONLY —
-            # positions >= plen write garbage above the cursor, masked
-            # + overwritten later. The (lb, vocab) head projection
-            # would be the dominant prefill FLOP and all but one row
-            # is discarded, so the next-token logits come from a
-            # one-position re-step of the LAST prompt token instead
-            # (idempotent K/V rewrite at plen-1, single-row head).
+            # chunk-run the FULL bucket (static shape) CACHE-ONLY, of
+            # which the first plen - 1 positions are the sequence so
+            # far (``valid_len``): keys and values are written for the
+            # whole bucket (positions >= plen land above the cursor,
+            # masked + overwritten later), a recurrence advances over
+            # those plen - 1 tokens and no further, from zeros. Then
+            # the LAST prompt token is stepped once, at plen - 1: the
+            # (lb, vocab) head projection would be the dominant prefill
+            # FLOP and all but one row is discarded, so the next-token
+            # logits come from that one-position step (for keys and
+            # values a rewrite of what the chunk wrote there; for a
+            # recurrence the one time that token is applied).
             def body(row):
-                _, row = model._chunk_logits(padded[None], row, 0,
-                                             head=False)
+                _, row = model._chunk_logits(
+                    padded[None], self._fresh_row(row), 0, head=False,
+                    valid_len=plen - 1)
                 last = lax.dynamic_index_in_dim(padded, plen - 1,
                                                 keepdims=False)
                 return model._step_logits(last[None], row, plen - 1)
@@ -1619,12 +1710,14 @@ class BatchedDecoder:
         self._maybe_finish(s)
 
     def _admit(self):
-        """Fill every free slot from the queue. Monolithic mode runs
-        the whole prefill (+ first token) here; chunked mode
-        (prefill_chunk=C) only allocates and queues the slot for
-        _prefill_tick. Paged mode backpressures: a request whose page
-        demand exceeds the free pool stays queued until completions
-        free pages."""
+        """Fill free slots from the queue, in its order. Monolithic
+        mode runs the whole prefill (+ first token) here, up to
+        ``prefill_budget`` padded prompt tokens a tick or one prompt
+        however long; chunked mode (prefill_chunk=C) only allocates and
+        queues the slot for _prefill_tick. Paged mode backpressures: a
+        request whose page demand exceeds the free pool stays queued
+        until completions free pages."""
+        spent = 0  # padded prompt tokens prefilled whole in this tick
         for s in range(self.slots):
             if (self.active[s] or self._pf[s] is not None
                     or not self.queue):
@@ -1642,6 +1735,15 @@ class BatchedDecoder:
                 break
             plen = len(r.prompt)
             lb = self._bucket_len(plen)
+            if r.handoff is None and self.prefill_chunk is None:
+                # every decoding row waits out every prefill of its
+                # tick: past the budget the queue keeps the rest for
+                # the next tick, one tick later for them and a bounded
+                # gap for all the others
+                if spent and spent + lb > self.prefill_budget:
+                    self.queue.insert(0, r)
+                    break
+                spent += lb
             padded = np.zeros((lb,), np.int32)
             padded[:plen] = r.prompt
             cached = 0
@@ -1800,6 +1902,11 @@ class BatchedDecoder:
                         body, (pools, tok, t), None, length=kd)
                 return pools, jnp.swapaxes(toks, 0, 1)   # (B, k)
         else:
+            # a model that counts (``step_counters``: the tokens each
+            # held expert got) has the step return the sums as a third
+            # output, fetched with the tokens
+            counted = self._counted
+
             def step(mstate, caches, tok, t, gens):
                 with inject_state((model, *mstate)):
                     def body(c, _):
@@ -1807,11 +1914,18 @@ class BatchedDecoder:
                         logits, caches = model._step_logits_rows(
                             tok, caches, t, decode_kernel=True)
                         nxt = pick(logits, gens, t + 1)
-                        return (caches, nxt, t + 1), nxt
+                        return (caches, nxt, t + 1), (
+                            (nxt, model.step_counters()) if counted
+                            else nxt)
 
-                    (caches, _, _), toks = lax.scan(
+                    (caches, _, _), out = lax.scan(
                         body, (caches, tok, t), None, length=kd)
-                return caches, jnp.swapaxes(toks, 0, 1)
+                if not counted:
+                    return caches, jnp.swapaxes(out, 0, 1)
+                toks, got = out
+                return (caches, jnp.swapaxes(toks, 0, 1),
+                        jax.tree_util.tree_map(
+                            lambda a: jnp.sum(a, axis=0), got))
 
         return jax.jit(_named(
             step, "pt_decode_step" if kd == 1 else f"pt_decode_step_k{kd}"))
@@ -1849,13 +1963,18 @@ class BatchedDecoder:
         with tick_cm:
             with Span("serve.step.dispatch"):
                 step_fn, args = self._step_call()
+                counted = ()
                 if self.paged:
                     self.pools, toks = step_fn(*args)
                 else:
-                    self.caches, toks = step_fn(*args)
-            # the host blocked on the device
+                    self.caches, toks, *counted = step_fn(*args)
+            # the host blocked on the device; what the step counted
+            # comes over in the same fetch
             with Span("serve.step.fetch"):
-                toks = np.asarray(jax.device_get(toks)).astype(np.int32)
+                toks, counted = jax.device_get((toks, counted))
+                toks = np.asarray(toks).astype(np.int32)
+            if counted:
+                self.counters.add(counted[0])
         self._warmed = True
         if telem:
             # cost-ledger registration, once per step variant (set

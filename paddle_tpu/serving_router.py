@@ -375,7 +375,9 @@ class LocalReplica:
     """One in-process replica: a :class:`serving.BatchedDecoder` driven
     by a background serve thread (admit → prefill tick → step, exactly
     ``run()``'s loop body) with a lock around every arena touch, so
-    router dispatch threads and the serve loop interleave safely.
+    router dispatch threads and the serve loop interleave safely: a
+    caller waits out the tick it arrives in and holds the lock before
+    the next one starts (``_locked``).
 
     Also the PREFILL-worker form: a replica that only ever receives
     :meth:`prefill` calls ticks nothing and just runs bucketed prefills
@@ -399,6 +401,12 @@ class LocalReplica:
         self.model = model
         self.idle_s = idle_s
         self._mu = threading.RLock()
+        # the handoff: callers waiting for ``_mu`` are counted, and the
+        # serve loop starts no tick while one of them still waits
+        self._callers_mu = threading.Lock()
+        self._callers = 0
+        self._no_callers = threading.Event()
+        self._no_callers.set()
         self._done: Dict[int, Dict[str, Any]] = {}
         # replica-side per-request token streams (stream=True submits)
         # keyed by rid until the router's fan-in pump claims them;
@@ -411,11 +419,22 @@ class LocalReplica:
     def _locked(self, who: str):
         """Hold ``_mu``; the program span ``replica.lock_wait.<who>``
         covers the wait for it on the caller's thread, not the hold.
-        For callers only: ``_loop`` takes the lock with a plain
-        ``with``, because anything between its release and its next
-        acquire changes how often a waiting caller gets in."""
-        with Span("replica.lock_wait." + who):
-            self._mu.acquire()
+        For callers only. A caller is counted while it waits, and
+        ``_loop`` starts no tick until the count is back at zero: the
+        loop retakes a lock it has just dropped within a microsecond,
+        long before a woken caller can, so without the count a caller
+        gets in once in several ticks, and by chance."""
+        with self._callers_mu:
+            self._callers += 1
+            self._no_callers.clear()
+        try:
+            with Span("replica.lock_wait." + who):
+                self._mu.acquire()
+        finally:
+            with self._callers_mu:
+                self._callers -= 1
+                if not self._callers:
+                    self._no_callers.set()
         try:
             yield
         finally:
@@ -630,6 +649,10 @@ class LocalReplica:
 
     def _loop(self) -> None:
         while not self._stop.is_set():
+            # callers first: whoever waited out the tick has ``_mu``
+            # before the next one starts (the limit only bounds what a
+            # caller stuck behind another caller's long hold can cost)
+            self._no_callers.wait(1.0)
             with self._mu:
                 busy = self._tick_locked()
             if not busy:

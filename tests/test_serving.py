@@ -60,6 +60,38 @@ def test_more_requests_than_slots_all_complete():
         np.testing.assert_array_equal(solo.run()[srid], outs[rid])
 
 
+@pytest.mark.parametrize("plens, want_active", [
+    # two buckets of 16 fit the tick's budget of 32; the third waits
+    ((5, 9, 12), (2, 3, 3)),
+    # a prompt longer than the budget is prefilled alone, never starved
+    ((40, 5, 5), (1, 3, 3)),
+    # what does not fit keeps its place in the queue: the short prompt
+    # behind the long one waits with it
+    ((5, 40, 5), (1, 2, 3)),
+])
+def test_a_tick_prefills_two_buckets_or_one_prompt(plens, want_active):
+    """Every decoding row waits out every prefill of its tick, so
+    ``_admit`` prefills at most ``prefill_budget`` padded prompt tokens
+    a tick (two buckets), or one prompt however long; the rest stay
+    queued in order for the next tick. Tokens are what a solo run
+    gives: the budget moves when a request starts, nothing else."""
+    m = _model(2)
+    dec = BatchedDecoder(m, slots=4, capacity=64)
+    assert dec.prefill_budget == 2 * dec.bucket == 32
+    rids = [dec.submit(_prompt(n, 30 + i), 12)
+            for i, n in enumerate(plens)]
+    seen = []
+    for _ in want_active:
+        dec._tick()
+        seen.append(int(dec.active.sum()))
+    assert tuple(seen) == want_active
+    outs = dec.run()
+    for i, (rid, n) in enumerate(zip(rids, plens)):
+        solo = BatchedDecoder(m, slots=1, capacity=64)
+        srid = solo.submit(_prompt(n, 30 + i), 12)
+        np.testing.assert_array_equal(solo.run()[srid], outs[rid])
+
+
 def test_eos_ends_request_early():
     m = _model(2)
     prompt = _prompt(5, 20)
