@@ -394,7 +394,11 @@ def load_programs(directory: str, manifest: Dict[str, Any]):
     ``(step_fns: {k: callable}, prefill_fns: {lb: callable})``. Each
     callable is ``jax.jit(exported.call)`` — jit-wrapped ONCE so the
     serving loop's per-tick dispatch hits the jit cache instead of
-    re-staging the call primitive."""
+    re-staging the call primitive — with the arena DONATED, as the
+    traced programs it stands in for have it (``serving._arena_jit``):
+    an exported module does not carry its source's donation, the jit
+    around its call states it again. The arena is argument 1 of every
+    exported program (step and prefill, contiguous and paged)."""
     exp_mod = jax.export
     checks = manifest.get("checksums", {})
 
@@ -409,7 +413,7 @@ def load_programs(directory: str, manifest: Dict[str, Any]):
                  "aot artifact %s: checksum mismatch on %s (torn or "
                  "corrupt program blob)", directory, fname)
         exported = exp_mod.deserialize(bytearray(data))
-        return jax.jit(exported.call)
+        return jax.jit(exported.call, donate_argnums=(1,))
 
     progs = manifest["programs"]
     step_fns = {int(k): _one(f) for k, f in progs["steps"].items()}

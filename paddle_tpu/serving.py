@@ -51,8 +51,9 @@ from jax import lax
 from . import telemetry
 from .core.enforce import enforce
 
-__all__ = ["ArenaCounters", "BatchedDecoder", "PagedKVPool", "Request",
-           "KVHandoff", "TokenStream", "reject_cause"]
+__all__ = ["ArenaCounters", "ArenaLostError", "BatchedDecoder",
+           "PagedKVPool", "Request", "KVHandoff", "TokenStream",
+           "reject_cause"]
 from .nn.layer import inject_state
 from .resilience import reliability as _reliability
 from .ops import paged_kv as paged_ops
@@ -63,11 +64,30 @@ from .telemetry import recompile as _recompile
 from .telemetry import server as _dbg_server
 from .telemetry import tracing as _tracing
 from .telemetry.trace import Span, named as _named
+from .utils.memory import owned_on_device
 
 # reusable inert context manager: span call-sites gate on
 # telemetry.enabled() (the zero-cost contract — a disabled run must
 # execute NO tracing code, pinned by test) and fall back to this
 _NULL_CM = contextlib.nullcontext()
+
+
+def _arena_jit(fn, name: str, arena_argnums):
+    """``fn`` jitted under the stable program name ``name`` with the
+    arena arguments DONATED: every serving program takes the arena
+    (contiguous caches, page pools, the draft's caches) and returns the
+    new one, and only a donated argument may be written in place —
+    undonated, XLA copies the whole arena into the output and updates
+    the copy. The one way a serving program is compiled: the weights,
+    cursors, tokens and page table are never in ``arena_argnums``."""
+    return jax.jit(_named(fn, name), donate_argnums=arena_argnums)
+
+
+class ArenaLostError(RuntimeError):
+    """A serving program failed after it had consumed the arena: the
+    decoder holds no keys, values or state any more and refuses every
+    further tick. Not an ``EnforceError`` (a request's fault): the
+    router reads it as the replica's death."""
 
 
 @telemetry.cached_instruments
@@ -656,6 +676,21 @@ class BatchedDecoder:
     the cache by position (pages, prefix reuse, handoff, speculative
     verify, chunked prefill) is refused.
 
+    **The arena is consumed by every program that takes it.** The
+    decode step, every prefill piece, the speculative round and the
+    handoff import are compiled with the arena donated (``_arena_jit``)
+    and write keys, values and recurrent state in place; the decoder
+    rebinds ``caches`` / ``pools`` / ``caches_d`` to what the program
+    returns, and the arrays it passed in are deleted. A caller who
+    wants to keep an arena (to compare against, to snapshot) copies it
+    first (``jax.tree_util.tree_map(jnp.copy, dec.caches)``); one who
+    drives a program by hand passes ``dec.caches`` and assigns the
+    result back, as the decoder does. The leaves must be buffers the
+    runtime owns: ``analysis/donation`` checks that once at
+    construction (``FLAGS_static_verify``). A program that fails after
+    it consumed the arena marks the decoder ``arena_lost``
+    (``_arena_guard``).
+
     ``submit()`` enqueues; ``run()`` drives to completion and returns
     {request_id: np.ndarray of generated ids (prompt excluded)}.
     Sampling params apply to every request (temperature=0 = greedy);
@@ -821,6 +856,10 @@ class BatchedDecoder:
             self.caches = model.init_cache(slots, capacity)
         if draft is not None:
             self.caches_d = draft.init_cache(slots, capacity)
+        # a program that failed after it consumed the arena leaves
+        # nothing to serve from (``_arena_guard``)
+        self.arena_lost = False
+        self._check_arena_donation()
         self._kinds = (list(kinds) if kinds and not self.paged
                        else ["kv"] * len(model.blocks))
         self._counted = (hasattr(model, "step_counters")
@@ -1107,13 +1146,67 @@ class BatchedDecoder:
         opens ``serve.tick`` around this and whatever else its tick
         holds (``run``'s loop; ``LocalReplica._tick_locked`` with its
         harvest), so the children tile a busy tick."""
-        if admit:
-            with Span("serve.admit"):
-                self._admit()
-        if self._pf_order:
-            with Span("serve.prefill_tick"):
-                self._prefill_tick()
-        self._step()
+        with self._arena_guard():
+            if admit:
+                with Span("serve.admit"):
+                    self._admit()
+            if self._pf_order:
+                with Span("serve.prefill_tick"):
+                    self._prefill_tick()
+            self._step()
+
+    def _arena(self):
+        """Every donated leaf the decoder holds: the target's arena and
+        the draft's."""
+        return (self.pools if self.paged else self.caches,
+                self.caches_d if self.draft is not None else None)
+
+    def _check_arena_donation(self) -> None:
+        """Construction-time donation-provenance check of the arena
+        (analysis/donation, as ``Trainer._check_donation_safety``):
+        every program donates it, so every leaf must be a buffer the
+        runtime owns and no two leaves may share one. Once a decoder,
+        skippable via FLAGS_static_verify=0."""
+        from .core.config import FLAGS
+
+        if not FLAGS.get("static_verify"):
+            return
+        from .analysis.diagnostics import format_diagnostics
+        from .analysis.donation import check_donation
+
+        diags = [d for d in check_donation(self._arena(), (0, 1))
+                 if d.severity == "error"]
+        enforce(not diags, "the serving arena failed the donation-"
+                "safety check (FLAGS_static_verify=0 skips):\n%s",
+                format_diagnostics(diags))
+
+    @contextlib.contextmanager
+    def _arena_guard(self):
+        """Around whatever dispatches arena programs. Each consumes the
+        arena it is given; one that raises after that (a device fault,
+        an allocation that fails at run time) leaves deleted or failed
+        buffers behind, and a tick on them could only raise "Array has
+        been deleted" for ever. So on the way out of an exception the
+        arena is waited for once; if that raises too the decoder is
+        marked ``arena_lost``: it reports not ready, refuses every
+        further tick with :class:`ArenaLostError`, and
+        ``LocalReplica`` fails its health probe so that the router
+        places the requests it held on a surviving replica. The rows
+        are not rebuilt in place: their keys, values and state are
+        gone, and only the router knows the prompts to replay."""
+        if self.arena_lost:
+            raise ArenaLostError(
+                "the serving arena was consumed by a program that then "
+                "failed; this decoder serves nothing more (build a new "
+                "one, or let the router fail the replica over)")
+        try:
+            yield
+        except BaseException:
+            try:
+                jax.block_until_ready(self._arena())
+            except Exception:
+                self.arena_lost = True
+            raise
 
     def _statusz(self) -> Dict[str, Any]:
         """Arena view for /statusz (host-side fields only — reading it
@@ -1147,12 +1240,17 @@ class BatchedDecoder:
         dispatched a step (jit warm) and it is not draining. Liveness
         stays /healthz's heartbeat clocks — a not-ready replica is
         healthy, just not placeable."""
-        return self._warmed and not self.preempted
+        return (self._warmed and not self.preempted
+                and not self.arena_lost)
 
     def _step_call(self):
         """(jitted decode step, its arguments) for the CURRENT tokens-
         per-dispatch (k=1 while degraded) and arena state — the one
-        place the step's calling convention lives."""
+        place the step's calling convention lives. Calling the step
+        consumes the arena among the arguments (argument 1, donated):
+        whoever dispatches assigns the returned arena back, and uses
+        the arguments afterwards for their shapes at most (lowering
+        reads no buffer)."""
         kd = 1 if self.degraded else self.decode_steps
         step_fn = self._step_fns.get(kd)
         if step_fn is None:
@@ -1185,31 +1283,31 @@ class BatchedDecoder:
         fully overwrites and no attention ever reads (nothing is
         active, and prefill rewrites [0, bucket) wholesale); a
         recurrent state advanced by junk is zeroed by the slot's next
-        prefill."""
-        step_fn, args = self._step_call()
-        if self.paged:
-            self.pools, toks = step_fn(*args)
-        else:
-            self.caches, toks, *_ = step_fn(*args)
-        jax.block_until_ready(toks)
-        if self.draft is not None and not self.degraded:
-            # spec arenas serve through the spec round: warm that
-            # executable too (same idle-arena safety argument; the
-            # draft cache junk is likewise overwritten at prefill)
-            if self._spec_fn is None:
-                self._spec_fn = self._build_spec_step()
+        prefill. Like every dispatch it consumes the arena and rebinds
+        the decoder to the one the programs return."""
+        with self._arena_guard():
+            step_fn, args = self._step_call()
             if self.paged:
-                out = self._spec_fn(self._mstate, self._dstate,
-                                    self.pools, jnp.asarray(self.table),
-                                    self.caches_d, self.tok, self.t,
-                                    gens)
-                self.pools, self.caches_d = out[0], out[1]
+                self.pools, toks = step_fn(*args)
             else:
-                out = self._spec_fn(self._mstate, self._dstate,
-                                    self.caches, None, self.caches_d,
-                                    self.tok, self.t, gens)
-                self.caches, self.caches_d = out[0], out[1]
-            jax.block_until_ready(out[2])
+                self.caches, toks, *_ = step_fn(*args)
+            jax.block_until_ready(toks)
+            if self.draft is not None and not self.degraded:
+                # spec arenas serve through the spec round: warm that
+                # executable too (same idle-arena safety argument; the
+                # draft cache junk is likewise overwritten at prefill)
+                if self._spec_fn is None:
+                    self._spec_fn = self._build_spec_step()
+                table = jnp.asarray(self.table) if self.paged else None
+                out = self._spec_fn(
+                    self._mstate, self._dstate,
+                    self.pools if self.paged else self.caches, table,
+                    self.caches_d, self.tok, self.t, args[-1])
+                if self.paged:
+                    self.pools, self.caches_d = out[0], out[1]
+                else:
+                    self.caches, self.caches_d = out[0], out[1]
+                jax.block_until_ready(out[2])
         self._warmed = True
 
     def set_degraded(self, on: bool) -> None:
@@ -1261,7 +1359,7 @@ class BatchedDecoder:
                             plen=plen, pages=int(m))
               if telem else _NULL_CM)
         try:
-            with cm:
+            with self._arena_guard(), cm:
                 row = np.zeros((self.n_log,), np.int32)
                 row[:m] = ids
                 lb = self._bucket_len(plen)
@@ -1367,13 +1465,28 @@ class BatchedDecoder:
         with cm:
             m = (plen + self.page_size - 1) // self.page_size
             ids = jnp.asarray(self._slot_pages[s][:m])
-            pools = []
-            for (kp, vp), (pk, pv) in zip(self.pools, h.blocks):
-                pools.append((paged_ops.import_pages(kp, ids, pk),
-                              paged_ops.import_pages(vp, ids, pv)))
-            self.pools = pools
+            # the payload is host memory, which the CPU client may
+            # alias and not copy: every leaf that goes into a donated
+            # program is first a buffer the runtime owns
+            blocks = jax.tree_util.tree_map(
+                lambda a: owned_on_device(jnp.asarray(a)), list(h.blocks))
+            self.pools = self._import_fn()(self.pools, ids, blocks)
             self._activate(s, r, self._first_token(
                 s, jnp.asarray(h.logits), plen), plen)
+
+    def _import_fn(self):
+        """Jitted page import: the handoff's pages written into the
+        pools in place (one compile a page count)."""
+        fn = self._prefill_cache.get(("import",))
+        if fn is None:
+            def imp(pools, ids, blocks):
+                return [(paged_ops.import_pages(kp, ids, pk),
+                         paged_ops.import_pages(vp, ids, pv))
+                        for (kp, vp), (pk, pv) in zip(pools, blocks)]
+
+            fn = _arena_jit(imp, "pt_handoff_import", (0,))
+            self._prefill_cache[("import",)] = fn
+        return fn
 
     # ----- internals -------------------------------------------------------
 
@@ -1437,7 +1550,7 @@ class BatchedDecoder:
                 logits, new = _row_apply(caches, s, body)
             return new, logits[0]
 
-        fn = jax.jit(_named(prefill, f"pt_prefill_{lb}"))
+        fn = _arena_jit(prefill, f"pt_prefill_{lb}", (1,))
         self._prefill_cache[lb] = fn
         return fn
 
@@ -1461,7 +1574,7 @@ class BatchedDecoder:
                     jnp.full((1,), plen - 1, jnp.int32))
             return pools, logits[0]
 
-        fn = jax.jit(_named(prefill, f"pt_prefill_paged_{lb}"))
+        fn = _arena_jit(prefill, f"pt_prefill_paged_{lb}", (1,))
         self._prefill_cache[("paged", lb)] = fn
         return fn
 
@@ -1479,7 +1592,7 @@ class BatchedDecoder:
                         padded[None], pools, table_row, t0, head=False)
                 return pools
 
-            chunk_fn = jax.jit(_named(chunk, f"pt_prefill_suffix_{lb}"))
+            chunk_fn = _arena_jit(chunk, f"pt_prefill_suffix_{lb}", (1,))
             self._prefill_cache[("suffix", lb)] = chunk_fn
         restep_fn = self._prefill_cache.get(("restep",))
         if restep_fn is None:
@@ -1490,7 +1603,7 @@ class BatchedDecoder:
                         jnp.full((1,), pos, jnp.int32))
                 return pools, logits[0]
 
-            restep_fn = jax.jit(_named(restep, "pt_prefill_restep"))
+            restep_fn = _arena_jit(restep, "pt_prefill_restep", (1,))
             self._prefill_cache[("restep",)] = restep_fn
         return chunk_fn, restep_fn
 
@@ -1510,7 +1623,7 @@ class BatchedDecoder:
                         toks[None], row, t0, head=False))
             return new
 
-        fn = jax.jit(_named(chunk, f"pt_prefill_chunk_{c}"))
+        fn = _arena_jit(chunk, f"pt_prefill_chunk_{c}", (1,))
         self._prefill_cache[("cchunk", c)] = fn
         return fn
 
@@ -1529,7 +1642,7 @@ class BatchedDecoder:
                     lambda row: model._step_logits(tok[None], row, pos))
             return new, logits[0]
 
-        fn = jax.jit(_named(restep, "pt_prefill_restep"))
+        fn = _arena_jit(restep, "pt_prefill_restep", (1,))
         self._prefill_cache[("crestep",)] = fn
         return fn
 
@@ -1669,7 +1782,7 @@ class BatchedDecoder:
                         padded[None], row, 0, head=False))
             return new
 
-        fn = jax.jit(_named(prefill, f"pt_draft_prefill_{lb}"))
+        fn = _arena_jit(prefill, f"pt_draft_prefill_{lb}", (1,))
         self._prefill_cache[("draft", lb)] = fn
         return fn
 
@@ -1927,8 +2040,9 @@ class BatchedDecoder:
                         jax.tree_util.tree_map(
                             lambda a: jnp.sum(a, axis=0), got))
 
-        return jax.jit(_named(
-            step, "pt_decode_step" if kd == 1 else f"pt_decode_step_k{kd}"))
+        return _arena_jit(
+            step, "pt_decode_step" if kd == 1 else f"pt_decode_step_k{kd}",
+            (1,))
 
     def _step_multi(self):
         """decode_steps host side: append each row's k tokens in order
@@ -1963,6 +2077,12 @@ class BatchedDecoder:
         with tick_cm:
             with Span("serve.step.dispatch"):
                 step_fn, args = self._step_call()
+                if telem:
+                    # cost-ledger registration, once per step variant
+                    # (set lookup after the first tick), BEFORE the
+                    # dispatch: the step consumes the arena in ``args``
+                    _costs.ensure_program(f"serving.step[k={kd}]",
+                                          step_fn, args, origin="serving")
                 counted = ()
                 if self.paged:
                     self.pools, toks = step_fn(*args)
@@ -1976,12 +2096,6 @@ class BatchedDecoder:
             if counted:
                 self.counters.add(counted[0])
         self._warmed = True
-        if telem:
-            # cost-ledger registration, once per step variant (set
-            # lookup after the first tick): lower() only reads avals,
-            # so the pre-dispatch arrays are fine
-            _costs.ensure_program(f"serving.step[k={kd}]", step_fn, args,
-                                  origin="serving")
         now = time.perf_counter()
         n_emitted = 0
         with Span("serve.step.emit"):
@@ -2149,7 +2263,8 @@ class BatchedDecoder:
             with inject_state((model, *mstate), (draft, *dstate)):
                 return spec(tstate, table, caches_d, tok, t, gens)
 
-        return jax.jit(_named(spec_injected, "pt_spec_step"))
+        # the target's arena (caches, or pools) and the draft's caches
+        return _arena_jit(spec_injected, "pt_spec_step", (2, 4))
 
     def _step_spec(self):
         """One speculative round (host side): run the jitted round,

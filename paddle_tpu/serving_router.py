@@ -92,7 +92,8 @@ import numpy as np
 from . import telemetry
 from .core.enforce import EnforceError, enforce
 from .resilience import reliability as _reliability
-from .serving import BatchedDecoder, KVHandoff, TokenStream, reject_cause
+from .serving import (ArenaLostError, BatchedDecoder, KVHandoff,
+                      TokenStream, reject_cause)
 from .telemetry import server as _dbg_server
 from .telemetry import tracing as _tracing
 from .telemetry.trace import Span
@@ -572,11 +573,22 @@ class LocalReplica:
         with self._locked("other"):
             self.decoder.set_degraded(on)
 
+    def _require_arena(self) -> None:
+        """A decoder whose arena a failed program consumed serves
+        nothing more: the probe (``healthz`` in process, ``/load`` over
+        HTTP) raises, the router counts the replica dead after
+        ``health_fails`` probes and places what it held elsewhere."""
+        if self.decoder.arena_lost:
+            raise ArenaLostError(
+                f"replica {self.name}: the serving arena was lost")
+
     def healthz(self) -> Dict[str, Any]:
+        self._require_arena()
         return {"status": "ok", "ready": self.decoder.ready,
                 "pid": os.getpid()}
 
     def load(self) -> Dict[str, Any]:
+        self._require_arena()
         d = self.decoder
         with self._locked("other"):
             out = {"queue_depth": len(d.queue),

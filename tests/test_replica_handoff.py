@@ -16,6 +16,7 @@ class _BusyArena:
     always busy, a tick is a few milliseconds of held lock."""
 
     paged = False
+    arena_lost = False
     slots = 2
 
     def __init__(self, tick_s):

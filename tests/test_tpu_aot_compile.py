@@ -21,6 +21,7 @@ own process, and all of them live in this one file.
 """
 
 import importlib
+import re
 import time
 
 import jax
@@ -288,3 +289,100 @@ def test_flash_dp4_compiles_via_shard_map(chips, monkeypatch):
     assert "all-gather" not in text
     # each chip works on its quarter of the batch
     assert f"bf16[{B // 4},{T},{H_KV}," in text
+
+
+# ---------------------------------------------------------------------------
+# whole serving programs: the donated arena is written in place
+# ---------------------------------------------------------------------------
+
+# mistral-7b-v0.1.chat_closed16 as benchmark/configs/mistral-7b-v0.1.json
+# runs it, depth 16 -> 2: an arena leaf is (16, 2048, 8, 128) bf16
+SERVE_SLOTS, SERVE_CAPACITY, SERVE_BUCKET = 16, 2048, 256
+ARENA_LEAF = f"bf16[{SERVE_SLOTS},{SERVE_CAPACITY},8,128]"
+
+
+@pytest.fixture(scope="module")
+def mistral_decoder(one_chip):
+    """A ``BatchedDecoder`` over the Mistral cell's model at depth 2
+    with no weights behind it (the constructor runs under
+    ``jax.eval_shape``, as ``benchmark/harness/program.build_model``
+    does), the shapes of its parameters and of its arena placed on the
+    described chip."""
+    from paddle_tpu.core.config import FLAGS
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import BatchedDecoder
+
+    was = FLAGS.get("default_dtype")
+    FLAGS.set("default_dtype", "bfloat16")
+    box = {}
+
+    def construct():
+        box["model"] = GPTForCausalLM(GPTConfig(
+            vocab_size=32000, hidden_size=4096, num_layers=2,
+            num_heads=32, num_kv_heads=8, intermediate_size=14336,
+            max_position=SERVE_CAPACITY, rope_theta=10000.0,
+            tie_embeddings=False)).eval()
+        return dict(box["model"].named_parameters())
+
+    try:
+        pt.seed(0)
+        shapes = jax.eval_shape(construct)
+        pt.seed(0)  # the global key held a tracer: make it concrete again
+        dec = BatchedDecoder(box["model"], slots=SERVE_SLOTS,
+                             capacity=SERVE_CAPACITY,
+                             prompt_bucket=SERVE_BUCKET)
+    finally:
+        FLAGS.set("default_dtype", was)
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    return dec, on_chip((shapes, {})), on_chip(dec.caches)
+
+
+def _arena_copies(text):
+    """The optimised HLO's ``copy`` / ``copy-start`` instructions whose
+    result is (or, for the asynchronous form, starts with) an arena
+    leaf."""
+    head = re.compile(r"= \(?" + re.escape(ARENA_LEAF)
+                      + r"\S* (?:\S+ )*?copy(-start)?\(")
+    return [line.strip()[:160] for line in text.splitlines()
+            if head.search(line)]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_256"])
+def test_serving_programs_write_the_arena_in_place(mistral_decoder,
+                                                   one_chip, monkeypatch,
+                                                   program):
+    """The check a session without a chip can make before its first
+    chip call: compiled for the v5e, the decode step and a prefill hold
+    no copy of an arena leaf, and every leaf is aliased to an output
+    (undonated, each leaf is copied whole once a program: 4.3 GB moved a
+    decode step at depth 16). The decode step keeps its kernel."""
+    import paddle_tpu.ops.attention as attn
+
+    dec, mstate, caches = mistral_decoder
+    for mod in ("flash_attention", "flash_decode"):
+        monkeypatch.setattr(importlib.import_module(
+            "paddle_tpu.ops.pallas." + mod), "_use_interpret", lambda: False)
+    i32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.int32, sharding=one_chip)
+    with attn.force_flash():
+        if program == "decode_step":
+            lowered = dec._build_multi_step(1).lower(
+                mstate, caches, i32(SERVE_SLOTS), i32(SERVE_SLOTS),
+                jax.ShapeDtypeStruct((SERVE_SLOTS,), jnp.uint32,
+                                     sharding=one_chip))
+        else:
+            lowered = dec._prefill_fn(SERVE_BUCKET).lower(
+                mstate, caches, i32(SERVE_BUCKET), 7, 0)
+        compiled = lowered.compile()
+    text = compiled.as_text()
+    assert ARENA_LEAF in text
+    assert not _arena_copies(text), _arena_copies(text)
+    leaves = jax.tree_util.tree_leaves(caches)
+    assert text.split("entry_computation_layout")[0].count(
+        "-alias)") == len(leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        leaf.size * leaf.dtype.itemsize for leaf in leaves)
+    if program == "decode_step":
+        assert text.count("tpu_custom_call") >= 2
