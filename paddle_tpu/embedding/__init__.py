@@ -19,9 +19,7 @@ TPU-native rebuild, three planes over one table:
   globally-committed two-phase path (per-shard files + checksums) and
   restore across ``ep`` shapes via the cross-plan-shape restore.
 
-``bench.py --model deepfm_sparse --plan ep=8`` drives the full
-vertical slice; the README's "Sharded embeddings" section is the
-user-facing tour.
+The README's "Sharded embeddings" section is the user-facing tour.
 """
 
 from .cache import RowCache

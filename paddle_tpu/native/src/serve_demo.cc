@@ -150,7 +150,7 @@ int main(int argc, char** argv) {
     double p99 = lat_ms[p99_idx > 0 ? p99_idx - 1 : 0];
     double mx = lat_ms[n - 1];
     double mean = sum / n;
-    // one JSON line, bench.py style — the analyzer-latency-test role
+    // one JSON line — the analyzer-latency-test role
     printf(
         "{\"metric\": \"native_serve_latency_ms\", \"p50\": %.3f, "
         "\"p99\": %.3f, \"max\": %.3f, \"mean\": %.3f, \"batch\": %lld, "

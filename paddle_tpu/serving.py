@@ -698,6 +698,14 @@ class BatchedDecoder:
     derive by fold_in, so a request's draw stream is independent of
     which slot served it only via the admission counter — deterministic
     for a fixed submission order.
+
+    **The weights are a snapshot.** The parameters and buffers are read
+    from the model at construction and passed to every compiled program
+    as arguments; ``run()`` (and ``prefill_export``) read them again.
+    A change made to the model afterwards
+    (``quant.apply_weight_only_int8``, a LoRA merge, a loaded
+    checkpoint) therefore takes effect at the next ``run()``, or with a
+    new decoder, and not in a program driven by hand in between.
     """
 
     def __init__(self, model, slots: int, capacity: int, *,
@@ -754,9 +762,10 @@ class BatchedDecoder:
                         prefill_chunk, page_size)
         # MULTI-TOKEN DECODE STEPS (opt-in, decode_steps=k): the jitted
         # step scans k single-token steps with the token picks moved
-        # IN-DEVICE, so every dispatch advances all slots k tokens —
-        # the steps-per-call lever applied to serving: one dispatch and
-        # one host fetch per k tokens. Semantics: token-identical to k=1
+        # IN-DEVICE, so every dispatch advances all slots k tokens:
+        # one dispatch and one host fetch per k tokens (what that buys
+        # is not measured on the chip; every benchmark cell runs k=1).
+        # Semantics: token-identical to k=1
         # (same fold_in key chain); admission/eos granularity coarsens
         # to k (a row hitting eos mid-window discards the tail
         # host-side and never emits past eos or its budget).
@@ -1980,8 +1989,8 @@ class BatchedDecoder:
         """decode_steps=k jitted step: scan k single-token steps with
         the picks IN-DEVICE (same fold_in key chain as the host picks,
         so outputs are token-identical to k=1) — every dispatch
-        advances all slots k tokens, amortizing the per-dispatch
-        round trip exactly like the training benches' steps-per-call.
+        advances all slots k tokens (one dispatch and one host fetch
+        per k tokens; not measured on the chip).
         Inactive/parked rows compute junk the host discards; their
         writes drop (paged) or land above any attended position.
         ``kd`` is a parameter (not ``self.decode_steps``) so the SLO
@@ -2150,12 +2159,14 @@ class BatchedDecoder:
         """One speculative ROUND over the whole arena, jitted: gamma
         per-row draft steps (lax.scan), ONE per-row target verify
         chunk, and the Leviathan/Chen modified rejection test — all at
-        per-row cursors, fixed shapes. Greedy mode (temperature=0) is
-        token-identical to the plain arena step loop; sampled mode
-        draws from the target's own filtered distribution (the same
-        construction models/speculative.py pins with a frequency
-        test). Inactive/parked rows compute junk that the host
-        discards; their writes drop (paged) or land above any
+        per-row cursors, fixed shapes. Greedy mode (temperature=0)
+        matches the plain arena step loop up to near-tie argmax flips
+        (the verify chunk and the step loop reduce in different orders;
+        ``TestSpeculativeArena`` pins at least 90% agreement, not
+        identity); sampled mode draws from the target's own filtered
+        distribution (the same construction models/speculative.py pins
+        with a frequency test). Inactive/parked rows compute junk that
+        the host discards; their writes drop (paged) or land above any
         attended position (contiguous clamp)."""
         from .ops.sampling import filter_logits
 

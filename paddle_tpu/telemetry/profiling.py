@@ -443,7 +443,7 @@ class RegressionSentinel:
 
     def seed(self, program: str, backend: str, seconds: float, *,
              kind: str = "step") -> None:
-        """Pre-arm a baseline from an external record (BENCH_HISTORY).
+        """Pre-arm a baseline from an external record.
 
         A seeded baseline starts PAST the ``min_samples`` warmup — the
         whole point is alarming on the very first measurement of a
@@ -539,53 +539,6 @@ def sentinel() -> RegressionSentinel:
     return _sentinel
 
 
-# Reserved BENCH_HISTORY.json key for sentinel baselines. Underscore
-# prefix keeps it out of the metric namespace (the `_superseded`
-# convention) — evaluate_against_history only ever looks up real
-# metric keys, so the section rides along untouched.
-SENTINEL_HISTORY_KEY = "_sentinel"
-
-
-def seed_sentinel_from_history(path: str) -> int:
-    """Arm the process sentinel from BENCH_HISTORY.json's reserved
-    ``"_sentinel"`` section (bench.py folds it in when it records), so
-    a bench session alarms on step-time drift against the LAST
-    session's timings instead of needing ``min_samples`` warmup runs of
-    its own. Returns the number of baselines seeded; a missing file,
-    torn JSON, or absent section seeds zero and never raises."""
-    try:
-        with open(path) as f:
-            data = json.load(f)
-    except (OSError, ValueError):
-        return 0
-    rows = data.get(SENTINEL_HISTORY_KEY) if isinstance(data, dict) \
-        else None
-    if not isinstance(rows, dict):
-        return 0
-    s = sentinel()
-    n = 0
-    for key, row in rows.items():
-        try:
-            program, backend = key.split("|", 1)
-            ewma = float(row["ewma"])
-        except (AttributeError, KeyError, TypeError, ValueError):
-            continue  # one malformed row must not block the rest
-        s.seed(program, backend, ewma,
-               kind=row.get("kind", "step") if isinstance(row, dict)
-               else "step")
-        n += 1
-    return n
-
-
-def sentinel_history_entry() -> Dict[str, Dict[str, Any]]:
-    """The ``"_sentinel"`` section bench.py writes into BENCH_HISTORY:
-    the current baselines keyed ``program|backend``, trimmed to the
-    fields :func:`seed_sentinel_from_history` reads back."""
-    return {k: {"ewma": v["ewma"], "n": v["n"],
-                "kind": v.get("kind", "step")}
-            for k, v in sentinel().baselines().items()}
-
-
 def statusz_section() -> Dict[str, Any]:
     """The /statusz ``perf`` section: sentinel alarms + baseline
     count."""
@@ -603,8 +556,6 @@ def reset() -> None:
 
 
 __all__ = ["CaptureBusyError", "GoodputLedger", "RegressionSentinel",
-           "SENTINEL_HISTORY_KEY", "artifact_base_dir", "artifact_tar",
-           "capture_busy", "capture_device_trace", "goodput",
-           "make_profilez", "profilez_fanout", "reset",
-           "seed_sentinel_from_history", "sentinel",
-           "sentinel_history_entry", "statusz_section"]
+           "artifact_base_dir", "artifact_tar", "capture_busy",
+           "capture_device_trace", "goodput", "make_profilez",
+           "profilez_fanout", "reset", "sentinel", "statusz_section"]

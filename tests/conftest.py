@@ -18,14 +18,15 @@ if "xla_force_host_platform_device_count" not in _flags:
 os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# Persistent XLA compilation cache (the same helper bench.py, the tools
-# and chip_smoke.py use: JAX_COMPILATION_CACHE_DIR where set, else the
+# Persistent XLA compilation cache (the same helper the tools and
+# chip_smoke.py use: JAX_COMPILATION_CACHE_DIR where set, else the
 # fixed <checkout>/.jax_cache): the CPU sim pays most of the suite in
 # compiles; entries over the default 1 s threshold are reused across
 # processes and runs, so re-certification runs (CI, judge) skip the
@@ -87,25 +88,22 @@ SLOW_PATTERNS = [
     "test_tracing.py::test_trace_smoke_two_process_merged_trace",
     # streaming-plane subprocess e2es (~30-60s each: worker spawns):
     # the stream-smoke one runs as ci.sh mid's own "stream smoke"
-    # stage; the SIGKILL chaos pair and the bench gate ride the full
-    # suite only
+    # stage; the SIGKILL chaos pair rides the full suite only
     "test_serving_stream.py::test_stream_smoke_two_worker_token_"
     "incremental",
     "test_serving_stream.py::test_sigkill_mid_stream_typed_resume_"
     "same_trace",
     "test_serving_stream.py::test_all_down_mid_stream_typed_error",
-    "test_serving_stream.py::test_stream_bench_gate",
     # embedding-plane chaos e2e (subprocess SIGKILL mid-save): ci.sh
     # mid runs it as its own "embedding smoke" stage (pytest -m chaos
     # on the file) — the bare MID filename must not pull it into -m mid
     "test_embedding_ckpt.py::test_sigkill_mid_ep_table_save_restores_"
     "one_committed_step",
     # autoscale subprocess chaos e2es (worker spawns + SIGKILL, ~60s
-    # each) and the spike A/B bench gate: full suite only — the bare
-    # test_autoscale.py MID pattern must not pull them into -m mid
+    # each): full suite only — the bare test_autoscale.py MID pattern
+    # must not pull them into -m mid
     "test_autoscale.py::test_sigkill_mid_scale_up_converges",
     "test_autoscale.py::test_sigkill_drain_target_mid_drain",
-    "test_autoscale.py::test_autoscale_bench_gate",
     # reliability-plane subprocess chaos e2es (worker spawns + SIGSTOP
     # wedge, ~60s): ci.sh mid runs them as their own "reliability
     # smoke" stage (pytest -m chaos on the file) — the bare
@@ -200,7 +198,7 @@ MID_PATTERNS = [
     "test_chaos.py",
     # autoscale control plane: policy ladder/cooldown units, replay
     # bit-identity, scaler stub loop, drain fail-closed (the SIGKILL
-    # chaos pair and the spike bench gate are pinned slow above)
+    # chaos pair is pinned slow above)
     "test_autoscale.py",
     "test_global_commit.py",
     "test_fleet.py",
@@ -219,7 +217,6 @@ SMOKE_PATTERNS = [
     "test_lockwatch.py",
     "test_mnist_e2e.py",
     "test_api_spec.py::test_public_api_matches_spec",
-    "test_bench.py::test_regression_contract",
     "test_golden_hlo.py",
     "test_optimizer.py",
     "test_data.py",
@@ -229,6 +226,30 @@ SMOKE_PATTERNS = [
     "test_pipeline.py",
     "test_amp.py",
 ]
+
+
+# The benchmark's own CPU tests (benchmark/tests/) guard the harness that
+# decides every PR, and the driver's command is `pytest tests/`: so each
+# benchmark/tests/test_x.py has a seat tests/test_harness_x.py that
+# imports its tests, each a case of its own, one seat a file so that
+# `--dist loadfile` spreads them over the workers. These two have been
+# stale since PR 30 added the third cell, and only a `benchmark` PR may
+# edit them: they count from the day one does. No other harness test may
+# be listed here.
+HARNESS_XFAIL = {
+    "test_harness_manifest.py::test_no_width_differs_from_the_published":
+        "looks configurations up in a table of two names; the next "
+        "`benchmark` issue takes the published widths from the "
+        "configuration's own file (ROADMAP.md, named debts)",
+    "test_harness_spans_readers.py::"
+    "test_the_seven_are_registered_for_their_cells":
+        "pins each metric's `workloads` to one cell; the next "
+        "`benchmark` issue derives them from the manifest "
+        "(ROADMAP.md, named debts)",
+}
+
+# their asserts are rewritten like those of the files pytest collects
+pytest.register_assert_rewrite("benchmark.tests")
 
 
 def load_tool(name):
@@ -256,10 +277,11 @@ def load_tool(name):
 
 
 def pytest_collection_modifyitems(config, items):
-    import pytest
-
     for item in items:
         nid = item.nodeid
+        for suffix, why in HARNESS_XFAIL.items():
+            if nid.endswith(suffix):
+                item.add_marker(pytest.mark.xfail(strict=False, reason=why))
         if any(p in nid for p in SLOW_PATTERNS):
             # slow wins: a compile-heavy test never rides into the mid
             # tier even when a broad MID pattern (e.g. a bare filename)
@@ -272,13 +294,23 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.mid)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _flags_stay_in_their_file():
+    """A program may set process-wide flags as it starts (the benchmark's
+    ``build_model`` sets ``default_dtype`` to the cell's bfloat16); a
+    test file must not hand them on to the next file on its worker."""
+    from paddle_tpu.core.config import FLAGS
+
+    before = FLAGS.all()
+    yield
+    for name, value in before.items():
+        FLAGS.set(name, value)
+
+
 # ---------------------------------------------------------------------------
 # Sharding-plan fixtures: the 8-device CPU sim above makes plan/mesh
 # tests first-class tier-1 citizens; these give them a uniform entry.
 # ---------------------------------------------------------------------------
-
-import pytest  # noqa: E402
-
 
 @pytest.fixture
 def eight_devices():

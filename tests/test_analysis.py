@@ -687,7 +687,7 @@ class TestLint:
         assert [d.code for d in diags] == ["PT-LINT-309"]
 
     def test_unfenced_timing_local_fence_helper_recognized(self):
-        """A file-local helper whose body fences (the bench.py idiom:
+        """A file-local helper whose body fences (the tools' idiom:
         ``def _fence(out): float(jax.device_get(out))``) counts as a
         fence at its call sites — the dogfood false-positive class."""
         src = ("import time, jax\n"
@@ -728,7 +728,7 @@ class TestLint:
         # outside the serving/telemetry/resilience planes: not flagged
         # (an offline tool may legitimately block)
         assert lint_source(src, "paddle_tpu/utils/fetch.py") == []
-        assert lint_source(src, "tools/bench_diff.py") == []
+        assert lint_source(src, "tools/timeline.py") == []
 
     def test_unbounded_socket_connect_flagged_timeout_clean(self):
         src = ("import socket\n"

@@ -12,7 +12,6 @@ acceptance path."""
 import json
 import os
 import shutil
-import sys
 import time
 import urllib.request
 
@@ -30,7 +29,7 @@ from paddle_tpu.models import gpt as G
 from paddle_tpu.serving import BatchedDecoder
 from paddle_tpu.serving_router import LocalReplica, Router
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from replica_spec import router_replica_spec, worker_env
 
 
 @pytest.fixture(autouse=True)
@@ -79,7 +78,7 @@ def test_round_trip_bit_identical(tmp_path):
     assert isinstance(dec2.model, ModelStub)
     got = _decode(dec2, p)
     np.testing.assert_array_equal(want, got)
-    # provenance rides the loaded decoder for /statusz + the bench
+    # provenance rides the loaded decoder for /statusz
     assert dec2.aot_info["artifact_id"]
     assert dec2.aot_info["programs"]["steps"] == [1]
 
@@ -335,32 +334,22 @@ def test_slo_policy_per_model_classes():
 # with NO --spec, flips /readyz off the rehydrated program, serves
 # ---------------------------------------------------------------------------
 
-def _worker_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    return env
-
-
 @pytest.mark.slow
 @pytest.mark.mid
 def test_worker_boots_from_artifact_trace_free(tmp_path):
     """Trace-free cold start, end to end through the deployment seam:
-    export the bench replica's programs, then spawn a worker process
+    export the test replica's programs, then spawn a worker process
     with ``--from-artifact`` and NO ``--spec`` — the worker has nothing
     to trace from, so readiness + served tokens PROVE the serialized
     programs booted it. /statusz reports the aot section."""
     from paddle_tpu.serving_router import spawn_replicas
 
-    sys.path.insert(0, REPO)
-    import bench
-
-    dec = bench._router_replica_spec(smoke=True)
+    dec = router_replica_spec(smoke=True)
     art = aot.export_decoder(dec, str(tmp_path / "art"))
     del dec
 
     reps = spawn_replicas(None, 1, log_dir=str(tmp_path),
-                          env=_worker_env(), from_artifact=art)
+                          env=worker_env(), from_artifact=art)
     router = Router(reps, poll_interval_s=0.05,
                     disagg_min_tokens=None)
     try:

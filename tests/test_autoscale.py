@@ -1,18 +1,16 @@
 """Autoscaling control plane (paddle_tpu/autoscale): the deterministic
 hysteresis+cooldown policy, recorded-signal replay bit-identity, the
 acting Scaler over a live router (spawn from the artifact shelf, drain
-and retire on sustained headroom), drain fail-closed placement, chaos
-(spawn failure, SIGKILL mid-scale-up / mid-drain), and the spike A/B
-bench gate.
+and retire on sustained headroom), drain fail-closed placement, and chaos
+(spawn failure, SIGKILL mid-scale-up / mid-drain).
 
 Three tiers, mirroring test_serving_router.py: pure-policy units and
 stub-replica scaler tests (no jax work), an in-process e2e over real
-tiny-GPT replicas, and slow-marked subprocess chaos / bench gates."""
+tiny-GPT replicas, and slow-marked subprocess chaos e2es."""
 
 import json
 import os
 import signal
-import sys
 import threading
 import time
 
@@ -30,7 +28,7 @@ from paddle_tpu.serving import BatchedDecoder
 from paddle_tpu.serving_router import (LocalReplica, NoReplicasError,
                                        Router, spawn_replicas)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from replica_spec import SPEC, worker_env
 
 
 @pytest.fixture(autouse=True)
@@ -714,22 +712,15 @@ def test_retired_replica_inflight_stream_keeps_trace_id():
 # Chaos: SIGKILL mid-scale-up and mid-drain (subprocess workers; slow)
 # ---------------------------------------------------------------------------
 
-def _worker_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    return env
-
-
 @pytest.mark.slow
 @pytest.mark.chaos
 def test_sigkill_mid_scale_up_converges(tmp_path):
     """SIGKILL the worker a scale-up is booting: the spawn attempt
     fails typed (PT-AS-701 path), the fleet stays serving, and the
     policy's next window retries to convergence — no request lost."""
-    reps = spawn_replicas("bench:_router_replica_spec", 1,
+    reps = spawn_replicas(SPEC, 1,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, poll_interval_s=0.05, health_fails=2)
     attempts = []
 
@@ -739,17 +730,17 @@ def test_sigkill_mid_scale_up_converges(tmp_path):
         if idx > 1:
             # the retry: a normal boot — spawn_replicas blocks until
             # the worker warms and flips ready
-            return spawn_replicas("bench:_router_replica_spec", 1,
+            return spawn_replicas(SPEC, 1,
                                   spec_kw={"smoke": True},
                                   log_dir=str(tmp_path),
-                                  env=_worker_env(),
+                                  env=worker_env(),
                                   start_index=idx)[0]
         # attempt 1 boots --no-warm (ready stays down until warmup,
         # giving a wide mid-boot window) and the chaos kills it there
-        rep = spawn_replicas("bench:_router_replica_spec", 1,
+        rep = spawn_replicas(SPEC, 1,
                              spec_kw={"smoke": True},
                              log_dir=str(tmp_path), warm=False,
-                             env=_worker_env(), start_index=idx)[0]
+                             env=worker_env(), start_index=idx)[0]
         os.kill(rep.proc.pid, signal.SIGKILL)
         deadline = time.time() + 300
         while time.time() < deadline:
@@ -792,9 +783,9 @@ def test_sigkill_drain_target_mid_drain(tmp_path):
     its in-flight work onto the survivor, drain_done reports true for
     the dead replica, the removal completes, and the fleet converges
     with no request lost."""
-    reps = spawn_replicas("bench:_router_replica_spec", 2,
+    reps = spawn_replicas(SPEC, 2,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, poll_interval_s=0.05, health_fails=2)
     policy = _fast_policy(headroom_hold_s=0.3, cooldown_up_s=60.0,
                           cooldown_down_s=0.3)
@@ -834,51 +825,3 @@ def test_sigkill_drain_target_mid_drain(tmp_path):
     finally:
         sc.stop()
         router.close(replicas=True)
-
-
-# ---------------------------------------------------------------------------
-# The acceptance bench gate (deterministic seeds; slow tier)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_autoscale_bench_gate():
-    """ISSUE 18 acceptance: under the seeded 3x spike the autoscaled
-    arm preserves the SLO (short-prompt p99 TTFT + p99 ITL within the
-    static-max arm's bounds, shed no worse) at strictly fewer
-    replica-seconds; the fleet never flaps (events <= the
-    cooldown-implied ceiling) and the recorded decision trace replays
-    bit-identically. The gates themselves are enforced INSIDE the
-    bench (it raises on violation); this test drives it and checks
-    the reported evidence columns."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    time.sleep(2.0)
-    last = None
-    for attempt in range(3):
-        try:
-            value, unit, extras = bench.bench_gpt_router(
-                8, 0, smoke=True, autoscale=(1, 3))
-            break
-        except EnforceError as e:
-            # perf gates on a noisy shared box: re-measure, don't
-            # move the bar
-            last = e
-    else:
-        raise last
-    assert unit == "tokens/sec"
-    for key in ("ttft_short_p99_ms", "itl_p99_ms", "shed_rate",
-                "replica_seconds", "replica_timeline",
-                "static_replica_seconds", "static_ttft_short_p99_ms",
-                "autoscale_scale_ups", "autoscale_scale_downs",
-                "autoscale_ttfr_s", "autoscale_peak"):
-        assert key in extras, key
-    assert extras["replica_seconds"] < \
-        extras["static_replica_seconds"], extras
-    assert extras["autoscale_scale_ups"] >= 1
-    assert extras["autoscale_scale_downs"] >= 1
-    assert extras["autoscale_peak"] > extras["autoscale_min"]
-    # the timeline is change-points: starts at MIN, ends at MIN
-    tl = extras["replica_timeline"]
-    assert tl[0][1] == extras["autoscale_min"]
-    assert tl[-1][1] == extras["autoscale_min"]

@@ -1,7 +1,7 @@
 """CNN model family: shape checks + convergence smoke (the reference's
 book-test pattern, reference: tests/book/test_image_classification).
 
-Uses tiny inputs; full-size ResNet-50 is exercised by bench.py on TPU.
+Uses tiny inputs.
 """
 
 import jax

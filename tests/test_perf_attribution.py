@@ -39,10 +39,12 @@ def _clean_telemetry():
     telemetry.reset()
 
 
-class _V5E:
-    """Stand-in for the device a TPU v5e reports."""
-    platform = "tpu"
-    device_kind = "TPU v5 lite"
+class _FakeDevice:
+    """Stand-in for a device; the defaults are what a TPU v5e reports."""
+
+    def __init__(self, platform="tpu", device_kind="TPU v5 lite"):
+        self.platform = platform
+        self.device_kind = device_kind
 
 
 def _pretend_v5e(monkeypatch):
@@ -53,7 +55,7 @@ def _pretend_v5e(monkeypatch):
     real = flops.device_peak_flops
     monkeypatch.setattr(
         flops, "device_peak_flops",
-        lambda device=None, dtype="bf16": real(_V5E(), dtype))
+        lambda device=None, dtype="bf16": real(_FakeDevice(), dtype))
 
 
 def _matmul_jit(n=64):
@@ -111,18 +113,42 @@ class TestCostLedger:
         assert rec["artifact_id"] == "art-123"
 
     def test_roofline_verdicts(self):
-        v5e = _V5E()
+        v5e = _FakeDevice()
         assert costs.roofline(1e12, 1e3, v5e)["verdict"] == "compute_bound"
         assert costs.roofline(1e3, 1e12, v5e)["verdict"] == "hbm_bound"
         assert costs.roofline(None, 1e6, v5e)["verdict"] == "unknown"
 
     def test_backend_peaks_v5e_row_and_no_cpu_peak(self):
         assert costs.backend_peaks() is None  # the CPU has no peak
-        peaks = costs.backend_peaks(_V5E())
+        peaks = costs.backend_peaks(_FakeDevice())
         assert peaks["backend"] == "tpu"
         assert peaks["peak_flops"] == 197e12
         assert peaks["peak_hbm_bytes_per_s"] == 819e9
         assert peaks["ridge_flops_per_byte"] == pytest.approx(240.5, rel=1e-3)
+
+    def test_peak_table_is_keyed_by_exact_device_kind(self):
+        """ONE table (utils.flops.DEVICE_PEAKS) keyed by the exact
+        device_kind the chip reports: the published v5e peaks resolve, a
+        substring of a known kind does not, the CPU has no peak."""
+        from paddle_tpu.core import NotFoundError
+        from paddle_tpu.utils import flops
+
+        v5e = _FakeDevice()  # "TPU v5 lite", what a v5e reports
+        assert flops.device_peak_flops(v5e) == 197e12
+        assert flops.device_peak_flops(v5e, dtype="int8") == 393e12
+        peaks = costs.backend_peaks(v5e)
+        assert peaks["peak_flops"] == 197e12
+        assert peaks["peak_hbm_bytes_per_s"] == 819e9
+        assert costs.roofline(1e12, 1e3, v5e)["verdict"] == "compute_bound"
+        assert costs.roofline(1e3, 1e12, v5e)["verdict"] == "hbm_bound"
+        for kind in ("TPU v5", "tpu v5 lite", "TPU v5 lite pod", "TPU v99"):
+            with pytest.raises(NotFoundError, match="no published peaks"):
+                flops.device_peaks(_FakeDevice(device_kind=kind))
+        cpu = _FakeDevice(platform="cpu", device_kind="cpu")
+        assert flops.device_peaks(cpu) is None
+        assert flops.device_peak_flops(cpu) is None
+        assert costs.backend_peaks(cpu) is None
+        assert costs.roofline(1e12, 1e3, cpu)["verdict"] == "unknown"
 
     def test_derive_mfu_from_ledger_not_caller_estimate(self,
                                                         monkeypatch):

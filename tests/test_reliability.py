@@ -33,7 +33,7 @@ from paddle_tpu.serving import KVHandoff
 from paddle_tpu.serving_router import (LocalReplica, Router, SLOPolicy,
                                        _trace_headers, spawn_replicas)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from replica_spec import SPEC, worker_env
 
 
 @pytest.fixture(autouse=True)
@@ -599,13 +599,6 @@ def test_arena_expires_queued_and_slot_resident_requests_typed():
 # stage via -m chaos)
 # ---------------------------------------------------------------------------
 
-def _worker_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    return env
-
-
 @pytest.mark.chaos
 def test_retry_budget_exhaustion_is_deterministic_e2e():
     """Every dispatch fails (seeded injector, no schedule = broken
@@ -641,9 +634,9 @@ def test_sigstop_worker_quarantined_hedge_completes_sigcont_restores(
     the survivor and every request completes within its deadline with
     the retry budget intact. SIGCONT + cooldown: the half-open probe
     restores the victim to rotation."""
-    reps = spawn_replicas("bench:_router_replica_spec", 2,
+    reps = spawn_replicas(SPEC, 2,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     for rep in reps:
         rep.timeout_s = 3.0  # bound every blocked hop on the victim
     r = Router(reps, poll_interval_s=0.05, health_fails=100,

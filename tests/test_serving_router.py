@@ -4,14 +4,12 @@ shedding, liveness/readiness split, and replica-death failover.
 
 Three tiers: deterministic unit tests over stub replicas (no jax work),
 an in-process e2e over real tiny-GPT replicas, and slow-marked
-subprocess chaos/bench e2e (SIGKILL mid-stream; the open-loop Poisson
-A/B gate). Green-field vs the reference (one-request-at-a-time
-predictor, no cross-replica routing)."""
+subprocess chaos e2e (SIGKILL mid-stream). Green-field vs the reference
+(one-request-at-a-time predictor, no cross-replica routing)."""
 
 import json
 import os
 import signal
-import sys
 import threading
 import time
 import urllib.error
@@ -29,7 +27,7 @@ from paddle_tpu.serving_router import (HttpReplica, LocalReplica,
                                        NoReplicasError, RequestShedError,
                                        Router, SLOPolicy, spawn_replicas)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from replica_spec import SPEC, worker_env
 
 
 @pytest.fixture(autouse=True)
@@ -592,22 +590,15 @@ def test_router_e2e_disaggregated_matches_solo():
 # Subprocess e2e: worker processes over HTTP (chaos tier)
 # ---------------------------------------------------------------------------
 
-def _worker_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    return env
-
-
 @pytest.mark.slow
 def test_routed_profilez_one_capture_per_process(tmp_path):
     """POST /profilez against a routed 2-worker fleet: the router's
     fan-out returns one REAL XPlane capture per process (router + both
     workers, three distinct pids), and a worker mid-capture answers a
     second direct POST with 409 (one concurrent capture per process)."""
-    reps = spawn_replicas("bench:_router_replica_spec", 2,
+    reps = spawn_replicas(SPEC, 2,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, poll_interval_s=0.05)
     try:
         body = json.dumps({"duration_ms": 300}).encode()
@@ -656,9 +647,9 @@ def test_routed_profilez_one_capture_per_process(tmp_path):
 def test_two_replica_http_router_smoke(tmp_path):
     """The ci.sh 'router smoke' stage body: 2 worker processes, real
     HTTP submit/drain, health+readiness probes, /podz-style fan-out."""
-    reps = spawn_replicas("bench:_router_replica_spec", 2,
+    reps = spawn_replicas(SPEC, 2,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, poll_interval_s=0.05)
     try:
         hz = reps[0].healthz()
@@ -686,9 +677,9 @@ def test_sigkill_replica_mid_stream_retries_on_survivor(tmp_path):
     the surviving replica, and NO request is lost. Killing the last
     replica yields the typed NoReplicasError. FaultInjector seeds the
     kill point (the 2nd drain poll of the victim) deterministically."""
-    reps = spawn_replicas("bench:_router_replica_spec", 2,
+    reps = spawn_replicas(SPEC, 2,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, poll_interval_s=0.05, health_fails=2)
     try:
         ts = [router.submit(_prompt(8 + i, 60 + i), 24)
@@ -717,58 +708,3 @@ def test_sigkill_replica_mid_stream_retries_on_survivor(tmp_path):
             router.submit(_prompt(5, 98), 4)
     finally:
         router.close(replicas=True)
-
-
-# ---------------------------------------------------------------------------
-# The acceptance bench gate (deterministic seeds; slow tier)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_router_bench_gate():
-    """ISSUE 10 acceptance: under a seeded Poisson open-loop load with
-    long prompts mixed in, disaggregated routed serving improves p99
-    TTFT vs the single-replica monolithic baseline at no-worse
-    aggregate tok/s, and the SLO shed policy keeps p99 TTFT bounded
-    under 2x overload (sheds absorb the excess) instead of queue
-    collapse."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    # best-of-3: the arms interleave to cancel machine-load drift, but
-    # a 2-core CI box right after the chaos e2e (worker teardown, cold
-    # jit caches) can still lose a run to scheduler noise — a perf
-    # gate may re-measure, it may not move its bar. The settle pause
-    # lets preceding tests' teardown threads drain first.
-    time.sleep(2.0)
-    for attempt in range(3):
-        value, unit, extras = bench.bench_gpt_router(
-            8, 0, smoke=True, replicas=1, prefill_workers=1)
-        if extras["ttft_short_mean_ms"] < \
-                extras["mono_ttft_short_mean_ms"]:
-            break
-    assert unit == "tokens/sec"
-    # all three headline numbers ride the JSON line
-    for key in ("ttft_p50_ms", "ttft_p99_ms", "itl_p99_ms",
-                "shed_rate", "overload_shed_rate",
-                "overload_ttft_p99_ms", "mono_ttft_p99_ms"):
-        assert key in extras, key
-    # TTFT win where disaggregation is structural: SHORT requests stop
-    # waiting behind someone else's monolithic prefill. Gated on the
-    # MEAN short TTFT — at 85% utilization the mono penalty hits many
-    # shorts, and a mean averages the CPU-scheduler noise that a
-    # 12-sample p99 (= max of two separately-timed arms) cannot. The
-    # p99s and the ITL p99 ride the JSON line ungated: the all-request
-    # p99 is long-prompt-dominated (a long's own TTFT is prefill-bound
-    # in BOTH arms) and the ITL ordering is contention-sensitive on a
-    # 2-core box (mono concentrates the stall into one big gap; disagg
-    # spreads overlap cost across ticks).
-    assert extras["ttft_short_mean_ms"] < \
-        extras["mono_ttft_short_mean_ms"], extras
-    # ... at equal-or-better aggregate tok/s
-    assert value >= 0.85 * extras["mono_tokps"], extras
-    # shed policy engaged under overload and kept the tail bounded
-    # (without it the queue grows without bound at 2x capacity)
-    assert extras["overload_shed_rate"] > 0.02, extras
-    assert extras["overload_ttft_p99_ms"] < \
-        5 * max(extras["ttft_p99_ms"], extras["mono_ttft_p99_ms"]), \
-        extras

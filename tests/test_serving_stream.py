@@ -6,13 +6,11 @@ arena warmup path.
 
 Tiers mirror test_serving_router.py: pure-python TokenStream units,
 deterministic stub-replica router logic, real tiny-GPT mid e2es, and
-slow+chaos subprocess e2es (SIGKILL mid-stream; the streaming bench
-gate)."""
+slow+chaos subprocess e2es (SIGKILL mid-stream)."""
 
 import json
 import os
 import signal
-import sys
 import threading
 import time
 import urllib.request
@@ -27,6 +25,8 @@ from paddle_tpu.serving import BatchedDecoder, KVHandoff, TokenStream
 from paddle_tpu.serving_router import (LocalReplica, NoReplicasError,
                                        Router, prefix_hash,
                                        spawn_replicas)
+
+from replica_spec import SPEC, worker_env
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -662,15 +662,8 @@ class TestLint307:
 
 
 # ---------------------------------------------------------------------------
-# Subprocess e2es (chaos tier) + the streaming bench gate
+# Subprocess e2es (chaos tier)
 # ---------------------------------------------------------------------------
-
-def _worker_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    return env
-
 
 @pytest.mark.slow
 @pytest.mark.chaos
@@ -679,9 +672,9 @@ def test_stream_smoke_two_worker_token_incremental(tmp_path):
     across 2 REAL worker processes arrives token-incrementally (per-
     token-flushed SSE: distinct, increasing arrival stamps) and
     matches the completion result exactly."""
-    reps = spawn_replicas("bench:_router_replica_spec", 2,
+    reps = spawn_replicas(SPEC, 2,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, poll_interval_s=0.05)
     try:
         ts = [router.submit(_prompt(8 + i, 80 + i), 6,
@@ -714,9 +707,9 @@ def test_sigkill_mid_stream_typed_resume_same_trace(tmp_path):
     no token delivered before the kill, and the resumed stream must
     complete with exactly the request's full token sequence."""
     telemetry.enable()
-    reps = spawn_replicas("bench:_router_replica_spec", 2,
+    reps = spawn_replicas(SPEC, 2,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, poll_interval_s=0.05, health_fails=2)
     try:
         t = router.submit(_prompt(8, 90), 40, stream=True)
@@ -767,9 +760,9 @@ def test_all_down_mid_stream_typed_error(tmp_path):
     """Killing the LAST replica mid-stream surfaces the typed error
     record on the stream (bounded time) and the ticket raises
     NoReplicasError — a client never sees a silent stall."""
-    reps = spawn_replicas("bench:_router_replica_spec", 1,
+    reps = spawn_replicas(SPEC, 1,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
+                          log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, poll_interval_s=0.05, health_fails=2)
     try:
         t = router.submit(_prompt(8, 91), 40, stream=True)
@@ -795,46 +788,3 @@ def test_all_down_mid_stream_typed_error(tmp_path):
             t.wait(timeout=60)
     finally:
         router.close(replicas=True)
-
-
-@pytest.mark.slow
-def test_stream_bench_gate():
-    """ISSUE 13 acceptance: the streaming arms of `bench.py gpt_serve
-    --router --stream` — streaming p99 TTFT no worse than the
-    non-streaming routed arm at equal load, streaming ITL p99
-    reported and structurally bounded, and the shared-system-prompt
-    workload showing prefix-hash routing with a STRICTLY higher
-    prefix-cache hit rate than session-only affinity (counter-verified
-    from pool stats)."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    time.sleep(2.0)
-    for attempt in range(3):
-        value, unit, extras = bench.bench_gpt_router(
-            8, 0, smoke=True, replicas=1, prefill_workers=1,
-            stream=True)
-        if extras["stream_ttft_p99_ms"] <= extras["ttft_p99_ms"]:
-            break
-    assert unit == "tokens/sec"
-    for key in ("stream_ttft_p50_ms", "stream_ttft_p99_ms",
-                "stream_itl_p99_ms", "stream_tokps",
-                "prefix_hit_rate_hash", "prefix_hit_rate_session",
-                "prefix_hits_hash", "prefix_lookups_hash"):
-        assert key in extras, key
-    # streaming must not cost first-token latency: its TTFT is the
-    # first-token edge, the non-streaming arm's is completion-derived
-    assert extras["stream_ttft_p99_ms"] <= extras["ttft_p99_ms"], \
-        extras
-    # ITL under streaming: reported, non-degenerate, and bounded near
-    # the fleet's per-token cadence (a stalled fan-in would blow this)
-    assert extras["stream_itl_p99_ms"] > 0
-    assert extras["stream_itl_p99_ms"] <= 5 * max(
-        extras["itl_p99_ms"], extras["mono_itl_p99_ms"]), extras
-    # prefix-hash routing beats session-only affinity STRICTLY, and
-    # the counts are the pool's own (deterministic by construction:
-    # one miss per prefix vs one miss per (replica, prefix))
-    assert extras["prefix_hit_rate_hash"] > \
-        extras["prefix_hit_rate_session"], extras
-    assert extras["prefix_hits_hash"] >= \
-        extras["prefix_lookups_hash"] - 3

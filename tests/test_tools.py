@@ -198,89 +198,3 @@ class TestCommReport:
         # async pair counted ONCE, at the -done payload
         assert got["collective-permute"] == (1, 4 * 4 * 4)
         assert "add" not in got and len(got) == 2
-
-
-class TestBenchDiff:
-    """tools/bench_diff.py: session-vs-history comparator (newest row
-    wins, degraded/skipped rows excluded, variant-tier baselines, exit
-    codes)."""
-
-    def _bd(self):
-        from conftest import load_tool
-
-        return load_tool("bench_diff")
-
-    def test_newest_row_per_metric_wins(self):
-        bd = self._bd()
-        rows = bd.parse_lines(
-            'not json\n'
-            '{"metric": "tp", "value": 10.0}\n'
-            '{"metric": "tp", "value": 20.0}\n'
-            '{"no_metric": 1}\n')
-        assert rows == {"tp": {"metric": "tp", "value": 20.0}}
-
-    def test_exclusion_reasons(self):
-        bd = self._bd()
-        assert bd.exclude_reason({"value": 1.0}) is None
-        assert bd.exclude_reason(
-            {"value": 1.0, "backend_degraded": True}) \
-            == "backend_degraded"
-        assert bd.exclude_reason(
-            {"skipped": True, "cause": "no_chip"}) == "skipped:no_chip"
-        assert bd.exclude_reason({"value": 1.0, "error": "x"}) == "error"
-        assert bd.exclude_reason({"value": "n/a"}) == "no_value"
-
-    def test_baseline_prefers_bare_key_then_best_variant(self):
-        bd = self._bd()
-        hist = {"a": {"value": 5.0}, "b@h1": {"value": 3.0},
-                "b@h1@tpu": {"value": 7.0}, "legacy": 2.5}
-        assert bd.baseline_for("a", hist) == 5.0
-        assert bd.baseline_for("b", hist) == 7.0  # best variant tier
-        assert bd.baseline_for("legacy", hist) == 2.5  # bare float
-        assert bd.baseline_for("nope", hist) is None
-
-    def test_diff_report_and_threshold(self):
-        bd = self._bd()
-        rows = {
-            "ok": {"metric": "ok", "value": 95.0},
-            "bad": {"metric": "bad", "value": 50.0},
-            "deg": {"metric": "deg", "value": 1.0,
-                    "backend_degraded": True},
-            "fresh": {"metric": "fresh", "value": 1.0},
-        }
-        hist = {"ok": {"value": 100.0}, "bad": {"value": 100.0},
-                "deg": {"value": 100.0}}
-        rep = bd.diff(rows, hist, threshold=0.10)
-        assert rep["regressions"] == ["bad"]
-        assert [e["metric"] for e in rep["excluded"]] == ["deg"]
-        assert rep["new"] == ["fresh"]
-        ok = next(c for c in rep["compared"] if c["metric"] == "ok")
-        assert ok["delta_pct"] == -5.0 and not ok["regressed"]
-        # render never raises and names the regression
-        assert "REGRESSED" in bd.render(rep)
-
-    def test_cli_exit_codes(self, tmp_path):
-        import subprocess
-        import sys
-
-        hist = tmp_path / "h.json"
-        hist.write_text(json.dumps({"tp": {"value": 100.0}}))
-        sess = tmp_path / "s.log"
-        sess.write_text('{"metric": "tp", "value": 99.0, "unit": "x"}\n')
-        cmd = [sys.executable,
-               os.path.join(REPO, "tools", "bench_diff.py")]
-        r = subprocess.run(
-            cmd + [str(sess), "--history", str(hist)],
-            capture_output=True, text=True, timeout=60)
-        assert r.returncode == 0, r.stdout + r.stderr
-        sess.write_text('{"metric": "tp", "value": 50.0, "unit": "x"}\n')
-        r = subprocess.run(
-            cmd + [str(sess), "--history", str(hist),
-                   "--format", "json"],
-            capture_output=True, text=True, timeout=60)
-        assert r.returncode == 1
-        assert json.loads(r.stdout)["regressions"] == ["tp"]
-        r = subprocess.run(
-            cmd + [str(tmp_path / "missing.log")],
-            capture_output=True, text=True, timeout=60)
-        assert r.returncode == 2

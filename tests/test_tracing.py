@@ -30,6 +30,8 @@ from paddle_tpu.serving_router import (LocalReplica, Router,
                                        spawn_replicas)
 from paddle_tpu.telemetry import tracing
 
+from replica_spec import SPEC, worker_env
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -575,13 +577,6 @@ def test_zero_tracing_code_when_disabled(monkeypatch):
 # (the ci.sh "trace smoke" stage; acceptance criterion)
 # ---------------------------------------------------------------------------
 
-def _worker_env():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    return env
-
-
 @pytest.mark.slow
 @pytest.mark.chaos
 def test_trace_smoke_two_process_merged_trace(tmp_path):
@@ -592,12 +587,12 @@ def test_trace_smoke_two_process_merged_trace(tmp_path):
     single trace id, with clock-aligned wall timestamps; the TTFT
     histogram's top bucket carries that trace id as an exemplar."""
     telemetry.enable()
-    reps = spawn_replicas("bench:_router_replica_spec", 2,
+    reps = spawn_replicas(SPEC, 2,
                           spec_kw={"smoke": True},
-                          log_dir=str(tmp_path), env=_worker_env())
-    pfs = spawn_replicas("bench:_router_replica_spec", 1,
+                          log_dir=str(tmp_path), env=worker_env())
+    pfs = spawn_replicas(SPEC, 1,
                          role="prefill", spec_kw={"smoke": True},
-                         log_dir=str(tmp_path), env=_worker_env())
+                         log_dir=str(tmp_path), env=worker_env())
     router = Router(reps, prefill_workers=pfs, disagg_min_tokens=32,
                     poll_interval_s=0.05)
     srv = router.start_server(port=0)

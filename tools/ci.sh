@@ -60,33 +60,6 @@ dtype keys)"
       -q -k "quantized_kernel or gather_upto" || exit $?
     JAX_PLATFORMS=cpu python -m pytest tests/test_pallas_decode.py \
       -q -k "dtype_key" || exit $?
-    # bench diff smoke: the session-vs-history comparator on a crafted
-    # 3-row session — a clean row compares, a >10% drop sets exit 1,
-    # and a degraded row is EXCLUDED (it must fail loudly here before it
-    # misreads a real session)
-    stage "bench diff smoke (tools/bench_diff.py on crafted rows)"
-    JAX_PLATFORMS=cpu python -c "
-import json, subprocess, sys, tempfile, os
-hist = {'a_tp': {'value': 100.0}, 'b_tp': {'value': 100.0},
-        'c_tp': {'value': 100.0}}
-rows = '\n'.join(json.dumps(r) for r in [
-    {'metric': 'a_tp', 'value': 99.0, 'unit': 'x/s', 'backend': 'tpu'},
-    {'metric': 'b_tp', 'value': 50.0, 'unit': 'x/s', 'backend': 'tpu'},
-    {'metric': 'c_tp', 'value': 40.0, 'unit': 'x/s',
-     'backend': 'cpu', 'backend_degraded': True}])
-with tempfile.TemporaryDirectory() as d:
-    hp, sp = os.path.join(d, 'h.json'), os.path.join(d, 's.log')
-    open(hp, 'w').write(json.dumps(hist))
-    open(sp, 'w').write(rows)
-    p = subprocess.run([sys.executable, 'tools/bench_diff.py', sp,
-                        '--history', hp, '--format', 'json'],
-                       capture_output=True, text=True)
-    rep = json.loads(p.stdout)
-    assert p.returncode == 1, p.returncode     # b_tp regressed
-    assert rep['regressions'] == ['b_tp'], rep
-    assert [e['metric'] for e in rep['excluded']] == ['c_tp'], rep
-print('bench diff smoke ok')
-" || exit $?
     ;;
 esac
 
@@ -127,7 +100,7 @@ ONE merged cross-process chrome-trace with a shared trace id)"
       -q -m chaos || exit $?
     stage "scaler smoke (recorded-trace policy replay bit-identity + \
 one spawn/retire e2e on real in-process replicas; the SIGKILL chaos \
-pair and the spike A/B bench gate ride the full suite only)"
+pair rides the full suite only)"
     JAX_PLATFORMS=cpu python -m pytest tests/test_autoscale.py \
       -q -k "replay or spawn_retire_e2e" || exit $?
     stage "reliability smoke (SIGSTOP a worker mid-stream -> gray \
@@ -153,9 +126,6 @@ test_dist_smoke_agreement_and_step_agreed_save" -q || exit $?
     stage "multichip dryrun (8-device CPU sim)"
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python -c "import __graft_entry__ as g; g.dryrun_multichip(8)" \
-      || exit $?
-    stage "bench smoke"
-    python bench.py --platform cpu --smoke --steps 4 --batch-size 64 \
       || exit $?
     ;;
   wheel)

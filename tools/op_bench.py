@@ -11,7 +11,8 @@ Array-valued entries in "args" are materialized with normal noise of that
 shape. Prints one JSON line per case: {"op", "forward_ms", "grad_ms",
 "repeat"}.
 
-Timing uses the host-fetch fence (see bench.py).
+Timing uses the host-fetch fence: a scalar device-to-host read of the
+output, since ``block_until_ready`` alone may return early.
 
 Usage:
   python tools/op_bench.py --config cases.json
