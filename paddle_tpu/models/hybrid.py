@@ -253,7 +253,7 @@ class HybridForCausalLM(Layer):
         self.norm_f = nn.RMSNorm(cfg.hidden_size,
                                  epsilon=cfg.rms_norm_eps)
         self.cache_kinds = [blk.state_kind for blk in self.blocks]
-        self._expert_tokens = None
+        self._expert_tokens = self._expert_dense_layers = None
 
     def init_cache(self, batch: int, capacity: int, dtype=None):
         """One pytree a block, every leaf with the sequence (slot) axis
@@ -264,9 +264,12 @@ class HybridForCausalLM(Layer):
     def step_counters(self):
         """What the latest cached call counted, for the program that
         made the call to return: ``expert_tokens`` (held,) int32, the
-        (token, pick) pairs each held expert got, summed over blocks.
-        Valid only inside the trace of that call."""
-        return {"expert_tokens": self._expert_tokens}
+        (token, pick) pairs each held expert got, summed over blocks;
+        ``expert_dense_layers`` int32, the expert layers of the call
+        whose rows took the dense body of ``nn.moe.dropless_moe`` (the
+        trace fixes it). Valid only inside the trace of that call."""
+        return {"expert_tokens": self._expert_tokens,
+                "expert_dense_layers": self._expert_dense_layers}
 
     def _embed(self, ids):
         e = self.embed(ids)
@@ -312,6 +315,9 @@ class HybridForCausalLM(Layer):
             tokens = tokens + got
             new_caches.append(cache)
         self._expert_tokens = tokens
+        rows = x.shape[0] * x.shape[1]
+        self._expert_dense_layers = jnp.int32(sum(
+            blk.moe.streams_densely(rows) for blk in self.blocks))
         return (self._head(x) if head else None), new_caches
 
     def _chunk_logits(self, toks, caches, t0, head: bool = True,

@@ -167,6 +167,78 @@ class SwitchFFN(Layer):
         return y.reshape(b, t, d)
 
 
+# Rows up to which the routed experts run as dense products over every
+# held expert. The dense body reads each held weight once and does
+# ``rows`` FLOPs a byte read, which hide under the stream while ``rows``
+# is well below the chip's ridge (v5e: 197e12 / 819e9 = 240 FLOP a
+# byte): half the ridge. The grouped body does top_k / E of that work
+# behind a sort, in kernels tiled for groups of hundreds of rows.
+# PERF.md section 3 has the chip timings of both bodies on either side
+# of this constant, and what they say about moving it.
+DENSE_MAX_ROWS = 128
+
+
+def streams_densely(rows: int, top_k: int, experts: int) -> bool:
+    """Whether :func:`dropless_moe` takes its dense body for ``rows``
+    tokens that each pick ``top_k`` of ``experts`` (static counts: the
+    trace fixes them). Under one pair an expert (``rows * top_k <
+    experts``: the lone last token of a prefill) most held experts get
+    no row; the grouped body skips those and the dense one would read
+    them all."""
+    return experts <= rows * top_k and rows <= DENSE_MAX_ROWS
+
+
+def _experts_dense(x, w_gate, w_up, w_down, local, gates):
+    """Every token through every held expert, weighted by ``gate (S,
+    held)``: the softmax weight of the pick that chose the expert and
+    exactly 0.0 elsewhere, so the terms are the picked pairs' and no
+    other. The weights are read once, in place: no sort, no gather."""
+    f32 = jnp.float32
+    held = w_gate.shape[0]
+    with jax.named_scope("moe_route"):
+        # a pick on an expert that is not held matches no column
+        hit = local[:, :, None] == jnp.arange(held, dtype=local.dtype)
+        gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)
+    with jax.named_scope("moe_experts"):
+        xs = x.astype(w_gate.dtype)
+        h = (jax.nn.silu(jnp.einsum("sd,edf->esf", xs, w_gate,
+                                    preferred_element_type=f32))
+             * jnp.einsum("sd,edf->esf", xs, w_up,
+                          preferred_element_type=f32))
+        out = jnp.einsum("esf,efd->esd", h.astype(w_down.dtype), w_down,
+                         preferred_element_type=f32)
+        return jnp.einsum("esd,se->sd", out, gate)
+
+
+def _experts_grouped(x, w_gate, w_up, w_down, local, gates, here, sizes):
+    """The (token, pick) pairs sorted by expert, through three grouped
+    matmuls (``lax.ragged_dot``) over the held experts, gathered back
+    and weighted."""
+    f32 = jnp.float32
+    s, top_k = local.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("moe_route"):
+        # pairs on absent experts sort last, into a group no weight has
+        group = jnp.where(here, local, held).reshape(-1)   # (S k,)
+        order = jnp.argsort(group, stable=True)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(s * top_k, dtype=order.dtype))
+    with jax.named_scope("moe_experts"):
+        xs = x[order // top_k].astype(w_gate.dtype)        # (S k, D)
+        h = (jax.nn.silu(jax.lax.ragged_dot(
+            xs, w_gate, sizes, preferred_element_type=f32))
+            * jax.lax.ragged_dot(xs, w_up, sizes,
+                                 preferred_element_type=f32))
+        out = jax.lax.ragged_dot(h.astype(w_down.dtype), w_down, sizes,
+                                 preferred_element_type=f32)
+        # rows past the last group belong to no held expert
+        out = jnp.where((jnp.arange(s * top_k) < jnp.sum(sizes))[:, None],
+                        out, 0)
+        picked = out[back].reshape(s, top_k, -1)
+        return jnp.einsum("skd,sk->sd", picked,
+                          jnp.where(here, gates, 0.0))
+
+
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                  experts_held=None):
     """Dropless top-k gated experts over tokens, for the experts held
@@ -179,16 +251,22 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     rule ``"topk_softmax"``: the ``top_k`` largest router logits a token
     (float32; ties broken as ``lax.top_k`` breaks them, lowest index
     first), gates = softmax over those ``top_k`` logits. No capacity:
-    every (token, pick) pair that falls on a held expert is computed.
-    The pairs are sorted by expert and the gated product
-    ``(silu(x Wg) * (x Wu)) Wd`` runs as grouped matmuls
-    (``lax.ragged_dot``) over the held experts; a pair on an expert
-    that is not held adds nothing (its part of the result belongs to
-    the chip that holds it; nothing stands in for that chip here).
+    every (token, pick) pair that falls on a held expert is computed,
+    as ``gate * (silu(x Wg) * (x Wu)) Wd``; a pair on an expert that is
+    not held adds nothing (its part of the result belongs to the chip
+    that holds it; nothing stands in for that chip here).
+
+    Two bodies give those terms, chosen by the static shapes alone
+    (:func:`streams_densely`): few rows that still reach every expert
+    (a decode step) go through every held expert as dense products with
+    a gate of 0.0 where an expert was not picked; many rows (a prefill)
+    or very few (its last token) are sorted by expert and run as
+    grouped matmuls. Both run in the WEIGHTS' type with
+    float32 sums: the tokens are cast to it, never the experts (a
+    float32 copy of bfloat16 experts would be written out whole a call).
 
     Returns (y (S, D) in x's dtype, tokens (held,) int32: the pairs
     each held expert got)."""
-    s = x.shape[0]
     e = router_w.shape[1]
     first, held = (0, e) if experts_held is None else experts_held
     with jax.named_scope("moe_route"):
@@ -197,30 +275,13 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         gates = jax.nn.softmax(top_l, axis=-1)
         local = top_i - first
         here = (local >= 0) & (local < held)
-        # pairs on absent experts sort last, into a group no weight has
-        group = jnp.where(here, local, held).reshape(-1)   # (S k,)
-        order = jnp.argsort(group, stable=True)
-        sizes = jnp.bincount(group, length=held + 1)[:held].astype(
-            jnp.int32)
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(s * top_k, dtype=order.dtype))
-    with jax.named_scope("moe_experts"):
-        # the grouped product runs in the WEIGHTS' type with float32
-        # sums: the tokens are cast to it, never the experts (a float32
-        # copy of bfloat16 experts would be written out whole a call)
-        f32 = jnp.float32
-        xs = x[order // top_k].astype(w_gate.dtype)        # (S k, D)
-        h = (jax.nn.silu(jax.lax.ragged_dot(
-            xs, w_gate, sizes, preferred_element_type=f32))
-            * jax.lax.ragged_dot(xs, w_up, sizes,
-                                 preferred_element_type=f32))
-        out = jax.lax.ragged_dot(h.astype(w_down.dtype), w_down, sizes,
-                                 preferred_element_type=f32)
-        # rows past the last group belong to no held expert
-        out = jnp.where((jnp.arange(s * top_k) < jnp.sum(sizes))[:, None],
-                        out, 0)
-        picked = out[back].reshape(s, top_k, -1)
-        y = jnp.einsum("skd,sk->sd", picked, jnp.where(here, gates, 0.0))
+        sizes = jnp.bincount(jnp.where(here, local, held).reshape(-1),
+                             length=held + 1)[:held].astype(jnp.int32)
+    if streams_densely(x.shape[0], top_k, e):
+        y = _experts_dense(x, w_gate, w_up, w_down, local, gates)
+    else:
+        y = _experts_grouped(x, w_gate, w_up, w_down, local, gates, here,
+                             sizes)
     return y.astype(x.dtype), sizes
 
 
@@ -235,7 +296,8 @@ class DroplessMoE(Layer):
     (``tests/test_hybrid.py``). A shared (always-on) MLP is the caller's.
 
     ``forward(x (..., D)) -> (..., D)``; ``forward_counted`` also
-    returns the (count,) int32 pairs each held expert got."""
+    returns the (count,) int32 pairs each held expert got;
+    ``streams_densely(rows)`` says which body that many rows take."""
 
     ROUTING = ("topk_softmax",)
 
@@ -272,6 +334,9 @@ class DroplessMoE(Layer):
 
     def forward(self, x):
         return self.forward_counted(x)[0]
+
+    def streams_densely(self, rows: int) -> bool:
+        return streams_densely(rows, self.top_k, self.num_experts)
 
 
 def expert_param_spec(axis: str = "ep"):

@@ -1,5 +1,9 @@
 """``nn.DroplessMoE`` / ``nn.moe.dropless_moe``: top-k routing with no
-capacity, the grouped product over the experts held here.
+capacity, over the experts held here, by both of its bodies: the dense
+products over every held expert that few rows take, and the sort and
+grouped product that many rows take. The rows alone choose
+(``moe.DENSE_MAX_ROWS``); a test that wants the other body for its 24
+rows moves that constant for its own duration, as nothing else can.
 
 The oracle is a loop over tokens and picks in numpy, at the published
 router shape (the 10 largest of 72 logits, softmax over those 10). Ties
@@ -16,10 +20,20 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import nn
 from paddle_tpu.core import EnforceError
+from paddle_tpu.nn import moe
 from paddle_tpu.nn.moe import dropless_moe
 
 S, D, F, E, K = 24, 16, 24, 72, 10
 TOL = dict(rtol=1e-5, atol=1e-5)
+BODIES = ["dense", "grouped"]
+
+
+@pytest.fixture
+def body(request, monkeypatch):
+    """Send this file's ``S`` rows through the named body."""
+    monkeypatch.setattr(moe, "DENSE_MAX_ROWS",
+                        S if request.param == "dense" else S - 1)
+    return request.param
 
 
 def weights(seed=0, ties=True):
@@ -36,6 +50,18 @@ def weights(seed=0, ties=True):
 
 def silu(a):
     return a / (1.0 + np.exp(-a))
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr, nested ones too."""
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub)
+
+
+def primitives(jaxpr):
+    return [eqn.primitive.name for eqn in equations(jaxpr)]
 
 
 def loop_oracle(w, first, count):
@@ -58,8 +84,9 @@ def loop_oracle(w, first, count):
     return y, tokens
 
 
+@pytest.mark.parametrize("body", BODIES, indirect=True)
 @pytest.mark.parametrize("held", [(0, 72), (0, 36), (36, 36), (30, 12)])
-def test_against_the_token_loop_with_ties(held):
+def test_against_the_token_loop_with_ties(held, body):
     w = weights()
     first, count = held
     sl = slice(first, first + count)
@@ -86,7 +113,29 @@ def test_ties_go_to_the_lowest_index():
         assert got == [2, 3, 40, 41][:len(got)]
 
 
-def test_the_shares_add_up_to_the_whole_layer():
+@pytest.mark.parametrize("body", BODIES, indirect=True)
+def test_a_tied_pick_counts_for_the_lowest_index_alone(body):
+    """Experts 2, 3, 40 and 41 have one logit a token. Held alone, 2
+    gets a pair from every token that picks any of them, and 41 from
+    those only that pick all four: in the result and in the count."""
+    w = weights()
+    logits = w["x"] @ w["router"]
+    picked = [sorted(range(E), key=lambda e: (-logits[s, e], e))[:K]
+              for s in range(S)]
+    for e in (2, 41):
+        y, tokens = dropless_moe(
+            jnp.asarray(w["x"]), jnp.asarray(w["router"]),
+            jnp.asarray(w["wg"][e:e + 1]), jnp.asarray(w["wu"][e:e + 1]),
+            jnp.asarray(w["wd"][e:e + 1]), top_k=K, experts_held=(e, 1))
+        rows = np.array([e in p for p in picked])
+        assert int(tokens[0]) == rows.sum()
+        np.testing.assert_array_equal(np.abs(np.asarray(y)).sum(-1) > 0,
+                                      rows)
+    assert all(2 in p for p in picked if 41 in p)
+
+
+@pytest.mark.parametrize("body", BODIES, indirect=True)
+def test_the_shares_add_up_to_the_whole_layer(body):
     w = weights(1, ties=False)
     args = lambda sl: (jnp.asarray(w["x"]), jnp.asarray(w["router"]),
                        jnp.asarray(w["wg"][sl]), jnp.asarray(w["wu"][sl]),
@@ -100,19 +149,68 @@ def test_the_shares_add_up_to_the_whole_layer():
     np.testing.assert_array_equal(np.concatenate([na, nb]), n)
 
 
-def test_bfloat16_weights_are_not_copied_up():
-    """The grouped product runs in the weights' type: tokens are cast
+@pytest.mark.parametrize("body", BODIES, indirect=True)
+def test_bfloat16_weights_are_not_copied_up(body):
+    """Either body's products run in the weights' type: tokens are cast
     down to it and the sums are float32. Against float32 weights the
-    result moves by bfloat16 rounding (1e-2), no more."""
+    result moves by bfloat16 rounding (some 1e-2 of its size), no
+    more."""
     w = weights(2, ties=False)
     lo = lambda a: jnp.asarray(a).astype(jnp.bfloat16)
     y_lo, _ = dropless_moe(jnp.asarray(w["x"]), lo(w["router"]),
                            lo(w["wg"]), lo(w["wu"]), lo(w["wd"]), top_k=K)
     assert y_lo.dtype == jnp.float32
-    jaxpr = str(jax.make_jaxpr(lambda *a: dropless_moe(*a, top_k=K))(
+    y_hi, _ = dropless_moe(*(jnp.asarray(w[k]) for k in
+                             ("x", "router", "wg", "wu", "wd")), top_k=K)
+    assert np.abs(y_lo - y_hi).max() < 0.05 * np.abs(y_hi).max()
+    jaxpr = jax.make_jaxpr(lambda *a: dropless_moe(*a, top_k=K))(
         jnp.asarray(w["x"]), lo(w["router"]), lo(w["wg"]), lo(w["wu"]),
-        lo(w["wd"])))
-    assert "f32[72,16,24]" not in jaxpr       # no float32 copy of experts
+        lo(w["wd"]))
+    # no expert weight is cast up: nothing of their shapes turns float32
+    assert not [eqn for eqn in equations(jaxpr)
+                if eqn.primitive.name == "convert_element_type"
+                and eqn.outvars[0].aval.dtype == jnp.float32
+                and eqn.outvars[0].aval.shape in ((E, D, F), (E, F, D))]
+    assert ("ragged_dot_general" in primitives(jaxpr)) == (
+        body == "grouped")
+
+
+@pytest.mark.parametrize("held", [(0, 72), (36, 36), (30, 12)])
+def test_both_bodies_count_the_same_tokens(held, monkeypatch):
+    w = weights(3)
+    first, count = held
+    sl = slice(first, first + count)
+    got = {}
+    for body, limit in (("dense", S), ("grouped", S - 1)):
+        monkeypatch.setattr(moe, "DENSE_MAX_ROWS", limit)
+        got[body] = dropless_moe(
+            jnp.asarray(w["x"]), jnp.asarray(w["router"]),
+            jnp.asarray(w["wg"][sl]), jnp.asarray(w["wu"][sl]),
+            jnp.asarray(w["wd"][sl]), top_k=K, experts_held=held)
+    np.testing.assert_array_equal(got["dense"][1], got["grouped"][1])
+    np.testing.assert_allclose(got["dense"][0], got["grouped"][0], **TOL)
+
+
+@pytest.mark.parametrize("rows,ragged", [
+    (1, 3), (7, 3), (8, 0), (32, 0), (moe.DENSE_MAX_ROWS, 0), (256, 3)])
+def test_the_rows_alone_choose_the_body(rows, ragged):
+    """A decode step's rows (32 slots in the serving cell) stream every
+    held expert densely: no grouped product and no sort of the pairs.
+    A prefill's rows (256 and up) are sorted into three grouped
+    products, and so is its lone last token, or any rows whose 10 picks
+    are fewer than the 72 experts: most held experts then get no row,
+    and only the grouped body skips them. Nothing but the static shapes
+    is asked."""
+    assert moe.streams_densely(rows, K, E) == (ragged == 0)
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    used = primitives(jax.make_jaxpr(
+        lambda *a: dropless_moe(*a, top_k=K, experts_held=(0, 36)))(
+            sds(rows, D), sds(D, E), sds(36, D, F), sds(36, D, F),
+            sds(36, F, D)))
+    assert used.count("ragged_dot_general") == ragged
+    # ``lax.top_k`` is a primitive of its own: a sort is the pairs' only
+    assert ("sort" in used) == (ragged > 0)
+    assert ("gather" in used) == (ragged > 0)
 
 
 def test_layer_checks_its_arguments():

@@ -8,8 +8,8 @@ a second time; the expert shares; the modes the arena refuses.
 
 Tolerance of every logits comparison, ``close``: both sides are float32
 and differ in the order of sums only (chunked scan against a position
-scan, grouped product against a masked loop, cached attention against a
-full one), over six blocks: some tens of float32 roundings, so 1e-4 of
+scan, dense or grouped products against a masked loop, cached attention
+against a full one), over six blocks: some tens of float32 roundings, so 1e-4 of
 the logits' standard deviation, absolute. What the arena could get
 wrong reads far above that: a state advanced over the bucket's padding,
 a last token applied twice or a state left from the slot's last request
@@ -208,11 +208,11 @@ def test_a_bfloat16_state_would_fail():
         check_wave(got, first, 9, params, dims_of(cfg))
 
 
-def test_served_tokens_are_the_references_best_over_reused_slots():
-    """The whole arena, end to end: seven requests over three slots, so
-    every slot serves a second and a third request; each served token is
-    the reference's best at its position (or within the tolerance of
-    it, where two logits all but tie)."""
+def serve_seven_over_three_slots():
+    """Seven requests over three slots, so every slot serves a second
+    and a third request; each served token is the reference's best at
+    its position (or within the tolerance of it, where two logits all
+    but tie). Returns (cfg, the drained decoder)."""
     cfg, model, params = build()
     dec = BatchedDecoder(model, slots=SLOTS, capacity=CAPACITY,
                          prompt_bucket=BUCKET)
@@ -226,11 +226,32 @@ def test_served_tokens_are_the_references_best_over_reused_slots():
         want = reference_logits(params, dims_of(cfg), full)[len(p) - 1:-1]
         took = want[np.arange(len(out[rid])), out[rid]]
         assert (want.max(-1) - took <= 1e-4 * want.std()).all()
+    return cfg, dec
+
+
+def test_served_tokens_are_the_references_best_over_reused_slots():
+    """The whole arena, end to end."""
+    cfg, dec = serve_seven_over_three_slots()
     # the step counted the pairs each held expert got, every row of it
     assert dec.counters.steps == dec.tick_count
     assert int(dec.counters.expert_tokens.sum()) == (
         dec.tick_count * SLOTS * cfg.experts_per_token
         * len(cfg.layer_types))
+
+
+@pytest.mark.parametrize("limit,dense", [(SLOTS, True), (SLOTS - 1, False)])
+def test_the_step_counts_the_expert_layers_that_streamed_densely(
+        limit, dense, monkeypatch):
+    """A decode step of as many rows as ``nn.moe.DENSE_MAX_ROWS`` takes
+    the dense body in every expert layer and says so beside the tokens;
+    one row more and every layer sorts and groups (the prefills here,
+    of 8 rows and more, with it), and the sum stays 0. The served tokens
+    are the reference's either way."""
+    monkeypatch.setattr(nn.moe, "DENSE_MAX_ROWS", limit)
+    cfg, dec = serve_seven_over_three_slots()
+    assert dec.counters.steps == dec.tick_count > 0
+    assert int(dec.counters.sums["expert_dense_layers"]) == (
+        len(cfg.layer_types) * dec.counters.steps if dense else 0)
 
 
 # --------------------------------------------------------------------------

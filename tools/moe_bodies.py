@@ -1,0 +1,94 @@
+#!/usr/bin/env python
+"""Time both bodies of ``nn.moe.dropless_moe`` on the chip, at the rows
+that surround the two ends of ``nn.moe.streams_densely``: the numbers
+that set its rule.
+
+One program a (body, rows) pair: ``--layers`` expert layers in a row,
+each the routed experts of one block at the given widths (default: the
+hybrid serving cell's share, 36 held of 72 experts of 4096 x 768, 10 a
+token, bfloat16), every layer's result fed to the next so that they run
+in order and each streams its weights from HBM again. Prints one JSON
+line a pair: ``ms_layer`` (host clock over fenced calls, a layer) and
+``roofline_pct`` (the held weights read once at the chip's HBM peak,
+over it). A body is forced by replacing the module's rule before the
+trace; the library itself has no switch.
+
+    chiprun -- python tools/moe_bodies.py [--rows 1,4,8,32,128,256,512]
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", default="1,4,8,32,128,256,512")
+    ap.add_argument("--widths", default="4096,768,72,36,10",
+                    help="D,F,experts,held,top_k")
+    ap.add_argument("--layers", type=int, default=10)
+    ap.add_argument("--calls", type=int, default=20)
+    args = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn import moe
+    from paddle_tpu.utils.flops import device_peaks
+
+    dev = jax.devices()[0]
+    peak = (device_peaks(dev) or {}).get("hbm_bytes_per_s")
+    d, f, e, held, k = (int(v) for v in args.widths.split(","))
+    keys = jax.random.split(jax.random.key(0), 5)
+    bf = jnp.bfloat16
+    rnd = lambda key, *shape: (jax.random.normal(key, shape, jnp.float32)
+                               * shape[-2] ** -0.5).astype(bf)
+    router, wg, wu, wd = (rnd(keys[0], d, e), rnd(keys[1], held, d, f),
+                          rnd(keys[2], held, d, f), rnd(keys[3], held, f, d))
+    weight_bytes = 2 * (wg.size + wu.size + wd.size)
+
+    def stack_fn():
+        # a function of its own a pair: jit's cache goes by the function
+        def stack(x, router, wg, wu, wd):
+            for _ in range(args.layers):
+                y, _ = moe.dropless_moe(x, router, wg, wu, wd, top_k=k,
+                                        experts_held=(0, held))
+                x = x + y
+            return x
+
+        return jax.jit(stack)
+
+    keep = moe.streams_densely
+    try:
+        for rows in (int(r) for r in args.rows.split(",")):
+            x = jax.random.normal(keys[4], (rows, d), jnp.float32).astype(bf)
+            for body in ("grouped", "dense"):
+                moe.streams_densely = lambda *_, b=body: b == "dense"
+                fn = stack_fn()
+                for _ in range(3):
+                    fn(x, router, wg, wu, wd).block_until_ready()
+                t0 = time.perf_counter()
+                for _ in range(args.calls):
+                    out = fn(x, router, wg, wu, wd)
+                out.block_until_ready()
+                ms = ((time.perf_counter() - t0) * 1e3
+                      / (args.calls * args.layers))
+                print(json.dumps({
+                    "device": dev.device_kind, "platform": dev.platform,
+                    "rows": rows, "body": body,
+                    "ms_layer": round(ms, 4),
+                    "roofline_pct": (round(
+                        100 * weight_bytes / peak / (ms * 1e-3), 2)
+                        if peak else None)}), flush=True)
+    finally:
+        moe.streams_densely = keep
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
