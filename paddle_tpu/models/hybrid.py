@@ -1,28 +1,49 @@
-"""Hybrid state-space / attention causal LM with routed experts.
+"""A causal-LM shell whose blocks are built from kinds: a token mixer
+and a channel mix a block, chosen by the configuration.
 
-A decoder whose blocks differ by index: ``layer_types[i]`` says whether
-block ``i`` mixes tokens by a Mamba-2 (SSD) state-space layer or by GQA
-attention without positional encoding, and every block, whichever its
-mixer, is followed by a dropless top-k expert layer plus one shared
-gated MLP. Three constant multipliers scale the embedding, each residual
-branch and the attention scores, and the logits are divided by a fourth
-(the muP-style parametrisation published with such models)::
+``layer_types[i]`` names block ``i``'s MIXER:
+
+- ``"mamba"``: a Mamba-2 (SSD) state-space layer (:class:`SSDMixer`);
+- ``"attention"``: GQA softmax attention without positional encoding
+  (``nn.MultiHeadAttention``);
+- ``"retention"``: power retention of degree 2, linear attention whose
+  score is the square of a dot product (:class:`RetentionMixer`), with
+  a per-head RMSNorm and a rotary embedding on queries and keys and
+  one learned gate a key-value head.
+
+``channel_mix`` names what follows the mixer in EVERY block:
+
+- ``"experts"``: a dropless top-k expert layer plus one shared gated
+  MLP (``nn.DroplessMoE`` + :class:`GatedMLP`), told which experts it
+  holds (``experts_held``), as one chip of an expert-parallel
+  deployment is;
+- ``"mlp"``: one gated MLP of width ``mlp_width`` (SwiGLU).
+
+Three constant multipliers scale the embedding, each residual branch
+and the attention scores, and the logits are divided by a fourth (the
+muP-style parametrisation published with some such models; all 1 by
+default); the head is the embedding transposed or, with
+``tie_embeddings=False``, a matrix of its own (``lm_head``)::
 
     h = E[ids] * embedding_multiplier
     h = h + residual_multiplier * Mixer(RMSNorm(h))
     u = RMSNorm(h)
-    h = h + residual_multiplier * (Experts(u) + Shared(u))
-    logits = RMSNorm(h) @ E^T / logits_scaling
+    h = h + residual_multiplier * ChannelMix(u)
+    logits = RMSNorm(h) @ W_head / logits_scaling
 
-What decoding keeps a sequence differs by block: an attention block
-keeps keys and values by position, (K, V) of shape (slots, capacity,
-kv_heads, head_dim); a state-space block keeps a state of fixed size,
+What decoding keeps a sequence differs by mixer: attention keeps keys
+and values by position, (K, V) of shape (slots, capacity, kv_heads,
+head_dim); a state-space block keeps a state of fixed size,
 (convolution tail (slots, conv - 1, channels), S (slots, heads,
-head_dim, state) float32). :meth:`HybridForCausalLM.init_cache` gives
-the list, one entry a block, and ``cache_kinds`` says which is which;
-``serving.BatchedDecoder`` holds it as its arena. The expert layer is
-told which experts it holds (``experts_held``), as one chip of an
-expert-parallel deployment is (``nn.DroplessMoE``).
+head_dim, state) float32); a retention block keeps (S (slots,
+kv_heads, D, head_dim), z (slots, kv_heads, D)) float32 with ``D`` =
+``ops.retention.phi_dim(head_dim)``, 34 MB a slot at head dimension
+128 whatever the context. :meth:`HybridForCausalLM.init_cache` gives
+the list, one entry a block, and ``cache_kinds`` says which is which
+(``"kv"``, addressed by position, or ``"recurrent"``);
+``serving.BatchedDecoder`` holds it as its arena. A recurrent mixer
+that needs positions (retention's rotary embedding) says so
+(``takes_positions``) and is handed the cursors attention is.
 """
 
 from __future__ import annotations
@@ -33,11 +54,16 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import initializer as I
 from .. import nn
 from ..core.dtypes import default_dtype
 from ..core.enforce import enforce
 from ..nn.layer import Layer
-from ..ops import ssm
+from ..ops import retention, ssm
+from ..ops.attention import rotary_embedding
+
+MIXERS = ("mamba", "attention", "retention")
+CHANNEL_MIXES = ("experts", "mlp")
 
 
 @dataclasses.dataclass
@@ -47,6 +73,8 @@ class HybridConfig:
     layer_types: Tuple[str, ...] = ("mamba", "attention")
     num_heads: int = 8
     num_kv_heads: Optional[int] = None
+    channel_mix: str = "experts"         # or "mlp": every block's
+    mlp_width: int = 0                   # the "mlp" channel mix's width
     expert_width: int = 256              # one routed expert's gated width
     shared_width: int = 512              # the always-on gated MLP's width
     num_experts: int = 8                 # the router's width
@@ -57,6 +85,11 @@ class HybridConfig:
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    rope_theta: float = 10000.0          # the retention mixer's rotary
+    retention_degree: int = 2            # the power of the score
+    retention_eps: float = 1e-6          # added to the sum of weights
+    retention_chunk: int = 128
+    tie_embeddings: bool = True          # False: a head of its own
     embedding_multiplier: float = 1.0
     attention_multiplier: Optional[float] = None     # None: 1/sqrt(hd)
     residual_multiplier: float = 1.0
@@ -77,6 +110,17 @@ class HybridConfig:
                    ssm_chunk=8, embedding_multiplier=12.0,
                    attention_multiplier=1.0 / 16, residual_multiplier=0.22,
                    logits_scaling=16.0)
+
+    @classmethod
+    def tiny_retention(cls, layers: int = 3):
+        """For tests: ``layers`` retention blocks with a gated MLP,
+        hidden 80, 10 q / 2 kv heads of 8 (five query heads a key-value
+        head, a state of 40 x 8 a head), MLP 96, an untied head."""
+        return cls(vocab_size=256, hidden_size=80,
+                   layer_types=("retention",) * layers, num_heads=10,
+                   num_kv_heads=2, channel_mix="mlp", mlp_width=96,
+                   retention_chunk=8, tie_embeddings=False,
+                   rms_norm_eps=1e-6)
 
 
 class GatedMLP(Layer):
@@ -193,18 +237,106 @@ class SSDMixer(Layer):
                                                      x.dtype))[0]
 
 
+class RetentionMixer(Layer):
+    """Power retention of degree 2 (``ops/retention.py``): q, k, v and
+    gate projections without biases; RMSNorm over each head of queries
+    and keys, then the rotary embedding; ``log g = logsigmoid(gate)``,
+    one a key-value head, float32; the state's update and read
+    (``retention_step``) or the chunked form over a sequence; an output
+    projection. The state and the denominators are float32."""
+
+    state_kind = "recurrent"
+    takes_positions = True
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__()
+        enforce(cfg.retention_degree == 2,
+                "power retention of degree %s is not written: the state "
+                "holds the symmetric square", cfg.retention_degree)
+        h, self.heads = cfg.hidden_size, cfg.num_heads
+        self.kv_heads = cfg.num_kv_heads or cfg.num_heads
+        self.head_dim = hd = h // self.heads
+        self.theta, self.eps = float(cfg.rope_theta), cfg.retention_eps
+        self.chunk = cfg.retention_chunk
+        self.q_proj = nn.Linear(h, self.heads * hd, bias_attr=False)
+        self.k_proj = nn.Linear(h, self.kv_heads * hd, bias_attr=False)
+        self.v_proj = nn.Linear(h, self.kv_heads * hd, bias_attr=False)
+        self.gate_proj = nn.Linear(h, self.kv_heads, bias_attr=False)
+        self.q_norm = nn.RMSNorm(hd, epsilon=cfg.rms_norm_eps)
+        self.k_norm = nn.RMSNorm(hd, epsilon=cfg.rms_norm_eps)
+        self.out_proj = nn.Linear(self.heads * hd, h, bias_attr=False)
+        self.small_norm = None
+
+    def init_cache(self, batch: int, capacity: int, dtype=None):
+        """(S, z) of a sequence that has seen no token, float32
+        whatever ``dtype``; ``capacity`` does not size it."""
+        return retention.zero_state(batch, self.kv_heads, self.head_dim)
+
+    def _project(self, x, positions):
+        """``x`` (B, S, hidden) at ``positions`` (S,) or (B, S) -> q (B,
+        S, H, d), k, v (B, S, KV, d), log g (B, S, KV) float32."""
+        b, s, _ = x.shape
+        q = self.q_norm(self.q_proj(x).reshape(b, s, self.heads,
+                                               self.head_dim))
+        k = self.k_norm(self.k_proj(x).reshape(b, s, self.kv_heads,
+                                               self.head_dim))
+        v = self.v_proj(x).reshape(b, s, self.kv_heads, self.head_dim)
+        log_g = jax.nn.log_sigmoid(self.gate_proj(x).astype(jnp.float32))
+        return (rotary_embedding(q, positions, self.theta),
+                rotary_embedding(k, positions, self.theta), v, log_g)
+
+    def _finish(self, y, x):
+        return self.out_proj(y.astype(x.dtype).reshape(*x.shape[:2], -1))
+
+    def forward_chunk(self, x, cache, t0, valid_len=None):
+        """``x`` (B, S, D) at positions [t0, t0 + S) continuing
+        ``cache``; only the first ``valid_len`` positions (default all)
+        advance it. Returns (out (B, S, D), new cache)."""
+        with jax.named_scope("retention_scan"):
+            q, k, v, log_g = self._project(
+                x, t0 + jnp.arange(x.shape[1]))
+            y, cache = retention.retention_chunked(
+                q, k, v, log_g, self.chunk, cache, valid_len, self.eps)
+            self.small_norm = jnp.int32(0)      # a chunk counts none
+            return self._finish(y, x), cache
+
+    def forward_step(self, x, cache, t_rows):
+        """One position a row at per-row positions ``t_rows`` (B,):
+        ``x`` (B, 1, D) -> (out (B, 1, D), new cache). ``small_norm``
+        then holds how many of the step's (row, head) denominators fell
+        under ``10 eps`` (an idle slot's junk row counted too)."""
+        with jax.named_scope("retention_step"):
+            q, k, v, log_g = self._project(x, t_rows[:, None])
+            num, den, cache = retention.retention_step_parts(
+                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], cache)
+            self.small_norm = jnp.sum(den < 10 * self.eps, dtype=jnp.int32)
+            y = num / (den[..., None] + self.eps)
+            return self._finish(y[:, None], x), cache
+
+    def forward(self, x):
+        return self.forward_chunk(
+            x, self.init_cache(x.shape[0], 0), 0)[0]
+
+
 class HybridBlock(Layer):
-    """h + m Mixer(norm(h)); then + m (Experts(u) + Shared(u)), u the
-    second norm. ``kind`` chooses the mixer."""
+    """h + m Mixer(norm(h)); then + m ChannelMix(u), u the second
+    norm. ``kind`` chooses the mixer, ``cfg.channel_mix`` what follows
+    it: routed experts plus a shared MLP (``moe``, ``shared``) or one
+    gated MLP (``mlp``)."""
 
     def __init__(self, cfg: HybridConfig, kind: str):
         super().__init__()
-        enforce(kind in ("mamba", "attention"),
-                "layer type %r is neither 'mamba' nor 'attention'", kind)
+        enforce(kind in MIXERS, "layer type %r is none of %s", kind,
+                MIXERS)
+        enforce(cfg.channel_mix in CHANNEL_MIXES,
+                "channel mix %r is none of %s", cfg.channel_mix,
+                CHANNEL_MIXES)
         self.kind, self.m = kind, float(cfg.residual_multiplier)
         self.norm1 = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
         if kind == "mamba":
             self.mixer = SSDMixer(cfg)
+        elif kind == "retention":
+            self.mixer = RetentionMixer(cfg)
         else:
             self.mixer = nn.MultiHeadAttention(
                 cfg.hidden_size, cfg.num_heads, bias=False,
@@ -212,6 +344,10 @@ class HybridBlock(Layer):
                 num_kv_heads=cfg.num_kv_heads or cfg.num_heads,
                 rotary=False, scale=cfg.attention_multiplier)
         self.norm2 = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
+        self.moe = None
+        if cfg.channel_mix == "mlp":
+            self.mlp = GatedMLP(cfg.hidden_size, cfg.mlp_width)
+            return
         self.moe = nn.DroplessMoE(
             cfg.hidden_size, cfg.expert_width, cfg.num_experts,
             cfg.experts_per_token, experts_held=cfg.experts_held)
@@ -222,9 +358,11 @@ class HybridBlock(Layer):
         return getattr(self.mixer, "state_kind", "kv")
 
     def channel_mix(self, x):
-        """(x + m (Experts(u) + Shared(u)), the (held,) tokens each held
-        expert got)."""
+        """(x + m ChannelMix(u), the (held,) tokens each held expert
+        got, or None where there are no experts)."""
         u = self.norm2(x)
+        if self.moe is None:
+            return x + self.m * self.mlp(u), None
         routed, tokens = self.moe.forward_counted(u)
         with jax.named_scope("moe_shared"):
             shared = self.shared(u)
@@ -232,16 +370,17 @@ class HybridBlock(Layer):
 
     def forward(self, x):
         h = self.norm1(x)
-        a = (self.mixer(h) if self.kind == "mamba"
-             else self.mixer(h, causal=True))
+        a = (self.mixer(h, causal=True) if self.kind == "attention"
+             else self.mixer(h))
         return self.channel_mix(x + self.m * a)[0]
 
 
 class HybridForCausalLM(Layer):
-    """Embedding -> blocks by ``cfg.layer_types`` -> RMSNorm -> tied
-    head. ``forward(ids)`` gives (B, T, V) logits from empty state; the
-    ``_chunk_logits`` / ``_step_logits`` / ``_step_logits_rows`` entries
-    are what ``serving.BatchedDecoder`` calls, over the cache list
+    """Embedding -> blocks by ``cfg.layer_types`` -> RMSNorm -> head
+    (the embedding's transpose, or ``lm_head``). ``forward(ids)`` gives
+    (B, T, V) logits from empty state; the ``_chunk_logits`` /
+    ``_step_logits`` / ``_step_logits_rows`` entries are what
+    ``serving.BatchedDecoder`` calls, over the cache list
     :meth:`init_cache` gives."""
 
     def __init__(self, cfg: HybridConfig):
@@ -252,31 +391,41 @@ class HybridForCausalLM(Layer):
                                     for kind in cfg.layer_types])
         self.norm_f = nn.RMSNorm(cfg.hidden_size,
                                  epsilon=cfg.rms_norm_eps)
+        if not cfg.tie_embeddings:
+            self.create_parameter(
+                "lm_head", (cfg.hidden_size, cfg.vocab_size), None,
+                I.XavierUniform())
         self.cache_kinds = [blk.state_kind for blk in self.blocks]
-        self._expert_tokens = self._expert_dense_layers = None
+        self._counted = {}
 
     def init_cache(self, batch: int, capacity: int, dtype=None):
         """One pytree a block, every leaf with the sequence (slot) axis
-        first: (K, V) for attention, (tail, S) for a state-space block."""
+        first: (K, V) for attention, (tail, S) for a state-space block,
+        (S, z) for a retention block."""
         return [blk.mixer.init_cache(batch, capacity, dtype)
                 for blk in self.blocks]
 
     def step_counters(self):
         """What the latest cached call counted, for the program that
-        made the call to return: ``expert_tokens`` (held,) int32, the
-        (token, pick) pairs each held expert got, summed over blocks;
-        ``expert_dense_layers`` int32, the expert layers of the call
-        whose rows took the dense body of ``nn.moe.dropless_moe`` (the
-        trace fixes it). Valid only inside the trace of that call."""
-        return {"expert_tokens": self._expert_tokens,
-                "expert_dense_layers": self._expert_dense_layers}
+        made the call to return. With routed experts: ``expert_tokens``
+        (held,) int32, the (token, pick) pairs each held expert got,
+        summed over blocks; ``expert_dense_layers`` int32, the expert
+        layers of the call whose rows took the dense body of
+        ``nn.moe.dropless_moe`` (the trace fixes it). With retention
+        blocks: ``retention_small_norm`` int32, the (row, head, block)
+        denominators of a step that fell under ``10 retention_eps``
+        (idle rows' included; a chunk counts none). Valid only inside
+        the trace of that call."""
+        return dict(self._counted)
 
     def _embed(self, ids):
         e = self.embed(ids)
         return e * jnp.asarray(self.cfg.embedding_multiplier, e.dtype)
 
     def _head(self, x):
-        logits = self.norm_f(x) @ self.embed.weight.T
+        logits = self.norm_f(x) @ (self.embed.weight.T
+                                   if self.cfg.tie_embeddings
+                                   else self.lm_head)
         return logits / jnp.asarray(self.cfg.logits_scaling, logits.dtype)
 
     def forward(self, ids):
@@ -297,27 +446,37 @@ class HybridForCausalLM(Layer):
                 axis=1)
         return loss_fn(self.forward(ids), labels, ignore_index)
 
-    def _cached_blocks(self, x, caches, attn_step, ssm_step,
+    def _cached_blocks(self, x, caches, attn_step, rec_step, rec_at,
                        head: bool = True):
         """The cached block composition, written once over the mixed
-        block list: ``attn_step(mixer, h, k, v) -> (a, k, v)`` and
-        ``ssm_step(mixer, h, cache) -> (a, cache)`` are all that vary
-        between the chunk, single-step and per-row entries."""
-        new_caches, tokens = [], 0
+        block list: ``attn_step(mixer, h, k, v) -> (a, k, v)``,
+        ``rec_step(mixer, h, cache) -> (a, cache)`` for a recurrent
+        mixer and ``rec_at(mixer, h, cache) -> (a, cache)`` for one that
+        takes positions are all that vary between the chunk, single-step
+        and per-row entries."""
+        new_caches, tokens, small = [], 0, 0
         for blk, cache in zip(self.blocks, caches):
             h = blk.norm1(x)
-            if blk.kind == "mamba":
-                a, cache = ssm_step(blk.mixer, h, cache)
-            else:
+            if blk.kind == "attention":
                 a, ck, cv = attn_step(blk.mixer, h, *cache)
                 cache = (ck, cv)
+            elif getattr(blk.mixer, "takes_positions", False):
+                a, cache = rec_at(blk.mixer, h, cache)
+                small = small + blk.mixer.small_norm
+            else:
+                a, cache = rec_step(blk.mixer, h, cache)
             x, got = blk.channel_mix(x + blk.m * a)
-            tokens = tokens + got
+            if got is not None:
+                tokens = tokens + got
             new_caches.append(cache)
-        self._expert_tokens = tokens
-        rows = x.shape[0] * x.shape[1]
-        self._expert_dense_layers = jnp.int32(sum(
-            blk.moe.streams_densely(rows) for blk in self.blocks))
+        self._counted = {}
+        if self.cfg.channel_mix == "experts":
+            rows = x.shape[0] * x.shape[1]
+            self._counted.update(
+                expert_tokens=tokens, expert_dense_layers=jnp.int32(sum(
+                    blk.moe.streams_densely(rows) for blk in self.blocks)))
+        if "retention" in self.cfg.layer_types:
+            self._counted["retention_small_norm"] = small
         return (self._head(x) if head else None), new_caches
 
     def _chunk_logits(self, toks, caches, t0, head: bool = True,
@@ -330,6 +489,7 @@ class HybridForCausalLM(Layer):
             lambda sa, h, ck, cv: sa.forward_chunk(
                 h, ck, cv, t0, decode_kernel=decode_kernel),
             lambda mx, h, c: mx.forward_chunk(h, c, valid_len),
+            lambda mx, h, c: mx.forward_chunk(h, c, t0, valid_len),
             head=head)
 
     def _step_logits(self, tok, caches, t, decode_kernel: bool = False):
@@ -338,17 +498,20 @@ class HybridForCausalLM(Layer):
             self._embed(tok[:, None]), caches,
             lambda sa, h, ck, cv: sa.forward_step(
                 h, ck, cv, t, decode_kernel=decode_kernel),
-            lambda mx, h, c: mx.forward_step(h, c))
+            lambda mx, h, c: mx.forward_step(h, c),
+            lambda mx, h, c: mx.forward_step(
+                h, c, jnp.broadcast_to(t, tok.shape)))
         return logits[:, 0], caches
 
     def _step_logits_rows(self, tok, caches, t_rows,
                           decode_kernel: bool = False):
         """One cached position PER ROW at per-row cursors ``t_rows``
         (the continuous-batching step): a state has no cursor, so only
-        the attention blocks read ``t_rows``."""
+        attention and a mixer with a rotary embedding read ``t_rows``."""
         logits, caches = self._cached_blocks(
             self._embed(tok[:, None]), caches,
             lambda sa, h, ck, cv: sa.forward_step_rows(
                 h, ck, cv, t_rows, decode_kernel=decode_kernel),
-            lambda mx, h, c: mx.forward_step(h, c))
+            lambda mx, h, c: mx.forward_step(h, c),
+            lambda mx, h, c: mx.forward_step(h, c, t_rows))
         return logits[:, 0], caches

@@ -1534,6 +1534,13 @@ class Router:
                     w.close()
                 except Exception:
                     pass
+        # a closed router dispatches nothing: let go of the replicas, so
+        # that whoever still holds the router (a caller's last ticket, a
+        # client thread's frame) does not keep an in-process replica's
+        # weights and arena, gigabytes of device memory, alive with it
+        with self._mu:
+            self._replicas = {}
+            self._prefill = []
 
     def __enter__(self) -> "Router":
         return self

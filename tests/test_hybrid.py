@@ -4,7 +4,9 @@
 program), at a tiny size in float32 on the CPU with seeded weights:
 the full forward pass; prefill then decode through ``BatchedDecoder``'s
 own programs with prompts that do not fill their bucket and slots used
-a second time; the expert shares; the modes the arena refuses.
+a second time; the expert shares; the modes the arena refuses. Section
+(g) holds the shell's other kinds (retention mixer, gated MLP, untied
+head) to ``benchmark/reference/retention_f32.py`` the same way.
 
 Tolerance of every logits comparison, ``close``: both sides are float32
 and differ in the order of sums only (chunked scan against a position
@@ -25,6 +27,7 @@ import pytest
 
 import paddle_tpu as pt
 from benchmark.reference import hybrid_moe_f32 as R
+from benchmark.reference import retention_f32 as RR
 from paddle_tpu import nn
 from paddle_tpu.core import EnforceError
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
@@ -75,15 +78,16 @@ def build(held=None, seed=0):
     return cfg, model, params
 
 
-def close(got, want):
+def close(got, want, tol=1e-4):
     want = np.asarray(want)
     np.testing.assert_allclose(np.asarray(got), want, rtol=0,
-                               atol=1e-4 * want.std())
+                               atol=tol * want.std())
 
 
 @functools.lru_cache(maxsize=None)
 def _reference(dims):
-    return jax.jit(lambda tokens, params: R.logits(tokens, params, dims))
+    ref = RR if isinstance(dims, RR.Dims) else R
+    return jax.jit(lambda tokens, params: ref.logits(tokens, params, dims))
 
 
 def reference_logits(params, dims, tokens):
@@ -171,11 +175,11 @@ def waves(vocab):
     return first, second
 
 
-def check_wave(got, wave, steps, params, dims):
+def check_wave(got, wave, steps, params, dims, tol=1e-4):
     for s, prompt, cont in wave:
         full = np.concatenate([prompt, cont[:steps]])
         want = reference_logits(params, dims, full)[len(prompt) - 1:]
-        close(np.stack(got[s]), want)
+        close(np.stack(got[s]), want, tol)
 
 
 def test_arena_prefill_and_decode_are_the_reference_and_slots_reuse():
@@ -333,3 +337,166 @@ def test_an_attention_only_model_is_refused_nothing():
     assert dec.counters.expert_tokens is None
     rid = dec.submit(np.arange(1, 20), 5)
     assert len(dec.run()[rid]) == 5
+
+
+# --------------------------------------------------------------------------
+# (g) the shell's other kinds: retention mixer, gated MLP, untied head
+# --------------------------------------------------------------------------
+
+# The tolerance is ``close``'s: the decode step reads the state through
+# ``phi``, whose D products are of either sign and cancel down to the
+# weight the attention form sums directly, but it computes the token's
+# own term directly too, so a denominator never rests on the
+# cancellation alone (largest difference seen here 5e-5 of the logits'
+# deviation; a state kept in bfloat16 reads 1e-2 to 4e-1).
+
+
+def build_retention(seed=0):
+    """Three retention blocks, five query heads a key-value head. The
+    norm scales (the per-head ones of queries and keys too) are drawn,
+    and the gate's weights are scaled up so that its decays spread over
+    0.05 .. 0.95 instead of sitting at 0.5."""
+    pt.seed(seed)
+    cfg = HybridConfig.tiny_retention(3)
+    model = HybridForCausalLM(cfg).eval()
+    rng = np.random.default_rng(seed + 1)
+    params = dict(model.named_parameters())
+    for k, v in params.items():
+        if k.endswith("norm.weight") or k.endswith(
+                ("norm1.weight", "norm2.weight", "norm_f.weight")):
+            params[k] = jnp.asarray(
+                1.0 + 0.3 * rng.standard_normal(v.shape), v.dtype)
+        if k.endswith("gate_proj.weight"):
+            params[k] = v * 8.0
+    model.set_parameters(params)
+    dims = RR.Dims(hidden=cfg.hidden_size, layers=len(cfg.layer_types),
+                   heads=cfg.num_heads, kv_heads=cfg.num_kv_heads,
+                   head_dim=cfg.hidden_size // cfg.num_heads,
+                   ffn=cfg.mlp_width, vocab=cfg.vocab_size,
+                   theta=cfg.rope_theta, eps=cfg.rms_norm_eps,
+                   retention_eps=cfg.retention_eps)
+    return cfg, model, params, dims
+
+
+def test_the_retention_model_names_its_own_leaves():
+    cfg, model, params, _ = build_retention()
+    assert model.cache_kinds == ["recurrent"] * 3
+    assert "lm_head" in params and params["lm_head"].shape == (80, 256)
+    assert {k.split(".", 2)[2] for k in params if k.startswith(
+        "blocks.0.")} == {
+        "norm1.weight", "norm2.weight", "mixer.q_proj.weight",
+        "mixer.k_proj.weight", "mixer.v_proj.weight",
+        "mixer.gate_proj.weight", "mixer.q_norm.weight",
+        "mixer.k_norm.weight", "mixer.out_proj.weight",
+        "mlp.gate.weight", "mlp.up.weight", "mlp.down.weight"}
+    S, z = model.init_cache(2, 64)[0]
+    assert S.shape == (2, 2, 40, 8) and z.shape == (2, 2, 40)
+    assert S.dtype == z.dtype == jnp.float32
+    with pytest.raises(EnforceError, match="degree"):
+        bad = HybridConfig.tiny_retention(1)
+        bad.retention_degree = 3
+        HybridForCausalLM(bad)
+    with pytest.raises(EnforceError, match="layer type"):
+        bad = HybridConfig.tiny_retention(1)
+        bad.layer_types = ("softmax",)
+        HybridForCausalLM(bad)
+
+
+@pytest.mark.parametrize("length", [19, 8])
+def test_retention_forward_is_the_reference(length):
+    cfg, model, params, dims = build_retention()
+    tokens = np.random.default_rng(7).integers(0, cfg.vocab_size,
+                                               (2, length))
+    got = model(jnp.asarray(tokens))
+    for row in range(2):
+        close(got[row], reference_logits(params, dims, tokens[row]))
+
+
+def test_retention_arena_prefill_and_decode_are_the_reference():
+    """Rows at different cursors (the rotary embedding reads each
+    row's own), prompts that do not fill their bucket, slots used a
+    second time on top of the first wave's states."""
+    cfg, model, params, dims = build_retention()
+    dec = BatchedDecoder(model, slots=SLOTS, capacity=CAPACITY,
+                         prompt_bucket=BUCKET)
+    # no keys and values at all: the state is the whole arena
+    assert dec.counters.state_bytes == {
+        "kv": 0, "recurrent": 3 * SLOTS * 2 * 40 * (8 + 1) * 4}
+    first, second = waves(cfg.vocab_size)
+    check_wave(arena_logits(dec, model, first, 9), first, 9, params, dims)
+    check_wave(arena_logits(dec, model, second, 6), second, 6, params,
+               dims)
+
+
+def test_a_bfloat16_retention_state_would_fail():
+    cfg, model, params, dims = build_retention()
+    dec = BatchedDecoder(model, slots=SLOTS, capacity=CAPACITY,
+                         prompt_bucket=BUCKET)
+    first, _ = waves(cfg.vocab_size)
+    rounded = lambda caches: [
+        tuple(a.astype(jnp.bfloat16).astype(a.dtype) for a in c)
+        for c in caches]
+    got = arena_logits(dec, model, first, 9, round_state=rounded)
+    with pytest.raises(AssertionError):
+        check_wave(got, first, 9, params, dims)
+
+
+def test_retention_served_tokens_and_the_small_norm_counter():
+    """Seven requests over three slots through ``submit`` / ``run``;
+    every served token is the reference's best. The step counts the
+    denominators under ``10 eps``: a slot that has served nothing yet
+    steps on a zero state with junk, whose own term keeps its
+    denominator up, so the count is small against rows x heads x
+    blocks x steps; and it rides the tokens' fetch."""
+    cfg, model, params, dims = build_retention()
+    dec = BatchedDecoder(model, slots=SLOTS, capacity=CAPACITY,
+                         prompt_bucket=BUCKET)
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 11, 8, 3, 17, 9, 1)]
+    rids = [dec.submit(p, 7) for p in prompts]
+    out = dec.run()
+    for p, rid in zip(prompts, rids):
+        full = np.concatenate([p, out[rid]])
+        want = reference_logits(params, dims, full)[len(p) - 1:-1]
+        took = want[np.arange(len(out[rid])), out[rid]]
+        assert (want.max(-1) - took <= 1e-4 * want.std()).all()
+    assert dec.counters.steps == dec.tick_count > 0
+    assert dec.counters.expert_tokens is None
+    small = int(dec.counters.sums["retention_small_norm"])
+    assert 0 <= small < 0.05 * dec.counters.steps * SLOTS * 10 * 3
+    assert all(np.isfinite(np.asarray(leaf)).all()
+               for c in dec.caches for leaf in c)
+
+
+def test_the_small_norm_counter_counts_a_zero_denominator():
+    """A step whose keys are zero on a zero state: every (row, head,
+    block) denominator is 0, the outputs are 0 and finite."""
+    cfg, model, params, _ = build_retention()
+    params = {k: (jnp.zeros_like(v) if k.endswith("k_proj.weight") else v)
+              for k, v in params.items()}
+    model.set_parameters(params)
+    caches = model.init_cache(SLOTS, CAPACITY)
+    logits, caches = model._step_logits_rows(
+        jnp.arange(SLOTS), caches, jnp.arange(SLOTS))
+    assert int(model.step_counters()["retention_small_norm"]) == (
+        SLOTS * cfg.num_heads * 3)
+    assert np.isfinite(np.asarray(logits)).all()
+    assert not any(np.asarray(leaf).any() for c in caches for leaf in c)
+
+
+@pytest.mark.parametrize("mode", [
+    dict(pages=8, page_size=64), dict(prefix_cache=True),
+    dict(kv_dtype="int8"), dict(prefill_chunk=8)])
+def test_position_addressed_modes_are_refused_for_retention(mode):
+    _, model, _, _ = build_retention()
+    with pytest.raises(EnforceError, match="recurrent state"):
+        BatchedDecoder(model, slots=2, capacity=64, prompt_bucket=8,
+                       **mode)
+
+
+def test_handoff_is_refused_for_retention():
+    _, model, _, _ = build_retention()
+    dec = BatchedDecoder(model, slots=2, capacity=64, prompt_bucket=8)
+    with pytest.raises(EnforceError, match="recurrent state"):
+        dec.prefill_export(np.arange(5))

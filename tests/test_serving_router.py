@@ -361,6 +361,26 @@ class TestRouterLogic:
         finally:
             r.close()
 
+    def test_a_closed_router_lets_go_of_its_replicas(self):
+        """Whoever still holds a closed router (a caller's ticket, a
+        client thread's frame) must not keep an in-process replica's
+        weights and arena alive with it: after ``close`` the replica is
+        reachable from its owner alone."""
+        import gc
+        import weakref
+
+        a = _FakeReplica("a")
+        r = _router([a])
+        t = r.submit(_prompt(4), 2)
+        r._poll_once()
+        r.wait([t], timeout=5)
+        ref = weakref.ref(a)
+        r.close()
+        del a
+        gc.collect()
+        assert ref() is None
+        assert r.stats()["replicas"] == 0
+
     def test_session_affinity_beats_load(self):
         a, b = _FakeReplica("a", slots=4), _FakeReplica("b", slots=4)
         r = _router([a, b], poll_interval_s=30)

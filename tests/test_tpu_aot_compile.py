@@ -194,6 +194,41 @@ def test_flash_decode_compiles(one_chip, dtype):
     assert "%pt_flash_decode" in text
 
 
+def test_retention_step_compiles_and_writes_the_state_in_place(one_chip):
+    """The power-retention step kernel at the Brumby cell's widths (40 q
+    / 8 kv heads of 128, a state of 8320 x 128 a head; 4 slots here):
+    Mosaic takes its rotations, its 4.3 MB blocks and its VMEM limit,
+    and the compiled step holds the state once (the kernel's output is
+    its input: no temporary of the state's size)."""
+    from paddle_tpu.ops import retention
+
+    b, h, kv, d = 4, 40, 8, 128
+    sd = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    big = retention.phi_dim(d)
+
+    def step(q, k, v, log_g, S, z):
+        from paddle_tpu.ops.pallas.retention_step import (
+            retention_state_step)
+
+        scale = d ** -0.25
+        return retention_state_step(
+            S, k.astype(jnp.float32) * scale, v,
+            q.astype(jnp.float32).reshape(b, kv, h // kv, d) * scale,
+            jnp.exp(log_g), interpret=False)
+
+    compiled = jax.jit(step, donate_argnums=(4,)).lower(
+        sd((b, h, d), jnp.bfloat16), sd((b, kv, d), jnp.bfloat16),
+        sd((b, kv, d), jnp.bfloat16), sd((b, kv)), sd((b, kv, big, d)),
+        sd((b, kv, big))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%pt_retention_step" in text
+    mem = compiled.memory_analysis()
+    state = b * kv * big * d * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 8
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
 def test_flash_decode_paged_compiles(one_chip, quantized):
     n_log = CAPACITY // PAGE
