@@ -23,6 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .. import initializer as I
 from .. import nn
@@ -182,39 +183,53 @@ class GPTForCausalLM(Layer):
         return [blk.self_attn.init_cache(batch, capacity, dtype)
                 for blk in self.blocks]
 
-    def _cached_blocks(self, x, caches, attn_step, head: bool = True):
+    def _cached_blocks(self, x, caches, attn_step, head: bool = True,
+                       head_at=None):
         """ONE definition of the cached-decode block composition
         (norm1 -> attn -> residual -> ffn -> norm_f@head) shared by the
         chunk, single-step, and per-row-cursor entries — the attention
         flavor is the only thing that varies. ``head=False`` skips the
         (S, V) head projection (cache-only prefill; XLA would DCE the
-        dead matmul under jit, but eager callers pay it for real)."""
+        dead matmul under jit, but eager callers pay it for real).
+        ``head_at`` (a position of the chunk, may be traced) applies the
+        head at that one position: ``x`` is cut to its row as soon as
+        only that row is wanted, after the last block's attention (whose
+        keys and values every position writes), so the last block's MLP
+        and the head's product have one row and the logits are (B, V)."""
         new_caches = []
-        for blk, (ck, cv) in zip(self.blocks, caches):
+        last = len(self.blocks) - 1
+        for i, (blk, (ck, cv)) in enumerate(zip(self.blocks, caches)):
             h = blk.norm1(x)
             a, ck, cv = attn_step(blk.self_attn, h, ck, cv)
             x = x + a
+            if head_at is not None and i == last:
+                x = lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
             x = x + blk.ffn(blk.norm2(x))
             new_caches.append((ck, cv))
+        if head_at is not None:
+            return (self.norm_f(x) @ self._head_weight())[:, 0], new_caches
         if not head:
             return None, new_caches
         return self.norm_f(x) @ self._head_weight(), new_caches
 
     def _chunk_logits(self, toks, caches, t0, head: bool = True,
-                      decode_kernel: bool = False, valid_len=None):
+                      decode_kernel: bool = False, valid_len=None,
+                      head_at=None):
         """S KV-cached positions in one pass: embed ``toks`` (B, S), run
         every block's forward_chunk at cache indices [t0, t0+S), return
-        ((B, S, V) logits, new caches). The speculative-decoding target
-        scores its gamma+1 candidates with one call. ``valid_len`` (how
-        many of the S tokens are the sequence, the rest padding) is
-        taken and not read: keys and values written past it sit above
-        the cursor, which masks them."""
+        ((B, S, V) logits, new caches), or (B, V) logits of the chunk's
+        position ``head_at`` alone (a whole-prompt prefill's first
+        token). The speculative-decoding target scores its gamma+1
+        candidates with one call. ``valid_len`` (how many of the S
+        tokens are the sequence, the rest padding) is taken and not
+        read: keys and values written past it sit above the cursor,
+        which masks them, and no position before it attends to them."""
         return self._cached_blocks(
             self.embed(toks), caches,
             lambda sa, h, ck, cv: sa.forward_chunk(
                 h, ck, cv, t0, window=self.cfg.attn_window,
                 decode_kernel=decode_kernel),
-            head=head)
+            head=head, head_at=head_at)
 
     def _step_logits(self, tok, caches, t, decode_kernel: bool = False):
         """One KV-cached position: ``tok`` (B,) -> ((B, V), caches)."""
@@ -294,8 +309,6 @@ class GPTForCausalLM(Layer):
         Green-field vs the reference (its decoding story is beam search
         over the NMT encoder-decoder, reference:
         benchmark/fluid/models/machine_translation.py)."""
-        from jax import lax
-
         from ..ops.sampling import sample_from_logits
 
         enforce(not self.training,

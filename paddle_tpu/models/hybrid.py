@@ -53,6 +53,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .. import initializer as I
 from .. import nn
@@ -447,15 +448,22 @@ class HybridForCausalLM(Layer):
         return loss_fn(self.forward(ids), labels, ignore_index)
 
     def _cached_blocks(self, x, caches, attn_step, rec_step, rec_at,
-                       head: bool = True):
+                       head: bool = True, head_at=None):
         """The cached block composition, written once over the mixed
         block list: ``attn_step(mixer, h, k, v) -> (a, k, v)``,
         ``rec_step(mixer, h, cache) -> (a, cache)`` for a recurrent
         mixer and ``rec_at(mixer, h, cache) -> (a, cache)`` for one that
         takes positions are all that vary between the chunk, single-step
-        and per-row entries."""
+        and per-row entries. It ends in the head over every position
+        (``head=True``), in none (``head=False``), or in the head at the
+        one position ``head_at`` (may be traced): ``x`` is cut to that
+        row as soon as only that row is wanted, after the last block's
+        mixer (whose cache every position writes), so the last block's
+        channel mix and the head have one row, and the logits are
+        (B, V)."""
         new_caches, tokens, small = [], 0, 0
-        for blk, cache in zip(self.blocks, caches):
+        rows, last = x.shape[0] * x.shape[1], len(self.blocks) - 1
+        for i, (blk, cache) in enumerate(zip(self.blocks, caches)):
             h = blk.norm1(x)
             if blk.kind == "attention":
                 a, ck, cv = attn_step(blk.mixer, h, *cache)
@@ -465,32 +473,41 @@ class HybridForCausalLM(Layer):
                 small = small + blk.mixer.small_norm
             else:
                 a, cache = rec_step(blk.mixer, h, cache)
-            x, got = blk.channel_mix(x + blk.m * a)
+            x = x + blk.m * a
+            if head_at is not None and i == last:
+                x = lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
+            x, got = blk.channel_mix(x)
             if got is not None:
                 tokens = tokens + got
             new_caches.append(cache)
         self._counted = {}
         if self.cfg.channel_mix == "experts":
-            rows = x.shape[0] * x.shape[1]
             self._counted.update(
                 expert_tokens=tokens, expert_dense_layers=jnp.int32(sum(
                     blk.moe.streams_densely(rows) for blk in self.blocks)))
         if "retention" in self.cfg.layer_types:
             self._counted["retention_small_norm"] = small
+        if head_at is not None:
+            return self._head(x)[:, 0], new_caches
         return (self._head(x) if head else None), new_caches
 
     def _chunk_logits(self, toks, caches, t0, head: bool = True,
-                      decode_kernel: bool = False, valid_len=None):
+                      decode_kernel: bool = False, valid_len=None,
+                      head_at=None):
         """S cached positions in one pass at cache indices [t0, t0+S):
         keys and values are written for the whole chunk, a recurrence
-        advances over its first ``valid_len`` positions only."""
+        advances over its first ``valid_len`` positions only and stays
+        there, while every position's output is that position's own
+        (so ``head_at=valid_len - 1`` gives a padded prompt's next-token
+        logits, (B, V), from the same pass that leaves the state after
+        the whole prompt)."""
         return self._cached_blocks(
             self._embed(toks), caches,
             lambda sa, h, ck, cv: sa.forward_chunk(
                 h, ck, cv, t0, decode_kernel=decode_kernel),
             lambda mx, h, c: mx.forward_chunk(h, c, valid_len),
             lambda mx, h, c: mx.forward_chunk(h, c, t0, valid_len),
-            head=head)
+            head=head, head_at=head_at)
 
     def _step_logits(self, tok, caches, t, decode_kernel: bool = False):
         """One cached position: ``tok`` (B,) -> ((B, V), caches)."""

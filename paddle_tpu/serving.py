@@ -306,12 +306,19 @@ class ArenaCounters:
     ``steps`` steps; ``expert_tokens`` is the one a model with routed
     experts gives, the (held,) (token, pick) pairs each held expert
     got, every row of the step counted (an idle slot's junk row too),
-    and None for any other model."""
+    and None for any other model. ``prefills``: the prompts this arena
+    prefilled itself (a handoff's import is none); ``prefill_resteps``:
+    those whose first token came from a step of the last prompt token
+    through the whole model, a second read of every weight, and not
+    from the prefill's own pass (0 on the contiguous arena with whole
+    prompts; the paged, prefix-hit and chunked paths still re-step)."""
 
     def __init__(self, state_bytes: Dict[str, int]):
         self.state_bytes = state_bytes
         self.sums: Dict[str, np.ndarray] = {}
         self.steps = 0
+        self.prefills = 0
+        self.prefill_resteps = 0
 
     def add(self, counted: Dict[str, Any]) -> None:
         for name, value in counted.items():
@@ -666,7 +673,9 @@ class Request:
 
 class BatchedDecoder:
     """Slot-based continuous batching over a causal LM: anything
-    exposing ``_step_logits``/``_chunk_logits``/``_step_logits_rows``
+    exposing ``_step_logits``/``_chunk_logits`` (with ``valid_len`` and
+    ``head_at``: a prefill is one chunk that ends in the head at its
+    own last prompt row)/``_step_logits_rows``
     and ``init_cache(slots, capacity)``, the arena as a list with one
     pytree a block whose leaves all lead with the slot axis. A model
     may declare ``cache_kinds`` (a block: ``"kv"``, addressed by
@@ -1379,6 +1388,8 @@ class BatchedDecoder:
                 self.pools, logits = self._prefill_fn_paged(lb)(
                     self._mstate, self.pools, jnp.asarray(row),
                     jnp.asarray(padded), plen)
+                self.counters.prefills += 1
+                self.counters.prefill_resteps += 1
                 al = self._allocator
                 blocks = []
                 for kp, vp in self.pools:
@@ -1511,8 +1522,9 @@ class BatchedDecoder:
     def _fresh_row(self, row):
         """The sliced row with every recurrent block's state zeroed:
         whatever the slot's last request (or an idle slot's junk steps)
-        left there, a new sequence starts from nothing. Keys and values
-        stay: the cursor masks them."""
+        left there, a new sequence starts from nothing, and the
+        prefill's one chunk advances it over the whole prompt. Keys and
+        values stay: the cursor masks them."""
         return [jax.tree_util.tree_map(jnp.zeros_like, r)
                 if kind == "recurrent" else r
                 for kind, r in zip(self._kinds, row)]
@@ -1526,34 +1538,31 @@ class BatchedDecoder:
         return min(max(b, ((n + b - 1) // b) * b), self.capacity)
 
     def _prefill_fn(self, lb: int):
-        """Jitted prefill for bucket length lb: run the padded prompt
-        through the model cache-only at positions [0, plen), writing
-        slot ``s`` of the arena. One compile per bucket."""
+        """Jitted prefill for bucket length lb: ONE pass of the padded
+        prompt through the model at positions [0, lb), writing slot
+        ``s`` of the arena and returning the logits of position
+        ``plen - 1`` (the first token's). One compile per bucket."""
         fn = self._prefill_cache.get(lb)
         if fn is not None:
             return fn
         model = self.model
 
         def prefill(mstate, caches, padded, plen, s):
-            # chunk-run the FULL bucket (static shape) CACHE-ONLY, of
-            # which the first plen - 1 positions are the sequence so
-            # far (``valid_len``): keys and values are written for the
-            # whole bucket (positions >= plen land above the cursor,
-            # masked + overwritten later), a recurrence advances over
-            # those plen - 1 tokens and no further, from zeros. Then
-            # the LAST prompt token is stepped once, at plen - 1: the
-            # (lb, vocab) head projection would be the dominant prefill
-            # FLOP and all but one row is discarded, so the next-token
-            # logits come from that one-position step (for keys and
-            # values a rewrite of what the chunk wrote there; for a
-            # recurrence the one time that token is applied).
+            # the FULL bucket (static shape) as one chunk, of which the
+            # first plen positions are the prompt (``valid_len``): keys
+            # and values are written for the whole bucket (positions
+            # >= plen land above the cursor, masked + overwritten
+            # later), a recurrence advances over those plen tokens and
+            # no further, from zeros. The first token's logits are the
+            # head applied to the chunk's own row plen - 1 (causal: it
+            # has seen positions <= plen - 1 and no padding): an
+            # (lb, vocab) head would be the dominant prefill FLOP, and
+            # a step of the last token through the model for them would
+            # read every weight a second time.
             def body(row):
-                _, row = model._chunk_logits(
-                    padded[None], self._fresh_row(row), 0, head=False,
-                    valid_len=plen - 1)
-                last = lax.dynamic_index_in_dim(padded, plen - 1,
-                                                keepdims=False)
-                return model._step_logits(last[None], row, plen - 1)
+                return model._chunk_logits(
+                    padded[None], self._fresh_row(row), 0,
+                    valid_len=plen, head_at=plen - 1)
 
             with inject_state((model, *mstate)):
                 logits, new = _row_apply(caches, s, body)
@@ -1566,7 +1575,8 @@ class BatchedDecoder:
     def _prefill_fn_paged(self, lb: int):
         """Jitted paged prefill for bucket length lb: chunk-write the
         prompt into the row's pages cache-only, then one re-step of the
-        last token for the next-token logits."""
+        last token for the next-token logits (kept: no cell runs the
+        paged arena yet; ``_prefill_fn`` shows the one-pass form)."""
         fn = self._prefill_cache.get(("paged", lb))
         if fn is not None:
             return fn
@@ -1591,7 +1601,8 @@ class BatchedDecoder:
         """Prefix-hit prefill pieces: cache-only chunk of the SUFFIX at
         a page-aligned offset (one compile per bucket) and the
         lb-independent last-token re-step (compiled ONCE; also used
-        alone when the whole prompt is cached)."""
+        alone when the whole prompt is cached: then there is no chunk
+        to take the last row from, so this path keeps the re-step)."""
         model = self.model
         chunk_fn = self._prefill_cache.get(("suffix", lb))
         if chunk_fn is None:
@@ -1638,7 +1649,8 @@ class BatchedDecoder:
 
     def _restep_contig(self):
         """Jitted last-token re-step for slot ``s`` (chunked-prefill
-        finish): idempotent K/V rewrite at pos, single-row head."""
+        finish): idempotent K/V rewrite at pos, single-row head (kept:
+        no cell runs chunked prefill yet)."""
         fn = self._prefill_cache.get(("crestep",))
         if fn is not None:
             return fn
@@ -1691,6 +1703,7 @@ class BatchedDecoder:
                 return
         # all chunks written: re-step the last prompt token for the
         # next-token logits and go live
+        self.counters.prefill_resteps += 1
         last = jnp.asarray(int(padded[plen - 1]), jnp.int32)
         if self.paged:
             _, restep_fn = self._suffix_fns(self.bucket)
@@ -1895,6 +1908,7 @@ class BatchedDecoder:
                 # (chunked-prefill deferral included)
                 self._import_handoff(s, r)
                 continue
+            self.counters.prefills += 1
             if self.prefill_chunk is not None:
                 # defer: chunk grid starts at the cached frontier
                 # (page-aligned, hence chunk-aligned); park the cursor
@@ -1928,6 +1942,7 @@ class BatchedDecoder:
                           bucket=lb, queued_us=int(
                               (time.perf_counter() - r.t_submit) * 1e6)):
                     if self.paged:
+                        self.counters.prefill_resteps += 1
                         row = self.table[s]
                         if cached == 0:
                             pf = self._prefill_fn_paged(lb)
