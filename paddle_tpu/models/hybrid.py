@@ -9,15 +9,31 @@ and a channel mix a block, chosen by the configuration.
 - ``"retention"``: power retention of degree 2, linear attention whose
   score is the square of a dot product (:class:`RetentionMixer`), with
   a per-head RMSNorm and a rotary embedding on queries and keys and
-  one learned gate a key-value head.
+  one learned gate a key-value head;
+- ``"latent"``: multi-head latent attention (``nn.LatentAttention``):
+  low-rank queries, one compressed record a position for all heads, a
+  (YaRN) rotary part, decompressed in a prefill and read absorbed by a
+  decode step.
 
-``channel_mix`` names what follows the mixer in EVERY block:
+``channel_mix`` names what follows the mixer, in every block (one
+name) or block by block (one name a block, as ``layer_types``):
 
 - ``"experts"``: a dropless top-k expert layer plus one shared gated
   MLP (``nn.DroplessMoE`` + :class:`GatedMLP`), told which experts it
   holds (``experts_held``), as one chip of an expert-parallel
-  deployment is;
+  deployment is, under the routing rule ``routing``
+  (``nn.DroplessMoE.ROUTING``);
 - ``"mlp"``: one gated MLP of width ``mlp_width`` (SwiGLU).
+
+The RESIDUAL PATH a block's two sublayers are written over is an
+object with ``read(X) -> (u, held)`` and ``write(X, y, held) -> X'``:
+``nn.latent.PlainResidual`` (``hc_mult`` 1: one stream, ``x + m
+F(norm(x))``) or, with ``hc_mult`` > 1, ``nn.latent.HyperConnection``
+(manifold-constrained hyper-connections: the state of a position is
+``hc_mult`` streams, (B, S, hc_mult, hidden), read in as copies of the
+embedding and read out as their sum; each sublayer has maps of its
+own, computed in float32, and the state stays float32 between
+sublayers).
 
 Three constant multipliers scale the embedding, each residual branch
 and the attention scores, and the logits are divided by a fourth (the
@@ -31,6 +47,9 @@ default); the head is the embedding transposed or, with
     h = h + residual_multiplier * ChannelMix(u)
     logits = RMSNorm(h) @ W_head / logits_scaling
 
+(the plain path; with hyper-connections ``u, held = read(X)``, ``X =
+write(X, F(RMSNorm(u)), held)`` around each sublayer).
+
 What decoding keeps a sequence differs by mixer: attention keeps keys
 and values by position, (K, V) of shape (slots, capacity, kv_heads,
 head_dim); a state-space block keeps a state of fixed size,
@@ -38,9 +57,13 @@ head_dim); a state-space block keeps a state of fixed size,
 head_dim, state) float32); a retention block keeps (S (slots,
 kv_heads, D, head_dim), z (slots, kv_heads, D)) float32 with ``D`` =
 ``ops.retention.phi_dim(head_dim)``, 34 MB a slot at head dimension
-128 whatever the context. :meth:`HybridForCausalLM.init_cache` gives
-the list, one entry a block, and ``cache_kinds`` says which is which
-(``"kv"``, addressed by position, or ``"recurrent"``);
+128 whatever the context; a latent block keeps its records by
+position, (c (slots, capacity, kv_rank), r (slots, capacity, rope)).
+:meth:`HybridForCausalLM.init_cache` gives
+the list, one entry a block, ``cache_kinds`` says which is which
+(``"kv"``, addressed by position, or ``"recurrent"``) and
+``cache_records`` what a ``"kv"`` entry holds (``"heads"``: keys and
+values by head, or ``"latent"``);
 ``serving.BatchedDecoder`` holds it as its arena. A recurrent mixer
 that needs positions (retention's rotary embedding) says so
 (``takes_positions``) and is handed the cursors attention is.
@@ -49,7 +72,7 @@ that needs positions (retention's rotary embedding) says so
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -59,11 +82,12 @@ from .. import initializer as I
 from .. import nn
 from ..core.dtypes import default_dtype
 from ..core.enforce import enforce
+from ..nn.latent import HyperConnection, LatentAttention, PlainResidual
 from ..nn.layer import Layer
 from ..ops import retention, ssm
 from ..ops.attention import rotary_embedding
 
-MIXERS = ("mamba", "attention", "retention")
+MIXERS = ("mamba", "attention", "retention", "latent")
 CHANNEL_MIXES = ("experts", "mlp")
 
 
@@ -74,19 +98,37 @@ class HybridConfig:
     layer_types: Tuple[str, ...] = ("mamba", "attention")
     num_heads: int = 8
     num_kv_heads: Optional[int] = None
-    channel_mix: str = "experts"         # or "mlp": every block's
+    # "experts" or "mlp": every block's, or one name a block
+    channel_mix: Union[str, Tuple[str, ...]] = "experts"
     mlp_width: int = 0                   # the "mlp" channel mix's width
     expert_width: int = 256              # one routed expert's gated width
     shared_width: int = 512              # the always-on gated MLP's width
     num_experts: int = 8                 # the router's width
     experts_per_token: int = 2
     experts_held: Optional[Tuple[int, int]] = None   # (first, count)
+    routing: str = "topk_softmax"        # nn.DroplessMoE.ROUTING
+    routed_scaling_factor: float = 1.0   # "sigmoid_noaux_tc" gates' sum
     ssm_heads: int = 32
     ssm_head_dim: int = 64
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
-    rope_theta: float = 10000.0          # the retention mixer's rotary
+    rope_theta: float = 10000.0          # retention's, latent's rotary
+    # the latent mixer: ranks and head widths, YaRN (the keyword
+    # arguments of ops.attention.yarn_frequencies) and its mscale_all_dim
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[dict] = None
+    rope_mscale_all_dim: float = 1.0
+    # hyper-connections: streams of the residual state (1: the plain
+    # path), Sinkhorn rounds and epsilon, the clamp on H_res's logits
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
     retention_degree: int = 2            # the power of the score
     retention_eps: float = 1e-6          # added to the sum of weights
     retention_chunk: int = 128
@@ -97,6 +139,36 @@ class HybridConfig:
     logits_scaling: float = 1.0
     rms_norm_eps: float = 1e-5
     use_flash: bool = True
+
+    def channel_mixes(self) -> Tuple[str, ...]:
+        """The channel mix of each block."""
+        if isinstance(self.channel_mix, str):
+            return (self.channel_mix,) * len(self.layer_types)
+        enforce(len(self.channel_mix) == len(self.layer_types),
+                "channel_mix names %s blocks, layer_types %s",
+                len(self.channel_mix), len(self.layer_types))
+        return tuple(self.channel_mix)
+
+    @classmethod
+    def tiny_latent(cls, layers: int = 3, dense: int = 1):
+        """For tests: ``layers`` latent-attention blocks over 4 residual
+        streams, the first ``dense`` with a gated MLP of 96 and the
+        rest with 16 sigmoid-routed experts of width 24, 4 a token,
+        scaled by 2, plus a shared MLP of 24; hidden 64, 4 heads of
+        16 + 8 (scores) / 16 (values), ranks 24 and 32, YaRN by 4 over
+        32 positions, an untied head."""
+        return cls(vocab_size=256, hidden_size=64,
+                   layer_types=("latent",) * layers, num_heads=4,
+                   channel_mix=("mlp",) * dense
+                   + ("experts",) * (layers - dense),
+                   mlp_width=96, expert_width=24, shared_width=24,
+                   num_experts=16, experts_per_token=4,
+                   routing="sigmoid_noaux_tc", routed_scaling_factor=2.0,
+                   q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+                   qk_rope_head_dim=8, v_head_dim=16,
+                   rope_yarn=dict(factor=4.0, original_max_position=32,
+                                  beta_fast=32.0, beta_slow=1.0),
+                   hc_mult=4, tie_embeddings=False, rms_norm_eps=1e-6)
 
     @classmethod
     def tiny(cls, periods: int = 1):
@@ -320,24 +392,44 @@ class RetentionMixer(Layer):
 
 
 class HybridBlock(Layer):
-    """h + m Mixer(norm(h)); then + m ChannelMix(u), u the second
-    norm. ``kind`` chooses the mixer, ``cfg.channel_mix`` what follows
-    it: routed experts plus a shared MLP (``moe``, ``shared``) or one
-    gated MLP (``mlp``)."""
+    """Two sublayers over one residual path (``res1``, ``res2``:
+    ``u, held = read(X)``, ``X = write(X, F(norm(u)), held)``): the
+    mixer ``kind`` names (``mamba``, ``attention``, ``retention``,
+    ``latent``), then the channel mix ``mix`` names, routed experts plus
+    a shared MLP (``moe``, ``shared``) or one gated MLP (``mlp``). The
+    path is the plain ``X + m F(norm(X))`` or, with ``cfg.hc_mult`` > 1,
+    hyper-connections over that many streams, each sublayer with maps
+    of its own."""
 
-    def __init__(self, cfg: HybridConfig, kind: str):
+    def __init__(self, cfg: HybridConfig, kind: str,
+                 mix: Optional[str] = None):
         super().__init__()
+        mix = cfg.channel_mix if mix is None else mix
         enforce(kind in MIXERS, "layer type %r is none of %s", kind,
                 MIXERS)
-        enforce(cfg.channel_mix in CHANNEL_MIXES,
-                "channel mix %r is none of %s", cfg.channel_mix,
-                CHANNEL_MIXES)
+        enforce(mix in CHANNEL_MIXES, "channel mix %r is none of %s",
+                mix, CHANNEL_MIXES)
         self.kind, self.m = kind, float(cfg.residual_multiplier)
+        if cfg.hc_mult > 1:
+            enforce(self.m == 1.0, "hyper-connections carry no residual "
+                    "multiplier, got %s", self.m)
+            self.res1, self.res2 = (HyperConnection(
+                cfg.hidden_size, cfg.hc_mult, cfg.hc_sinkhorn_iters,
+                cfg.hc_eps, cfg.hc_clamp, cfg.rms_norm_eps)
+                for _ in range(2))
+        else:
+            self.res1 = self.res2 = PlainResidual(self.m)
         self.norm1 = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
         if kind == "mamba":
             self.mixer = SSDMixer(cfg)
         elif kind == "retention":
             self.mixer = RetentionMixer(cfg)
+        elif kind == "latent":
+            self.mixer = LatentAttention(
+                cfg.hidden_size, cfg.num_heads, cfg.q_lora_rank,
+                cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.rope_theta,
+                cfg.rope_yarn, cfg.rope_mscale_all_dim, cfg.rms_norm_eps)
         else:
             self.mixer = nn.MultiHeadAttention(
                 cfg.hidden_size, cfg.num_heads, bias=False,
@@ -346,12 +438,13 @@ class HybridBlock(Layer):
                 rotary=False, scale=cfg.attention_multiplier)
         self.norm2 = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
         self.moe = None
-        if cfg.channel_mix == "mlp":
+        if mix == "mlp":
             self.mlp = GatedMLP(cfg.hidden_size, cfg.mlp_width)
             return
         self.moe = nn.DroplessMoE(
             cfg.hidden_size, cfg.expert_width, cfg.num_experts,
-            cfg.experts_per_token, experts_held=cfg.experts_held)
+            cfg.experts_per_token, experts_held=cfg.experts_held,
+            routing=cfg.routing, scaling=cfg.routed_scaling_factor)
         self.shared = GatedMLP(cfg.hidden_size, cfg.shared_width)
 
     @property
@@ -359,21 +452,24 @@ class HybridBlock(Layer):
         return getattr(self.mixer, "state_kind", "kv")
 
     def channel_mix(self, x):
-        """(x + m ChannelMix(u), the (held,) tokens each held expert
-        got, or None where there are no experts)."""
-        u = self.norm2(x)
+        """(the state after the channel mix's sublayer, the (held,)
+        tokens each held expert got, or None where there are no
+        experts)."""
+        u, held = self.res2.read(x)
+        u = self.norm2(u)
         if self.moe is None:
-            return x + self.m * self.mlp(u), None
+            return self.res2.write(x, self.mlp(u), held), None
         routed, tokens = self.moe.forward_counted(u)
         with jax.named_scope("moe_shared"):
             shared = self.shared(u)
-        return x + self.m * (routed + shared), tokens
+        return self.res2.write(x, routed + shared, held), tokens
 
     def forward(self, x):
-        h = self.norm1(x)
-        a = (self.mixer(h, causal=True) if self.kind == "attention"
-             else self.mixer(h))
-        return self.channel_mix(x + self.m * a)[0]
+        u, held = self.res1.read(x)
+        h = self.norm1(u)
+        a = (self.mixer(h, causal=True)
+             if self.kind in ("attention", "latent") else self.mixer(h))
+        return self.channel_mix(self.res1.write(x, a, held))[0]
 
 
 class HybridForCausalLM(Layer):
@@ -388,8 +484,9 @@ class HybridForCausalLM(Layer):
         super().__init__()
         self.cfg = cfg
         self.embed = nn.Embedding(cfg.vocab_size, cfg.hidden_size)
-        self.blocks = nn.LayerList([HybridBlock(cfg, kind)
-                                    for kind in cfg.layer_types])
+        self.blocks = nn.LayerList([
+            HybridBlock(cfg, kind, mix) for kind, mix in
+            zip(cfg.layer_types, cfg.channel_mixes())])
         self.norm_f = nn.RMSNorm(cfg.hidden_size,
                                  epsilon=cfg.rms_norm_eps)
         if not cfg.tie_embeddings:
@@ -397,12 +494,16 @@ class HybridForCausalLM(Layer):
                 "lm_head", (cfg.hidden_size, cfg.vocab_size), None,
                 I.XavierUniform())
         self.cache_kinds = [blk.state_kind for blk in self.blocks]
+        self.cache_records = [
+            getattr(blk.mixer, "cache_record", "heads")
+            if kind == "kv" else None
+            for blk, kind in zip(self.blocks, self.cache_kinds)]
         self._counted = {}
 
     def init_cache(self, batch: int, capacity: int, dtype=None):
         """One pytree a block, every leaf with the sequence (slot) axis
         first: (K, V) for attention, (tail, S) for a state-space block,
-        (S, z) for a retention block."""
+        (S, z) for a retention block, (c, r) for a latent block."""
         return [blk.mixer.init_cache(batch, capacity, dtype)
                 for blk in self.blocks]
 
@@ -415,15 +516,25 @@ class HybridForCausalLM(Layer):
         ``nn.moe.dropless_moe`` (the trace fixes it). With retention
         blocks: ``retention_small_norm`` int32, the (row, head, block)
         denominators of a step that fell under ``10 retention_eps``
-        (idle rows' included; a chunk counts none). Valid only inside
+        (idle rows' included; a chunk counts none). With
+        hyper-connections: ``mhc_unbalanced`` int32, the (position,
+        sublayer) maps whose ``H_res`` has a row or column sum off 1 by
+        more than 1e-3 after the Sinkhorn rounds. Valid only inside
         the trace of that call."""
         return dict(self._counted)
 
     def _embed(self, ids):
         e = self.embed(ids)
-        return e * jnp.asarray(self.cfg.embedding_multiplier, e.dtype)
+        e = e * jnp.asarray(self.cfg.embedding_multiplier, e.dtype)
+        if self.cfg.hc_mult > 1:    # every stream starts as the embedding
+            e = jnp.broadcast_to(e[..., None, :], (
+                *e.shape[:-1], self.cfg.hc_mult, e.shape[-1])).astype(
+                    HyperConnection.state_dtype)
+        return e
 
     def _head(self, x):
+        if self.cfg.hc_mult > 1:    # the streams are read out as their sum
+            x = jnp.sum(x.astype(jnp.float32), axis=-2)
         logits = self.norm_f(x) @ (self.embed.weight.T
                                    if self.cfg.tie_embeddings
                                    else self.lm_head)
@@ -450,7 +561,8 @@ class HybridForCausalLM(Layer):
     def _cached_blocks(self, x, caches, attn_step, rec_step, rec_at,
                        head: bool = True, head_at=None):
         """The cached block composition, written once over the mixed
-        block list: ``attn_step(mixer, h, k, v) -> (a, k, v)``,
+        block list: ``attn_step(mixer, h, k, v) -> (a, k, v)`` (a latent
+        mixer's records in the place of keys and values),
         ``rec_step(mixer, h, cache) -> (a, cache)`` for a recurrent
         mixer and ``rec_at(mixer, h, cache) -> (a, cache)`` for one that
         takes positions are all that vary between the chunk, single-step
@@ -461,11 +573,12 @@ class HybridForCausalLM(Layer):
         mixer (whose cache every position writes), so the last block's
         channel mix and the head have one row, and the logits are
         (B, V)."""
-        new_caches, tokens, small = [], 0, 0
+        new_caches, tokens, small, unbalanced = [], 0, 0, 0
         rows, last = x.shape[0] * x.shape[1], len(self.blocks) - 1
         for i, (blk, cache) in enumerate(zip(self.blocks, caches)):
-            h = blk.norm1(x)
-            if blk.kind == "attention":
+            u, held = blk.res1.read(x)
+            h = blk.norm1(u)
+            if blk.kind in ("attention", "latent"):
                 a, ck, cv = attn_step(blk.mixer, h, *cache)
                 cache = (ck, cv)
             elif getattr(blk.mixer, "takes_positions", False):
@@ -473,20 +586,26 @@ class HybridForCausalLM(Layer):
                 small = small + blk.mixer.small_norm
             else:
                 a, cache = rec_step(blk.mixer, h, cache)
-            x = x + blk.m * a
+            x = blk.res1.write(x, a, held)
             if head_at is not None and i == last:
                 x = lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
             x, got = blk.channel_mix(x)
             if got is not None:
                 tokens = tokens + got
+            if self.cfg.hc_mult > 1:
+                unbalanced = (unbalanced + blk.res1.unbalanced
+                              + blk.res2.unbalanced)
             new_caches.append(cache)
         self._counted = {}
-        if self.cfg.channel_mix == "experts":
+        if "experts" in self.cfg.channel_mixes():
             self._counted.update(
                 expert_tokens=tokens, expert_dense_layers=jnp.int32(sum(
-                    blk.moe.streams_densely(rows) for blk in self.blocks)))
+                    blk.moe.streams_densely(rows) for blk in self.blocks
+                    if blk.moe is not None)))
         if "retention" in self.cfg.layer_types:
             self._counted["retention_small_norm"] = small
+        if self.cfg.hc_mult > 1:
+            self._counted["mhc_unbalanced"] = unbalanced
         if head_at is not None:
             return self._head(x)[:, 0], new_caches
         return (self._head(x) if head else None), new_caches

@@ -35,7 +35,8 @@ from .. import initializer as I
 from .layer import Layer
 from .layers import Linear
 
-__all__ = ["DroplessMoE", "SwitchFFN", "dropless_moe", "switch_moe"]
+__all__ = ["DroplessMoE", "SwitchFFN", "dropless_moe", "route",
+           "switch_moe"]
 
 
 def switch_moe(x, router_w, w1, b1, w2, b2, *, capacity: int,
@@ -239,18 +240,46 @@ def _experts_grouped(x, w_gate, w_up, w_down, local, gates, here, sizes):
                           jnp.where(here, gates, 0.0))
 
 
+def route(logits, top_k: int, routing: str = "topk_softmax",
+          score_bias=None, scaling: float = 1.0):
+    """(gates (S, k) float32, picks (S, k) int) of router ``logits``
+    (S, E) float32 under the rule ``routing``:
+
+    - ``"topk_softmax"``: the ``top_k`` largest logits (ties broken as
+      ``lax.top_k`` breaks them, lowest index first), gates = softmax
+      over those ``top_k`` logits;
+    - ``"sigmoid_noaux_tc"`` (DeepSeek-V3's auxiliary-loss-free rule,
+      one group): scores = sigmoid(logits); the picks are the ``top_k``
+      largest of ``scores + score_bias`` (the bias selects and does not
+      weigh); gates = ``scaling`` x the picks' own scores over their
+      sum (plus 1e-20)."""
+    if routing == "topk_softmax":
+        top_l, top_i = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(top_l, axis=-1), top_i
+    enforce(routing == "sigmoid_noaux_tc" and score_bias is not None,
+            "routing rule %r is not one of %s, or lacks its bias",
+            routing, DroplessMoE.ROUTING)
+    scores = jax.nn.sigmoid(logits)
+    _, top_i = jax.lax.top_k(scores + score_bias.astype(jnp.float32),
+                             top_k)
+    picked = jnp.take_along_axis(scores, top_i, axis=-1)
+    return (scaling * picked
+            / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)), top_i
+
+
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
-                 experts_held=None):
+                 experts_held=None, routing: str = "topk_softmax",
+                 score_bias=None, scaling: float = 1.0):
     """Dropless top-k gated experts over tokens, for the experts held
     here.
 
     x: (S, D) tokens; router_w: (D, E), the router over ALL ``E``
     experts; w_gate, w_up: (held, D, F); w_down: (held, F, D), the
     weights of experts ``first .. first + held - 1`` where
-    ``experts_held = (first, held)`` (default: all of them). Routing
-    rule ``"topk_softmax"``: the ``top_k`` largest router logits a token
-    (float32; ties broken as ``lax.top_k`` breaks them, lowest index
-    first), gates = softmax over those ``top_k`` logits. No capacity:
+    ``experts_held = (first, held)`` (default: all of them). The
+    router's logits are float32 and ``routing`` (with ``score_bias``
+    (E,) and ``scaling`` where the rule has them) turns them into
+    ``top_k`` picks and gates a token: :func:`route`. No capacity:
     every (token, pick) pair that falls on a held expert is computed,
     as ``gate * (silu(x Wg) * (x Wu)) Wd``; a pair on an expert that is
     not held adds nothing (its part of the result belongs to the chip
@@ -271,8 +300,8 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     first, held = (0, e) if experts_held is None else experts_held
     with jax.named_scope("moe_route"):
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-        top_l, top_i = jax.lax.top_k(logits, top_k)        # (S, k)
-        gates = jax.nn.softmax(top_l, axis=-1)
+        gates, top_i = route(logits, top_k, routing, score_bias,
+                             scaling)                      # (S, k)
         local = top_i - first
         here = (local >= 0) & (local < held)
         sizes = jnp.bincount(jnp.where(here, local, held).reshape(-1),
@@ -294,16 +323,21 @@ class DroplessMoE(Layer):
     leaves the rest to the chips that hold the others: summed over a
     partition of the experts the parts are the whole layer
     (``tests/test_hybrid.py``). A shared (always-on) MLP is the caller's.
+    ``routing`` names the rule that turns router logits into picks and
+    gates (:func:`route`, one of :attr:`ROUTING`);
+    ``"sigmoid_noaux_tc"`` brings a parameter ``score_bias``
+    (num_experts,) and multiplies the normalised gates by ``scaling``.
 
     ``forward(x (..., D)) -> (..., D)``; ``forward_counted`` also
     returns the (count,) int32 pairs each held expert got;
     ``streams_densely(rows)`` says which body that many rows take."""
 
-    ROUTING = ("topk_softmax",)
+    ROUTING = ("topk_softmax", "sigmoid_noaux_tc")
 
     def __init__(self, d_model: int, d_ff: int, num_experts: int,
                  top_k: int, experts_held=None,
-                 routing: str = "topk_softmax", dtype=None):
+                 routing: str = "topk_softmax", dtype=None,
+                 scaling: float = 1.0):
         super().__init__()
         first, count = experts_held or (0, num_experts)
         enforce(routing in self.ROUTING,
@@ -317,8 +351,12 @@ class DroplessMoE(Layer):
                 (first, count), num_experts)
         self.num_experts, self.top_k = num_experts, top_k
         self.experts_held = (int(first), int(count))
+        self.routing, self.scaling = routing, float(scaling)
         self.router = Linear(d_model, num_experts, bias_attr=False,
                              dtype=dtype)
+        if routing == "sigmoid_noaux_tc":
+            self.create_parameter("score_bias", (num_experts,), dtype,
+                                  is_bias=True)
         init = I.XavierUniform()
         self.create_parameter("w_gate", (count, d_model, d_ff), dtype, init)
         self.create_parameter("w_up", (count, d_model, d_ff), dtype, init)
@@ -329,7 +367,10 @@ class DroplessMoE(Layer):
         y, tokens = dropless_moe(
             x.reshape(-1, x.shape[-1]), self.router.weight, self.w_gate,
             self.w_up, self.w_down, top_k=self.top_k,
-            experts_held=self.experts_held)
+            experts_held=self.experts_held, routing=self.routing,
+            score_bias=(self.score_bias
+                        if self.routing == "sigmoid_noaux_tc" else None),
+            scaling=self.scaling)
         return y.reshape(*lead, -1), tokens
 
     def forward(self, x):

@@ -175,7 +175,32 @@ class force_flash:
         return False
 
 
-def rotary_embedding(x, positions, theta: float = 10000.0):
+def yarn_frequencies(half: int, theta: float, factor: float,
+                     original_max_position: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0):
+    """YaRN's rotary frequencies (Peng et al., arXiv:2309.00071, "NTK by
+    parts"), (half,) float32: pair ``i`` turns at ``theta^(-i / half)``
+    where it makes more than ``beta_fast`` turns over the
+    ``original_max_position`` positions the model was trained on, at
+    that over ``factor`` (positions interpolated) where it makes fewer
+    than ``beta_slow``, and at a linear blend between the two pairs
+    where those counts fall. The attention's own temperature
+    (``mscale``) is the caller's: it scales scores, not angles."""
+    import math
+
+    def pair_of(turns):     # the pair that makes ``turns`` turns
+        return half * math.log(original_max_position
+                               / (turns * 2 * math.pi)) / math.log(theta)
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), half - 1)
+    plain = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def rotary_embedding(x, positions, theta: float = 10000.0, yarn=None):
     """Rotary position embedding (RoPE) over (B, T, H, D) with even D.
 
     ``positions``: (T,) or (B, T) integer absolute positions — decode
@@ -183,6 +208,9 @@ def rotary_embedding(x, positions, theta: float = 10000.0):
     positions (rotation happens on the pre-shard arrays, so sharded
     attention sees position-correct q/k). Rotate-half convention
     (GPT-NeoX/Llama): pairs are (x[..., i], x[..., i + D/2]).
+    ``yarn``: None, or the keyword arguments of
+    :func:`yarn_frequencies` (``factor``, ``original_max_position``,
+    ``beta_fast``, ``beta_slow``) for a context extended that way.
 
     Green-field (the reference era predates RoPE; its positional story
     is learned position tables, reference:
@@ -191,7 +219,10 @@ def rotary_embedding(x, positions, theta: float = 10000.0):
     d = x.shape[-1]
     enforce(d % 2 == 0, "rotary needs an even head_dim, got %s", d)
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = yarn_frequencies(half, theta, **dict(yarn))
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., T, half)
     # insert the head axis before the feature axis; (T, half) inputs
     # broadcast over batch AND heads, (B, T, half) over heads only

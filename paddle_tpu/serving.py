@@ -679,7 +679,14 @@ class BatchedDecoder:
     and ``init_cache(slots, capacity)``, the arena as a list with one
     pytree a block whose leaves all lead with the slot axis. A model
     may declare ``cache_kinds`` (a block: ``"kv"``, addressed by
-    position, or ``"recurrent"``, a state of fixed size). With
+    position, or ``"recurrent"``, a state of fixed size). A ``"kv"``
+    entry need not be keys and values by head: the contiguous arena
+    takes any pytree whose leaves lead with (slots, capacity), and a
+    model whose ``cache_records`` names a ``"latent"`` entry (one
+    compressed record a position, ``nn.LatentAttention``) is served
+    from it, while the modes that assume keys and values by head
+    (pages, prefix reuse, a quantised pool, handoff, speculative
+    verify, chunked prefill) are refused for it by name. With
     recurrent state a prefill starts the slot's state from zeros and
     advances it over exactly the prompt, and every mode that addresses
     the cache by position (pages, prefix reuse, handoff, speculative
@@ -732,21 +739,31 @@ class BatchedDecoder:
         self.model = model
         kinds = getattr(model, "cache_kinds", None)
         self._recurrent = bool(kinds) and "recurrent" in kinds
-        if self._recurrent:
-            # a recurrent state is one value a slot, not a value a
-            # position: nothing below can page it, share a prefix of
-            # it, hand it over as pages, roll it back after a rejected
-            # draft, or resume it mid-prompt without a snapshot
-            for what, on in (("pages=", pages is not None),
-                             ("prefix_cache", prefix_cache),
-                             ("kv_dtype", kv_dtype is not None),
-                             ("draft= (speculative verify)",
-                              draft is not None),
-                             ("prefill_chunk", prefill_chunk is not None)):
-                enforce(not on, "%s is refused for a model with "
-                        "recurrent state: the state is not addressable "
-                        "by position, and snapshots of it are not "
-                        "kept", what)
+        records = getattr(model, "cache_records", None)
+        self._latent = bool(records) and "latent" in records
+        # a recurrent state is one value a slot, not a value a position:
+        # nothing below can page it, share a prefix of it, hand it over
+        # as pages, roll it back after a rejected draft, or resume it
+        # mid-prompt without a snapshot. A latent record is addressed by
+        # position, but it is not keys and values by head: everything
+        # below that pages, quantises, shares, hands over or verifies
+        # against a cache goes through ops/paged_kv.attend, which is; a
+        # chunk that continues a cache is not written for a record
+        # (LatentAttention.forward_chunk takes the offset 0 alone)
+        refused = (
+            "recurrent state: the state is not addressable by position, "
+            "and snapshots of it are not kept" if self._recurrent else
+            "a latent record: the cache holds one compressed record a "
+            "position, not keys and values by head, and the paged pool, "
+            "its quantised form, the handoff and the verify chunk assume "
+            "those" if self._latent else None)
+        for what, on in (("pages=", pages is not None),
+                         ("prefix_cache", prefix_cache),
+                         ("kv_dtype", kv_dtype is not None),
+                         ("draft= (speculative verify)", draft is not None),
+                         ("prefill_chunk", prefill_chunk is not None)):
+            enforce(not (on and refused), "%s is refused for a model "
+                    "with %s", what, refused)
         # CHUNKED PREFILL (opt-in): admission only ALLOCATES; the
         # prompt then prefills prefill_chunk tokens per serving-loop
         # tick (one chunk per tick across all admitting slots), so
@@ -1351,6 +1368,10 @@ class BatchedDecoder:
         enforce(not self._recurrent, "prefill_export is refused for a "
                 "model with recurrent state: a KVHandoff carries pages "
                 "of keys and values, and the state is neither")
+        enforce(not self._latent, "prefill_export is refused for a model "
+                "with a latent record: a KVHandoff carries pages of keys "
+                "and values by head, and the record is one compressed "
+                "vector a position")
         enforce(self.paged, "prefill_export requires paged mode "
                 "(pages=N) — the handoff payload is KV pages")
         # deadline check BEFORE the prefill compute: an expired request
@@ -1419,6 +1440,10 @@ class BatchedDecoder:
         enforce(not self._recurrent, "inject_prefilled is refused for a "
                 "model with recurrent state: a KVHandoff carries pages "
                 "of keys and values, and the state is neither")
+        enforce(not self._latent, "inject_prefilled is refused for a "
+                "model with a latent record: a KVHandoff carries pages of "
+                "keys and values by head, and the record is one "
+                "compressed vector a position")
         enforce(self.paged, "inject_prefilled requires paged mode "
                 "(pages=N) on the decode replica")
         enforce(isinstance(handoff, KVHandoff),

@@ -229,6 +229,28 @@ def test_retention_step_compiles_and_writes_the_state_in_place(one_chip):
     assert mem.temp_size_in_bytes < state // 8
 
 
+def test_mla_decode_compiles_at_the_cells_widths(one_chip):
+    """The latent decode read at the Xing4.0 cell's widths (16 rows, 32
+    heads over one record of 512 + 64 bfloat16 numbers, 16384 positions,
+    blocks of 1024 records): Mosaic takes the 64-wide rotary block, the
+    two products that make one score and the clamped block walk, and the
+    read holds no copy of the records."""
+    from paddle_tpu.ops.pallas.mla_decode import block_k, mla_decode
+
+    b, h, lat, rope, cap = 16, 32, 512, 64, 16384
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    assert block_k(cap) == 1024
+    compiled = jax.jit(lambda qa, qr, c, r, t: mla_decode(
+        qa, qr, c, r, t, scale=0.1, interpret=False)).lower(
+        sd((b, h, lat)), sd((b, h, rope)), sd((b, cap, lat)),
+        sd((b, cap, rope)), sd((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%pt_mla_decode" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        b * cap * lat * 2) // 8
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
 def test_flash_decode_paged_compiles(one_chip, quantized):
     n_log = CAPACITY // PAGE
