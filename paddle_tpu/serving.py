@@ -7,7 +7,8 @@ attention with per-slot positions). Requests queue host-side; when a
 slot finishes (eos or its max_len), the next prompt is prefilled into
 that slot between steps and the batch keeps moving — no padding the
 whole batch to the slowest request, no recompiles (prompt lengths pad
-to fixed buckets; everything else is static).
+to fixed buckets; everything else is static). On the contiguous arena
+the step runs one ahead of the host (``BatchedDecoder._step_multi``).
 
 This is the serving-runtime capstone over the decode stack: generate()
 semantics per request (greedy or temperature/top-k/top-p sampling, eos
@@ -41,7 +42,7 @@ import contextlib
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -79,8 +80,35 @@ def _arena_jit(fn, name: str, arena_argnums):
     new one, and only a donated argument may be written in place —
     undonated, XLA copies the whole arena into the output and updates
     the copy. The one way a serving program is compiled: the weights,
-    cursors, tokens and page table are never in ``arena_argnums``."""
+    cursors, tokens and page table are never in ``arena_argnums``, and
+    a program that takes no arena (``pt_step_cursor``) donates
+    nothing."""
     return jax.jit(_named(fn, name), donate_argnums=arena_argnums)
+
+
+def _advance_cursor(tok, t, toks, live):
+    """The cursor a decode step leaves, computed where the step's
+    tokens are (program ``pt_step_cursor``): a ``live`` row goes on
+    from the last of its k tokens at the position after them, any other
+    row stays where it was. Its own small program and not two more
+    outputs of ``pt_decode_step``: that program's arguments and outputs
+    are what the benchmark's compile rehearsal, the AOT artifacts and
+    their tests call it with."""
+    return (jnp.where(live, toks[:, -1], tok),
+            jnp.where(live, t + toks.shape[1], t))
+
+
+_advance_cursor = _arena_jit(_advance_cursor, "pt_step_cursor", ())
+
+
+class _StepInFlight(NamedTuple):
+    """A decode step dispatched and not yet read by the host."""
+    toks: Any          # (slots, k) tokens, on the device
+    counted: Any       # () or (the step's counters,), on the device
+    live: np.ndarray   # the rows it stepped for a request
+    owners: list       # the request of every slot at dispatch
+    kd: int            # tokens a row
+    t_start: float     # its dispatch, or the step before it read, if later
 
 
 class ArenaLostError(RuntimeError):
@@ -311,7 +339,14 @@ class ArenaCounters:
     those whose first token came from a step of the last prompt token
     through the whole model, a second read of every weight, and not
     from the prefill's own pass (0 on the contiguous arena with whole
-    prompts; the paged, prefix-hit and chunked paths still re-step)."""
+    prompts; the paged, prefix-hit and chunked paths still re-step).
+    ``steps`` counts every decode step the host has read, whether or
+    not the model counts anything in it. ``steps_ahead``: those of them
+    that were dispatched while the step before was still unread (the
+    contiguous arena's look-ahead; 0 on a paged or speculative arena,
+    whose tick is synchronous); ``rows_dropped``: the rows of such
+    steps whose tokens were thrown away, because the row had ended on
+    ``eos`` or was torn down while the step was in flight."""
 
     def __init__(self, state_bytes: Dict[str, int]):
         self.state_bytes = state_bytes
@@ -319,8 +354,11 @@ class ArenaCounters:
         self.steps = 0
         self.prefills = 0
         self.prefill_resteps = 0
+        self.steps_ahead = 0
+        self.rows_dropped = 0
 
     def add(self, counted: Dict[str, Any]) -> None:
+        """One decode step read, with what it counted ({}: nothing)."""
         for name, value in counted.items():
             value = np.asarray(value, np.int64)
             self.sums[name] = self.sums.get(name, 0) + value
@@ -707,6 +745,22 @@ class BatchedDecoder:
     it consumed the arena marks the decoder ``arena_lost``
     (``_arena_guard``).
 
+    **On a contiguous arena the decode step runs one ahead of the
+    host** (``_step_multi``): the step's cursor (``tok``, ``t``) stays
+    on the device, step N+1 is dispatched before step N's tokens are
+    fetched, and the host's part of a tick (emit, harvest, admit, the
+    replica's lock going round) runs under the device's next step. The
+    tokens are the synchronous loop's. A request's tokens reach it one
+    step later in absolute time; a slot freed when step N is read is
+    prefilled after step N+1 and joins step N+2; a budget's end costs
+    no surplus step (the host knows it before the dispatch), an ``eos``
+    costs one row of one step, dropped and counted
+    (``counters.rows_dropped``); a device fault shows when the step is
+    read, one tick after its dispatch. A paged pool and a speculative
+    draft need the cursor set by the host before the next dispatch
+    (freed pages are handed on; a round's accepted count moves the
+    cursor) and keep the synchronous tick (``_step_sync``).
+
     ``submit()`` enqueues; ``run()`` drives to completion and returns
     {request_id: np.ndarray of generated ids (prompt excluded)}.
     Sampling params apply to every request (temperature=0 = greedy);
@@ -930,6 +984,9 @@ class BatchedDecoder:
         # mode drops to k=1 without retracing the k=decode_steps fn
         self._step_fns: Dict[int, object] = {}
         self._spec_fn = None
+        # the decode step dispatched and not yet read (contiguous arena
+        # without a draft only: ``_step_multi``)
+        self._ahead: Optional[_StepInFlight] = None
         # SLO degrade lever (router-driven): forces decode_steps=1 and
         # bypasses speculative rounds until cleared — see set_degraded
         self.degraded = False
@@ -1285,8 +1342,12 @@ class BatchedDecoder:
         consumes the arena among the arguments (argument 1, donated):
         whoever dispatches assigns the returned arena back, and uses
         the arguments afterwards for their shapes at most (lowering
-        reads no buffer)."""
-        kd = 1 if self.degraded else self.decode_steps
+        reads no buffer). ``self.tok`` / ``self.t`` among them are, on
+        a contiguous arena, the arrays the last step's
+        ``_advance_cursor`` returned, patched by ``_activate``: the
+        host never fetches them, so they may still be in the making
+        when the next step is dispatched on them."""
+        kd = self._kd()
         step_fn = self._step_fns.get(kd)
         if step_fn is None:
             step_fn = self._step_fns[kd] = self._build_multi_step(kd)
@@ -1297,6 +1358,10 @@ class BatchedDecoder:
                              gens)
         return step_fn, (self._mstate, self.caches, self.tok, self.t,
                          gens)
+
+    def _kd(self) -> int:
+        """Tokens a row the next dispatch produces."""
+        return 1 if self.degraded else self.decode_steps
 
     def lower_step(self):
         """``jax.stages.Lowered`` of the decode-step program the arena
@@ -1319,13 +1384,19 @@ class BatchedDecoder:
         active, and prefill rewrites [0, bucket) wholesale); a
         recurrent state advanced by junk is zeroed by the slot's next
         prefill. Like every dispatch it consumes the arena and rebinds
-        the decoder to the one the programs return."""
+        the decoder to the one the programs return. On a contiguous
+        arena it also runs the cursor's program (``_advance_cursor``)
+        with no row live, which leaves the cursor as it was; the step
+        is waited for, so nothing stays in flight."""
         with self._arena_guard():
             step_fn, args = self._step_call()
             if self.paged:
                 self.pools, toks = step_fn(*args)
             else:
                 self.caches, toks, *_ = step_fn(*args)
+                self.tok, self.t = _advance_cursor(
+                    self.tok, self.t, toks,
+                    jnp.zeros((self.slots,), bool))
             jax.block_until_ready(toks)
             if self.draft is not None and not self.degraded:
                 # spec arenas serve through the spec round: warm that
@@ -2094,15 +2165,91 @@ class BatchedDecoder:
             (1,))
 
     def _step_multi(self):
-        """decode_steps host side: append each row's k tokens in order
-        with per-TOKEN budget/eos finishing (nothing emits past eos or
-        budget; a mid-window finish discards the tail). Degraded mode
-        dispatches the k=1 executable instead (separate cache entry —
-        no retrace when toggling)."""
-        if not self.active.any():
+        """The contiguous arena's decode step, run ONE AHEAD of the
+        host: dispatch step N+1 on the cursor step N left on the device,
+        then settle step N (fetch its tokens, emit, finish rows), so the
+        host's part of a tick runs under the device's next step and not
+        between two. ``_ahead`` is the step in flight.
+
+        What the order rests on: a row whose budget the tokens in flight
+        exhaust is not stepped again (``live``), so a budget's end never
+        costs a surplus step; a row that ends on ``eos`` is learned one
+        step late, and the one surplus row of tokens is dropped at
+        settle (``rows_dropped``), as is the row of a request torn down
+        meanwhile (a row is emitted only to the request it was
+        dispatched for). A slot freed at settle is prefilled by the next
+        tick's ``_admit`` into the arena step N+1 returned, so the
+        device orders the prefill after N+1 and the row joins N+2. A
+        step that fails on the device surfaces at its settle, inside
+        that tick's ``_arena_guard``. With no row live nothing is
+        dispatched, and a step left in flight with no row active is
+        settled at once: ``run()`` and ``LocalReplica`` stop on
+        ``active`` and leave nothing in flight."""
+        prev = self._ahead
+        if prev is None and not self.active.any():
             return
-        kd = 1 if self.degraded else self.decode_steps
-        was_active = self.active.copy()
+        tick_ctx, tick_cm = self._decode_tick_span()
+        with tick_cm:
+            # a failed dispatch leaves ``prev`` in flight
+            self._ahead = self._dispatch_ahead(prev)
+            if prev is not None:
+                self._settle(prev, tick_ctx)
+                if self._ahead is not None:
+                    # the device began it when ``prev`` ended: the time
+                    # a token of it took is counted from here
+                    self._ahead = self._ahead._replace(
+                        t_start=time.perf_counter())
+            if self._ahead is not None and not self.active.any():
+                # every row it stepped ended at this settle (``eos``,
+                # a teardown): read it now, for its counters and its
+                # failures, and drop its rows
+                nxt, self._ahead = self._ahead, None
+                self._settle(nxt, tick_ctx)
+
+    def _decode_tick_span(self):
+        """(trace context, span) of one decode tick. One dispatch
+        advances every active slot, so the tick rides the first SAMPLED
+        slot's context (an unsampled context must not shadow a sampled
+        neighbor — it would starve that request's timeline of its
+        decode ticks); inert while telemetry is off."""
+        if not telemetry.enabled():
+            return None, _NULL_CM
+        ctx = next((c for c in self._slot_trace
+                    if c is not None and c.sampled), None)
+        if ctx is None:
+            return None, _NULL_CM
+        return ctx, _tracing.span("serve.decode.tick", ctx=ctx,
+                                  k=self._kd(),
+                                  n_active=int(self.active.sum()))
+
+    def _dispatch_ahead(self, prev: Optional[_StepInFlight]):
+        """Dispatch the decode step over the rows that have a token
+        left to produce beyond those in flight in ``prev``, and leave
+        the cursor it ends on on the device; None where no row has."""
+        pending = np.zeros((self.slots,), np.int64)
+        if prev is not None:
+            same = np.fromiter(
+                (a is b for a, b in zip(prev.owners, self.owner)),
+                bool, self.slots)
+            pending[prev.live & same] = prev.kd
+        live = self.active & (self.budget - pending > 0)
+        if not live.any():
+            return None
+        step = self._dispatch_step(live)
+        # the cursor is never fetched: its program is dispatched behind
+        # the step, and the next step reads it where it is
+        with Span("serve.step.cursor"):
+            self.tok, self.t = _advance_cursor(
+                self.tok, self.t, step.toks, jnp.asarray(live))
+        if prev is not None:
+            self.counters.steps_ahead += 1
+        return step
+
+    def _dispatch_step(self, live: np.ndarray) -> _StepInFlight:
+        """Dispatch the decode step (k=1 while degraded: a separate
+        cache entry, so toggling retraces nothing) and rebind the arena
+        to the one it returns; ``live`` are the rows it steps for a
+        request."""
         telem = telemetry.enabled()
         if telem:
             # the weight token participates: run()'s weight re-snapshot
@@ -2111,46 +2258,50 @@ class BatchedDecoder:
             # just (tok, t) would never see it
             _recompile.record("serving.step", self.tok, self.t,
                               weights=self._weights_fp)
-            t_dispatch = time.perf_counter()
-        # per-decode-tick span: one dispatch advances every active
-        # slot, so the tick rides the first SAMPLED slot's context
-        # (an unsampled context must not shadow a sampled neighbor —
-        # it would starve that request's timeline of its decode ticks)
-        tick_ctx = (next((c for c in self._slot_trace
-                          if c is not None and c.sampled), None)
-                    if telem else None)
-        tick_cm = (_tracing.span("serve.decode.tick", ctx=tick_ctx,
-                                 k=kd,
-                                 n_active=int(was_active.sum()))
-                   if telem and tick_ctx is not None else _NULL_CM)
-        with tick_cm:
-            with Span("serve.step.dispatch"):
-                step_fn, args = self._step_call()
-                if telem:
-                    # cost-ledger registration, once per step variant
-                    # (set lookup after the first tick), BEFORE the
-                    # dispatch: the step consumes the arena in ``args``
-                    _costs.ensure_program(f"serving.step[k={kd}]",
-                                          step_fn, args, origin="serving")
-                counted = ()
-                if self.paged:
-                    self.pools, toks = step_fn(*args)
-                else:
-                    self.caches, toks, *counted = step_fn(*args)
-            # the host blocked on the device; what the step counted
-            # comes over in the same fetch
-            with Span("serve.step.fetch"):
-                toks, counted = jax.device_get((toks, counted))
-                toks = np.asarray(toks).astype(np.int32)
-            if counted:
-                self.counters.add(counted[0])
+        with Span("serve.step.dispatch"):
+            step_fn, args = self._step_call()
+            kd = self._kd()
+            if telem:
+                # cost-ledger registration, once per step variant
+                # (set lookup after the first tick), BEFORE the
+                # dispatch: the step consumes the arena in ``args``
+                _costs.ensure_program(f"serving.step[k={kd}]",
+                                      step_fn, args, origin="serving")
+            t_start = time.perf_counter()
+            counted = ()
+            if self.paged:
+                self.pools, toks = step_fn(*args)
+            else:
+                self.caches, toks, *counted = step_fn(*args)
+        return _StepInFlight(toks, counted, live, list(self.owner), kd,
+                             t_start)
+
+    def _settle(self, step: _StepInFlight, tick_ctx) -> np.ndarray:
+        """Read a dispatched step (the host blocks on the device here;
+        what the step counted comes over in the same fetch) and emit
+        its tokens to the requests it stepped; returns the tokens."""
+        with Span("serve.step.fetch"):
+            toks, counted = jax.device_get((step.toks, step.counted))
+            toks = np.asarray(toks).astype(np.int32)
+        self.counters.add(counted[0] if counted else {})
+        rows = [s for s in np.flatnonzero(step.live)
+                if self.owner[s] is step.owners[s]]
+        self.counters.rows_dropped += int(step.live.sum()) - len(rows)
+        self._emit_step(toks, rows, step.kd, step.t_start, tick_ctx)
+        return toks
+
+    def _emit_step(self, toks, rows, kd: int, t_start: float,
+                   tick_ctx) -> None:
+        """A fetched step's host side: append each of ``rows``' k
+        tokens in order with per-TOKEN budget/eos finishing (nothing
+        emits past eos or budget; a mid-window finish discards the
+        tail), then the tick's accounting; ``t_start`` is when the
+        step's own time began."""
         self._warmed = True
         now = time.perf_counter()
         n_emitted = 0
         with Span("serve.step.emit"):
-            for s in range(self.slots):
-                if not was_active[s]:
-                    continue
+            for s in rows:
                 r = self.owner[s]
                 for j in range(kd):
                     self.emitted[s].append(int(toks[s, j]))
@@ -2169,10 +2320,10 @@ class BatchedDecoder:
         self.tick_count += 1
         self.tick_tokens += n_emitted
         self.tick_capacity += self.slots * kd
-        if telem and n_emitted:
+        if telemetry.enabled() and n_emitted:
             m = _serving_metrics()
             m["tokens"].inc(n_emitted)
-            itl = (time.perf_counter() - t_dispatch) / n_emitted
+            itl = (time.perf_counter() - t_start) / n_emitted
             m["decode_latency"].observe(
                 itl,
                 exemplar=(tick_ctx.trace_id
@@ -2185,6 +2336,23 @@ class BatchedDecoder:
                 f"serving.step[k={kd}]", self._backend(), itl,
                 kind="itl",
                 degraded=self.degraded)
+
+    def _step_sync(self):
+        """The synchronous decode step of a paged or speculative arena:
+        dispatch, fetch, emit, and only then the cursor, set from the
+        fetched tokens on the host. These arenas need that order: a
+        retired row's pages are freed and may be handed to another
+        request, which is safe because the host parks the row's cursor
+        (``_maybe_finish``, ``_expire_slots``) BEFORE the next dispatch,
+        and a speculative round sets every cursor from its accepted
+        count on the host (``_step_spec``)."""
+        if not self.active.any():
+            return
+        was_active = self.active.copy()
+        tick_ctx, tick_cm = self._decode_tick_span()
+        with tick_cm:
+            step = self._dispatch_step(was_active)
+            toks = self._settle(step, tick_ctx)
         # retired rows keep what _maybe_finish left (paged parking);
         # np.asarray of self.t and self.tok are two more device fetches
         with Span("serve.step.cursor"):
@@ -2193,7 +2361,7 @@ class BatchedDecoder:
             self.tok = jnp.asarray(np.where(
                 keep, toks[:, -1], np.asarray(self.tok)).astype(np.int32))
             self.t = jnp.asarray(np.where(
-                keep, cur_t + kd, cur_t).astype(np.int32))
+                keep, cur_t + step.kd, cur_t).astype(np.int32))
 
     def _build_spec_step(self):
         """One speculative ROUND over the whole arena, jitted: gamma
@@ -2412,8 +2580,12 @@ class BatchedDecoder:
         # k == 1 rides the same generalized scan path (length-1 scan,
         # in-device pick — pinned token-identical to the historical
         # host-pick loop by TestMultiStepDecode): ONE epilogue for
-        # emit/budget/eos and one key chain, never two copies to keep
-        # in lockstep
+        # emit/budget/eos (``_emit_step``) and one key chain, never two
+        # copies to keep in lockstep. Which body runs is a fact of the
+        # arena, not an option: a paged pool and a draft need the host
+        # to set the cursor before the next dispatch
+        if self.paged or self.draft is not None:
+            return self._step_sync()
         return self._step_multi()
 
     def _backend(self) -> str:

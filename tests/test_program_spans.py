@@ -161,13 +161,16 @@ def _host_spans(trace_dir):
 def capture(tmp_path_factory):
     """Telemetry is OFF throughout: a profiler session started by
     anyone (here the test, as a benchmark's tracer or POST /profilez
-    would) sees the program's spans. Two replicas share the session, a
-    monolithic and a chunked-prefill arena."""
+    would) sees the program's spans. Three replicas share the session:
+    a monolithic and a chunked-prefill contiguous arena, whose step
+    runs one ahead of the host, and a paged one, whose tick is
+    synchronous."""
     telemetry.disable()
     out = str(tmp_path_factory.mktemp("xplane"))
     mono = LocalReplica(_decoder(), name="mono")
     chunked = LocalReplica(_decoder(prefill_chunk=16), name="chunked")
-    for rep in (mono, chunked):
+    paged = LocalReplica(_decoder(pages=8, page_size=64), name="paged")
+    for rep in (mono, chunked, paged):
         rep.warmup()
         rep.start()
     rng = np.random.default_rng(0)
@@ -176,7 +179,7 @@ def capture(tmp_path_factory):
         with mono._locked("other"):     # a caller that is neither
             time.sleep(0.01)
         want = {}
-        for rep in (mono, chunked):
+        for rep in (mono, chunked, paged):
             want[rep] = {rep.submit(rng.integers(1, 500, (n,)).astype(
                 np.int32), 6) for n in (5, 23, 40)}
         for rep, rids in want.items():
@@ -187,8 +190,8 @@ def capture(tmp_path_factory):
             assert rids <= set(got), (rep.name, rids, set(got))
     finally:
         jax.profiler.stop_trace()
-        mono.stop()
-        chunked.stop()
+        for rep in (mono, chunked, paged):
+            rep.stop()
     return _host_spans(out)
 
 
@@ -216,27 +219,52 @@ def test_prefill_lies_inside_admit(capture):
 
 
 def test_the_phases_tile_a_stepping_tick(capture):
-    """No child overlaps another, and dispatch, fetch, emit and cursor
-    follow each other in that order inside a tick."""
-    order = ["serve.step.dispatch", "serve.step.fetch", "serve.step.emit",
-             "serve.step.cursor"]
-    n = 0
+    """No child overlaps another. A synchronous tick (the paged arena)
+    holds dispatch, fetch, emit and cursor in that order: the host sets
+    the cursor from the tokens it fetched. A tick of a contiguous arena
+    dispatches the NEXT step and the program that leaves its cursor on
+    the device, and only then fetches the step in flight; the first
+    tick after an empty arena only dispatches, and the tick with no row
+    left to step only fetches and emits."""
+    d, f, e, c = ("serve.step.dispatch", "serve.step.fetch",
+                  "serve.step.emit", "serve.step.cursor")
+    seen = {}
     for _, a, b, line, _ in (s for s in capture if s[0] == "serve.tick"):
         kids = sorted((s for s in capture
                        if s[3] == line and s[0] in TICK_CHILDREN
                        and a <= s[1] and s[2] <= b), key=lambda s: s[1])
         for x, y in zip(kids, kids[1:]):
             assert x[2] <= y[1], (x, y)
-        steps = [k[0] for k in kids if k[0] in order]
+        steps = tuple(k[0] for k in kids if k[0] in (d, f, e, c))
         if steps:
-            assert steps == order
-            n += 1
-    assert n >= 5
+            seen[steps] = seen.get(steps, 0) + 1
+    assert set(seen) == {(d, f, e, c), (d, c, f, e), (d, c), (f, e)}, seen
+    assert seen[d, f, e, c] >= 5                # the paged replica's
+    assert seen[d, c, f, e] >= 5                # a step ahead, one read
+    assert seen[d, c] >= 2 and seen[f, e] >= 2
+
+
+def test_only_the_synchronous_tick_fetches_the_cursor(capture):
+    """``serve.step.cursor`` on the two contiguous replicas is one
+    dispatch; on the paged one it is two fetches and two uploads, after
+    the emit. The first is never the last child of its tick."""
+    last = {}
+    for _, a, b, line, _ in (s for s in capture if s[0] == "serve.tick"):
+        kids = [s for s in capture if s[3] == line and a <= s[1] <= b
+                and s[0].startswith("serve.step.")]
+        if kids:
+            last.setdefault(line, set()).add(
+                max(kids, key=lambda s: s[1])[0])
+    assert len(last) == 3
+    assert sorted(map(sorted, last.values())) == [
+        ["serve.step.cursor"],
+        ["serve.step.cursor", "serve.step.emit"],
+        ["serve.step.cursor", "serve.step.emit"]]
 
 
 def test_span_arguments_come_back_as_stats(capture):
     prefills = [s for s in capture if s[0] == "serve.prefill"]
-    assert len(prefills) == 3          # the monolithic replica's three
+    assert len(prefills) == 6          # the monolithic and the paged three
     rids, plens = set(), set()
     for *_, stats in prefills:
         assert {"rid", "plen", "bucket", "queued_us"} <= set(stats)

@@ -431,8 +431,10 @@ class TestChunkedPrefill:
         s_short = next(s for s in range(2)
                        if dec.owner[s] is not None and dec.active[s])
         before = len(dec.emitted[s_short])
+        dec._step()                        # short slot's step dispatched
         dec._prefill_tick()                # one chunk of the long prompt
-        dec._step()                        # short slot decodes meanwhile
+        dec._step()                        # short slot decodes meanwhile:
+        # the step that ran beside the chunk is read, the next is off
         assert dec._pf_order               # long STILL prefilling
         assert len(dec.emitted[s_short]) == before + 1  # ...but short
         # emitted a token between the long prompt's chunk ticks

@@ -8,8 +8,11 @@ harness does not log about a serving cell on standard error beside it:
 of 3 ms that hold at least 0.5% of them, with their share), because
 ``itl_p95_ms`` reads whichever cluster the 95% point falls in (PERF.md
 section 7) and a comparison should see the clusters move; ``[arena]``:
-``serving.last_counters`` (``steps``, ``prefills``, ``prefill_resteps``;
-"None" where that checkout's arena does not count one). The result line
+``serving.last_counters`` (``steps``, ``prefills``, ``prefill_resteps``,
+and of the look-ahead ``steps_ahead``, the decode steps dispatched while
+the one before was unread, and ``rows_dropped``, the rows of such steps
+whose tokens were thrown away; "None" where that checkout's arena does
+not count one). The result line
 and every number in it are ``benchmark/run.py``'s own: the job is run
 by it, unchanged, and this only reads what it returns."""
 
@@ -54,7 +57,8 @@ def main():
         c = serving.last_counters
         print("[arena] " + ", ".join(
             f"{k} {getattr(c, k, None)}"
-            for k in ("steps", "prefills", "prefill_resteps")),
+            for k in ("steps", "prefills", "prefill_resteps",
+                      "steps_ahead", "rows_dropped")),
             file=sys.stderr, flush=True)
         return job
 
