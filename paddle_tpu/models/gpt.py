@@ -29,6 +29,7 @@ from .. import initializer as I
 from .. import nn
 from ..core.enforce import enforce
 from ..nn.layer import Layer
+from ..telemetry.scopes import scope
 
 
 @dataclasses.dataclass
@@ -106,11 +107,13 @@ class GPTBlock(Layer):
         self.drop = nn.Dropout(cfg.dropout)
 
     def forward(self, x, kv_mask=None):
-        x = x + self.drop(self.self_attn(
-            self.norm1(x), causal=True, window=self.attn_window,
-            attn_mask=None if kv_mask is None
-            else kv_mask[:, None, None, :]))
-        return x + self.ffn(self.norm2(x))
+        with scope("attn"):
+            x = x + self.drop(self.self_attn(
+                self.norm1(x), causal=True, window=self.attn_window,
+                attn_mask=None if kv_mask is None
+                else kv_mask[:, None, None, :]))
+        with scope("mlp"):
+            return x + self.ffn(self.norm2(x))
 
 
 class GPTForCausalLM(Layer):
@@ -143,19 +146,26 @@ class GPTForCausalLM(Layer):
         return (self.embed.weight.T if self.cfg.tie_embeddings
                 else self.lm_head)
 
+    @scope("embed")
+    def _embed(self, ids):
+        return self.embed(ids)
+
+    @scope("head")
+    def _head(self, x):
+        return self.norm_f(x) @ self._head_weight()
+
     def _trunk(self, ids, kv_mask=None):
-        x = self.embed(ids)
+        x = self._embed(ids)
         for blk in self.blocks:
             if self.cfg.remat:
                 x = jax.checkpoint(
                     lambda h, b=blk: b(h, kv_mask=kv_mask))(x)
             else:
                 x = blk(x, kv_mask=kv_mask)
-        return self.norm_f(x)
+        return x
 
     def forward(self, ids, kv_mask=None):
-        h = self._trunk(ids, kv_mask=kv_mask)
-        return h @ self._head_weight()
+        return self._head(self._trunk(ids, kv_mask=kv_mask))
 
     def forward_loss(self, ids, labels=None, kv_mask=None,
                      vocab_chunk: int = 1024, ignore_index: int = -100):
@@ -164,7 +174,9 @@ class GPTForCausalLM(Layer):
         ``ignore_index`` holes for masked/padded positions."""
         from ..ops.fused_loss import mean_linear_cross_entropy
 
-        h = self._trunk(ids, kv_mask=kv_mask)
+        x = self._trunk(ids, kv_mask=kv_mask)
+        with scope("head"):     # the head itself is ``linear_ce``
+            h = self.norm_f(x)
         if labels is None:
             labels = jnp.concatenate(
                 [ids[:, 1:],
@@ -199,18 +211,20 @@ class GPTForCausalLM(Layer):
         new_caches = []
         last = len(self.blocks) - 1
         for i, (blk, (ck, cv)) in enumerate(zip(self.blocks, caches)):
-            h = blk.norm1(x)
-            a, ck, cv = attn_step(blk.self_attn, h, ck, cv)
-            x = x + a
+            with scope("attn"):
+                h = blk.norm1(x)
+                a, ck, cv = attn_step(blk.self_attn, h, ck, cv)
+                x = x + a
             if head_at is not None and i == last:
                 x = lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
-            x = x + blk.ffn(blk.norm2(x))
+            with scope("mlp"):
+                x = x + blk.ffn(blk.norm2(x))
             new_caches.append((ck, cv))
         if head_at is not None:
-            return (self.norm_f(x) @ self._head_weight())[:, 0], new_caches
+            return self._head(x)[:, 0], new_caches
         if not head:
             return None, new_caches
-        return self.norm_f(x) @ self._head_weight(), new_caches
+        return self._head(x), new_caches
 
     def _chunk_logits(self, toks, caches, t0, head: bool = True,
                       decode_kernel: bool = False, valid_len=None,
@@ -225,7 +239,7 @@ class GPTForCausalLM(Layer):
         read: keys and values written past it sit above the cursor,
         which masks them, and no position before it attends to them."""
         return self._cached_blocks(
-            self.embed(toks), caches,
+            self._embed(toks), caches,
             lambda sa, h, ck, cv: sa.forward_chunk(
                 h, ck, cv, t0, window=self.cfg.attn_window,
                 decode_kernel=decode_kernel),
@@ -243,7 +257,7 @@ class GPTForCausalLM(Layer):
         (B,) — the continuous-batching step (serving.BatchedDecoder).
         ``tok`` (B,) -> ((B, V) logits, caches)."""
         logits, caches = self._cached_blocks(
-            self.embed(tok[:, None]), caches,
+            self._embed(tok[:, None]), caches,
             lambda sa, h, ck, cv: sa.forward_step_rows(
                 h, ck, cv, t_rows, window=self.cfg.attn_window,
                 decode_kernel=decode_kernel))
@@ -255,7 +269,7 @@ class GPTForCausalLM(Layer):
         scores its gamma+1 candidates at its OWN cursor in ONE pass.
         ``toks`` (B, S) -> ((B, S, V) logits, caches)."""
         return self._cached_blocks(
-            self.embed(toks), caches,
+            self._embed(toks), caches,
             lambda sa, h, ck, cv: sa.forward_chunk_rows(
                 h, ck, cv, t0_rows, window=self.cfg.attn_window))
 
@@ -263,7 +277,7 @@ class GPTForCausalLM(Layer):
         """S positions PER ROW against PAGED caches at per-row chunk
         starts (see _chunk_logits_rows). ``toks`` (B, S)."""
         return self._cached_blocks(
-            self.embed(toks), pools,
+            self._embed(toks), pools,
             lambda sa, h, kp, vp: sa.forward_chunk_paged_rows(
                 h, kp, vp, table, t0_rows,
                 window=self.cfg.attn_window))
@@ -273,7 +287,7 @@ class GPTForCausalLM(Layer):
         per-block [(kpool, vpool), ...] list, ``table`` the shared
         (B, n_log) page table. ``tok`` (B,) -> ((B, V) logits, pools)."""
         logits, pools = self._cached_blocks(
-            self.embed(tok[:, None]), pools,
+            self._embed(tok[:, None]), pools,
             lambda sa, h, kp, vp: sa.forward_step_paged(
                 h, kp, vp, table, t_rows,
                 window=self.cfg.attn_window))
@@ -284,7 +298,7 @@ class GPTForCausalLM(Layer):
         """S prefill positions for ONE row against paged caches (see
         _step_logits_paged). ``toks`` (1, S)."""
         return self._cached_blocks(
-            self.embed(toks), pools,
+            self._embed(toks), pools,
             lambda sa, h, kp, vp: sa.forward_chunk_paged(
                 h, kp, vp, table_row, t0,
                 window=self.cfg.attn_window),

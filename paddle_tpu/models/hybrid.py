@@ -71,6 +71,7 @@ that needs positions (retention's rotary embedding) says so
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional, Tuple, Union
 
@@ -86,6 +87,7 @@ from ..nn.latent import HyperConnection, LatentAttention, PlainResidual
 from ..nn.layer import Layer
 from ..ops import retention, ssm
 from ..ops.attention import rotary_embedding
+from ..telemetry.scopes import scope
 
 MIXERS = ("mamba", "attention", "retention", "latent")
 CHANNEL_MIXES = ("experts", "mlp")
@@ -279,7 +281,7 @@ class SSDMixer(Layer):
         ``valid_len`` positions (default all) advance it. Returns
         (out (B, S, D), new cache)."""
         tail, state = cache
-        with jax.named_scope("ssm_scan"):
+        with scope("ssm_scan"):
             z, xbc, dt = self._project(x)
             xbc, new_tail = ssm.causal_conv1d(
                 xbc, self.conv_weight, self.conv_bias, tail, valid_len)
@@ -294,7 +296,7 @@ class SSDMixer(Layer):
         """One position a row: ``x`` (B, 1, D) -> (out (B, 1, D), new
         cache)."""
         tail, state = cache
-        with jax.named_scope("ssm_step"):
+        with scope("ssm_step"):
             z, xbc, dt = self._project(x[:, 0])
             xbc, new_tail = ssm.causal_conv1d_step(
                 xbc, self.conv_weight, self.conv_bias, tail)
@@ -365,7 +367,7 @@ class RetentionMixer(Layer):
         """``x`` (B, S, D) at positions [t0, t0 + S) continuing
         ``cache``; only the first ``valid_len`` positions (default all)
         advance it. Returns (out (B, S, D), new cache)."""
-        with jax.named_scope("retention_scan"):
+        with scope("retention_scan"):
             q, k, v, log_g = self._project(
                 x, t0 + jnp.arange(x.shape[1]))
             y, cache = retention.retention_chunked(
@@ -378,7 +380,7 @@ class RetentionMixer(Layer):
         ``x`` (B, 1, D) -> (out (B, 1, D), new cache). ``small_norm``
         then holds how many of the step's (row, head) denominators fell
         under ``10 eps`` (an idle slot's junk row counted too)."""
-        with jax.named_scope("retention_step"):
+        with scope("retention_step"):
             q, k, v, log_g = self._project(x, t_rows[:, None])
             num, den, cache = retention.retention_step_parts(
                 q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], cache)
@@ -456,20 +458,33 @@ class HybridBlock(Layer):
         tokens each held expert got, or None where there are no
         experts)."""
         u, held = self.res2.read(x)
-        u = self.norm2(u)
         if self.moe is None:
-            return self.res2.write(x, self.mlp(u), held), None
+            with scope("mlp"):
+                y = self.mlp(self.norm2(u))
+            return self.res2.write(x, y, held), None
+        u = self.norm2(u)
         routed, tokens = self.moe.forward_counted(u)
-        with jax.named_scope("moe_shared"):
+        with scope("moe_shared"):
             shared = self.shared(u)
         return self.res2.write(x, routed + shared, held), tokens
 
+    def mixer_scope(self):
+        """``attn`` around the whole sublayer of a softmax-attention
+        block (norm1, the mixer, the residual add); the other mixers
+        enter scopes of their own around the mixer alone, and the norms
+        and adds beside them are under none."""
+        return (scope("attn") if self.kind == "attention"
+                else contextlib.nullcontext())
+
     def forward(self, x):
-        u, held = self.res1.read(x)
-        h = self.norm1(u)
-        a = (self.mixer(h, causal=True)
-             if self.kind in ("attention", "latent") else self.mixer(h))
-        return self.channel_mix(self.res1.write(x, a, held))[0]
+        with self.mixer_scope():
+            u, held = self.res1.read(x)
+            h = self.norm1(u)
+            a = (self.mixer(h, causal=True)
+                 if self.kind in ("attention", "latent")
+                 else self.mixer(h))
+            x = self.res1.write(x, a, held)
+        return self.channel_mix(x)[0]
 
 
 class HybridForCausalLM(Layer):
@@ -523,6 +538,7 @@ class HybridForCausalLM(Layer):
         the trace of that call."""
         return dict(self._counted)
 
+    @scope("embed")
     def _embed(self, ids):
         e = self.embed(ids)
         e = e * jnp.asarray(self.cfg.embedding_multiplier, e.dtype)
@@ -532,6 +548,7 @@ class HybridForCausalLM(Layer):
                     HyperConnection.state_dtype)
         return e
 
+    @scope("head")
     def _head(self, x):
         if self.cfg.hc_mult > 1:    # the streams are read out as their sum
             x = jnp.sum(x.astype(jnp.float32), axis=-2)
@@ -576,17 +593,18 @@ class HybridForCausalLM(Layer):
         new_caches, tokens, small, unbalanced = [], 0, 0, 0
         rows, last = x.shape[0] * x.shape[1], len(self.blocks) - 1
         for i, (blk, cache) in enumerate(zip(self.blocks, caches)):
-            u, held = blk.res1.read(x)
-            h = blk.norm1(u)
-            if blk.kind in ("attention", "latent"):
-                a, ck, cv = attn_step(blk.mixer, h, *cache)
-                cache = (ck, cv)
-            elif getattr(blk.mixer, "takes_positions", False):
-                a, cache = rec_at(blk.mixer, h, cache)
-                small = small + blk.mixer.small_norm
-            else:
-                a, cache = rec_step(blk.mixer, h, cache)
-            x = blk.res1.write(x, a, held)
+            with blk.mixer_scope():
+                u, held = blk.res1.read(x)
+                h = blk.norm1(u)
+                if blk.kind in ("attention", "latent"):
+                    a, ck, cv = attn_step(blk.mixer, h, *cache)
+                    cache = (ck, cv)
+                elif getattr(blk.mixer, "takes_positions", False):
+                    a, cache = rec_at(blk.mixer, h, cache)
+                    small = small + blk.mixer.small_norm
+                else:
+                    a, cache = rec_step(blk.mixer, h, cache)
+                x = blk.res1.write(x, a, held)
             if head_at is not None and i == last:
                 x = lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
             x, got = blk.channel_mix(x)

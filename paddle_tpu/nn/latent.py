@@ -16,6 +16,7 @@ from ..core.dtypes import default_dtype
 from ..core.enforce import enforce
 from ..ops import latent_attention as LA
 from ..ops.attention import rotary_embedding
+from ..telemetry.scopes import scope
 from .layer import Layer
 from .layers import Linear, RMSNorm
 
@@ -148,7 +149,7 @@ class LatentAttention(Layer):
                 "a cache is not written", t0)
         s = x.shape[1]
         pos = jnp.arange(s, dtype=jnp.int32)
-        with jax.named_scope("mla_prefill"):
+        with scope("mla_prefill"):
             qn, qr = self._queries(x, pos)
             c, r = self._records(x, pos)
             c, r = c.astype(cache_c.dtype), r.astype(cache_r.dtype)
@@ -170,7 +171,7 @@ class LatentAttention(Layer):
         continuous-batching step: each row's record is written at its
         own cursor and its query reads the row's records ``<= t``,
         absorbed. ``x``: (B, 1, D)."""
-        with jax.named_scope("mla_decode"):
+        with scope("mla_decode"):
             pos = t_rows.astype(jnp.int32)[:, None]               # (B, 1)
             qn, qr = self._queries(x, pos)
             c, r = self._records(x, pos)
@@ -186,7 +187,7 @@ class LatentAttention(Layer):
         enforce(causal, "latent attention is written causal")
         pos = jnp.arange(x.shape[1], dtype=jnp.int32)
         kept = self.kv_b_proj.weight.dtype      # as a cache would keep them
-        with jax.named_scope("mla_prefill"):
+        with scope("mla_prefill"):
             c, r = self._records(x, pos)
             return self._decompressed(x, *self._queries(x, pos),
                                       c.astype(kept), r.astype(kept))
@@ -288,7 +289,7 @@ class HyperConnection(Layer):
         return pre, post, res
 
     def read(self, x):
-        with jax.named_scope("mhc_mix"):
+        with scope("mhc_mix"):
             pre, post, res = self.maps(x)
             # elementwise, so float32 stays float32 (a product on the
             # MXU would round its inputs)
@@ -297,7 +298,7 @@ class HyperConnection(Layer):
 
     def write(self, x, y, held):
         post, res = held
-        with jax.named_scope("mhc_mix"):
+        with scope("mhc_mix"):
             f32 = jnp.float32
             mixed = jnp.sum(res[..., None] * x.astype(f32)[..., None, :, :],
                             axis=-2)

@@ -34,6 +34,7 @@ from ..core.enforce import enforce
 from .. import initializer as I
 from .layer import Layer
 from .layers import Linear
+from ..telemetry.scopes import scope
 
 __all__ = ["DroplessMoE", "SwitchFFN", "dropless_moe", "route",
            "switch_moe"]
@@ -196,11 +197,11 @@ def _experts_dense(x, w_gate, w_up, w_down, local, gates):
     other. The weights are read once, in place: no sort, no gather."""
     f32 = jnp.float32
     held = w_gate.shape[0]
-    with jax.named_scope("moe_route"):
+    with scope("moe_route"):
         # a pick on an expert that is not held matches no column
         hit = local[:, :, None] == jnp.arange(held, dtype=local.dtype)
         gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)
-    with jax.named_scope("moe_experts"):
+    with scope("moe_experts"):
         xs = x.astype(w_gate.dtype)
         h = (jax.nn.silu(jnp.einsum("sd,edf->esf", xs, w_gate,
                                     preferred_element_type=f32))
@@ -218,13 +219,13 @@ def _experts_grouped(x, w_gate, w_up, w_down, local, gates, here, sizes):
     f32 = jnp.float32
     s, top_k = local.shape
     held = w_gate.shape[0]
-    with jax.named_scope("moe_route"):
+    with scope("moe_route"):
         # pairs on absent experts sort last, into a group no weight has
         group = jnp.where(here, local, held).reshape(-1)   # (S k,)
         order = jnp.argsort(group, stable=True)
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(s * top_k, dtype=order.dtype))
-    with jax.named_scope("moe_experts"):
+    with scope("moe_experts"):
         xs = x[order // top_k].astype(w_gate.dtype)        # (S k, D)
         h = (jax.nn.silu(jax.lax.ragged_dot(
             xs, w_gate, sizes, preferred_element_type=f32))
@@ -298,7 +299,7 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     each held expert got)."""
     e = router_w.shape[1]
     first, held = (0, e) if experts_held is None else experts_held
-    with jax.named_scope("moe_route"):
+    with scope("moe_route"):
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
         gates, top_i = route(logits, top_k, routing, score_bias,
                              scaling)                      # (S, k)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.enforce import enforce
+from ..telemetry.scopes import scope
 
 
 def _chunk_w(weight, bias, num_chunks, chunk):
@@ -55,7 +56,7 @@ def linear_cross_entropy(hidden, weight, bias, labels, chunk: int = 4096,
     return loss
 
 
-@jax.named_scope("linear_ce")
+@scope("linear_ce")
 def _lce_fwd_impl(hidden, weight, bias, labels, chunk, ignore_index):
     n, d = hidden.shape
     d2, v = weight.shape
@@ -97,7 +98,7 @@ def _lce_fwd_impl(hidden, weight, bias, labels, chunk, ignore_index):
     return loss, (hidden, weight, bias, labels, lse)
 
 
-@jax.named_scope("linear_ce")
+@scope("linear_ce")
 def _lce_bwd(chunk, ignore_index, res, g):
     hidden, weight, bias, labels, lse = res
     n, d = hidden.shape

@@ -33,6 +33,7 @@ from ..core.enforce import enforce
 from ..core.mesh import get_mesh, mesh_scope
 from ..nn.layer import Layer
 from ..optimizer.optimizers import Optimizer
+from ..telemetry import scopes as _scopes
 from ..telemetry.trace import RecordEvent
 from .plan import Plan, compile_step, pmean_axes
 
@@ -333,8 +334,9 @@ class Trainer:
         loss, metrics, new_buffers = self._pmean(
             (loss, metrics, new_buffers))
         grads = self._reduce_grads(grads, rng)
-        new_params, new_opt_state = self.optimizer.apply(params, grads,
-                                                         opt_state)
+        with _scopes.scope("optimizer"):
+            new_params, new_opt_state = self.optimizer.apply(
+                params, grads, opt_state)
         return loss, metrics, new_params, new_buffers, new_opt_state
 
     def _accum_step(self, params, buffers, opt_state, accum, count, rng,
@@ -368,8 +370,9 @@ class Trainer:
         count = count + 1
         do_apply = count >= k
         mean_grads = jax.tree_util.tree_map(lambda a: a / k, accum)
-        cand_params, cand_opt = self.optimizer.apply(params, mean_grads,
-                                                     opt_state)
+        with _scopes.scope("optimizer"):
+            cand_params, cand_opt = self.optimizer.apply(
+                params, mean_grads, opt_state)
         sel = lambda new, old: jax.tree_util.tree_map(
             lambda n, o: jnp.where(do_apply, n, o), new, old)
         new_params = sel(cand_params, params)

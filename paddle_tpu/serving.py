@@ -64,6 +64,7 @@ from .telemetry import profiling as _profiling
 from .telemetry import recompile as _recompile
 from .telemetry import server as _dbg_server
 from .telemetry import tracing as _tracing
+from .telemetry.scopes import scope as _scope
 from .telemetry.trace import Span, named as _named
 from .utils.memory import owned_on_device
 
@@ -2086,6 +2087,7 @@ class BatchedDecoder:
                     tok = self._first_token(s, logits, plen)
                 self._activate(s, r, tok, plen)
 
+    @_scope("head")
     def _pick(self, logits, s: int, pos: int):
         """Admission-time single-row pick (the steady-state loop picks
         batched in _step); caller sets _slot_gen[s] first."""
@@ -2111,6 +2113,7 @@ class BatchedDecoder:
         top_k, top_p, key = self.top_k, self.top_p, self.key
         paged = self.paged
 
+        @_scope("head")
         def pick(logits, gens, poss):
             if not sampled:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -435,9 +435,14 @@ class TestTrainLoopWiring:
         # rollback would destroy up to checkpoint_every steps of real
         # progress; the anomaly is recorded + dumped and the run
         # proceeds at full step count
+        # (the stall watch is disarmed: it reads the wall clock, and a
+        # stub step is 20 us, so after two warm-up steps any 0.3 ms
+        # hiccup of a loaded host in step 5 or 6 is a "step_stall" that
+        # becomes the LAST anomaly; one run in 200 on an idle host)
         telemetry.reset()
         fr2 = FlightRecorder(str(tmp_path / "d2"), policy="skip_step",
-                             warmup_steps=2, grad_spike_factor=5.0)
+                             warmup_steps=2, grad_spike_factor=5.0,
+                             stall_factor=float("inf"))
         loop2 = TrainLoop(StubTrainer(), str(tmp_path / "c2"),
                           checkpoint_every=100, nan_policy="off")
 
