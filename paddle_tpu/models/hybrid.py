@@ -528,7 +528,8 @@ class HybridForCausalLM(Layer):
         (held,) int32, the (token, pick) pairs each held expert got,
         summed over blocks; ``expert_dense_layers`` int32, the expert
         layers of the call whose rows took the dense body of
-        ``nn.moe.dropless_moe`` (the trace fixes it). With retention
+        ``nn.moe.dropless_moe`` (the trace fixes it:
+        :meth:`expert_layers`). With retention
         blocks: ``retention_small_norm`` int32, the (row, head, block)
         denominators of a step that fell under ``10 retention_eps``
         (idle rows' included; a chunk counts none). With
@@ -575,6 +576,23 @@ class HybridForCausalLM(Layer):
                 axis=1)
         return loss_fn(self.forward(ids), labels, ignore_index)
 
+    def expert_layers(self, rows: int, last_rows=None):
+        """(the expert layers of a cached call over ``rows`` positions,
+        those of them that take the dense body of
+        ``nn.moe.dropless_moe``): static counts, so a caller that knows
+        a program's shape knows them without running it. ``last_rows``:
+        the rows of the last block's channel mix where the call is cut
+        to the head's position before it (``head_at``: a prefill of one
+        prompt leaves it 1 row)."""
+        n = dense = 0
+        for i, blk in enumerate(self.blocks):
+            if blk.moe is None:
+                continue
+            cut = last_rows is not None and i == len(self.blocks) - 1
+            n += 1
+            dense += blk.moe.streams_densely(last_rows if cut else rows)
+        return n, dense
+
     def _cached_blocks(self, x, caches, attn_step, rec_step, rec_at,
                        head: bool = True, head_at=None):
         """The cached block composition, written once over the mixed
@@ -592,6 +610,7 @@ class HybridForCausalLM(Layer):
         (B, V)."""
         new_caches, tokens, small, unbalanced = [], 0, 0, 0
         rows, last = x.shape[0] * x.shape[1], len(self.blocks) - 1
+        last_rows = None if head_at is None else x.shape[0]
         for i, (blk, cache) in enumerate(zip(self.blocks, caches)):
             with blk.mixer_scope():
                 u, held = blk.res1.read(x)
@@ -617,9 +636,8 @@ class HybridForCausalLM(Layer):
         self._counted = {}
         if "experts" in self.cfg.channel_mixes():
             self._counted.update(
-                expert_tokens=tokens, expert_dense_layers=jnp.int32(sum(
-                    blk.moe.streams_densely(rows) for blk in self.blocks
-                    if blk.moe is not None)))
+                expert_tokens=tokens, expert_dense_layers=jnp.int32(
+                    self.expert_layers(rows, last_rows)[1]))
         if "retention" in self.cfg.layer_types:
             self._counted["retention_small_norm"] = small
         if self.cfg.hc_mult > 1:

@@ -169,32 +169,51 @@ class SwitchFFN(Layer):
         return y.reshape(b, t, d)
 
 
-# Rows up to which the routed experts run as dense products over every
-# held expert. The dense body reads each held weight once and does
-# ``rows`` FLOPs a byte read, which hide under the stream while ``rows``
-# is well below the chip's ridge (v5e: 197e12 / 819e9 = 240 FLOP a
-# byte): half the ridge. The grouped body does top_k / E of that work
-# behind a sort, in kernels tiled for groups of hundreds of rows.
-# PERF.md section 3 has the chip timings of both bodies on either side
-# of this constant, and what they say about moving it.
-DENSE_MAX_ROWS = 128
+# Which body the routed experts take, from the static counts of a call.
+# Both constants are read off ``tools/moe_bodies.py`` on a v5e at the two
+# expert shapes the benchmark holds (PERF.md section 3 has the table).
+#
+# The dense body does ``experts / top_k`` times the grouped body's
+# products (held x rows against rows x top_k x held / experts: the held
+# count and both widths cancel), at 0.92 to 0.97 of the bf16 peak once
+# it is past the ridge. The grouped body costs 9 to 20 times its own
+# products' time at that peak (sort, gathers, a float32 row a pair
+# written and read several times, grouped kernels whose groups are a
+# few hundred rows), so the dense body is the faster one while it does
+# up to about that many times the work: 7.2 (72 experts, 10 a token) is
+# 1.2 to 2.9 times faster dense at every row count from 16 to 4096; 16
+# (64 experts, 4 a token) is a tie from 4096 rows up, within 2 to 6%.
+DENSE_MAX_WORK = 12
+# Whatever the work ratio, few rows stream densely: before its first row
+# the grouped body costs about 2.3 times the stream of the held weights,
+# and the dense body's products take that long at 2.3 times the chip's
+# ridge (v5e: 197e12 / 819e9 = 240 FLOP a byte, so 550 rows; the held
+# bytes cancel here too). At a work ratio of 16 the dense body is 3.3 /
+# 2.4 / 1.6 times faster at 128 / 256 / 512 rows and level at 1024.
+DENSE_MAX_ROWS = 512
 
 
 def streams_densely(rows: int, top_k: int, experts: int) -> bool:
     """Whether :func:`dropless_moe` takes its dense body for ``rows``
-    tokens that each pick ``top_k`` of ``experts`` (static counts: the
-    trace fixes them). Under one pair an expert (``rows * top_k <
-    experts``: the lone last token of a prefill) most held experts get
-    no row; the grouped body skips those and the dense one would read
-    them all."""
-    return experts <= rows * top_k and rows <= DENSE_MAX_ROWS
+    tokens that each pick ``top_k`` of ``experts`` router outputs
+    (static counts: the trace fixes them; how many experts are held and
+    how wide they are cancels out of both comparisons above). Under one
+    pair an expert (``rows * top_k < experts``: the lone last token of a
+    prefill) most held experts get no row; the grouped body skips those
+    and the dense one would read them all."""
+    return experts <= rows * top_k and (
+        rows <= DENSE_MAX_ROWS or experts <= DENSE_MAX_WORK * top_k)
 
 
 def _experts_dense(x, w_gate, w_up, w_down, local, gates):
     """Every token through every held expert, weighted by ``gate (S,
     held)``: the softmax weight of the pick that chose the expert and
     exactly 0.0 elsewhere, so the terms are the picked pairs' and no
-    other. The weights are read once, in place: no sort, no gather."""
+    other. The weights are read once, in place: no sort, no gather. The
+    gate weighs the hidden activations, so that the down product sums
+    over (expert, width) at once into (S, D): a result a held expert,
+    (held, S, D) float32, would be written and read whole at hundreds
+    of rows."""
     f32 = jnp.float32
     held = w_gate.shape[0]
     with scope("moe_route"):
@@ -203,13 +222,13 @@ def _experts_dense(x, w_gate, w_up, w_down, local, gates):
         gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)
     with scope("moe_experts"):
         xs = x.astype(w_gate.dtype)
-        h = (jax.nn.silu(jnp.einsum("sd,edf->esf", xs, w_gate,
+        h = (jax.nn.silu(jnp.einsum("sd,edf->sef", xs, w_gate,
                                     preferred_element_type=f32))
-             * jnp.einsum("sd,edf->esf", xs, w_up,
+             * jnp.einsum("sd,edf->sef", xs, w_up,
                           preferred_element_type=f32))
-        out = jnp.einsum("esf,efd->esd", h.astype(w_down.dtype), w_down,
-                         preferred_element_type=f32)
-        return jnp.einsum("esd,se->sd", out, gate)
+        h = (h * gate[:, :, None]).astype(w_down.dtype)
+        return jnp.einsum("sef,efd->sd", h, w_down,
+                          preferred_element_type=f32)
 
 
 def _experts_grouped(x, w_gate, w_up, w_down, local, gates, here, sizes):
@@ -287,11 +306,12 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     that holds it; nothing stands in for that chip here).
 
     Two bodies give those terms, chosen by the static shapes alone
-    (:func:`streams_densely`): few rows that still reach every expert
-    (a decode step) go through every held expert as dense products with
-    a gate of 0.0 where an expert was not picked; many rows (a prefill)
-    or very few (its last token) are sorted by expert and run as
-    grouped matmuls. Both run in the WEIGHTS' type with
+    (:func:`streams_densely`): rows that reach every expert (a decode
+    step; a prefill whose router picks a large share of its experts) go
+    through every held expert as dense products with a gate of 0.0
+    where an expert was not picked; many rows that each pick a small
+    share, or very few rows (a prefill's last token), are sorted by
+    expert and run as grouped matmuls. Both run in the WEIGHTS' type with
     float32 sums: the tokens are cast to it, never the experts (a
     float32 copy of bfloat16 experts would be written out whole a call).
 

@@ -341,6 +341,11 @@ class ArenaCounters:
     through the whole model, a second read of every weight, and not
     from the prefill's own pass (0 on the contiguous arena with whole
     prompts; the paged, prefix-hit and chunked paths still re-step).
+    ``prefill_expert_layers``: the routed-expert layers of the one-pass
+    prefills among them, and ``prefill_dense_layers`` those that took
+    the dense body of ``nn.moe.dropless_moe`` (static a bucket: the
+    model's ``expert_layers`` says them from the shape; both stay 0 for
+    a model without routed experts).
     ``steps`` counts every decode step the host has read, whether or
     not the model counts anything in it. ``steps_ahead``: those of them
     that were dispatched while the step before was still unread (the
@@ -355,6 +360,8 @@ class ArenaCounters:
         self.steps = 0
         self.prefills = 0
         self.prefill_resteps = 0
+        self.prefill_expert_layers = 0
+        self.prefill_dense_layers = 0
         self.steps_ahead = 0
         self.rows_dropped = 0
 
@@ -2078,6 +2085,12 @@ class BatchedDecoder:
                         self.caches, logits = pf(
                             self._mstate, self.caches, jnp.asarray(padded),
                             plen, s)
+                        if hasattr(self.model, "expert_layers"):
+                            # the bucket as one chunk, cut to one row
+                            # before the last block's channel mix
+                            n, dense = self.model.expert_layers(lb, 1)
+                            self.counters.prefill_expert_layers += n
+                            self.counters.prefill_dense_layers += dense
                         if telem:
                             _costs.ensure_program(
                                 f"serving.prefill[{lb}]", pf,

@@ -1,9 +1,9 @@
 """``nn.DroplessMoE`` / ``nn.moe.dropless_moe``: top-k routing with no
 capacity, over the experts held here, by both of its bodies: the dense
-products over every held expert that few rows take, and the sort and
-grouped product that many rows take. The rows alone choose
-(``moe.DENSE_MAX_ROWS``); a test that wants the other body for its 24
-rows moves that constant for its own duration, as nothing else can.
+products over every held expert, and the sort and grouped product. The
+static counts of the call alone choose (``moe.streams_densely``: rows,
+picks a token, router outputs); a test that wants a given body for its
+rows replaces that rule for its own duration, as nothing else can.
 
 The oracle is a loop over tokens and picks in numpy, at the published
 router shape (the 10 largest of 72 logits, softmax over those 10). Ties
@@ -28,15 +28,19 @@ TOL = dict(rtol=1e-5, atol=1e-5)
 BODIES = ["dense", "grouped"]
 
 
+def force(monkeypatch, name):
+    """Send every call through the named body."""
+    monkeypatch.setattr(moe, "streams_densely",
+                        lambda *counts: name == "dense")
+
+
 @pytest.fixture
 def body(request, monkeypatch):
-    """Send this file's ``S`` rows through the named body."""
-    monkeypatch.setattr(moe, "DENSE_MAX_ROWS",
-                        S if request.param == "dense" else S - 1)
+    force(monkeypatch, request.param)
     return request.param
 
 
-def weights(seed=0, ties=True):
+def weights(seed=0, ties=True, rows=S):
     rng = np.random.default_rng(seed)
     f = lambda *s: rng.standard_normal(s).astype(np.float32)
     router = f(D, E)
@@ -44,7 +48,7 @@ def weights(seed=0, ties=True):
         # experts 3, 40 and 41 tie with expert 2; 70 ties with 36
         router[:, [3, 40, 41]] = router[:, [2]]
         router[:, 70] = router[:, 36]
-    return dict(x=f(S, D), router=router, wg=0.3 * f(E, D, F),
+    return dict(x=f(rows, D), router=router, wg=0.3 * f(E, D, F),
                 wu=0.3 * f(E, D, F), wd=0.3 * f(E, F, D))
 
 
@@ -67,9 +71,9 @@ def primitives(jaxpr):
 def loop_oracle(w, first, count):
     """Token by token, pick by pick."""
     logits = w["x"] @ w["router"]
-    y = np.zeros((S, D), np.float32)
+    y = np.zeros(w["x"].shape, np.float32)
     tokens = np.zeros(count, np.int64)
-    for s in range(S):
+    for s in range(len(y)):
         # the k largest, the lowest index first among equals
         picks = sorted(range(E), key=lambda e: (-logits[s, e], e))[:K]
         top = logits[s, picks]
@@ -181,8 +185,8 @@ def test_both_bodies_count_the_same_tokens(held, monkeypatch):
     first, count = held
     sl = slice(first, first + count)
     got = {}
-    for body, limit in (("dense", S), ("grouped", S - 1)):
-        monkeypatch.setattr(moe, "DENSE_MAX_ROWS", limit)
+    for body in BODIES:
+        force(monkeypatch, body)
         got[body] = dropless_moe(
             jnp.asarray(w["x"]), jnp.asarray(w["router"]),
             jnp.asarray(w["wg"][sl]), jnp.asarray(w["wu"][sl]),
@@ -191,26 +195,115 @@ def test_both_bodies_count_the_same_tokens(held, monkeypatch):
     np.testing.assert_allclose(got["dense"][0], got["grouped"][0], **TOL)
 
 
-@pytest.mark.parametrize("rows,ragged", [
-    (1, 3), (7, 3), (8, 0), (32, 0), (moe.DENSE_MAX_ROWS, 0), (256, 3)])
-def test_the_rows_alone_choose_the_body(rows, ragged):
-    """A decode step's rows (32 slots in the serving cell) stream every
-    held expert densely: no grouped product and no sort of the pairs.
-    A prefill's rows (256 and up) are sorted into three grouped
-    products, and so is its lone last token, or any rows whose 10 picks
-    are fewer than the 72 experts: most held experts then get no row,
-    and only the grouped body skips them. Nothing but the static shapes
-    is asked."""
-    assert moe.streams_densely(rows, K, E) == (ragged == 0)
+@pytest.mark.parametrize("body", BODIES, indirect=True)
+@pytest.mark.parametrize("rows", [256, 1024])
+def test_a_prefills_rows_against_the_token_loop(rows, body):
+    """A prefill's row counts, which the rule sends through either body
+    by the router's shape, with a share of the experts held (30 to 41
+    of 72: most picks fall on absent experts) and a held expert, 33,
+    that no row picks (every token's first feature is 1 and that
+    router weight -100: its logit lies 100 under the others')."""
+    w = weights(4, ties=False, rows=rows)
+    w["x"][:, 0] = 1.0
+    w["router"][0, 33] = -100.0
+    first, count = 30, 12
+    sl = slice(first, first + count)
+    y, tokens = dropless_moe(
+        jnp.asarray(w["x"]), jnp.asarray(w["router"]),
+        jnp.asarray(w["wg"][sl]), jnp.asarray(w["wu"][sl]),
+        jnp.asarray(w["wd"][sl]), top_k=K, experts_held=(first, count))
+    y_ref, tokens_ref = loop_oracle(w, first, count)
+    np.testing.assert_allclose(y, y_ref, **TOL)
+    np.testing.assert_array_equal(tokens, tokens_ref)
+    assert tokens[33 - first] == 0 < np.delete(tokens, 33 - first).min()
+    assert int(tokens.sum()) < rows * K     # picks on absent experts
+
+
+def experts_dense_by_expert(x, w_gate, w_up, w_down, local, gates):
+    """The dense body as it stood until PR 44: a result a held expert,
+    (held, S, D) float32, weighted by the gate after the down product."""
+    held = w_gate.shape[0]
+    hit = local[:, :, None] == jnp.arange(held, dtype=local.dtype)
+    gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)
+    h = (jax.nn.silu(jnp.einsum("sd,edf->esf", x, w_gate))
+         * jnp.einsum("sd,edf->esf", x, w_up))
+    return jnp.einsum("esd,se->sd", jnp.einsum("esf,efd->esd", h, w_down),
+                      gate)
+
+
+@pytest.mark.parametrize("rows", [16, 32, 512])
+def test_the_dense_body_weighs_before_the_down_product_to_no_effect(
+        rows, monkeypatch):
+    """Gate times hidden activations, summed over (expert, width) at
+    once, is the former gate times a result an expert, term for term:
+    float32 roundoff apart at a decode step's rows and at a prefill's,
+    and exactly 0.0 where no held expert was picked."""
+    w = weights(5, ties=False, rows=rows)
+    args = (jnp.asarray(w["x"]), jnp.asarray(w["router"]),
+            jnp.asarray(w["wg"][:36]), jnp.asarray(w["wu"][:36]),
+            jnp.asarray(w["wd"][:36]))
+    force(monkeypatch, "dense")
+    new, n_new = dropless_moe(*args, top_k=K, experts_held=(0, 36))
+    monkeypatch.setattr(moe, "_experts_dense", experts_dense_by_expert)
+    old, n_old = dropless_moe(*args, top_k=K, experts_held=(0, 36))
+    np.testing.assert_allclose(new, old, **TOL)
+    np.testing.assert_array_equal(n_new, n_old)
+    # a lone held expert: rows that do not pick it get exact zeros
+    y, tokens = dropless_moe(*args[:2], *(a[:1] for a in args[2:]),
+                             top_k=K, experts_held=(0, 1))
+    monkeypatch.undo()
+    picked = np.asarray(jax.lax.top_k(args[0] @ args[1], K)[1] == 0).any(-1)
+    assert 0 < picked.sum() == int(tokens[0]) < rows
+    np.testing.assert_array_equal(np.asarray(y)[~picked], 0.0)
+
+
+# (rows, dense) on both sides of every edge of the rule, for the two
+# router shapes the benchmark holds
+HYBRID = dict(top_k=10, experts=72, held=36)    # work ratio 7.2
+XING = dict(top_k=4, experts=64, held=8)        # work ratio 16
+RULE = [(HYBRID, 1, False), (HYBRID, 7, False), (HYBRID, 8, True),
+        (HYBRID, 32, True), (HYBRID, 256, True), (HYBRID, 512, True),
+        (HYBRID, 1024, True), (HYBRID, 4096, True),
+        (XING, 1, False), (XING, 15, False), (XING, 16, True),
+        (XING, moe.DENSE_MAX_ROWS, True),
+        (XING, moe.DENSE_MAX_ROWS + 1, False), (XING, 2048, False),
+        (XING, 14336, False)]
+
+
+@pytest.mark.parametrize("shape,rows,dense", RULE, ids=[
+    f"{'hybrid' if shape is HYBRID else 'xing'}-{rows}"
+    for shape, rows, _ in RULE])
+def test_the_static_counts_alone_choose_the_body(shape, rows, dense):
+    """A decode step's rows (32 slots and 16 in the serving cells)
+    stream every held expert densely: no grouped product and no sort of
+    the pairs. So does a prefill whose router picks 10 of 72 (the dense
+    body does 7.2 times the grouped body's products, under
+    ``DENSE_MAX_WORK``), at any row count. A prefill whose router picks
+    4 of 64 (16 times the products) is sorted into three grouped
+    products once it has more than ``DENSE_MAX_ROWS`` rows, and so is
+    any call whose picks are fewer than the experts, a prefill's lone
+    last token for one: most held experts then get no row, and only the
+    grouped body skips them. Nothing but the static counts is asked:
+    not how many experts are held, nor how wide they are."""
+    k, e, held = shape["top_k"], shape["experts"], shape["held"]
+    assert moe.streams_densely(rows, k, e) == dense
+    assert nn.DroplessMoE(D, F, e, k, experts_held=(0, held)
+                          ).streams_densely(rows) == dense
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
-    used = primitives(jax.make_jaxpr(
-        lambda *a: dropless_moe(*a, top_k=K, experts_held=(0, 36)))(
-            sds(rows, D), sds(D, E), sds(36, D, F), sds(36, D, F),
-            sds(36, F, D)))
-    assert used.count("ragged_dot_general") == ragged
+    jaxpr = jax.make_jaxpr(
+        lambda *a: dropless_moe(*a, top_k=k, experts_held=(0, held)))(
+            sds(rows, D), sds(D, e), sds(held, D, F), sds(held, D, F),
+            sds(held, F, D))
+    used = primitives(jaxpr)
+    assert used.count("ragged_dot_general") == (0 if dense else 3)
     # ``lax.top_k`` is a primitive of its own: a sort is the pairs' only
-    assert ("sort" in used) == (ragged > 0)
-    assert ("gather" in used) == (ragged > 0)
+    assert ("sort" in used) == (not dense)
+    assert ("gather" in used) == (not dense)
+    # the dense body sums over the experts inside its down product: no
+    # result a held expert, (held, rows, D), is ever made
+    assert (held, rows, D) not in [
+        getattr(v.aval, "shape", None) for eqn in equations(jaxpr)
+        for v in eqn.outvars]
 
 
 def test_layer_checks_its_arguments():

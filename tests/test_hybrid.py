@@ -243,19 +243,74 @@ def test_served_tokens_are_the_references_best_over_reused_slots():
         * len(cfg.layer_types))
 
 
-@pytest.mark.parametrize("limit,dense", [(SLOTS, True), (SLOTS - 1, False)])
+@pytest.mark.parametrize("dense", [True, False])
 def test_the_step_counts_the_expert_layers_that_streamed_densely(
-        limit, dense, monkeypatch):
-    """A decode step of as many rows as ``nn.moe.DENSE_MAX_ROWS`` takes
-    the dense body in every expert layer and says so beside the tokens;
-    one row more and every layer sorts and groups (the prefills here,
-    of 8 rows and more, with it), and the sum stays 0. The served tokens
-    are the reference's either way."""
-    monkeypatch.setattr(nn.moe, "DENSE_MAX_ROWS", limit)
+        dense, monkeypatch):
+    """A decode step whose rows ``nn.moe.streams_densely`` sends
+    through the dense body says so beside the tokens, every expert
+    layer of it; under a rule that sorts and groups those rows the sum
+    stays 0. The arena counts the same for its prefills on the host,
+    from the bucket alone: every expert layer at the bucket's rows but
+    the last block's, which runs at the one row the head reads and is
+    grouped under the library's rule (4 picks for 12 experts). The
+    served tokens are the reference's either way."""
+    if not dense:
+        monkeypatch.setattr(nn.moe, "streams_densely", lambda *_: False)
     cfg, dec = serve_seven_over_three_slots()
+    layers = len(cfg.layer_types)
     assert dec.counters.steps == dec.tick_count > 0
     assert int(dec.counters.sums["expert_dense_layers"]) == (
-        len(cfg.layer_types) * dec.counters.steps if dense else 0)
+        layers * dec.counters.steps if dense else 0)
+    assert dec.counters.prefills == 7
+    assert dec.counters.prefill_expert_layers == 7 * layers
+    assert dec.counters.prefill_dense_layers == (
+        7 * (layers - 1) if dense else 0)
+    assert dec.model.expert_layers(BUCKET, 1) == (
+        layers, layers - 1 if dense else 0)
+    assert dec.model.expert_layers(SLOTS) == (layers,
+                                              layers if dense else 0)
+
+
+def test_a_prefill_at_bucket_256_is_the_stepped_prompt():
+    """A prompt of 200 tokens in a bucket of 256: the expert layers
+    take the dense body at 256 rows (all but the last block's one row)
+    and the one-pass prefill leaves the first token's logits and the
+    slot's state that stepping the prompt token by token through the
+    decode entry leaves (the experts at one row a step: grouped)."""
+    cfg, model, params = build()
+    layers = len(cfg.layer_types)
+    assert model.expert_layers(256, 1) == (layers, layers - 1)
+    assert model.expert_layers(1) == (layers, 0)
+    plen, s = 200, 1
+    prompt = np.random.default_rng(14).integers(
+        0, cfg.vocab_size, plen).astype(np.int32)
+    dec = BatchedDecoder(model, slots=SLOTS, capacity=256,
+                         prompt_bucket=256)
+    padded = np.zeros((256,), np.int32)
+    padded[:plen] = prompt
+    pf = dec._prefill_fn(256)
+    args = (dec._mstate, dec.caches, jnp.asarray(padded), plen, s)
+    # the last block's one row is the program's only grouped layer
+    assert str(jax.make_jaxpr(pf)(*args)).count("ragged_dot_general[") == 3
+    dec.caches, logits = pf(*args)
+
+    @jax.jit
+    def step(mstate, caches, tok, t):
+        with inject_state((model, *mstate)):
+            return model._step_logits(tok, caches, t)
+
+    row = model.init_cache(1, 256)
+    for t, tok in enumerate(prompt):
+        want, row = step(dec._mstate, row, jnp.asarray([tok]), t)
+    close(np.asarray(logits), np.asarray(want[0]))
+    assert int(np.argmax(logits)) == int(np.argmax(want[0]))
+    for kind, got, ref in zip(model.cache_kinds, dec.caches, row):
+        # keys and values of the prompt's positions; a state whole
+        upto = slice(plen) if kind == "kv" else slice(None)
+        for g, r in zip(got, ref):
+            g, r = np.asarray(g[s])[upto], np.asarray(r[0])[upto]
+            np.testing.assert_allclose(g, r, rtol=1e-3,
+                                       atol=1e-4 * np.abs(r).max())
 
 
 # --------------------------------------------------------------------------

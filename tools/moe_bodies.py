@@ -1,19 +1,29 @@
 #!/usr/bin/env python
-"""Time both bodies of ``nn.moe.dropless_moe`` on the chip, at the rows
-that surround the two ends of ``nn.moe.streams_densely``: the numbers
-that set its rule.
+"""Time both bodies of ``nn.moe.dropless_moe`` on the chip, at rows on
+either side of every edge of ``nn.moe.streams_densely``: the numbers
+that set its rule (``DENSE_MAX_ROWS``, ``DENSE_MAX_WORK``).
 
 One program a (body, rows) pair: ``--layers`` expert layers in a row,
 each the routed experts of one block at the given widths (default: the
 hybrid serving cell's share, 36 held of 72 experts of 4096 x 768, 10 a
 token, bfloat16), every layer's result fed to the next so that they run
 in order and each streams its weights from HBM again. Prints one JSON
-line a pair: ``ms_layer`` (host clock over fenced calls, a layer) and
-``roofline_pct`` (the held weights read once at the chip's HBM peak,
-over it). A body is forced by replacing the module's rule before the
-trace; the library itself has no switch.
+line a pair: ``ms_layer`` (host clock over fenced calls, a layer),
+``roofline_pct`` (the larger of the held weights read once at the
+chip's HBM peak and the body's own products at its bf16 peak, over it;
+a tool's print, not a benchmark metric: it passes 100 where the grouped
+body reads only the picked experts, at a row or two, and where the
+layers' shared weights partly stay in fast memory, as 176 MB do) and
+``rule``, the body ``streams_densely`` itself picks there. A body is
+forced by replacing the module's rule before the trace; the library
+itself has no switch.
 
-    chiprun -- python tools/moe_bodies.py [--rows 1,4,8,32,128,256,512]
+    chiprun -- python tools/moe_bodies.py [--rows 1,16,32,128,256,512,768,1024,2048]
+    chiprun -- python tools/moe_bodies.py --widths 3584,1024,64,8,4 \
+        --rows 16,128,512,1024,2048,4096,8192,14336
+
+(the second: the Xing4.0 cell's share, 8 held of 64 experts of 3584 x
+1024, 4 a token).
 """
 
 from __future__ import annotations
@@ -28,7 +38,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--rows", default="1,4,8,32,128,256,512")
+    ap.add_argument("--rows",
+                    default="1,16,32,128,256,512,768,1024,2048")
     ap.add_argument("--widths", default="4096,768,72,36,10",
                     help="D,F,experts,held,top_k")
     ap.add_argument("--layers", type=int, default=10)
@@ -42,7 +53,9 @@ def main(argv=None) -> int:
     from paddle_tpu.utils.flops import device_peaks
 
     dev = jax.devices()[0]
-    peak = (device_peaks(dev) or {}).get("hbm_bytes_per_s")
+    peaks = device_peaks(dev) or {}
+    peak, flops_peak = (peaks.get("hbm_bytes_per_s"),
+                        peaks.get("bf16_flops"))
     d, f, e, held, k = (int(v) for v in args.widths.split(","))
     keys = jax.random.split(jax.random.key(0), 5)
     bf = jnp.bfloat16
@@ -67,6 +80,10 @@ def main(argv=None) -> int:
     try:
         for rows in (int(r) for r in args.rows.split(",")):
             x = jax.random.normal(keys[4], (rows, d), jnp.float32).astype(bf)
+            rule = "dense" if keep(rows, k, e) else "grouped"
+            # products of the picked pairs on held experts in the mean
+            # (grouped), or of every row through every held expert
+            pairs = {"grouped": rows * k * held / e, "dense": rows * held}
             for body in ("grouped", "dense"):
                 moe.streams_densely = lambda *_, b=body: b == "dense"
                 fn = stack_fn()
@@ -78,13 +95,15 @@ def main(argv=None) -> int:
                 out.block_until_ready()
                 ms = ((time.perf_counter() - t0) * 1e3
                       / (args.calls * args.layers))
+                least = (max(weight_bytes / peak,
+                             6 * d * f * pairs[body] / flops_peak)
+                         if peak and flops_peak else None)
                 print(json.dumps({
                     "device": dev.device_kind, "platform": dev.platform,
-                    "rows": rows, "body": body,
+                    "rows": rows, "body": body, "rule": rule,
                     "ms_layer": round(ms, 4),
-                    "roofline_pct": (round(
-                        100 * weight_bytes / peak / (ms * 1e-3), 2)
-                        if peak else None)}), flush=True)
+                    "roofline_pct": (round(100 * least / (ms * 1e-3), 2)
+                                     if least else None)}), flush=True)
     finally:
         moe.streams_densely = keep
     return 0

@@ -11,8 +11,10 @@ section 7) and a comparison should see the clusters move; ``[arena]``:
 ``serving.last_counters`` (``steps``, ``prefills``, ``prefill_resteps``,
 and of the look-ahead ``steps_ahead``, the decode steps dispatched while
 the one before was unread, and ``rows_dropped``, the rows of such steps
-whose tokens were thrown away; "None" where that checkout's arena does
-not count one). The result line
+whose tokens were thrown away; ``prefill_expert_layers``, the routed
+expert layers of the prefills, ``prefill_dense_layers``, those of them
+that took the dense body, and their quotient ``prefill_dense_share``;
+"None" where that checkout's arena does not count one). The result line
 and every number in it are ``benchmark/run.py``'s own: the job is run
 by it, unchanged, and this only reads what it returns."""
 
@@ -55,10 +57,15 @@ def main():
                       f"{lo:.0f}-{lo + BIN_MS:.0f} ms {share:.1%}"
                       for lo, share in clusters(gaps)), file=sys.stderr)
         c = serving.last_counters
+        layers = getattr(c, "prefill_expert_layers", None)
+        share = (round(c.prefill_dense_layers / layers, 4) if layers
+                 else None)
         print("[arena] " + ", ".join(
             f"{k} {getattr(c, k, None)}"
             for k in ("steps", "prefills", "prefill_resteps",
-                      "steps_ahead", "rows_dropped")),
+                      "steps_ahead", "rows_dropped",
+                      "prefill_expert_layers", "prefill_dense_layers"))
+            + f", prefill_dense_share {share}",
             file=sys.stderr, flush=True)
         return job
 
