@@ -13,7 +13,9 @@ and a channel mix a block, chosen by the configuration.
 - ``"latent"``: multi-head latent attention (``nn.LatentAttention``):
   low-rank queries, one compressed record a position for all heads, a
   (YaRN) rotary part, decompressed in a prefill and read absorbed by a
-  decode step.
+  decode step; with ``index_topk`` a learned indexer in front of it
+  picks the positions each query attends, and its key is a third array
+  of the record.
 
 ``channel_mix`` names what follows the mixer, in every block (one
 name) or block by block (one name a block, as ``layer_types``):
@@ -58,7 +60,9 @@ head_dim, state) float32); a retention block keeps (S (slots,
 kv_heads, D, head_dim), z (slots, kv_heads, D)) float32 with ``D`` =
 ``ops.retention.phi_dim(head_dim)``, 34 MB a slot at head dimension
 128 whatever the context; a latent block keeps its records by
-position, (c (slots, capacity, kv_rank), r (slots, capacity, rope)).
+position, (c (slots, capacity, kv_rank), r (slots, capacity, rope))
+and, with an indexer, k^I (slots, capacity, index_head_dim): a ``"kv"``
+mixer's cache is whatever tuple its ``init_cache`` gives.
 :meth:`HybridForCausalLM.init_cache` gives
 the list, one entry a block, ``cache_kinds`` says which is which
 (``"kv"``, addressed by position, or ``"recurrent"``) and
@@ -125,9 +129,15 @@ class HybridConfig:
     v_head_dim: int = 0
     rope_yarn: Optional[dict] = None
     rope_mscale_all_dim: float = 1.0
+    # the latent mixer's indexer (all 0: none): a query attends the
+    # index_topk positions its index_n_heads heads of index_head_dim pick
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # hyper-connections: streams of the residual state (1: the plain
     # path), Sinkhorn rounds and epsilon, the clamp on H_res's logits
     hc_mult: int = 1
+    settle_residual: bool = False        # nn.latent.PlainResidual's
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: Tuple[float, float] = (-30.0, 30.0)
@@ -171,6 +181,18 @@ class HybridConfig:
                    rope_yarn=dict(factor=4.0, original_max_position=32,
                                   beta_fast=32.0, beta_slow=1.0),
                    hc_mult=4, tie_embeddings=False, rms_norm_eps=1e-6)
+
+    @classmethod
+    def tiny_sparse_latent(cls, layers: int = 3, dense: int = 1,
+                           topk: int = 8):
+        """For tests: :meth:`tiny_latent`'s blocks over the plain
+        residual path, without YaRN, with an indexer of 4 heads of 16
+        that picks ``topk`` positions a query; heads of 16 + 8 (scores)
+        / 24 (values)."""
+        return dataclasses.replace(
+            cls.tiny_latent(layers, dense), rope_yarn=None, hc_mult=1,
+            v_head_dim=24, index_n_heads=4, index_head_dim=16,
+            index_topk=topk)
 
     @classmethod
     def tiny(cls, periods: int = 1):
@@ -420,7 +442,8 @@ class HybridBlock(Layer):
                 cfg.hc_eps, cfg.hc_clamp, cfg.rms_norm_eps)
                 for _ in range(2))
         else:
-            self.res1 = self.res2 = PlainResidual(self.m)
+            self.res1 = self.res2 = PlainResidual(self.m,
+                                                  cfg.settle_residual)
         self.norm1 = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
         if kind == "mamba":
             self.mixer = SSDMixer(cfg)
@@ -431,7 +454,8 @@ class HybridBlock(Layer):
                 cfg.hidden_size, cfg.num_heads, cfg.q_lora_rank,
                 cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                 cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.rope_theta,
-                cfg.rope_yarn, cfg.rope_mscale_all_dim, cfg.rms_norm_eps)
+                cfg.rope_yarn, cfg.rope_mscale_all_dim, cfg.rms_norm_eps,
+                cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk)
         else:
             self.mixer = nn.MultiHeadAttention(
                 cfg.hidden_size, cfg.num_heads, bias=False,
@@ -518,7 +542,8 @@ class HybridForCausalLM(Layer):
     def init_cache(self, batch: int, capacity: int, dtype=None):
         """One pytree a block, every leaf with the sequence (slot) axis
         first: (K, V) for attention, (tail, S) for a state-space block,
-        (S, z) for a retention block, (c, r) for a latent block."""
+        (S, z) for a retention block, (c, r) or (c, r, k^I) for a latent
+        block."""
         return [blk.mixer.init_cache(batch, capacity, dtype)
                 for blk in self.blocks]
 
@@ -535,8 +560,11 @@ class HybridForCausalLM(Layer):
         (idle rows' included; a chunk counts none). With
         hyper-connections: ``mhc_unbalanced`` int32, the (position,
         sublayer) maps whose ``H_res`` has a row or column sum off 1 by
-        more than 1e-3 after the Sinkhorn rounds. Valid only inside
-        the trace of that call."""
+        more than 1e-3 after the Sinkhorn rounds. With an indexer:
+        ``dsa_positions_live`` / ``dsa_positions_read`` int32, the
+        records a step's rows held and those their attention was given,
+        summed over rows (idle rows' too) and latent blocks (a chunk
+        counts none). Valid only inside the trace of that call."""
         return dict(self._counted)
 
     @scope("embed")
@@ -596,8 +624,8 @@ class HybridForCausalLM(Layer):
     def _cached_blocks(self, x, caches, attn_step, rec_step, rec_at,
                        head: bool = True, head_at=None):
         """The cached block composition, written once over the mixed
-        block list: ``attn_step(mixer, h, k, v) -> (a, k, v)`` (a latent
-        mixer's records in the place of keys and values),
+        block list: ``attn_step(mixer, h, *cache) -> (a, *cache)`` (keys
+        and values, or a latent mixer's two or three record arrays),
         ``rec_step(mixer, h, cache) -> (a, cache)`` for a recurrent
         mixer and ``rec_at(mixer, h, cache) -> (a, cache)`` for one that
         takes positions are all that vary between the chunk, single-step
@@ -609,6 +637,7 @@ class HybridForCausalLM(Layer):
         channel mix and the head have one row, and the logits are
         (B, V)."""
         new_caches, tokens, small, unbalanced = [], 0, 0, 0
+        live = read = 0
         rows, last = x.shape[0] * x.shape[1], len(self.blocks) - 1
         last_rows = None if head_at is None else x.shape[0]
         for i, (blk, cache) in enumerate(zip(self.blocks, caches)):
@@ -616,8 +645,11 @@ class HybridForCausalLM(Layer):
                 u, held = blk.res1.read(x)
                 h = blk.norm1(u)
                 if blk.kind in ("attention", "latent"):
-                    a, ck, cv = attn_step(blk.mixer, h, *cache)
-                    cache = (ck, cv)
+                    a, *cache = attn_step(blk.mixer, h, *cache)
+                    cache = tuple(cache)
+                    if getattr(blk.mixer, "positions_live", None) is not None:
+                        live = live + blk.mixer.positions_live
+                        read = read + blk.mixer.positions_read
                 elif getattr(blk.mixer, "takes_positions", False):
                     a, cache = rec_at(blk.mixer, h, cache)
                     small = small + blk.mixer.small_norm
@@ -642,6 +674,10 @@ class HybridForCausalLM(Layer):
             self._counted["retention_small_norm"] = small
         if self.cfg.hc_mult > 1:
             self._counted["mhc_unbalanced"] = unbalanced
+        if self.cfg.index_topk and "latent" in self.cfg.layer_types:
+            self._counted.update(
+                dsa_positions_live=jnp.asarray(live, jnp.int32),
+                dsa_positions_read=jnp.asarray(read, jnp.int32))
         if head_at is not None:
             return self._head(x)[:, 0], new_caches
         return (self._head(x) if head else None), new_caches
@@ -658,8 +694,8 @@ class HybridForCausalLM(Layer):
         the whole prompt)."""
         return self._cached_blocks(
             self._embed(toks), caches,
-            lambda sa, h, ck, cv: sa.forward_chunk(
-                h, ck, cv, t0, decode_kernel=decode_kernel),
+            lambda sa, h, *cache: sa.forward_chunk(
+                h, *cache, t0, decode_kernel=decode_kernel),
             lambda mx, h, c: mx.forward_chunk(h, c, valid_len),
             lambda mx, h, c: mx.forward_chunk(h, c, t0, valid_len),
             head=head, head_at=head_at)
@@ -668,8 +704,8 @@ class HybridForCausalLM(Layer):
         """One cached position: ``tok`` (B,) -> ((B, V), caches)."""
         logits, caches = self._cached_blocks(
             self._embed(tok[:, None]), caches,
-            lambda sa, h, ck, cv: sa.forward_step(
-                h, ck, cv, t, decode_kernel=decode_kernel),
+            lambda sa, h, *cache: sa.forward_step(
+                h, *cache, t, decode_kernel=decode_kernel),
             lambda mx, h, c: mx.forward_step(h, c),
             lambda mx, h, c: mx.forward_step(
                 h, c, jnp.broadcast_to(t, tok.shape)))
@@ -682,8 +718,8 @@ class HybridForCausalLM(Layer):
         attention and a mixer with a rotary embedding read ``t_rows``."""
         logits, caches = self._cached_blocks(
             self._embed(tok[:, None]), caches,
-            lambda sa, h, ck, cv: sa.forward_step_rows(
-                h, ck, cv, t_rows, decode_kernel=decode_kernel),
+            lambda sa, h, *cache: sa.forward_step_rows(
+                h, *cache, t_rows, decode_kernel=decode_kernel),
             lambda mx, h, c: mx.forward_step(h, c),
             lambda mx, h, c: mx.forward_step(h, c, t_rows))
         return logits[:, 0], caches

@@ -18,7 +18,7 @@ from ..ops import latent_attention as LA
 from ..ops.attention import rotary_embedding
 from ..telemetry.scopes import scope
 from .layer import Layer
-from .layers import Linear, RMSNorm
+from .layers import LayerNorm, Linear, RMSNorm
 
 HIGHEST = lax.Precision.HIGHEST
 
@@ -51,7 +51,30 @@ class LatentAttention(Layer):
     ``MultiHeadAttention``'s signatures with ``(c, r)`` in the place of
     ``(K, V)``; ``decode_kernel`` is the shell's argument to every
     attention mixer and is not read here: the bodies are chosen by
-    static shapes and the platform alone."""
+    static shapes and the platform alone.
+
+    **With an indexer** (``index_heads``, ``index_dim``, ``index_topk``
+    all given; DeepSeek-V3.2-Exp's sparse attention) a query attends
+    only the ``index_topk`` positions ``s <= t`` of largest index
+    score, every one while there are no more than that (ties to the
+    lower position; the selection is exact)::
+
+        q^I_j = (c_q W^I_q)_j (j < index_heads, index_dim numbers)
+        k^I_s = LayerNorm(x_s W^I_k)  (index_dim, one for all heads;
+                                       scale, bias, epsilon 1e-6)
+        w_j = (x W^I_w)_j * index_heads^-1/2 * index_dim^-1/2
+        I_ts = sum_j w_tj relu(rope(q^I_tj, t) . rope(k^I_s, s))
+
+    with the rotary embedding on the first ``rope_dim`` numbers of
+    ``q^I`` and ``k^I`` (unscaled frequencies), bfloat16 inputs and
+    float32 sums. The cache then holds a third array a position, the
+    index key: :meth:`init_cache` gives ``(c, r, k^I)``, and the three
+    cached entries take and return all three, ``(x, c, r, k^I, t)``.
+    The indexer runs under the scope ``dsa_index``, beside the
+    mixer's own scopes and not inside them. After a step
+    ``positions_live`` / ``positions_read`` hold the records the
+    step's rows held and those their attention was given (int32, idle
+    rows' counted too; valid inside the trace of the call)."""
 
     state_kind = "kv"
     cache_record = "latent"
@@ -59,7 +82,9 @@ class LatentAttention(Layer):
     def __init__(self, hidden: int, num_heads: int, q_rank: int,
                  kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
                  rope_theta: float = 10000.0, yarn: Optional[dict] = None,
-                 mscale_all_dim: float = 1.0, epsilon: float = 1e-6):
+                 mscale_all_dim: float = 1.0, epsilon: float = 1e-6,
+                 index_heads: int = 0, index_dim: int = 0,
+                 index_topk: int = 0):
         super().__init__()
         self.heads, self.kv_rank = num_heads, kv_rank
         self.nope, self.rope, self.v_dim = nope_dim, rope_dim, v_dim
@@ -78,21 +103,77 @@ class LatentAttention(Layer):
         self.kv_b_proj = Linear(kv_rank, num_heads * (nope_dim + v_dim),
                                 bias_attr=False)
         self.out_proj = Linear(num_heads * v_dim, hidden, bias_attr=False)
+        enforce(bool(index_heads) == bool(index_dim) == bool(index_topk),
+                "an indexer is its three sizes together, got heads %s "
+                "dim %s topk %s", index_heads, index_dim, index_topk)
+        self.index_heads, self.index_dim = index_heads, index_dim
+        self.index_topk = index_topk
+        self.positions_live = self.positions_read = None
+        if index_topk:
+            enforce(rope_dim <= index_dim, "the indexer's rotary part "
+                    "(%s) is wider than its heads (%s)", rope_dim, index_dim)
+            self.index_q_proj = Linear(q_rank, index_heads * index_dim,
+                                       bias_attr=False)
+            self.index_k_proj = Linear(hidden, index_dim, bias_attr=False)
+            self.index_k_norm = LayerNorm(index_dim, epsilon=1e-6)
+            self.index_w_proj = Linear(hidden, index_heads, bias_attr=False)
 
     def init_cache(self, batch: int, capacity: int, dtype=None):
         """Zeroed records: ``c`` (B, capacity, kv_rank) and ``r`` (B,
-        capacity, rope)."""
+        capacity, rope); with an indexer ``k^I`` (B, capacity,
+        index_dim) as a third."""
         dt = dtype or default_dtype()
-        return (jnp.zeros((batch, capacity, self.kv_rank), dt),
-                jnp.zeros((batch, capacity, self.rope), dt))
+        rec = (jnp.zeros((batch, capacity, self.kv_rank), dt),
+               jnp.zeros((batch, capacity, self.rope), dt))
+        if self.index_topk:
+            rec += (jnp.zeros((batch, capacity, self.index_dim), dt),)
+        return rec
 
-    def _queries(self, x, positions):
-        """(q^N (B, S, H, nope), rope(q^R) (B, S, H, rope))."""
-        b, s, _ = x.shape
-        q = self.q_b_proj(self.q_a_norm(self.q_a_proj(x))).reshape(
+    def _query_latent(self, x):
+        """``c_q`` (B, S, q_rank), RMS-normed: what ``W_qb`` and the
+        indexer's ``W^I_q`` both read."""
+        return self.q_a_norm(self.q_a_proj(x))
+
+    def _queries(self, cq, positions):
+        """(q^N (B, S, H, nope), rope(q^R) (B, S, H, rope)) of the query
+        latents ``cq``."""
+        b, s, _ = cq.shape
+        q = self.q_b_proj(cq).reshape(
             b, s, self.heads, self.nope + self.rope)
         return q[..., :self.nope], rotary_embedding(
             q[..., self.nope:], positions, self.theta, self.yarn)
+
+    def _cache_and_cursor(self, rest):
+        """``rest`` of a cached entry's arguments after ``(x, c, r)``:
+        ``(t,)`` or, with an indexer, ``(cache_ki, t)`` -> (cache_ki or
+        None, t)."""
+        *cache_ki, t = rest
+        enforce(len(cache_ki) == bool(self.index_topk), "a latent mixer "
+                "%s an indexer takes %s cache arrays, got %s",
+                "with" if self.index_topk else "without",
+                2 + bool(self.index_topk), 2 + len(cache_ki))
+        return (cache_ki[0] if cache_ki else None), t
+
+    def _index_rope(self, a, positions):
+        """The rotary embedding on the first ``rope`` numbers of (B, S,
+        heads, index_dim)."""
+        return jnp.concatenate(
+            [rotary_embedding(a[..., :self.rope], positions, self.theta),
+             a[..., self.rope:]], axis=-1)
+
+    def _index(self, x, cq, positions):
+        """(q^I (B, S, index_heads, index_dim), w (B, S, index_heads)
+        float32, k^I (B, S, index_dim)) of the positions."""
+        b, s, _ = x.shape
+        f32 = jnp.float32
+        qi = self._index_rope(self.index_q_proj(cq).reshape(
+            b, s, self.index_heads, self.index_dim), positions)
+        ki = self.index_k_norm(self.index_k_proj(x).astype(f32)).astype(
+            x.dtype)
+        ki = self._index_rope(ki[:, :, None, :], positions)[:, :, 0, :]
+        w = self.index_w_proj(x).astype(f32) * (
+            self.index_heads ** -0.5 * self.index_dim ** -0.5)
+        return qi, w, ki
 
     def _records(self, x, positions):
         """(c (B, S, kv_rank), r (B, S, rope)) of the positions."""
@@ -123,64 +204,144 @@ class LatentAttention(Layer):
                                 self.scale)
         return self.out_proj(o.astype(x.dtype).reshape(b, s, -1))
 
-    def _absorbed(self, x, qn, qr, c, r, t_rows):
+    def _sparse(self, x, cq, c, r, qi, wi, ki):
+        """:meth:`_decompressed` where each position attends its pick,
+        from the query latent ``cq``. The picks first, a span of queries
+        at a time over the keys up to the span's end
+        (``ops.latent_attention.sparse_spans``; one span where that
+        gives none); then the heads in groups, one after another
+        (``lax.map``): a group's queries, keys and values are made,
+        attended span by span under the picks and dropped, so a
+        28672-token chunk holds a quarter of its heads at a time."""
+        b, s, _ = x.shape
+        f32, kept = jnp.float32, c.dtype
+        spans = LA.sparse_spans(s) or [(0, s)]
+        with scope("dsa_index"):
+            keeps = [LA.span_pick(qi, wi, ki, q0, q1, self.index_topk
+                                  ).astype(jnp.int8) for q0, q1 in spans]
+        with scope("mla_prefill"):
+            hg = LA.head_group(self.heads)
+            by_group = lambda w, lead: jnp.moveaxis(w.reshape(
+                lead, self.heads // hg, hg, -1), 1, 0)
+            pos = jnp.arange(s, dtype=jnp.int32)
+
+            def group(ws):
+                wq, wkv = ws                  # (q_rank | kv_rank, hg, .)
+                q = jnp.einsum("bsl,lhd->bhsd", cq, wq,
+                               preferred_element_type=f32).astype(cq.dtype)
+                qr = rotary_embedding(
+                    q[..., self.nope:].reshape(b * hg, s, 1, self.rope),
+                    pos, self.theta, self.yarn).reshape(b, hg, s, self.rope)
+                q = jnp.concatenate([q[..., :self.nope].astype(kept),
+                                     qr.astype(kept)], axis=-1)
+                k = jnp.concatenate(
+                    [jnp.einsum("bsl,lhn->bhsn", c, wkv[..., :self.nope],
+                                preferred_element_type=f32).astype(kept),
+                     jnp.broadcast_to(r[:, None], (b, hg, s, self.rope))],
+                    axis=-1)
+                v = jnp.einsum("bsl,lhv->bhsv", c, wkv[..., self.nope:],
+                               preferred_element_type=f32).astype(kept)
+                return jnp.concatenate(
+                    [LA.masked_attention(q, k, v, keep, self.scale, q0)
+                     for (q0, _), keep in zip(spans, keeps)], axis=2)
+
+            o = lax.map(group, (
+                by_group(self.q_b_proj.weight, self.q_b_proj.weight.shape[0]),
+                by_group(self.kv_b_proj.weight, self.kv_rank)))
+            wo = self.out_proj.weight.reshape(
+                self.heads // hg, hg, self.v_dim, -1)
+            return jnp.einsum("gbhsv,ghvd->bsd", o.astype(x.dtype), wo,
+                              preferred_element_type=f32).astype(x.dtype)
+
+    def _absorbed(self, x, qn, qr, c, r, t_rows, keep=None):
         """The absorbed form of one position a row, ``x`` (B, 1, D),
         over the records ``c``, ``r`` (B, T, .): row ``b``'s query sees
-        records ``<= t_rows[b]``."""
+        records ``<= t_rows[b]``; with an indexer's ``keep`` (B, T), those
+        of them it allows (``ops.latent_attention.step_pick``)."""
         w = self._w_kvb()
         f32 = jnp.float32
         qa = jnp.einsum("bhn,lhn->bhl", qn[:, 0], w[..., :self.nope],
                         preferred_element_type=f32)
-        o = LA.latent_read(qa, qr[:, 0], c, r, t_rows, self.scale)
+        o = LA.latent_read(qa, qr[:, 0], c, r, t_rows, self.scale, keep)
         o = jnp.einsum("bhl,lhv->bhv", o.astype(x.dtype),
                        w[..., self.nope:], preferred_element_type=f32)
         return self.out_proj(o.astype(x.dtype).reshape(x.shape[0], 1, -1))
 
-    def forward_chunk(self, x, cache_c, cache_r, t0,
+    def forward_chunk(self, x, cache_c, cache_r, *rest,
                       decode_kernel: bool = False):
         """A prefill: ``x`` (B, S, D) at positions [0, S) writes their
         records there and attends each position over records ``<=`` its
-        own, decompressed. Returns (out (B, S, D), cache_c, cache_r).
-        ``t0`` is the static 0: a chunk that continues a cache would
-        have to read it, which no serving path does for a latent record
+        own (with an indexer: over its pick of them), decompressed.
+        ``rest`` is ``(t0,)`` or, with an indexer, ``(cache_ki, t0)``.
+        Returns (out (B, S, D), cache_c, cache_r[, cache_ki]). ``t0`` is
+        the static 0: a chunk that continues a cache would have to read
+        it, which no serving path does for a latent record
         (``serving.BatchedDecoder`` refuses each by name)."""
+        cache_ki, t0 = self._cache_and_cursor(rest)
         enforce(isinstance(t0, int) and t0 == 0, "a latent chunk starts "
                 "at the static offset 0, got %r: a chunk that continues "
                 "a cache is not written", t0)
         s = x.shape[1]
         pos = jnp.arange(s, dtype=jnp.int32)
+        put = lambda cache, new: lax.dynamic_update_slice_in_dim(
+            cache, new, 0, axis=1)
         with scope("mla_prefill"):
-            qn, qr = self._queries(x, pos)
+            cq = self._query_latent(x)
+            if not self.index_topk:
+                qn, qr = self._queries(cq, pos)
             c, r = self._records(x, pos)
             c, r = c.astype(cache_c.dtype), r.astype(cache_r.dtype)
-            cache_c = lax.dynamic_update_slice_in_dim(cache_c, c, 0,
-                                                      axis=1)
-            cache_r = lax.dynamic_update_slice_in_dim(cache_r, r, 0,
-                                                      axis=1)
-            return self._decompressed(x, qn, qr, c, r), cache_c, cache_r
+            cache_c, cache_r = put(cache_c, c), put(cache_r, r)
+            if not self.index_topk:
+                return self._decompressed(x, qn, qr, c, r), cache_c, cache_r
+        with scope("dsa_index"):
+            qi, wi, ki = self._index(x, cq, pos)
+            ki = ki.astype(cache_ki.dtype)
+            cache_ki = put(cache_ki, ki)
+            self.positions_live = self.positions_read = jnp.int32(0)
+        return (self._sparse(x, cq, c, r, qi, wi, ki), cache_c, cache_r,
+                cache_ki)
 
-    def forward_step(self, x, cache_c, cache_r, t,
+    def forward_step(self, x, cache_c, cache_r, *rest,
                      decode_kernel: bool = False):
-        """One decode step at the shared cursor ``t``: ``x`` (B, 1, D)."""
+        """One decode step at the shared cursor ``t`` (the last of
+        ``rest``): ``x`` (B, 1, D)."""
         return self.forward_step_rows(
-            x, cache_c, cache_r, jnp.broadcast_to(t, x.shape[:1]))
+            x, cache_c, cache_r, *rest[:-1],
+            jnp.broadcast_to(rest[-1], x.shape[:1]))
 
-    def forward_step_rows(self, x, cache_c, cache_r, t_rows,
+    def forward_step_rows(self, x, cache_c, cache_r, *rest,
                           decode_kernel: bool = False):
         """One position PER ROW at per-row cursors ``t_rows`` (B,), the
         continuous-batching step: each row's record is written at its
-        own cursor and its query reads the row's records ``<= t``,
-        absorbed. ``x``: (B, 1, D)."""
+        own cursor and its query reads the row's records ``<= t`` (with
+        an indexer: its pick of them), absorbed. ``x``: (B, 1, D);
+        ``rest`` is ``(t_rows,)`` or ``(cache_ki, t_rows)``."""
+        cache_ki, t_rows = self._cache_and_cursor(rest)
+        write = jax.vmap(lambda a, u, s: lax.dynamic_update_slice_in_dim(
+            a, u, s, axis=0))
         with scope("mla_decode"):
             pos = t_rows.astype(jnp.int32)[:, None]               # (B, 1)
-            qn, qr = self._queries(x, pos)
+            cq = self._query_latent(x)
+            qn, qr = self._queries(cq, pos)
             c, r = self._records(x, pos)
-            write = jax.vmap(lambda a, u, s: lax.dynamic_update_slice_in_dim(
-                a, u, s, axis=0))
             cache_c = write(cache_c, c.astype(cache_c.dtype), pos[:, 0])
             cache_r = write(cache_r, r.astype(cache_r.dtype), pos[:, 0])
-            out = self._absorbed(x, qn, qr, cache_c, cache_r, pos[:, 0])
-            return out, cache_c, cache_r
+            if not self.index_topk:
+                out = self._absorbed(x, qn, qr, cache_c, cache_r, pos[:, 0])
+                return out, cache_c, cache_r
+        with scope("dsa_index"):
+            qi, wi, ki = self._index(x, cq, pos)
+            cache_ki = write(cache_ki, ki.astype(cache_ki.dtype), pos[:, 0])
+            keep, n = LA.step_pick(
+                LA.step_index_scores(qi[:, 0], wi[:, 0], cache_ki),
+                pos[:, 0], self.index_topk)
+            self.positions_read = jnp.sum(n, dtype=jnp.int32)
+            self.positions_live = jnp.sum(pos + 1, dtype=jnp.int32)
+        with scope("mla_decode"):
+            out = self._absorbed(x, qn, qr, cache_c, cache_r, pos[:, 0],
+                                 keep)
+        return out, cache_c, cache_r, cache_ki
 
     def forward(self, x, causal: bool = True):
         """Causal self-attention of (B, T, D) from no cache."""
@@ -189,8 +350,14 @@ class LatentAttention(Layer):
         kept = self.kv_b_proj.weight.dtype      # as a cache would keep them
         with scope("mla_prefill"):
             c, r = self._records(x, pos)
-            return self._decompressed(x, *self._queries(x, pos),
-                                      c.astype(kept), r.astype(kept))
+            cq = self._query_latent(x)
+            if not self.index_topk:
+                return self._decompressed(x, *self._queries(cq, pos),
+                                          c.astype(kept), r.astype(kept))
+        with scope("dsa_index"):
+            qi, wi, ki = self._index(x, cq, pos)
+        return self._sparse(x, cq, c.astype(kept), r.astype(kept), qi, wi,
+                            ki.astype(kept))
 
 
 def sinkhorn(logits, iters: int, eps: float):
@@ -207,16 +374,22 @@ def sinkhorn(logits, iters: int, eps: float):
 class PlainResidual:
     """``x + m F(norm(x))``: the one-stream residual path. ``read``
     hands the stream itself to the sublayer's norm, ``write`` adds the
-    sublayer's output times ``m``. No parameters."""
+    sublayer's output times ``m``. No parameters. ``settle``: the sum
+    is made where it is written (``lax.optimization_barrier``) and not
+    wherever the compiler next reads it. Left to itself XLA keeps a long
+    chunk's stream as the embedding plus every sublayer's float32
+    output so far and adds them up again at each use: at 28672 positions
+    of 6144 numbers that is 0.67 GB a sublayer held to the end."""
 
-    def __init__(self, multiplier: float = 1.0):
-        self.m = float(multiplier)
+    def __init__(self, multiplier: float = 1.0, settle: bool = False):
+        self.m, self.settle = float(multiplier), bool(settle)
 
     def read(self, x):
         return x, None
 
     def write(self, x, y, held):
-        return x + self.m * y
+        out = x + self.m * y
+        return lax.optimization_barrier(out) if self.settle else out
 
 
 class HyperConnection(Layer):

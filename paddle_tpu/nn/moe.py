@@ -193,6 +193,23 @@ DENSE_MAX_WORK = 12
 DENSE_MAX_ROWS = 512
 
 
+# The grouped body writes a float32 row of the model's width a (token,
+# pick) pair several times over, held expert or not: past this many
+# bytes of such rows a call's tokens go through it in equal parts, one
+# after another (229,376 pairs of 6144 numbers, a 28672-token prefill at
+# 8 picks, are 5.25 GB a copy). Every cell's prefills before PR 45 stay
+# whole (the largest: 14336 rows x 4 picks x 3584 numbers, 0.82 GB).
+GROUPED_MAX_BYTES = 1 << 30
+
+
+def grouped_parts(rows: int, top_k: int, width: int) -> int:
+    """The equal parts the grouped body takes ``rows`` tokens of
+    ``width`` numbers in: the fewest that leave a part's float32 pairs
+    at most :data:`GROUPED_MAX_BYTES` (one token a part at the least)."""
+    return next(n for n in range(1, rows + 1) if rows % n == 0 and (
+        rows // n * top_k * width * 4 <= GROUPED_MAX_BYTES or n == rows))
+
+
 def streams_densely(rows: int, top_k: int, experts: int) -> bool:
     """Whether :func:`dropless_moe` takes its dense body for ``rows``
     tokens that each pick ``top_k`` of ``experts`` router outputs
@@ -314,10 +331,23 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     expert and run as grouped matmuls. Both run in the WEIGHTS' type with
     float32 sums: the tokens are cast to it, never the experts (a
     float32 copy of bfloat16 experts would be written out whole a call).
+    Past :data:`GROUPED_MAX_BYTES` of pairs the grouped body takes the rows
+    in equal parts, one after another (:func:`grouped_parts`).
 
     Returns (y (S, D) in x's dtype, tokens (held,) int32: the pairs
     each held expert got)."""
     e = router_w.shape[1]
+    parts = grouped_parts(x.shape[0], top_k, x.shape[1])
+    if parts > 1 and not streams_densely(x.shape[0], top_k, e):
+        # the body a part takes is the whole call's: past DENSE_MAX_ROWS
+        # the rule does not read the rows
+        y, sizes = jax.lax.map(
+            lambda part: dropless_moe(
+                part, router_w, w_gate, w_up, w_down, top_k=top_k,
+                experts_held=experts_held, routing=routing,
+                score_bias=score_bias, scaling=scaling),
+            x.reshape(parts, x.shape[0] // parts, x.shape[1]))
+        return y.reshape(x.shape), jnp.sum(sizes, axis=0)
     first, held = (0, e) if experts_held is None else experts_held
     with scope("moe_route"):
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)

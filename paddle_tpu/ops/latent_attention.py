@@ -23,6 +23,21 @@ kernels to), chosen by static shapes and the platform alone
 prefill the flash kernel over heads padded to 256, which never holds
 an ``S x S`` score. ``tools/mla_bodies.py`` times the read's bodies on
 the chip; PERF.md section 3 has the numbers.
+
+**Under a learned selection** (the "DSA" lightning indexer of
+DeepSeek-V3.2-Exp) a query reads only the ``topk`` positions of largest
+index score ``I[t, s] = sum_j w[t, j] relu(q^I[t, j] . k^I[s])``
+(:func:`index_scores`, :func:`step_index_scores`), exactly
+(:func:`pick_mask`: a search for the ``topk``-th largest value bit by
+bit, ties to the lower position; no approximate top-k). A decode step
+then reads every live record under the pick as a mask
+(:func:`step_pick`, :func:`latent_read` with ``keep``: ``mla_decode``'s
+kernel under a keep-mask a row); a prefill
+goes by spans of :data:`QUERY_SPAN` queries, each span's scores, pick
+and masked flash attention over the keys up to its end
+(:func:`span_pick`, :func:`masked_attention`), so no ``S x S`` float32
+exists. ``tools/dsa_bodies.py`` times them on the chip, beside the
+forms that gather the picked records.
 """
 
 from __future__ import annotations
@@ -54,19 +69,20 @@ def read_kernel_ok(capacity: int, kv_rank: int, rope: int,
             and heads <= 128 and mla_decode.block_k(capacity) is not None)
 
 
-def _read_jnp(qa, qr, c, r, t_rows, scale):
+def _read_jnp(qa, qr, c, r, t_rows, scale, keep=None):
     f32 = jnp.float32
     s = (jnp.einsum("bhl,btl->bht", qa.astype(c.dtype), c,
                     preferred_element_type=f32)
          + jnp.einsum("bhr,btr->bht", qr.astype(r.dtype), r,
                       preferred_element_type=f32)) * scale
-    keep = jnp.arange(c.shape[1])[None, :] <= t_rows[:, None]    # (B, T)
+    live = jnp.arange(c.shape[1])[None, :] <= t_rows[:, None]    # (B, T)
+    keep = live if keep is None else live & keep
     p = jax.nn.softmax(jnp.where(keep[:, None], s, _NEG), axis=-1)
     return jnp.einsum("bht,btl->bhl", p.astype(c.dtype), c,
                       preferred_element_type=f32)
 
 
-def latent_read(qa, qr, c, r, t_rows, scale: float):
+def latent_read(qa, qr, c, r, t_rows, scale: float, keep=None):
     """The absorbed read of the latent records, one query position a
     row.
 
@@ -75,14 +91,25 @@ def latent_read(qa, qr, c, r, t_rows, scale: float):
     (B, T, rope): the records of ``T`` positions a row; ``t_rows`` (B,)
     each row's cursor: its query sees records ``<= t_rows[b]``. Returns
     (B, H, kv_rank) float32, ``sum_i p_i c_i``: the caller multiplies
-    by ``W^V``."""
+    by ``W^V``.
+
+    ``keep`` (B, T) bool, a learned selection's pick
+    (:func:`step_pick`): every live record is read and the unpicked
+    masked out (the kernel's call is then ``pt_dsa_read``). On a v5e at
+    16 rows x 32768 positions and a pick of 2048
+    (``tools/dsa_bodies.py``; PERF.md section 3) scores, pick and this
+    read take 0.50 / 0.71 / 1.12 ms a layer at contexts of 4096 / 12288 /
+    28672; the other form, the picked records gathered and read alone,
+    6.6 at each in XLA (its gather of 16 x 2048 records of 1152 bytes
+    costs twenty times the bytes it moves) and 1.5 with ``lax.top_k``'s
+    indices: it waits for a kernel that gathers."""
     b, h, _ = qa.shape
     t_rows = jnp.broadcast_to(jnp.asarray(t_rows, jnp.int32), (b,))
     if read_kernel_ok(c.shape[1], c.shape[2], r.shape[2], h):
         from .pallas.mla_decode import mla_decode
 
-        return mla_decode(qa, qr, c, r, t_rows, scale=scale)
-    return _read_jnp(qa, qr, c, r, t_rows, scale)
+        return mla_decode(qa, qr, c, r, t_rows, scale=scale, keep=keep)
+    return _read_jnp(qa, qr, c, r, t_rows, scale, keep)
 
 
 # the Pallas flash kernel's blocks for a prefill's padded heads
@@ -130,4 +157,178 @@ def causal_attention(q, k, v, scale: float):
     p = jax.nn.softmax(jnp.where(at[None, :] <= at[:, None], s, _NEG),
                        axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                      preferred_element_type=f32).astype(v.dtype)
+
+
+# --------------------------------------------------------------------------
+# a learned selection in front of the read
+# --------------------------------------------------------------------------
+
+# the queries one span of a sparse prefill holds: its index scores are
+# (QUERY_SPAN, keys) float32, 235 MB at 28672 keys
+QUERY_SPAN = 2048
+
+
+def _pad_last(a, to: int):
+    """The last axis zero-padded to a multiple of ``to``."""
+    extra = -a.shape[-1] % to
+    return a if not extra else jnp.pad(
+        a, ((0, 0),) * (a.ndim - 1) + ((0, extra),))
+
+
+def step_index_scores(qi, wi, ki):
+    """One query a row: ``qi`` (B, H, d), ``wi`` (B, H) float32, ``ki``
+    (B, T, d) -> (B, T) float32 ``sum_h w_h relu(q_h . k_s)``; the
+    weighted sum is elementwise, so float32 stays float32."""
+    s = jnp.einsum("bhd,btd->bht", qi.astype(ki.dtype), ki,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(wi.astype(jnp.float32)[:, :, None]
+                   * jnp.maximum(s, 0.0), axis=1)
+
+
+def scores_kernel_ok(sq: int, sk: int) -> bool:
+    """Whether a chunk's index scores take ``pallas/dsa.py::dsa_scores``
+    (heads padded to whole lanes): on the TPU or under ``force_flash``,
+    lengths its blocks divide."""
+    from .pallas import dsa
+
+    return _kernels_run() and dsa.scores_ok(sq, sk, 128)
+
+
+def index_scores(qi, wi, ki, q0: int = 0, span=None):
+    """A chunk's index scores: ``qi`` (B, S, H, d), ``wi`` (B, S, H)
+    float32, ``ki`` (B, S, d), all of positions ``[0, S)``; the queries
+    ``[q0, q0 + span)`` (default: all from ``q0``) against the keys
+    ``[0, q0 + span)`` -> (B, span, q0 + span) float32. Entries past the
+    causal bound are undefined (the kernel skips their blocks): mask
+    ``s > t``. The ``jax.numpy`` body adds one head at a time, so the
+    (queries, heads, keys) product never exists here either."""
+    q1 = qi.shape[1] if span is None else q0 + span
+    if scores_kernel_ok(q1 - q0, q1):
+        from .pallas.dsa import dsa_scores
+
+        return dsa_scores(_pad_last(qi, 128), wi, _pad_last(ki, 128),
+                          q0=q0, span=q1 - q0)
+    f32 = jnp.float32
+    qi, wi, ki = qi[:, q0:q1], wi[:, q0:q1], ki[:, :q1]
+
+    def one(acc, head):
+        q, w = head                                 # (B, Sq, d), (B, Sq)
+        s = jnp.einsum("bqd,bkd->bqk", q, ki, preferred_element_type=f32)
+        return acc + w[..., None] * jnp.maximum(s, 0.0), None
+
+    acc = jnp.zeros((qi.shape[0], q1 - q0, q1), f32)
+    return jax.lax.scan(one, acc, (
+        jnp.moveaxis(qi.astype(ki.dtype), 2, 0),
+        jnp.moveaxis(wi.astype(f32), 2, 0)))[0]
+
+
+def _ordered(scores, live):
+    """float32 scores as uint32 keys of the same order, 0 where not
+    ``live`` (under every live key but that of a NaN with its sign
+    set)."""
+    scores = scores.astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0.0, 0.0, scores), jnp.int32)  # -0.0 is 0.0
+    key = jnp.where(bits < 0, ~bits, bits | jnp.int32(-2 ** 31))
+    return jnp.where(live, jax.lax.bitcast_convert_type(key, jnp.uint32),
+                     jnp.uint32(0))
+
+
+def pick_mask(scores, live, k: int):
+    """The EXACT selection: ``scores`` (..., T) float32, ``live`` (...,
+    T) bool -> (..., T) bool, true at the ``min(k, live count)`` live
+    positions of largest score, ties to the lower position (what a
+    stable descending sort's first ``k`` are). The ``k``-th largest key
+    is found bit by bit, 32 counts over the row, never a sort; the
+    prefix sum that breaks ties runs only where some row holds more
+    than ``k`` keys at or above its threshold."""
+    key = _ordered(scores, live)
+
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(key >= cand[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(key.shape[:-1], jnp.uint32))[..., None]
+    above = key > thr
+    tied = key == thr
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32, keepdims=True)
+    over = jnp.sum(tied, axis=-1, dtype=jnp.int32, keepdims=True) > room
+    tied = jax.lax.cond(
+        jnp.any(over),
+        lambda: tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= room),
+        lambda: tied)
+    return (above | tied) & live
+
+
+def step_pick(scores, t_rows, topk: int):
+    """A decode step's pick: ``scores`` (B, T) float32 at per-row cursors
+    ``t_rows`` (B,) -> (keep (B, T) bool, or None where the capacity is
+    within ``topk`` and the pick is every live record; the (B,) records
+    each row's attention is given)."""
+    b, t = scores.shape
+    t_rows = jnp.broadcast_to(jnp.asarray(t_rows, jnp.int32), (b,))
+    n = jnp.minimum(t_rows + 1, topk)
+    if t <= topk:
+        return None, n
+    return pick_mask(scores, jnp.arange(t)[None, :] <= t_rows[:, None],
+                     topk), n
+
+
+def sparse_spans(s: int):
+    """The (first, end) query spans a sparse prefill of ``s`` positions
+    goes by, or None where it is one span (the CPU's body and an odd
+    length's, whose index scores and attention scores are whole): on
+    the TPU or under ``force_flash``, a length :data:`QUERY_SPAN`
+    divides."""
+    if not (_kernels_run() and s % QUERY_SPAN == 0):
+        return None
+    return [(a, a + QUERY_SPAN) for a in range(0, s, QUERY_SPAN)]
+
+
+def head_group(heads: int) -> int:
+    """The heads a sparse prefill decompresses at a time: 16 where that
+    divides them (a group's queries, keys and values at 28672 positions
+    and widths of 256 are 0.7 GB where all 64 heads' are 2.8)."""
+    return 16 if heads % 16 == 0 else heads
+
+
+def causal_keep(q0: int, q1: int):
+    """(1, q1 - q0, q1) bool: query ``q0 + i`` sees keys ``<= q0 + i``."""
+    return (jnp.arange(q1)[None, :]
+            <= jnp.arange(q0, q1)[:, None])[None]
+
+
+def span_pick(qi, wi, ki, q0: int, q1: int, topk: int):
+    """The pick of the queries ``[q0, q1)`` over the keys ``[0, q1)``:
+    (B, q1 - q0, q1) bool. ``qi`` (B, S, H, d), ``wi`` (B, S, H), ``ki``
+    (B, S, d) hold the whole chunk."""
+    live = causal_keep(q0, q1)
+    if q1 <= topk:
+        return jnp.broadcast_to(live, (qi.shape[0], *live.shape[1:]))
+    return pick_mask(index_scores(qi, wi, ki, q0, q1 - q0), live, topk)
+
+
+def masked_attention(q, k, v, keep, scale: float, q0: int = 0):
+    """Softmax attention of the queries ``[q0, q0 + Sq)`` of ``q`` (B,
+    H, S, dq) over the keys ``[0, q0 + Sq)`` of ``k`` (B, H, S, dq),
+    ``v`` (B, H, S, dv) where ``keep`` (B, Sq, q0 + Sq) allows -> (B, H,
+    Sq, dv). The Pallas kernel on the TPU or under ``force_flash``, at
+    lengths its blocks divide (widths padded to whole lanes; the arrays
+    go in whole), else the whole score."""
+    from .pallas import dsa
+
+    sq, sk = keep.shape[1], keep.shape[2]
+    if _kernels_run() and dsa.prefill_ok(sq, sk, 128, 128):
+        return dsa.dsa_prefill(
+            _pad_last(q, 128), _pad_last(k, 128), _pad_last(v, 128), keep,
+            scale=scale, q0=q0)[..., :v.shape[-1]]
+    f32 = jnp.float32
+    s = jnp.einsum("bhqd,bhkd->bhqk", q[:, :, q0:q0 + sq], k[:, :, :sk],
+                   preferred_element_type=f32) * scale
+    p = jax.nn.softmax(jnp.where(keep[:, None], s, _NEG), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v[:, :, :sk],
                       preferred_element_type=f32).astype(v.dtype)

@@ -10,7 +10,7 @@ is the ONE list: a site enters a scope through :func:`scope`, which
 refuses a name that is not here, and the reader matches these names
 and no others. A scope is entered in the model shells, the mixers and
 the trainer, never inside a ``nn/`` library layer, which serves many
-callers. Kernel names (``pt_flash_*``, ``pt_mla_decode``,
+callers. Kernel names (``pt_flash_*``, ``pt_mla_decode``, ``pt_dsa_*``,
 ``pt_retention_step``) name one ``custom-call`` each and are not block
 scopes.
 """
@@ -47,6 +47,10 @@ SCOPES = {
                   "projections, norms, rotary, the record's write, the "
                   "absorbed read, output projection",
     "mla_prefill": "the same mixer over a chunk, decompressed",
+    "dsa_index": "a latent mixer's indexer, beside mla_decode / "
+                 "mla_prefill and never inside them: its three "
+                 "projections, the index key's norm, rotary and write, "
+                 "the index scores and the pick",
     "mhc_mix": "a hyper-connection's maps: both halves around a "
                "sublayer, never the sublayer itself",
     "moe_route": "an expert layer's router: logits, top-k, counts and "

@@ -251,6 +251,49 @@ def test_mla_decode_compiles_at_the_cells_widths(one_chip):
         b * cap * lat * 2) // 8
 
 
+def test_the_sparse_latent_kernels_compile_at_the_cells_widths(one_chip):
+    """Learned sparse attention at the GLM-5 cell's widths: the masked
+    read of a decode step (16 rows, 64 heads, records of 512 + 64, 32768
+    positions, a keep-mask a row), a span's index scores (2048 queries
+    of 32 heads of 128 against 12288 keys of a 28672-token chunk) and
+    the masked flash attention of that span (16 heads of 256 / 256, an
+    int8 mask shared by the heads): Mosaic takes each, named, and none
+    copies the chunk's arrays to cut its span out of them."""
+    from paddle_tpu.ops.pallas import dsa, mla_decode
+
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    b, h, lat, rope, cap = 16, 64, 512, 64, 32768
+    read = jax.jit(lambda qa, qr, c, r, keep, t: mla_decode.mla_decode(
+        qa, qr, c, r, t, scale=0.1, keep=keep, interpret=False)).lower(
+        sd((b, h, lat)), sd((b, h, rope)), sd((b, cap, lat)),
+        sd((b, cap, rope)), sd((b, cap), jnp.int32),
+        sd((b,), jnp.int32)).compile()
+    assert "%pt_dsa_read" in read.as_text()
+    # no copy of the latents (the 64-wide rotary keys are laid out anew)
+    assert read.memory_analysis().temp_size_in_bytes < (
+        b * cap * lat * 2) // 3
+    s, q0, span = 28672, 10240, 2048
+    scores = jax.jit(lambda q, w, k: dsa.dsa_scores(
+        q, w, k, q0=q0, span=span, interpret=False)).lower(
+        sd((1, s, 32, 128)), sd((1, s, 32), jnp.float32),
+        sd((1, s, 128))).compile()
+    assert "%pt_dsa_scores" in scores.as_text()
+    out = span * (q0 + span) * 4
+    assert scores.memory_analysis().output_size_in_bytes == out
+    # the queries laid out anew with their heads side by side, once; no
+    # (queries, heads, keys) product and no slice of the keys
+    assert scores.memory_analysis().temp_size_in_bytes < (
+        1.1 * s * 32 * 128 * 2)
+    attend = jax.jit(lambda q, k, v, keep: dsa.dsa_prefill(
+        q, k, v, keep, scale=1 / 16, q0=q0, interpret=False)).lower(
+        sd((1, 16, s, 256)), sd((1, 16, s, 256)), sd((1, 16, s, 256)),
+        sd((1, span, q0 + span), jnp.int8)).compile()
+    assert "%pt_dsa_prefill" in attend.as_text()
+    assert attend.memory_analysis().temp_size_in_bytes < (
+        16 * s * 256 * 2) // 8
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
 def test_flash_decode_paged_compiles(one_chip, quantized):
     n_log = CAPACITY // PAGE

@@ -87,8 +87,15 @@ def main(argv=None) -> int:
             for body in ("grouped", "dense"):
                 moe.streams_densely = lambda *_, b=body: b == "dense"
                 fn = stack_fn()
-                for _ in range(3):
-                    fn(x, router, wg, wu, wd).block_until_ready()
+                try:
+                    for _ in range(3):
+                        fn(x, router, wg, wu, wd).block_until_ready()
+                except Exception as err:  # noqa: BLE001 — a body that
+                    # does not fit at these rows is a finding, not an end
+                    print(json.dumps({"rows": rows, "body": body,
+                                      "rule": rule, "error": repr(err)[:200]}),
+                          flush=True)
+                    continue
                 t0 = time.perf_counter()
                 for _ in range(args.calls):
                     out = fn(x, router, wg, wu, wd)

@@ -72,6 +72,14 @@ def main(argv=None) -> int:
     ap.add_argument("--picks", type=int, default=0)
     ap.add_argument("--out", default="chiprun_out/margins")
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--unselect", default="",
+                    choices=("", "program", "both"),
+                    help="a family with a learned selection "
+                    "(ops.latent_attention.pick_mask): attend every live "
+                    "position in the program, switched off from outside "
+                    "the library (what the comparison must catch), or in "
+                    "the program and the reference both (what the noise "
+                    "is without the selection)")
     args = ap.parse_args(argv)
 
     import jax
@@ -103,8 +111,17 @@ def main(argv=None) -> int:
     # logits as they stand; the margins are kept on the side
     raw = types.SimpleNamespace(margin=None)
 
+    if args.unselect:
+        from paddle_tpu.ops import latent_attention as LA
+
+        LA.pick_mask = lambda scores, live, k: live & (scores == scores)
+    both = {"select": False} if args.unselect == "both" else {}
+
     def layerwise_raw(*a, **k):
-        lg, raw.margin = R.layerwise(*a, **k)
+        # a family with a learned selection gives a second margin, the
+        # queries' position margins (reference.pick)
+        lg, raw.margin, *rest = R.layerwise(*a, **k, **both)
+        raw.position = rest[0] if rest else None
         return lg
 
     shim = types.SimpleNamespace(
@@ -174,12 +191,40 @@ def main(argv=None) -> int:
                 row["control"] = check.serve_gap(held, ctl, mask)[0]
             by[str(m)] = row
             del held
+        by_position = None
+        if raw.position is not None:
+            # the expert hold as shipped, then the position hold at each
+            # (margin, depth)
+            at = raw.position
+            keep["position_margin"] = at
+            level = jax.jit(R.hold)
+            by_expert = jnp.where(margin < R.PICK_MARGIN, depth, 0.0)
+            seen = np.asarray(at)[np.asarray(mask)]
+            by_position = {"quantiles": {
+                str(q): float(np.quantile(seen[np.isfinite(seen)], q))
+                for q in (0.5, 0.9, 0.99, 1.0)} if np.isfinite(
+                    seen).any() else None}
+            for m in (0.0, 0.001, 0.003, 0.01, 0.03):
+                for d in (0.5, 1.0, 1.5):
+                    both_held = level(lg, jnp.maximum(
+                        by_expert, jnp.where(at < m, d, 0.0)))
+                    row = {"sound": check.serve_gap(both_held, served,
+                                                    mask)[0],
+                           "undecided_share": float(
+                               jnp.sum(mask & (at < m)) / jnp.sum(mask))}
+                    if ctl is not None:
+                        row["control"] = check.serve_gap(both_held, ctl,
+                                                         mask)[0]
+                    by_position[f"{m}/{d}"] = row
+            del both_held
         g, same, n = check.serve_gap(lg, served, mask)
         np.savez(os.path.join(args.out, f"{seed}.npz"),
                  **{k: np.asarray(v) for k, v in keep.items()})
-        say(what="served", seed=seed, requests=len(finished),
+        say(what="served", seed=seed, unselect=args.unselect,
+            requests=len(finished),
             compared=len(sample), tokens=n, same=same, raw_gap_max=g,
-            depth=depth, by_margin=by, served_s=round(t_served, 1),
+            depth=depth, by_margin=by, by_position_margin=by_position,
+            served_s=round(t_served, 1),
             seed_s=round(time.perf_counter() - t0, 1))
         del lg, keep, margin, served, mask, ctl
         gc.collect()
