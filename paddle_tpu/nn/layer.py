@@ -24,8 +24,9 @@ import jax
 import jax.numpy as jnp
 
 from ..core import random as prandom
-from ..core.dtypes import default_dtype
+from ..core.dtypes import default_dtype, get_policy, to_dtype
 from ..core.enforce import enforce, not_found
+from ..telemetry.scopes import scope
 
 
 class Layer:
@@ -36,6 +37,11 @@ class Layer:
         object.__setattr__(self, "_params", {})
         object.__setattr__(self, "_buffers", {})
         object.__setattr__(self, "_sublayers", {})
+        object.__setattr__(self, "_compute_cast", set())
+        # the pairs bound for one functional_call, and how deep this
+        # layer's own code is running (see ``_cast_once``)
+        object.__setattr__(self, "_narrow", {})
+        object.__setattr__(self, "_own_depth", 0)
         object.__setattr__(self, "training", True)
         object.__setattr__(self, "_rng_ctx", None)
 
@@ -73,6 +79,12 @@ class Layer:
             # parameter/buffer placeholder — fetch live value
             d = object.__getattribute__(self, "__dict__")
             params = d.get("_params", {})
+            if d.get("_own_depth"):
+                pair = d["_narrow"].get(name)
+                # a wrapper may have put another value in the stored
+                # leaf's place since: the copy is of what it was cast from
+                if pair is not None and pair.stored is params.get(name):
+                    return pair.narrow
             if name in params:
                 return params[name]
             buffers = d.get("_buffers", {})
@@ -84,9 +96,11 @@ class Layer:
 
     def create_parameter(self, name: str, shape, dtype=None,
                          initializer: Optional[Callable] = None,
-                         is_bias: bool = False):
+                         is_bias: bool = False, compute_cast: bool = False):
         """LayerHelper.create_parameter analog (reference: layer_helper.py:29
-        param creation + default initializers)."""
+        param creation + default initializers). ``compute_cast`` declares
+        that the layer's own code reads the parameter ONLY through
+        ``Policy.cast_to_compute`` (see :meth:`_cast_once`)."""
         from ..initializer import Constant, XavierUniform
 
         dtype = dtype or default_dtype()
@@ -97,6 +111,8 @@ class Layer:
         value = initializer(key, tuple(shape), dtype)
         self._params[name] = value
         object.__setattr__(self, name, None)
+        if compute_cast:
+            self._compute_cast.add(name)
         return value
 
     def register_buffer(self, name: str, value) -> None:
@@ -135,6 +151,15 @@ class Layer:
     def parameters(self) -> List[Any]:
         return list(self.named_parameters().values())
 
+    def compute_cast_names(self) -> frozenset:
+        """The dotted names of the parameters that their layers' own code
+        reads only through ``Policy.cast_to_compute``: the leaves
+        :meth:`_cast_once` may cast ahead."""
+        out = set(self._compute_cast)
+        for name, sub in self._sublayers.items():
+            out.update(f"{name}.{k}" for k in sub.compute_cast_names())
+        return frozenset(out)
+
     def named_buffers(self) -> Dict[str, Any]:
         out = {k: v for k, v in self._buffers.items()}
         for name, sub in self._sublayers.items():
@@ -161,7 +186,13 @@ class Layer:
         for k, v in own.items():
             enforce(k in self._params, "unknown parameter %s on %s", k,
                     type(self).__name__)
-            self._params[k] = jnp.asarray(v)
+            if isinstance(v, ComputeCast):
+                v = self._narrow[k] = ComputeCast(jnp.asarray(v.stored),
+                                                  v.narrow)
+                self._params[k] = v.stored
+            else:
+                self._narrow.pop(k, None)
+                self._params[k] = jnp.asarray(v)
         for name, sub in self._sublayers.items():
             prefix = f"{name}."
             subflat = {k[len(prefix):]: v for k, v in flat.items()
@@ -213,7 +244,23 @@ class Layer:
         raise NotImplementedError
 
     def __call__(self, *args, **kwargs):
-        return self.forward(*args, **kwargs)
+        with self._own_code():
+            return self.forward(*args, **kwargs)
+
+    @contextlib.contextmanager
+    def _own_code(self):
+        """While this layer's own code runs (``__call__``, or the method
+        a ``functional_call`` names), its declared parameters read as
+        the narrow copies a ``functional_call`` bound. Any other reader
+        (a parent that takes ``child.weight``, a wrapper that goes
+        through ``child._params`` or calls ``child.forward`` itself)
+        gets the parameter as it is stored."""
+        d = self.__dict__
+        d["_own_depth"] += 1
+        try:
+            yield
+        finally:
+            d["_own_depth"] -= 1
 
     def functional_call(self, params: Dict[str, Any], *args,
                         buffers: Optional[Dict[str, Any]] = None,
@@ -222,12 +269,13 @@ class Layer:
                         method: str = "forward", **kwargs):
         """Pure-function entry point: run ``method`` (default forward) with
         `params`/`buffers` injected; returns (output, new_buffers). Safe to
-        jit/grad over."""
-        saved_params = dict(self.named_parameters())
+        jit/grad over. The declared parameters that the policy narrows
+        are cast here, once (:meth:`_cast_once`)."""
+        saved_params = self._bound_parameters()
         saved_buffers = dict(self.named_buffers())
         saved_training = self.training
         try:
-            self.set_parameters(params)
+            self.set_parameters(self._cast_once(params))
             if buffers is not None:
                 self.set_buffers(buffers)
             if training is not None:
@@ -236,7 +284,8 @@ class Layer:
                    "count": 0}
             _RNG_STACK.append(ctx)
             try:
-                out = getattr(self, method)(*args, **kwargs)
+                with self._own_code():
+                    out = getattr(self, method)(*args, **kwargs)
             finally:
                 _RNG_STACK.pop()
             new_buffers = dict(self.named_buffers())
@@ -245,6 +294,59 @@ class Layer:
             self.set_parameters(saved_params)
             self.set_buffers(saved_buffers)
             (self.train if saved_training else self.eval)()
+
+    def _cast_once(self, params: Dict[str, Any]) -> Dict[str, Any]:
+        """``params`` with each declared leaf (:meth:`compute_cast_names`)
+        that is wider than the policy's compute type beside its cast to
+        it, a :class:`ComputeCast`; ``params`` itself where there is
+        none. :meth:`functional_call` does this to what it is given and
+        binds the pair: the declaring layer's own code reads the narrow
+        copy, so every product of the call reads that leaf where each
+        use would have converted the wide one again, and a gradient
+        comes back through the one convert in the parameter's own type;
+        every other reader gets the stored leaf (``_own_code``). A
+        loop over stacked blocks (``scan_layers``, a pipeline) casts in
+        its body, a call a slice: on a v5e that read faster than the
+        stack cast before the loop, which is also WRONG where a block
+        runs once a microbatch (its cotangents would meet in the
+        compute type instead of the stored one).
+
+        Each narrow copy passes a ``lax.optimization_barrier`` of its
+        own: the compiler then keeps it as an array in memory instead
+        of fusing the convert into every product again, and in the
+        backward pass a weight's gradient product stands alone instead
+        of sharing a fusion with the optimizer's update of that weight
+        (on a v5e the train cell's ``mlp`` backward read 77 ms a step
+        so, 104 without the barrier, 113 cast at each use). A barrier a
+        leaf, not one over all: a gradient is free to be read as soon
+        as it is made."""
+        pol = get_policy()
+        width = to_dtype(pol.compute_dtype).itemsize
+
+        def wide(leaf):
+            dtype = getattr(leaf, "dtype", None)
+            return (dtype is not None and jnp.issubdtype(dtype, jnp.floating)
+                    and dtype.itemsize > width)
+
+        names = [k for k in sorted(self.compute_cast_names())
+                 if wide(params.get(k))]
+        if not names:
+            return params
+        with scope("weight_cast"):
+            cast = {k: ComputeCast(params[k], jax.lax.optimization_barrier(
+                pol.cast_to_compute(params[k]))) for k in names}
+        return {**params, **cast}
+
+    def _bound_parameters(self) -> Dict[str, Any]:
+        """``named_parameters`` with the bound pairs as pairs: what
+        ``set_parameters`` takes to put this state back."""
+        out = {k: self._narrow[k] if k in self._narrow
+               and self._narrow[k].stored is v else v
+               for k, v in self._params.items()}
+        for name, sub in self._sublayers.items():
+            for k, v in sub._bound_parameters().items():
+                out[f"{name}.{k}"] = v
+        return out
 
     def apply_fn(self) -> Callable:
         """Returns f(params, *args) -> output — convenience for loss closures
@@ -258,6 +360,15 @@ class Layer:
 
 
 _RNG_STACK: List[Dict[str, Any]] = []
+
+
+class ComputeCast:
+    """A parameter as it is stored beside its copy in the compute type:
+    what ``Layer._cast_once`` puts in a declared leaf's place and
+    ``set_parameters`` binds."""
+
+    def __init__(self, stored, narrow):
+        self.stored, self.narrow = stored, narrow
 
 
 @contextlib.contextmanager

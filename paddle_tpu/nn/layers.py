@@ -32,11 +32,13 @@ class Linear(Layer):
         self.in_features, self.out_features = in_features, out_features
         self.act = act
         self.create_parameter("weight", (in_features, out_features), dtype,
-                              weight_init or I.XavierUniform())
+                              weight_init or I.XavierUniform(),
+                              compute_cast=True)
         self.has_bias = bias_attr
         if bias_attr:
             self.create_parameter("bias", (out_features,), dtype,
-                                  bias_init or I.Constant(0.0), is_bias=True)
+                                  bias_init or I.Constant(0.0), is_bias=True,
+                                  compute_cast=True)
 
     def forward(self, x):
         pol = get_policy()
@@ -63,11 +65,12 @@ class Conv2D(Layer):
         self.data_format = data_format
         self.create_parameter(
             "weight", (out_channels, in_channels // groups) + k, dtype,
-            weight_init or I.MSRA(uniform=False))
+            weight_init or I.MSRA(uniform=False), compute_cast=True)
         self.has_bias = bias_attr
         if bias_attr:
             self.create_parameter("bias", (out_channels,), dtype,
-                                  I.Constant(0.0), is_bias=True)
+                                  I.Constant(0.0), is_bias=True,
+                                  compute_cast=True)
 
     def forward(self, x):
         pol = get_policy()
@@ -93,11 +96,12 @@ class Conv2DTranspose(Layer):
         self.act = act
         self.create_parameter("weight",
                               (in_channels, out_channels // groups) + k, dtype,
-                              I.XavierUniform())
+                              I.XavierUniform(), compute_cast=True)
         self.has_bias = bias_attr
         if bias_attr:
             self.create_parameter("bias", (out_channels,), dtype,
-                                  I.Constant(0.0), is_bias=True)
+                                  I.Constant(0.0), is_bias=True,
+                                  compute_cast=True)
 
     def forward(self, x):
         pol = get_policy()

@@ -543,6 +543,11 @@ class GPipe:
 
         # one stable closure for the pipeline compile cache (a fresh
         # closure per __call__ would defeat _jitted_pipeline's lru_cache)
+        # Under a mixed policy the call casts the block's declared leaves
+        # in the loop's body, once a call (nn.Layer._cast_once). Casting
+        # the stack before the loop would be WRONG: a block runs once a
+        # microbatch, and its weight's cotangents would meet in the
+        # compute type where they now meet in the stored one.
         def _block_fn(p, h, _t=self._template):
             out, _ = _t.functional_call(p, h)
             return out

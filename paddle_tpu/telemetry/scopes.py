@@ -10,7 +10,9 @@ is the ONE list: a site enters a scope through :func:`scope`, which
 refuses a name that is not here, and the reader matches these names
 and no others. A scope is entered in the model shells, the mixers and
 the trainer, never inside a ``nn/`` library layer, which serves many
-callers. Kernel names (``pt_flash_*``, ``pt_mla_decode``, ``pt_dsa_*``,
+callers; ``weight_cast`` alone sits in ``nn.Layer.functional_call``,
+where every model's parameters enter it. Kernel names
+(``pt_flash_*``, ``pt_mla_decode``, ``pt_dsa_*``,
 ``pt_retention_step``) name one ``custom-call`` each and are not block
 scopes.
 """
@@ -58,6 +60,10 @@ SCOPES = {
     "moe_experts": "the routed experts' products and their weighted "
                    "sum or gather back",
     "moe_shared": "the shared gated MLP beside the routed experts",
+    "weight_cast": "the one convert of each declared parameter to a "
+                   "narrower compute type where the parameters enter the "
+                   "model (nn.Layer.functional_call); in training the "
+                   "gradient's convert back",
 }
 
 

@@ -35,6 +35,9 @@ EXPERTS = {"moe_route", "moe_experts", "moe_shared"}
 # program -> the scopes of the list it holds
 HOLDS = {
     "gpt_train": TRUNK | {"attn", "mlp", "linear_ce", "optimizer"},
+    # under mixed_bf16 the declared leaves are cast once on entry
+    "gpt_train_mixed": TRUNK | {"attn", "mlp", "linear_ce", "optimizer",
+                                "weight_cast"},
     "gpt_decode": TRUNK | {"attn", "mlp"},
     "hybrid_decode": TRUNK | EXPERTS | {"attn", "ssm_step"},
     "retention_decode": TRUNK | {"retention_step", "mlp"},
@@ -42,7 +45,7 @@ HOLDS = {
 }
 
 
-def _gpt_train():
+def _gpt_train(amp=None):
     pt.seed(0)
     model = G.GPTForCausalLM(dataclasses.replace(G.GPTConfig.tiny(),
                                                  remat=True))
@@ -54,7 +57,7 @@ def _gpt_train():
         return loss, ({}, new_buffers)
 
     trainer = pt.parallel.Trainer(
-        model, pt.optimizer.Adam(learning_rate=1e-3), loss_builder)
+        model, pt.optimizer.Adam(learning_rate=1e-3), loss_builder, amp=amp)
     return trainer.lower_step(jnp.zeros((2, 16), jnp.int32))
 
 
@@ -70,6 +73,7 @@ def _hybrid_decode(cfg):
 
 LOWER = {
     "gpt_train": _gpt_train,
+    "gpt_train_mixed": lambda: _gpt_train("mixed_bf16"),
     "gpt_decode": lambda: _decode(G.GPTForCausalLM(G.GPTConfig.tiny()),
                                   slots=2, capacity=128),
     "hybrid_decode": lambda: _hybrid_decode(H.HybridConfig.tiny(1)),
@@ -113,15 +117,24 @@ def test_every_dot_general_lies_under_a_listed_scope(program):
             if scope_table.place(op, LISTED)[0] is None] == []
 
 
-def test_a_train_step_with_remat_shows_its_blocks_in_three_passes():
+@pytest.mark.parametrize("program", ["gpt_train", "gpt_train_mixed"])
+def test_a_train_step_with_remat_shows_its_blocks_in_three_passes(program):
     passes = {}
-    for op in op_names("gpt_train"):
+    for op in op_names(program):
         scope, which = scope_table.place(op, LISTED)
         passes.setdefault(scope, set()).add(which)
     for block in ("attn", "mlp"):
         assert passes[block] == {"forward", "recompute", "backward"}
     assert passes["linear_ce"] == {"forward", "backward"}
     assert passes["optimizer"] == {"forward"}
+    if program == "gpt_train_mixed":
+        # the gradient's convert back fuses into whatever reads it
+        assert "forward" in passes["weight_cast"]
+
+
+def test_weight_cast_is_on_the_list_with_its_line():
+    assert LISTED[-1] == "weight_cast" and len(LISTED) == 18
+    assert "functional_call" in scopes.SCOPES["weight_cast"]
 
 
 def test_a_name_off_the_list_is_refused():

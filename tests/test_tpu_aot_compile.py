@@ -486,3 +486,128 @@ def test_serving_programs_write_the_arena_in_place(mistral_decoder,
         leaf.size * leaf.dtype.itemsize for leaf in leaves)
     if program == "decode_step":
         assert text.count("tpu_custom_call") >= 2
+
+
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%(\S+)\s+\(.*\)\s+->\s+.*\{\s*$")
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT\s+)?%(\S+)\s+=\s+(.*?)\s([a-z][a-z-]*)\((.*)$")
+
+
+def _wide_weights_of_products(text, names):
+    """The float32 entry parameters among ``names`` that a fusion
+    holding a ``convolution`` (what a ``dot_general`` compiles to) takes
+    as an operand, straight or through a copy, a prefetch into fast
+    memory or a bitcast."""
+    comps, current = {}, None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = comps.setdefault(head.group(2), [])
+        elif current is not None and _INSTRUCTION.match(line):
+            current.append(_INSTRUCTION.match(line).groups())
+
+    def holds_product(comp):
+        return any(op == "convolution" or (
+            op == "fusion" and holds_product(
+                re.search(r"calls=%([^\s,]+)", rest).group(1)))
+            for _, _, op, rest in comps.get(comp, ()))
+
+    found = set()
+    for rows in comps.values():
+        origin = {}
+        for name, dtype, op, rest in rows:
+            operands = re.findall(r"%([^\s,()]+)", rest.split("), ")[0])
+            leaf = re.search(r"op_name=\"params\[\\'([^\\]+)\\'\]\"", rest)
+            if op == "parameter" and leaf and dtype.startswith("f32") \
+                    and leaf.group(1) in names:
+                origin[name] = leaf.group(1)
+            elif op in ("copy", "copy-start", "copy-done", "bitcast") \
+                    and operands and operands[0] in origin:
+                origin[name] = origin[operands[0]]
+            elif op == "fusion" and holds_product(
+                    re.search(r"calls=%([^\s,]+)", rest).group(1)):
+                found |= {origin[o] for o in operands if o in origin}
+    return found
+
+
+def test_no_product_of_the_train_step_reads_a_float32_linear_weight(
+        one_chip, chips, monkeypatch):
+    """A two-layer train step at ``internlm2-1.8b.pretrain_2k``'s widths
+    under ``mixed_bf16`` with remat, compiled for the v5e as
+    ``benchmark/rehearse_compile.py`` compiles the cell's: **no product
+    is fed by a float32 entry parameter of a ``Linear``** (cast at each
+    use, 54 of the 112 product fusions of the cell's four-layer step
+    were: the compiler fuses the convert into the product, which then
+    streams the float32 master weight and rounds it tile by tile, for
+    every block of rows), and the step holds one ``weight_cast`` convert
+    a declared leaf."""
+    import importlib
+
+    import paddle_tpu.ops.attention as attn
+    from paddle_tpu import optimizer, parallel
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    monkeypatch.setattr(importlib.import_module(
+        "paddle_tpu.ops.pallas.flash_attention"), "_use_interpret",
+        lambda: False)
+    rows, seq = 8, 2048
+    box = {}
+
+    def construct():
+        box["model"] = GPTForCausalLM(GPTConfig(
+            vocab_size=92544, hidden_size=2048, num_layers=2, num_heads=16,
+            num_kv_heads=8, intermediate_size=8192, max_position=seq,
+            rope_theta=1e6, remat=True, tie_embeddings=False))
+        return dict(box["model"].named_parameters())
+
+    pt.seed(0)
+    params = jax.eval_shape(construct)
+    pt.seed(0)  # the global key held a tracer: make it concrete again
+    model = box["model"]
+    names = model.compute_cast_names()
+    assert len(names) == 2 * 7
+
+    tr = object.__new__(parallel.Trainer)
+    tr.amp_policy, tr.optimizer = "mixed_bf16", optimizer.Adam(1e-4)
+    tr._pmean_axes, tr.grad_compression, tr.plan = (), None, None
+
+    def loss_builder(p, buffers, rng, ids):
+        loss, nb = model.functional_call(p, ids, buffers=buffers, rng=rng,
+                                         training=True,
+                                         method="forward_loss")
+        return loss, ({}, nb)
+
+    tr.loss_builder = loss_builder
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    args = on_chip((params, {}, jax.eval_shape(tr.optimizer.init, params),
+                    jax.eval_shape(lambda: jax.random.key(0)),
+                    jax.ShapeDtypeStruct((rows, seq), jnp.int32)))
+    with attn.force_flash(), mesh_scope(jax.sharding.Mesh(chips[:1],
+                                                          ("dp",))):
+        text = jax.jit(tr._step, donate_argnums=(0, 1, 2)).lower(
+            *args).compile().as_text()
+    assert text.count(" convolution(") >= 2 * 7 * 4    # forward, second
+    assert _wide_weights_of_products(text, names) == set()  # forward, two back
+    control = """
+%fused.1 (p0: f32[4,8], p1: bf16[2,4]) -> bf16[2,8] {
+  %p1 = bf16[2,4]{1,0} parameter(1)
+  %p0 = f32[4,8]{1,0} parameter(0)
+  %convert.1 = bf16[4,8]{1,0} convert(%p0)
+  ROOT %convolution.1 = bf16[2,8]{1,0} convolution(%p1, %convert.1), dim_labels=bf_io->bf
+}
+
+ENTRY %main (w: f32[4,8], x: bf16[2,4]) -> bf16[2,8] {
+  %w = f32[4,8]{1,0} parameter(0), metadata={op_name="params[\\'up.weight\\']"}
+  %x = bf16[2,4]{1,0} parameter(1), metadata={op_name="ids"}
+  %copy-start.1 = (f32[4,8]{1,0:S(1)}, f32[4,8]{1,0}, u32[]) copy-start(%w)
+  %copy-done.1 = f32[4,8]{1,0:S(1)} copy-done(%copy-start.1)
+  ROOT %fusion.1 = bf16[2,8]{1,0} fusion(%copy-done.1, %x), kind=kOutput, calls=%fused.1
+}
+"""
+    assert _wide_weights_of_products(control, {"up.weight"}) == {"up.weight"}
+    converts = [line for line in text.splitlines() if re.search(
+        r"= bf16\[\S+ convert\(.*op_name=\"[^\"]*weight_cast", line)
+        and "transpose(" not in line]
+    assert len(converts) == len(names)
