@@ -105,6 +105,16 @@ def policy_scope(p: Union[str, Policy]):
         set_policy(prev)
 
 
+def compute_dtype_of(stored):
+    """The type a layer computes in over a leaf of type ``stored``: the
+    current policy's compute type where that is narrower (a mixed
+    policy over float32 master weights), else ``stored`` itself (bf16
+    weights served under the float32 policy stay bf16: no wide copy)."""
+    stored = jnp.dtype(stored)
+    compute = to_dtype(get_policy().compute_dtype)
+    return compute if compute.itemsize < stored.itemsize else stored
+
+
 def _cast_floating(x, dtype):
     import jax
 

@@ -24,7 +24,9 @@ name) or block by block (one name a block, as ``layer_types``):
   MLP (``nn.DroplessMoE`` + :class:`GatedMLP`), told which experts it
   holds (``experts_held``), as one chip of an expert-parallel
   deployment is, under the routing rule ``routing``
-  (``nn.DroplessMoE.ROUTING``);
+  (``nn.DroplessMoE.ROUTING``); with ``router_bias_update_rate`` > 0
+  a training call moves the router's selection bias by the
+  auxiliary-loss-free rule (``nn.DroplessMoE.bias_update``);
 - ``"mlp"``: one gated MLP of width ``mlp_width`` (SwiGLU).
 
 The RESIDUAL PATH a block's two sublayers are written over is an
@@ -71,6 +73,12 @@ values by head, or ``"latent"``);
 ``serving.BatchedDecoder`` holds it as its arena. A recurrent mixer
 that needs positions (retention's rotary embedding) says so
 (``takes_positions``) and is handed the cursors attention is.
+
+TRAINING goes through :meth:`HybridForCausalLM.forward_loss` under the
+one ``parallel.Trainer``, as ``models/gpt.py``'s does: the blocks from
+empty state (``remat``: each under ``jax.checkpoint``), the fused
+linear cross-entropy head, and the routers' new state handed on as
+buffers.
 """
 
 from __future__ import annotations
@@ -114,6 +122,9 @@ class HybridConfig:
     experts_held: Optional[Tuple[int, int]] = None   # (first, count)
     routing: str = "topk_softmax"        # nn.DroplessMoE.ROUTING
     routed_scaling_factor: float = 1.0   # "sigmoid_noaux_tc" gates' sum
+    # "sigmoid_noaux_tc": how far a training call moves each selection
+    # bias towards the mean load (0: the bias stays where it is)
+    router_bias_update_rate: float = 0.0
     ssm_heads: int = 32
     ssm_head_dim: int = 64
     ssm_state: int = 128
@@ -122,7 +133,7 @@ class HybridConfig:
     rope_theta: float = 10000.0          # retention's, latent's rotary
     # the latent mixer: ranks and head widths, YaRN (the keyword
     # arguments of ops.attention.yarn_frequencies) and its mscale_all_dim
-    q_lora_rank: int = 0
+    q_lora_rank: Optional[int] = 0       # 0 / None: queries from x directly
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -151,6 +162,7 @@ class HybridConfig:
     logits_scaling: float = 1.0
     rms_norm_eps: float = 1e-5
     use_flash: bool = True
+    remat: bool = False                  # jax.checkpoint a block (training)
 
     def channel_mixes(self) -> Tuple[str, ...]:
         """The channel mix of each block."""
@@ -451,7 +463,7 @@ class HybridBlock(Layer):
             self.mixer = RetentionMixer(cfg)
         elif kind == "latent":
             self.mixer = LatentAttention(
-                cfg.hidden_size, cfg.num_heads, cfg.q_lora_rank,
+                cfg.hidden_size, cfg.num_heads, cfg.q_lora_rank or 0,
                 cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                 cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.rope_theta,
                 cfg.rope_yarn, cfg.rope_mscale_all_dim, cfg.rms_norm_eps,
@@ -470,27 +482,29 @@ class HybridBlock(Layer):
         self.moe = nn.DroplessMoE(
             cfg.hidden_size, cfg.expert_width, cfg.num_experts,
             cfg.experts_per_token, experts_held=cfg.experts_held,
-            routing=cfg.routing, scaling=cfg.routed_scaling_factor)
+            routing=cfg.routing, scaling=cfg.routed_scaling_factor,
+            bias_update_rate=cfg.router_bias_update_rate)
         self.shared = GatedMLP(cfg.hidden_size, cfg.shared_width)
 
     @property
     def state_kind(self) -> str:
         return getattr(self.mixer, "state_kind", "kv")
 
-    def channel_mix(self, x):
+    def channel_mix(self, x, with_load: bool = False):
         """(the state after the channel mix's sublayer, the (held,)
         tokens each held expert got, or None where there are no
-        experts)."""
+        experts) and, ``with_load``, a third: the (num_experts,) pairs
+        each router output got, or None."""
         u, held = self.res2.read(x)
         if self.moe is None:
             with scope("mlp"):
                 y = self.mlp(self.norm2(u))
-            return self.res2.write(x, y, held), None
+            return (self.res2.write(x, y, held), None) + (None,) * with_load
         u = self.norm2(u)
-        routed, tokens = self.moe.forward_counted(u)
+        routed, *counts = self.moe.forward_counted(u, with_load)
         with scope("moe_shared"):
             shared = self.shared(u)
-        return self.res2.write(x, routed + shared, held), tokens
+        return (self.res2.write(x, routed + shared, held), *counts)
 
     def mixer_scope(self):
         """``attn`` around the whole sublayer of a softmax-attention
@@ -500,15 +514,29 @@ class HybridBlock(Layer):
         return (scope("attn") if self.kind == "attention"
                 else contextlib.nullcontext())
 
-    def forward(self, x):
-        with self.mixer_scope():
+    def forward_counted(self, x):
+        """The block from empty state, a pure function of ``x`` (what
+        ``jax.checkpoint`` wraps): (the state after both sublayers, the
+        (held,) tokens each held expert got or None, the (num_experts,)
+        load of every router output or None where the block has no bias
+        rule). A softmax-attention OR latent block's mixer sublayer runs
+        under ``attn`` here (norm1, the mixer with ``mla_prefill``
+        inside, the residual add): a training step's table reads it as
+        the dense decoder's."""
+        with (scope("attn") if self.kind in ("attention", "latent")
+              else contextlib.nullcontext()):
             u, held = self.res1.read(x)
             h = self.norm1(u)
             a = (self.mixer(h, causal=True)
                  if self.kind in ("attention", "latent")
                  else self.mixer(h))
             x = self.res1.write(x, a, held)
-        return self.channel_mix(x)[0]
+        with_load = self.moe is not None and bool(self.moe.bias_update_rate)
+        out = self.channel_mix(x, with_load)
+        return out if with_load else (*out, None)
+
+    def forward(self, x):
+        return self.forward_counted(x)[0]
 
 
 class HybridForCausalLM(Layer):
@@ -548,13 +576,20 @@ class HybridForCausalLM(Layer):
                 for blk in self.blocks]
 
     def step_counters(self):
-        """What the latest cached call counted, for the program that
-        made the call to return. With routed experts: ``expert_tokens``
+        """What the latest cached call, or the latest call from empty
+        state (:meth:`forward`, :meth:`forward_loss`: a training step),
+        counted, for the program that made the call to return. With
+        routed experts: ``expert_tokens``
         (held,) int32, the (token, pick) pairs each held expert got,
         summed over blocks; ``expert_dense_layers`` int32, the expert
         layers of the call whose rows took the dense body of
         ``nn.moe.dropless_moe`` (the trace fixes it:
-        :meth:`expert_layers`). With retention
+        :meth:`expert_layers`); a call from empty state fills these two
+        and, where the routers have the bias rule
+        (``router_bias_update_rate``), ``expert_load`` (expert layers,
+        num_experts) int32, the pairs each router output got a layer,
+        and NONE of the names below (its mixers' own counts are left
+        inside ``jax.checkpoint``). With retention
         blocks: ``retention_small_norm`` int32, the (row, head, block)
         denominators of a step that fell under ``10 retention_eps``
         (idle rows' included; a chunk counts none). With
@@ -577,38 +612,92 @@ class HybridForCausalLM(Layer):
                     HyperConnection.state_dtype)
         return e
 
+    def _head_weight(self):
+        return (self.embed.weight.T if self.cfg.tie_embeddings
+                else self.lm_head)
+
+    def _final_hidden(self, x):
+        """The final norm over the state (its streams read out as their
+        sum): what the head's product reads."""
+        if self.cfg.hc_mult > 1:
+            x = jnp.sum(x.astype(jnp.float32), axis=-2)
+        return self.norm_f(x)
+
     @scope("head")
     def _head(self, x):
-        if self.cfg.hc_mult > 1:    # the streams are read out as their sum
-            x = jnp.sum(x.astype(jnp.float32), axis=-2)
-        logits = self.norm_f(x) @ (self.embed.weight.T
-                                   if self.cfg.tie_embeddings
-                                   else self.lm_head)
+        logits = self._final_hidden(x) @ self._head_weight()
         return logits / jnp.asarray(self.cfg.logits_scaling, logits.dtype)
 
-    def forward(self, ids):
+    def _trunk(self, ids):
+        """The blocks over ``ids`` from empty state, each under
+        ``jax.checkpoint`` with ``cfg.remat``. In training mode a block
+        whose router has the bias rule then moves its bias by the load
+        the call's batch gave it (``nn.DroplessMoE.bias_update``, scope
+        ``moe_bias_update``): a buffer update made HERE, outside the
+        checkpoint, that ``functional_call`` hands on as ``new_buffers``;
+        the call itself routes by the bias it was given."""
         x = self._embed(ids)
+        tokens, loads = 0, []
         for blk in self.blocks:
-            x = blk(x)
-        return self._head(x)
+            run = (jax.checkpoint(blk.forward_counted) if self.cfg.remat
+                   else blk.forward_counted)
+            x, got, load = run(x)
+            if got is not None:
+                tokens = tokens + got
+            if load is not None:
+                loads.append(load)
+                if self.training:
+                    with scope("moe_bias_update"):
+                        blk.moe.bias_update(load)
+        self._counted = {}
+        if "experts" in self.cfg.channel_mixes():
+            self._counted.update(
+                expert_tokens=tokens, expert_dense_layers=jnp.int32(
+                    self.expert_layers(ids.shape[0] * ids.shape[1])[1]))
+        if loads:
+            self._counted["expert_load"] = jnp.stack(loads)
+        return x
 
-    def forward_loss(self, ids, labels=None, ignore_index: int = -100):
-        """Mean next-token cross-entropy (plain, unfused: the training
-        path of this model is not tuned)."""
-        from .gpt import loss_fn
+    def forward(self, ids):
+        return self._head(self._trunk(ids))
 
+    def forward_loss(self, ids, labels=None, vocab_chunk: int = 1024,
+                     ignore_index: int = -100):
+        """Mean next-token cross-entropy, the training entry
+        (``parallel.Trainer`` through ``functional_call(...,
+        method="forward_loss")``, as ``GPTForCausalLM.forward_loss``):
+        the blocks from empty state (:meth:`_trunk`: remat, the routers'
+        bias rule), the final norm under ``head``, then the fused
+        chunked linear cross-entropy (``ops/fused_loss.py``, scope
+        ``linear_ce``): the (B, T, V) logits never exist. ``labels``
+        default to ``ids`` shifted left; equal to ``models.gpt.loss_fn``
+        over :meth:`forward`'s logits (``tests/test_hybrid_train.py``).
+        The routers' new state comes back as the call's buffers."""
+        from ..ops.fused_loss import mean_linear_cross_entropy
+
+        x = self._trunk(ids)
+        with scope("head"):     # the head itself is ``linear_ce``
+            h = self._final_hidden(x)
+            h = h / jnp.asarray(self.cfg.logits_scaling, h.dtype)
         if labels is None:
             labels = jnp.concatenate(
                 [ids[:, 1:],
                  jnp.full((ids.shape[0], 1), ignore_index, ids.dtype)],
                 axis=1)
-        return loss_fn(self.forward(ids), labels, ignore_index)
+        b, t, d = h.shape
+        return mean_linear_cross_entropy(
+            h.reshape(b * t, d), self._head_weight(), None,
+            labels.reshape(-1), chunk=vocab_chunk,
+            ignore_index=ignore_index)
 
     def expert_layers(self, rows: int, last_rows=None):
-        """(the expert layers of a cached call over ``rows`` positions,
-        those of them that take the dense body of
+        """(the expert layers of a call over ``rows`` positions, cached
+        or from empty state (a training step's ``rows`` are its batch's
+        tokens), those of them that take the dense body of
         ``nn.moe.dropless_moe``): static counts, so a caller that knows
-        a program's shape knows them without running it. ``last_rows``:
+        a program's shape knows them without running it; the second is
+        the ``expert_dense_layers`` both kinds of call count.
+        ``last_rows``:
         the rows of the last block's channel mix where the call is cut
         to the head's position before it (``head_at``: a prefill of one
         prompt leaves it 1 row)."""

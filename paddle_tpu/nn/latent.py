@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.dtypes import default_dtype
+from ..core.dtypes import compute_dtype_of, default_dtype
 from ..core.enforce import enforce
 from ..ops import latent_attention as LA
 from ..ops.attention import rotary_embedding
@@ -28,6 +28,7 @@ class LatentAttention(Layer):
     position's input, head ``j`` of ``num_heads``::
 
         c_q = RMSNorm(x W_qa);  [q^N_j ; q^R_j] = (c_q W_qb)_j
+        (``q_rank`` 0 or None, no query latent:  [q^N_j ; q^R_j] = (x W_q)_j)
         [c ; k^R] = x W_kva;  c_t = RMSNorm(c);  r_t = rope(k^R, t)
         [k^N_ij ; v_ij] = (c_i W_kvb)_j
         s_tij = (q^N_j . k^N_ij + rope(q^R_j, t) . r_i) * scale
@@ -93,10 +94,15 @@ class LatentAttention(Layer):
         m = (0.1 * mscale_all_dim * math.log(self.yarn["factor"]) + 1.0
              if self.yarn and self.yarn["factor"] > 1 else 1.0)
         self.scale = (nope_dim + rope_dim) ** -0.5 * m * m
-        self.q_a_proj = Linear(hidden, q_rank, bias_attr=False)
-        self.q_a_norm = RMSNorm(q_rank, epsilon=epsilon)
-        self.q_b_proj = Linear(q_rank, num_heads * (nope_dim + rope_dim),
-                               bias_attr=False)
+        self.q_rank = q_rank = int(q_rank or 0)
+        if q_rank:
+            self.q_a_proj = Linear(hidden, q_rank, bias_attr=False)
+            self.q_a_norm = RMSNorm(q_rank, epsilon=epsilon)
+            self.q_b_proj = Linear(
+                q_rank, num_heads * (nope_dim + rope_dim), bias_attr=False)
+        else:
+            self.q_proj = Linear(hidden, num_heads * (nope_dim + rope_dim),
+                                 bias_attr=False)
         self.kv_a_proj = Linear(hidden, kv_rank + rope_dim,
                                 bias_attr=False)
         self.kv_a_norm = RMSNorm(kv_rank, epsilon=epsilon)
@@ -112,6 +118,8 @@ class LatentAttention(Layer):
         if index_topk:
             enforce(rope_dim <= index_dim, "the indexer's rotary part "
                     "(%s) is wider than its heads (%s)", rope_dim, index_dim)
+            enforce(q_rank, "an indexer reads the query latent: it needs "
+                    "a q_rank, got %s", q_rank)
             self.index_q_proj = Linear(q_rank, index_heads * index_dim,
                                        bias_attr=False)
             self.index_k_proj = Linear(hidden, index_dim, bias_attr=False)
@@ -131,14 +139,15 @@ class LatentAttention(Layer):
 
     def _query_latent(self, x):
         """``c_q`` (B, S, q_rank), RMS-normed: what ``W_qb`` and the
-        indexer's ``W^I_q`` both read."""
-        return self.q_a_norm(self.q_a_proj(x))
+        indexer's ``W^I_q`` both read; ``x`` itself where the queries
+        are projected directly."""
+        return self.q_a_norm(self.q_a_proj(x)) if self.q_rank else x
 
     def _queries(self, cq, positions):
         """(q^N (B, S, H, nope), rope(q^R) (B, S, H, rope)) of the query
         latents ``cq``."""
         b, s, _ = cq.shape
-        q = self.q_b_proj(cq).reshape(
+        q = (self.q_b_proj if self.q_rank else self.q_proj)(cq).reshape(
             b, s, self.heads, self.nope + self.rope)
         return q[..., :self.nope], rotary_embedding(
             q[..., self.nope:], positions, self.theta, self.yarn)
@@ -347,7 +356,8 @@ class LatentAttention(Layer):
         """Causal self-attention of (B, T, D) from no cache."""
         enforce(causal, "latent attention is written causal")
         pos = jnp.arange(x.shape[1], dtype=jnp.int32)
-        kept = self.kv_b_proj.weight.dtype      # as a cache would keep them
+        # as a cache would keep them: the weights' type, or the policy's
+        kept = compute_dtype_of(self.kv_b_proj.weight.dtype)
         with scope("mla_prefill"):
             c, r = self._records(x, pos)
             cq = self._query_latent(x)

@@ -30,6 +30,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..core.dtypes import compute_dtype_of
 from ..core.enforce import enforce
 from .. import initializer as I
 from .layer import Layer
@@ -248,6 +249,32 @@ def _experts_dense(x, w_gate, w_up, w_down, local, gates):
                           preferred_element_type=f32)
 
 
+@jax.custom_vjp
+def _held_rows_cotangent(xs, sizes):
+    """``xs`` (S k, D), the pairs' rows sorted by expert, as it is; going
+    backward, its cotangent is zeroed past the last group (``sizes``
+    (held,) rows a group). ``lax.ragged_dot`` leaves the rows of its
+    result that belong to no group UNWRITTEN on the TPU, and the
+    transpose with respect to its left operand is such a product: the
+    cotangent of a pair on an absent expert would otherwise be whatever
+    the memory held, scattered into the tokens' gradient. The identity
+    forward (it adds no operation to a program that is not
+    differentiated)."""
+    return xs
+
+
+def _held_rows_fwd(xs, sizes):
+    return xs, sizes
+
+
+def _held_rows_bwd(sizes, g):
+    live = jnp.arange(g.shape[0]) < jnp.sum(sizes)
+    return jnp.where(live[:, None], g, 0), None
+
+
+_held_rows_cotangent.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 def _experts_grouped(x, w_gate, w_up, w_down, local, gates, here, sizes):
     """The (token, pick) pairs sorted by expert, through three grouped
     matmuls (``lax.ragged_dot``) over the held experts, gathered back
@@ -263,6 +290,7 @@ def _experts_grouped(x, w_gate, w_up, w_down, local, gates, here, sizes):
             jnp.arange(s * top_k, dtype=order.dtype))
     with scope("moe_experts"):
         xs = x[order // top_k].astype(w_gate.dtype)        # (S k, D)
+        xs = _held_rows_cotangent(xs, sizes)
         h = (jax.nn.silu(jax.lax.ragged_dot(
             xs, w_gate, sizes, preferred_element_type=f32))
             * jax.lax.ragged_dot(xs, w_up, sizes,
@@ -306,7 +334,8 @@ def route(logits, top_k: int, routing: str = "topk_softmax",
 
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                  experts_held=None, routing: str = "topk_softmax",
-                 score_bias=None, scaling: float = 1.0):
+                 score_bias=None, scaling: float = 1.0,
+                 with_load: bool = False):
     """Dropless top-k gated experts over tokens, for the experts held
     here.
 
@@ -335,19 +364,29 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     in equal parts, one after another (:func:`grouped_parts`).
 
     Returns (y (S, D) in x's dtype, tokens (held,) int32: the pairs
-    each held expert got)."""
+    each held expert got) and, ``with_load``, a third: load (E,) int32,
+    the pairs each of the router's ``E`` outputs got, held or not (what
+    the bias rule of ``"sigmoid_noaux_tc"`` reads,
+    :meth:`DroplessMoE.bias_update`).
+
+    Differentiable in ``x`` and every weight but ``score_bias``, which
+    selects and does not weigh (its gradient is zero). Going backward
+    the grouped body transposes its sort and gathers into scatters over
+    all ``S k`` pairs and ``lax.ragged_dot`` into two more grouped
+    products a matrix."""
     e = router_w.shape[1]
     parts = grouped_parts(x.shape[0], top_k, x.shape[1])
     if parts > 1 and not streams_densely(x.shape[0], top_k, e):
         # the body a part takes is the whole call's: past DENSE_MAX_ROWS
         # the rule does not read the rows
-        y, sizes = jax.lax.map(
+        y, *counts = jax.lax.map(
             lambda part: dropless_moe(
                 part, router_w, w_gate, w_up, w_down, top_k=top_k,
                 experts_held=experts_held, routing=routing,
-                score_bias=score_bias, scaling=scaling),
+                score_bias=score_bias, scaling=scaling,
+                with_load=with_load),
             x.reshape(parts, x.shape[0] // parts, x.shape[1]))
-        return y.reshape(x.shape), jnp.sum(sizes, axis=0)
+        return (y.reshape(x.shape), *(jnp.sum(c, axis=0) for c in counts))
     first, held = (0, e) if experts_held is None else experts_held
     with scope("moe_route"):
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
@@ -357,12 +396,16 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         here = (local >= 0) & (local < held)
         sizes = jnp.bincount(jnp.where(here, local, held).reshape(-1),
                              length=held + 1)[:held].astype(jnp.int32)
+        if with_load:
+            load = jnp.bincount(top_i.reshape(-1), length=e).astype(
+                jnp.int32)
     if streams_densely(x.shape[0], top_k, e):
         y = _experts_dense(x, w_gate, w_up, w_down, local, gates)
     else:
         y = _experts_grouped(x, w_gate, w_up, w_down, local, gates, here,
                              sizes)
-    return y.astype(x.dtype), sizes
+    y = y.astype(x.dtype)
+    return (y, sizes, load) if with_load else (y, sizes)
 
 
 class DroplessMoE(Layer):
@@ -372,15 +415,39 @@ class DroplessMoE(Layer):
     chip runs it. It routes over all ``num_experts``, computes the part
     of the result its own experts give (:func:`dropless_moe`), and
     leaves the rest to the chips that hold the others: summed over a
-    partition of the experts the parts are the whole layer
-    (``tests/test_hybrid.py``). A shared (always-on) MLP is the caller's.
-    ``routing`` names the rule that turns router logits into picks and
-    gates (:func:`route`, one of :attr:`ROUTING`);
-    ``"sigmoid_noaux_tc"`` brings a parameter ``score_bias``
-    (num_experts,) and multiplies the normalised gates by ``scaling``.
+    partition of the experts the parts are the whole layer, forward and
+    backward (``tests/test_hybrid.py``, ``tests/test_hybrid_train.py``).
+    A shared (always-on) MLP is the caller's. ``routing`` names the rule
+    that turns router logits into picks and gates (:func:`route`, one
+    of :attr:`ROUTING`); ``"sigmoid_noaux_tc"`` brings a parameter
+    ``score_bias`` (num_experts,) and multiplies the normalised gates
+    by ``scaling``.
+
+    **Trained**: the three expert tensors are declared to
+    ``Layer._cast_once`` (``compute_cast``), so under a policy that
+    computes narrower than they are stored (``amp="mixed_bf16"`` over
+    float32 master weights) a training step casts each once and both
+    bodies run in the policy's type with float32 sums; the router reads
+    the tokens and its weight as they come, and its logits are float32.
+    ``score_bias`` takes no gradient (it selects and does not weigh),
+    so an optimizer leaves it where it is. **The bias rule**
+    (DeepSeek-V3's auxiliary-loss-free balancing, ``topk_method``
+    ``noaux_tc``) is :meth:`bias_update`: with ``bias_update_rate`` =
+    ``gamma`` > 0 the layer holds two buffers, ``bias_shift`` (E,)
+    float32, zero at the start, and ``expert_load`` (E,) int32; the
+    picks are the largest of ``scores + score_bias + bias_shift``, and
+    after a training call ``bias_shift += gamma sign(mean(load) -
+    load)`` over all ``E`` outputs, from the (token, pick) pairs the
+    call's batch sent each way. The state travels as buffers (the path
+    batch-norm statistics take through ``parallel.Trainer``), so the
+    first step of a run routes by the seed's bias, the parameter is
+    never written, and a served model reads the moved bias (the
+    buffers are arguments of every serving program). With ``gamma`` 0
+    (the default) there is no buffer and no rule.
 
     ``forward(x (..., D)) -> (..., D)``; ``forward_counted`` also
-    returns the (count,) int32 pairs each held expert got;
+    returns the (count,) int32 pairs each held expert got and,
+    ``with_load``, the (num_experts,) pairs of every router output;
     ``streams_densely(rows)`` says which body that many rows take."""
 
     ROUTING = ("topk_softmax", "sigmoid_noaux_tc")
@@ -388,7 +455,7 @@ class DroplessMoE(Layer):
     def __init__(self, d_model: int, d_ff: int, num_experts: int,
                  top_k: int, experts_held=None,
                  routing: str = "topk_softmax", dtype=None,
-                 scaling: float = 1.0):
+                 scaling: float = 1.0, bias_update_rate: float = 0.0):
         super().__init__()
         first, count = experts_held or (0, num_experts)
         enforce(routing in self.ROUTING,
@@ -400,32 +467,74 @@ class DroplessMoE(Layer):
                 and first + count <= num_experts,
                 "experts_held %s is not a range of the %s experts",
                 (first, count), num_experts)
+        enforce(not bias_update_rate or routing == "sigmoid_noaux_tc",
+                "the bias rule belongs to routing 'sigmoid_noaux_tc', "
+                "got %r", routing)
         self.num_experts, self.top_k = num_experts, top_k
         self.experts_held = (int(first), int(count))
         self.routing, self.scaling = routing, float(scaling)
+        self.bias_update_rate = float(bias_update_rate)
         self.router = Linear(d_model, num_experts, bias_attr=False,
                              dtype=dtype)
         if routing == "sigmoid_noaux_tc":
             self.create_parameter("score_bias", (num_experts,), dtype,
                                   is_bias=True)
+        if self.bias_update_rate:
+            # concrete even where the model is built under a trace
+            # (``jax.eval_shape`` around a constructor): nobody fills a
+            # buffer in afterwards, as a loader does the parameters
+            with jax.ensure_compile_time_eval():
+                self.register_buffer(
+                    "bias_shift", jnp.zeros((num_experts,), jnp.float32))
+                self.register_buffer(
+                    "expert_load", jnp.zeros((num_experts,), jnp.int32))
         init = I.XavierUniform()
-        self.create_parameter("w_gate", (count, d_model, d_ff), dtype, init)
-        self.create_parameter("w_up", (count, d_model, d_ff), dtype, init)
-        self.create_parameter("w_down", (count, d_ff, d_model), dtype, init)
+        for name, shape in (("w_gate", (count, d_model, d_ff)),
+                            ("w_up", (count, d_model, d_ff)),
+                            ("w_down", (count, d_ff, d_model))):
+            self.create_parameter(name, shape, dtype, init,
+                                  compute_cast=True)
 
-    def forward_counted(self, x):
+    def selection_bias(self):
+        """What the picks add to the scores: ``score_bias`` and, under
+        the bias rule, what the rule has moved it by so far; None where
+        the routing rule has no bias."""
+        if self.routing != "sigmoid_noaux_tc":
+            return None
+        if not self.bias_update_rate:
+            return self.score_bias
+        return self.score_bias.astype(jnp.float32) + self.bias_shift
+
+    def forward_counted(self, x, with_load: bool = False):
         lead = x.shape[:-1]
-        y, tokens = dropless_moe(
-            x.reshape(-1, x.shape[-1]), self.router.weight, self.w_gate,
-            self.w_up, self.w_down, top_k=self.top_k,
-            experts_held=self.experts_held, routing=self.routing,
-            score_bias=(self.score_bias
-                        if self.routing == "sigmoid_noaux_tc" else None),
-            scaling=self.scaling)
-        return y.reshape(*lead, -1), tokens
+        with self._own_code():      # the expert tensors' narrow copies
+            narrow = compute_dtype_of(self.w_gate.dtype)
+            y, *counts = dropless_moe(
+                x.reshape(-1, x.shape[-1]), self.router.weight,
+                self.w_gate.astype(narrow), self.w_up.astype(narrow),
+                self.w_down.astype(narrow), top_k=self.top_k,
+                experts_held=self.experts_held, routing=self.routing,
+                score_bias=self.selection_bias(), scaling=self.scaling,
+                with_load=with_load)
+        return (y.reshape(*lead, -1), *counts)
 
     def forward(self, x):
         return self.forward_counted(x)[0]
+
+    def bias_update(self, load):
+        """The rule's step after a training call whose batch sent
+        ``load`` (E,) pairs to each router output: ``bias_shift +=
+        gamma sign(mean(load) - load)``, and ``expert_load`` keeps
+        ``load``. Both are buffer updates (``functional_call`` hands
+        them on as ``new_buffers``); call it outside any
+        ``jax.checkpoint`` the call itself ran under. Nothing where the
+        layer has no rule."""
+        if not self.bias_update_rate:
+            return
+        pairs = load.astype(jnp.float32)
+        self.update_buffer("bias_shift", self.bias_shift + (
+            self.bias_update_rate * jnp.sign(jnp.mean(pairs) - pairs)))
+        self.update_buffer("expert_load", load.astype(jnp.int32))
 
     def streams_densely(self, rows: int) -> bool:
         return streams_densely(rows, self.top_k, self.num_experts)

@@ -45,6 +45,20 @@ def _trainer_metrics(reg):
         "dispatch": reg.histogram(
             "pt_trainer_dispatch_seconds",
             "train_step dispatch wall time (unfenced)", unit="s"),
+        # an expert layer with the bias rule, by layer (and held expert):
+        # Trainer.router_telemetry sets them, at the caller's own fence
+        "expert_pairs": lambda layer, expert: reg.gauge(
+            "pt_trainer_expert_pairs",
+            "(token, pick) pairs a held expert got in the last step",
+            labels={"layer": layer, "expert": str(expert)}),
+        "load_peak": lambda layer: reg.gauge(
+            "pt_trainer_expert_load_peak_ratio",
+            "the busiest router output's pairs over the mean over all "
+            "outputs, last step", labels={"layer": layer}),
+        "bias_max": lambda layer: reg.gauge(
+            "pt_trainer_router_bias_max_abs",
+            "largest magnitude of the selection bias as moved so far",
+            labels={"layer": layer}),
     }
 
 
@@ -430,6 +444,41 @@ class Trainer:
 
             record_payload_bytes(*self._comm_bytes)
         return loss, metrics
+
+    def router_telemetry(self) -> Dict[str, Dict[str, Any]]:
+        """What the last step's routers counted, by expert layer that has
+        the bias rule (``nn.DroplessMoE.bias_update``): ``pairs`` (held,)
+        the (token, pick) pairs each held expert got, ``load_peak`` the
+        busiest of all the router's outputs over their mean, ``bias_max``
+        the largest magnitude of ``score_bias + bias_shift``. The step
+        leaves these in its buffers on the device and fetches nothing:
+        call this where the loop fences anyway (it is one
+        ``device_get`` of a few hundred numbers, and waits for the step
+        in flight). With telemetry on the values also land in the
+        trainer's gauges (``pt_trainer_expert_pairs``,
+        ``pt_trainer_expert_load_peak_ratio``,
+        ``pt_trainer_router_bias_max_abs``)."""
+        tail = ".expert_load"
+        layers = [k[:-len(tail)] for k in self.buffers if k.endswith(tail)]
+        if not layers:
+            return {}
+        got = jax.device_get({
+            n: (self.buffers[n + tail], self.buffers[n + ".bias_shift"],
+                self.params[n + ".score_bias"]) for n in layers})
+        subs = dict(self.model.named_sublayers())
+        out = {}
+        for n, (load, shift, bias) in got.items():
+            first, held = subs[n].experts_held
+            out[n] = {"pairs": load[first:first + held],
+                      "load_peak": float(load.max() / max(load.mean(), 1e-9)),
+                      "bias_max": float(abs(bias + shift).max())}
+            if telemetry.enabled():
+                m = _trainer_metrics()
+                for e, pairs in enumerate(out[n]["pairs"], first):
+                    m["expert_pairs"](n, e).set(pairs)
+                m["load_peak"](n).set(out[n]["load_peak"])
+                m["bias_max"](n).set(out[n]["bias_max"])
+        return out
 
     def lower_step(self, batch):
         """``jax.stages.Lowered`` of the program :meth:`train_step`

@@ -60,6 +60,9 @@ SCOPES = {
     "moe_experts": "the routed experts' products and their weighted "
                    "sum or gather back",
     "moe_shared": "the shared gated MLP beside the routed experts",
+    "moe_bias_update": "after a training call, the auxiliary-loss-free "
+                       "rule's step on a router's selection bias, from "
+                       "the load the call's batch gave each output",
     "weight_cast": "the one convert of each declared parameter to a "
                    "narrower compute type where the parameters enter the "
                    "model (nn.Layer.functional_call); in training the "

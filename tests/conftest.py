@@ -272,6 +272,17 @@ HARNESS_XFAIL = {
         "`test_harness_sparse_latent_moe.py::"
         "test_every_cells_files_are_found_and_no_width_is_reduced` holds "
         "the rest of it, with the widths as the contract lists them",
+    **{"test_harness_scope_table.py::"
+       f"test_a_metric_is_registered_for_cells_that_exist[{metric}]":
+       f"pins `{metric}`'s `workloads` to the dense train cell alone; PR "
+       "47 appended the second train cell, as ISSUE 47 names the list"
+       for metric in ("block_attn_ms", "block_mlp_ms", "optimizer_ms",
+                      "remat_forward_ms", "train_unscoped_ms")},
+    "test_harness_dsa_readers.py::"
+    "test_they_are_registered_for_the_cell_and_move_the_gap":
+        "pins PR 45's five metrics as the LAST five per-layer entries; "
+        "PR 47 appended four, as the manifest's rules have it (new "
+        "entries go at the end of their lists)",
 }
 
 # their asserts are rewritten like those of the files pytest collects

@@ -122,8 +122,12 @@ def test_convs_declare_and_no_other_layer_does():
         == {"weight", "bias"}
     assert nn.RMSNorm(8).compute_cast_names() == frozenset()
     assert nn.Embedding(8, 4).compute_cast_names() == frozenset()
-    moe = nn.DroplessMoE(8, 16, 4, top_k=2)     # its own stacks: not declared
-    assert moe.compute_cast_names() == {"router.weight"}
+    # PR 47: the three expert stacks are declared too (the layer's own
+    # code reads them through the policy's type alone); the selection
+    # bias is not
+    moe = nn.DroplessMoE(8, 16, 4, top_k=2, routing="sigmoid_noaux_tc")
+    assert moe.compute_cast_names() == {"router.weight", "w_gate", "w_up",
+                                        "w_down"}
 
 
 class Spy:
@@ -220,8 +224,9 @@ def test_only_the_declaring_layers_own_call_reads_the_narrow_copy():
 def test_no_layer_subclasses_a_declaring_one():
     """The one reader the mechanism cannot tell from the declaring
     layer is a subclass: its ``forward`` runs as the layer's own code.
-    ``compute_cast=True`` stands in ``nn/layers.py`` alone, and no class
-    of the package derives from a layer that declares. Whoever adds one
+    ``compute_cast=True`` stands in ``nn/layers.py`` and, for the routed
+    experts' three stacks, in ``nn/moe.py::DroplessMoE`` (PR 47), and no
+    class of the package derives from a layer that declares. Whoever adds one
     keeps its reads behind ``cast_to_compute`` and lists it here."""
     import ast
     import pathlib
@@ -241,7 +246,8 @@ def test_no_layer_subclasses_a_declaring_one():
                 declaring[node.name] = str(path.relative_to(root))
             derived.append((node.name, bases))
     assert declaring == {"Linear": "nn/layers.py", "Conv2D": "nn/layers.py",
-                         "Conv2DTranspose": "nn/layers.py"}
+                         "Conv2DTranspose": "nn/layers.py",
+                         "DroplessMoE": "nn/moe.py"}
     assert [name for name, bases in derived if bases & set(declaring)] == []
 
 

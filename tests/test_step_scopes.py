@@ -42,13 +42,26 @@ HOLDS = {
     "hybrid_decode": TRUNK | EXPERTS | {"attn", "ssm_step"},
     "retention_decode": TRUNK | {"retention_step", "mlp"},
     "latent_decode": TRUNK | EXPERTS | {"mla_decode", "mhc_mix", "mlp"},
+    # a latent block trained through the hybrid shell: its mixer runs
+    # under ``attn`` (``mla_prefill`` inside it, so never the outermost),
+    # the routers' bias rule after the blocks
+    "latent_train_mixed": TRUNK | EXPERTS | {
+        "attn", "mlp", "linear_ce", "optimizer", "weight_cast",
+        "moe_bias_update"},
 }
 
 
-def _gpt_train(amp=None):
+def _latent_model():
+    return H.HybridForCausalLM(dataclasses.replace(
+        H.HybridConfig.tiny_latent(3), hc_mult=1, rope_yarn=None,
+        q_lora_rank=None, experts_held=(0, 4), remat=True,
+        router_bias_update_rate=0.01))
+
+
+def _gpt_train(amp=None, build=None):
     pt.seed(0)
-    model = G.GPTForCausalLM(dataclasses.replace(G.GPTConfig.tiny(),
-                                                 remat=True))
+    model = build() if build else G.GPTForCausalLM(
+        dataclasses.replace(G.GPTConfig.tiny(), remat=True))
 
     def loss_builder(params, buffers, rng, batch):
         loss, new_buffers = model.functional_call(
@@ -80,6 +93,7 @@ LOWER = {
     "retention_decode": lambda: _hybrid_decode(
         H.HybridConfig.tiny_retention(3)),
     "latent_decode": lambda: _hybrid_decode(H.HybridConfig.tiny_latent(3)),
+    "latent_train_mixed": lambda: _gpt_train("mixed_bf16", _latent_model),
 }
 
 
@@ -117,23 +131,31 @@ def test_every_dot_general_lies_under_a_listed_scope(program):
             if scope_table.place(op, LISTED)[0] is None] == []
 
 
-@pytest.mark.parametrize("program", ["gpt_train", "gpt_train_mixed"])
+@pytest.mark.parametrize("program", ["gpt_train", "gpt_train_mixed",
+                                     "latent_train_mixed"])
 def test_a_train_step_with_remat_shows_its_blocks_in_three_passes(program):
     passes = {}
     for op in op_names(program):
         scope, which = scope_table.place(op, LISTED)
         passes.setdefault(scope, set()).add(which)
-    for block in ("attn", "mlp"):
+    blocks = ("attn", "mlp")
+    if program == "latent_train_mixed":
+        blocks += ("moe_experts", "moe_shared")
+        # the router's top-k has no transpose worth an operation
+        assert passes["moe_route"] >= {"forward", "recompute"}
+        # the rule runs once, after the blocks, outside the checkpoint
+        assert passes["moe_bias_update"] == {"forward"}
+    for block in blocks:
         assert passes[block] == {"forward", "recompute", "backward"}
     assert passes["linear_ce"] == {"forward", "backward"}
     assert passes["optimizer"] == {"forward"}
-    if program == "gpt_train_mixed":
+    if program != "gpt_train":
         # the gradient's convert back fuses into whatever reads it
         assert "forward" in passes["weight_cast"]
 
 
 def test_weight_cast_is_on_the_list_with_its_line():
-    assert LISTED[-1] == "weight_cast" and len(LISTED) == 18
+    assert LISTED[-1] == "weight_cast" and len(LISTED) == 19
     assert "functional_call" in scopes.SCOPES["weight_cast"]
 
 
