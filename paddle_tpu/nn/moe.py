@@ -25,6 +25,7 @@ Semantics (Switch Transformer, top-1):
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -184,6 +185,11 @@ class SwitchFFN(Layer):
 # up to about that many times the work: 7.2 (72 experts, 10 a token) is
 # 1.2 to 2.9 times faster dense at every row count from 16 to 4096; 16
 # (64 experts, 4 a token) is a tie from 4096 rows up, within 2 to 6%.
+# (PR 44's readings, of a grouped body that gathered and wrote a row for
+# every pair, held or not. Since PR 49 it touches the held pairs alone,
+# and at a ratio of 16 with an eighth of the experts held it is 1.3 to
+# 1.8 times the faster from 2048 rows up: the rule sends those rows the
+# right way with more room than it had.)
 DENSE_MAX_WORK = 12
 # Whatever the work ratio, few rows stream densely: before its first row
 # the grouped body costs about 2.3 times the stream of the held weights,
@@ -194,21 +200,62 @@ DENSE_MAX_WORK = 12
 DENSE_MAX_ROWS = 512
 
 
-# The grouped body writes a float32 row of the model's width a (token,
-# pick) pair several times over, held expert or not: past this many
-# bytes of such rows a call's tokens go through it in equal parts, one
-# after another (229,376 pairs of 6144 numbers, a 28672-token prefill at
-# 8 picks, are 5.25 GB a copy). Every cell's prefills before PR 45 stay
-# whole (the largest: 14336 rows x 4 picks x 3584 numbers, 0.82 GB).
+# The grouped body keeps nothing of a (token, pick) pair but integers (a
+# sort key, its place in the order, the counts): the rows it gathers and
+# multiplies are a window of the pairs on HELD experts (:func:`window_rows`).
+# What is left that grows with a call's tokens is the float32 result,
+# (rows, width), beside a window's rows. Past this many bytes of float32
+# rows a (token, pick) pair would have taken, a call's tokens still go
+# through the body in equal parts, one after another: a 28672-token
+# prefill at 8 picks of 6144 numbers (GLM-5, 5.25 GB by that count) runs
+# as 7 parts of 4096 tokens, each with a result of 0.10 GB where the
+# whole call's would be 0.70 GB beside 11.5 GB of weights and arena.
+# Every other cell's calls stay whole (the largest: 16384 rows x 6 picks
+# x 2048 numbers, 0.81 GB; 14336 x 4 x 3584, 0.82 GB).
 GROUPED_MAX_BYTES = 1 << 30
 
 
 def grouped_parts(rows: int, top_k: int, width: int) -> int:
     """The equal parts the grouped body takes ``rows`` tokens of
-    ``width`` numbers in: the fewest that leave a part's float32 pairs
-    at most :data:`GROUPED_MAX_BYTES` (one token a part at the least)."""
+    ``width`` numbers in: the fewest that leave a part's pairs, counted
+    as float32 rows, at most :data:`GROUPED_MAX_BYTES` (one token a part
+    at the least)."""
     return next(n for n in range(1, rows + 1) if rows % n == 0 and (
         rows // n * top_k * width * 4 <= GROUPED_MAX_BYTES or n == rows))
+
+
+# The grouped body's window: the rows of one pass of its three products.
+# A quarter over the pairs that land on held experts when every router
+# output is picked alike, rounded up to the grouped kernels' row tile: a
+# call whose routing is near that runs ONE window, and the room costs
+# little (rows past the held pairs belong to no group, and a grouped
+# product skips them). Seeded routers are far from even: the trained
+# cell's layers hold 0.72 to 1.32 of the even share, so about one layer
+# in ten runs a second window there (PERF.md section 6, PR 49).
+WINDOW_ROOM = 1.25
+WINDOW_TILE = 512
+
+
+def window_rows(rows: int, top_k: int, experts: int, held: int) -> int:
+    """The rows of one window of the grouped body, for ``rows`` tokens
+    that each pick ``top_k`` of ``experts`` router outputs of which
+    ``held`` are here (static counts: the trace fixes them). All the
+    pairs where that is fewer: a call with every expert held, or with
+    few rows, is one window."""
+    pairs = rows * top_k
+    room = math.ceil(pairs * held / experts * WINDOW_ROOM / WINDOW_TILE)
+    return min(pairs, room * WINDOW_TILE)
+
+
+def windows_run(held_pairs, rows: int, top_k: int, experts: int,
+                held: int):
+    """How many windows the grouped body runs for a call of those static
+    counts (:func:`window_rows`) in which ``held_pairs`` (token, pick)
+    pairs landed on held experts: ``ceil(held_pairs / window)``, 0 where
+    none did (the one-window form has no loop and always runs its
+    window; it counts as 1 with a pair and 0 without, like the rest).
+    Host arithmetic on a count the call already returns."""
+    return -(-int(held_pairs) // window_rows(rows, top_k, experts, held))
 
 
 def streams_densely(rows: int, top_k: int, experts: int) -> bool:
@@ -249,60 +296,136 @@ def _experts_dense(x, w_gate, w_up, w_down, local, gates):
                           preferred_element_type=f32)
 
 
-@jax.custom_vjp
-def _held_rows_cotangent(xs, sizes):
-    """``xs`` (S k, D), the pairs' rows sorted by expert, as it is; going
-    backward, its cotangent is zeroed past the last group (``sizes``
-    (held,) rows a group). ``lax.ragged_dot`` leaves the rows of its
-    result that belong to no group UNWRITTEN on the TPU, and the
-    transpose with respect to its left operand is such a product: the
-    cotangent of a pair on an absent expert would otherwise be whatever
-    the memory held, scattered into the tokens' gradient. The identity
-    forward (it adds no operation to a program that is not
-    differentiated)."""
-    return xs
+def _owned(a, live):
+    """``a`` (R, ...) with the rows no group owns zeroed (``live`` (R,)
+    marks the rows some group owns). ``lax.ragged_dot`` leaves the rows
+    of its result that belong to no group UNWRITTEN on the TPU (the CPU
+    writes zeros there): every grouped product's result goes through
+    here, so that neither the result nor, through the masks' own
+    transposes, the weights' gradients ever read such a row."""
+    return jnp.where(live[:, None], a, 0)
 
 
-def _held_rows_fwd(xs, sizes):
-    return xs, sizes
+def _window_terms(xs, w_gate, w_up, w_down, gate, sizes, live):
+    """One window's rows ``xs`` (R, D), sorted by expert with ``sizes``
+    (held,) rows a group from row 0 on, through the three grouped
+    products: ``(gate * silu(xs Wg) * (xs Wu)) Wd`` (R, D) float32, the
+    hidden activations in the weights' type and the gate on them, as
+    the dense body has it."""
+    def product(a, w):
+        return _owned(jax.lax.ragged_dot(
+            a, w, sizes, preferred_element_type=jnp.float32), live)
+
+    h = (jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)
+         * gate[:, None])
+    return product(h.astype(w_down.dtype), w_down)
 
 
-def _held_rows_bwd(sizes, g):
-    live = jnp.arange(g.shape[0]) < jnp.sum(sizes)
-    return jnp.where(live[:, None], g, 0), None
+def _window_of(w, rows: int, top_k: int, order, edges):
+    """Window ``w`` of the sorted pairs: (pair (R,) the pairs' indices
+    into the (S k,) pairs, tok (R,) their tokens, sizes (held,) the rows
+    each held expert has INSIDE the window, live (R,) the rows that are
+    held pairs). ``edges`` (held + 1,) are the groups' boundaries in the
+    sorted order: the held pairs are its first ``edges[-1]`` entries."""
+    lo = w * rows
+    pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    cut = jnp.clip(edges, lo, lo + rows)
+    live = jnp.arange(rows, dtype=edges.dtype) < edges[-1] - lo
+    return pair, pair // top_k, cut[1:] - cut[:-1], live
 
 
-_held_rows_cotangent.defvjp(_held_rows_fwd, _held_rows_bwd)
+def _over_windows(rows: int, order, edges, one, carry):
+    """``one(w, carry)`` over the windows that hold a held pair,
+    ``ceil(edges[-1] / rows)`` of them: ONE loop body whatever the
+    count, a loop whose trip count the routing decides (real control
+    flow on the TPU: a window past the held pairs is never entered).
+    Where all the pairs fit one window there is no loop."""
+    if order.shape[0] == rows:
+        return one(0, carry)
+    return jax.lax.fori_loop(0, (edges[-1] + rows - 1) // rows, one, carry)
 
 
-def _experts_grouped(x, w_gate, w_up, w_down, local, gates, here, sizes):
-    """The (token, pick) pairs sorted by expert, through three grouped
-    matmuls (``lax.ragged_dot``) over the held experts, gathered back
-    and weighted."""
-    f32 = jnp.float32
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _grouped_windows(rows: int, top_k: int, x, w_gate, w_up, w_down,
+                     pair_gates, order, edges):
+    """The held pairs' terms summed by token, (S, D) float32: window by
+    window of ``rows`` sorted pairs, gather the window's token rows,
+    the three grouped products (:func:`_window_terms`), add the rows
+    into the result by token.
+    ``pair_gates`` (S k,) float32 is a pair's gate, 0.0 on an absent
+    expert; ``order`` the pairs sorted by expert, held ones first,
+    padded to whole windows; ``edges`` as :func:`_window_of` takes them.
+
+    Its own reverse mode (a loop whose trip count is traced has none in
+    JAX): the same loop again, each window pulled back through its
+    products from the rows it gathers anew, so nothing is kept from the
+    forward pass but the arguments, and under ``jax.checkpoint`` the
+    recompute pass has nothing of the experts to compute. The
+    cotangents accumulate in the loop's carry in their primals' types,
+    as the transposes JAX writes itself do; a window's rows' cotangent
+    is masked past the held pairs before it is added by token (the
+    transpose of a grouped product with respect to its left operand is
+    a grouped product: rows no group owns are unwritten there too)."""
+    def one(w, y):
+        pair, tok, sizes, live = _window_of(w, rows, top_k, order, edges)
+        return y.at[tok].add(_window_terms(
+            x[tok].astype(w_gate.dtype), w_gate, w_up, w_down,
+            pair_gates[pair], sizes, live))
+
+    return _over_windows(rows, order, edges, one,
+                         jnp.zeros(x.shape, jnp.float32))
+
+
+def _grouped_windows_fwd(rows, top_k, *args):
+    return _grouped_windows(rows, top_k, *args), args
+
+
+def _grouped_windows_bwd(rows, top_k, args, dy):
+    x, w_gate, w_up, w_down, pair_gates, order, edges = args
+
+    def one(w, acc):
+        pair, tok, sizes, live = _window_of(w, rows, top_k, order, edges)
+        # (the window's own terms are traced here and never read: the
+        # compiler drops the third product's forward)
+        d_xs, d_gate_w, d_up, d_down, d_gate = jax.vjp(
+            lambda *a: _window_terms(*a, sizes, live),
+            x[tok].astype(w_gate.dtype), w_gate, w_up, w_down,
+            pair_gates[pair])[1](dy[tok])
+        return (acc[0].at[tok].add(_owned(d_xs, live).astype(x.dtype)),
+                acc[1] + d_gate_w, acc[2] + d_up, acc[3] + d_down,
+                acc[4].at[pair].add(jnp.where(live, d_gate, 0)))
+
+    with scope("moe_experts"):
+        return (*_over_windows(rows, order, edges, one, tuple(
+            jnp.zeros_like(a) for a in args[:5])), None, None)
+
+
+_grouped_windows.defvjp(_grouped_windows_fwd, _grouped_windows_bwd)
+
+
+def _experts_grouped(x, w_gate, w_up, w_down, local, gates, here, sizes,
+                     experts: int):
+    """The (token, pick) pairs on HELD experts, sorted by expert,
+    through three grouped matmuls (``lax.ragged_dot``) a window of
+    :func:`window_rows` pairs, weighted and added up by token. Nothing
+    of a pair on an absent expert is gathered or multiplied, and nothing
+    of (pairs, width) is made: what is as long as the pairs is integers
+    and the gates."""
     s, top_k = local.shape
     held = w_gate.shape[0]
+    rows = window_rows(s, top_k, experts, held)
     with scope("moe_route"):
-        # pairs on absent experts sort last, into a group no weight has
+        # pairs on absent experts sort last, into a group no weight has:
+        # the held pairs are the first sum(sizes) of the order
         group = jnp.where(here, local, held).reshape(-1)   # (S k,)
-        order = jnp.argsort(group, stable=True)
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(s * top_k, dtype=order.dtype))
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, -order.shape[0] % rows))
+        edges = jnp.concatenate([jnp.zeros((1,), sizes.dtype),
+                                 jnp.cumsum(sizes)])
+        pair_gates = jnp.where(here, gates, 0.0).reshape(-1)
     with scope("moe_experts"):
-        xs = x[order // top_k].astype(w_gate.dtype)        # (S k, D)
-        xs = _held_rows_cotangent(xs, sizes)
-        h = (jax.nn.silu(jax.lax.ragged_dot(
-            xs, w_gate, sizes, preferred_element_type=f32))
-            * jax.lax.ragged_dot(xs, w_up, sizes,
-                                 preferred_element_type=f32))
-        out = jax.lax.ragged_dot(h.astype(w_down.dtype), w_down, sizes,
-                                 preferred_element_type=f32)
-        # rows past the last group belong to no held expert
-        out = jnp.where((jnp.arange(s * top_k) < jnp.sum(sizes))[:, None],
-                        out, 0)
-        picked = out[back].reshape(s, top_k, -1)
-        return jnp.einsum("skd,sk->sd", picked,
-                          jnp.where(here, gates, 0.0))
+        return _grouped_windows(rows, top_k, x, w_gate, w_up, w_down,
+                                pair_gates, order, edges)
 
 
 def route(logits, top_k: int, routing: str = "topk_softmax",
@@ -357,11 +480,15 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     through every held expert as dense products with a gate of 0.0
     where an expert was not picked; many rows that each pick a small
     share, or very few rows (a prefill's last token), are sorted by
-    expert and run as grouped matmuls. Both run in the WEIGHTS' type with
+    expert and the pairs on HELD experts, the sorted order's head, run
+    as grouped matmuls in windows of :func:`window_rows` pairs: one
+    window under even routing, as many as the held pairs fill whatever
+    the routing (no capacity: :func:`windows_run` says how many a call
+    ran). Both run in the WEIGHTS' type with
     float32 sums: the tokens are cast to it, never the experts (a
     float32 copy of bfloat16 experts would be written out whole a call).
-    Past :data:`GROUPED_MAX_BYTES` of pairs the grouped body takes the rows
-    in equal parts, one after another (:func:`grouped_parts`).
+    Past :data:`GROUPED_MAX_BYTES` the grouped body takes the rows in
+    equal parts, one after another (:func:`grouped_parts`).
 
     Returns (y (S, D) in x's dtype, tokens (held,) int32: the pairs
     each held expert got) and, ``with_load``, a third: load (E,) int32,
@@ -371,9 +498,10 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
 
     Differentiable in ``x`` and every weight but ``score_bias``, which
     selects and does not weigh (its gradient is zero). Going backward
-    the grouped body transposes its sort and gathers into scatters over
-    all ``S k`` pairs and ``lax.ragged_dot`` into two more grouped
-    products a matrix."""
+    the grouped body runs its windows again (:func:`_grouped_windows`:
+    a window's rows gathered anew, each ``lax.ragged_dot`` transposed
+    into two more grouped products, the rows' cotangents added by
+    token): in no pass does it touch a pair on an absent expert."""
     e = router_w.shape[1]
     parts = grouped_parts(x.shape[0], top_k, x.shape[1])
     if parts > 1 and not streams_densely(x.shape[0], top_k, e):
@@ -403,7 +531,7 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         y = _experts_dense(x, w_gate, w_up, w_down, local, gates)
     else:
         y = _experts_grouped(x, w_gate, w_up, w_down, local, gates, here,
-                             sizes)
+                             sizes, e)
     y = y.astype(x.dtype)
     return (y, sizes, load) if with_load else (y, sizes)
 
@@ -538,6 +666,21 @@ class DroplessMoE(Layer):
 
     def streams_densely(self, rows: int) -> bool:
         return streams_densely(rows, self.top_k, self.num_experts)
+
+    def windows_run(self, held_pairs, rows: int) -> int:
+        """The windows the grouped body ran for a call of ``rows``
+        tokens that sent ``held_pairs`` (token, pick) pairs to the
+        experts held here (:func:`windows_run`): 1 under even routing,
+        more when the held experts drew over :data:`WINDOW_ROOM` of
+        their share, 0 where no pair was held or the rows took the
+        dense body."""
+        if not rows or self.streams_densely(rows):
+            return 0
+        # a call taken in parts: as if its parts drew alike
+        parts = grouped_parts(rows, self.top_k, self.w_gate.shape[1])
+        return parts * windows_run(
+            -(-int(held_pairs) // parts), rows // parts, self.top_k,
+            self.num_experts, self.experts_held[1])
 
 
 def expert_param_spec(axis: str = "ep"):

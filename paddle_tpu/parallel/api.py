@@ -59,6 +59,11 @@ def _trainer_metrics(reg):
             "pt_trainer_router_bias_max_abs",
             "largest magnitude of the selection bias as moved so far",
             labels={"layer": layer}),
+        "windows": lambda layer: reg.gauge(
+            "pt_trainer_expert_windows",
+            "windows of held pairs the grouped expert body ran in the "
+            "last step (1 under even routing; 0: the dense body)",
+            labels={"layer": layer}),
     }
 
 
@@ -163,6 +168,16 @@ class Trainer:
             # optimizer-state capability, reference:
             # transpiler/distribute_transpiler.py:702)
             self.opt_state = opt_state_rules.place(self.opt_state, self.mesh)
+        else:
+            # the leaves the optimizer made itself (its step counter) join
+            # the mesh as the moments have: left on the default device
+            # their type is not the type the step hands back, and the
+            # SECOND step would be traced, lowered and compiled (or
+            # loaded from the cache) all over again
+            self.opt_state = jax.tree_util.tree_map(
+                lambda leaf: leaf if isinstance(
+                    getattr(leaf, "sharding", None), NamedSharding)
+                else jax.device_put(leaf, rep), self.opt_state)
         # static per-step collective payload for the host-side byte
         # counters (grads tree mirrors params; shapes never change
         # after init, so compute once and bump per dispatched step)
@@ -450,14 +465,18 @@ class Trainer:
         the bias rule (``nn.DroplessMoE.bias_update``): ``pairs`` (held,)
         the (token, pick) pairs each held expert got, ``load_peak`` the
         busiest of all the router's outputs over their mean, ``bias_max``
-        the largest magnitude of ``score_bias + bias_shift``. The step
+        the largest magnitude of ``score_bias + bias_shift``, ``windows``
+        how many windows of held pairs the grouped expert body ran
+        (``nn.DroplessMoE.windows_run``: host arithmetic on ``pairs``
+        and the call's static counts; 1 under even routing). The step
         leaves these in its buffers on the device and fetches nothing:
         call this where the loop fences anyway (it is one
         ``device_get`` of a few hundred numbers, and waits for the step
         in flight). With telemetry on the values also land in the
         trainer's gauges (``pt_trainer_expert_pairs``,
         ``pt_trainer_expert_load_peak_ratio``,
-        ``pt_trainer_router_bias_max_abs``)."""
+        ``pt_trainer_router_bias_max_abs``,
+        ``pt_trainer_expert_windows``)."""
         tail = ".expert_load"
         layers = [k[:-len(tail)] for k in self.buffers if k.endswith(tail)]
         if not layers:
@@ -469,15 +488,19 @@ class Trainer:
         out = {}
         for n, (load, shift, bias) in got.items():
             first, held = subs[n].experts_held
-            out[n] = {"pairs": load[first:first + held],
+            pairs = load[first:first + held]
+            out[n] = {"pairs": pairs,
                       "load_peak": float(load.max() / max(load.mean(), 1e-9)),
-                      "bias_max": float(abs(bias + shift).max())}
+                      "bias_max": float(abs(bias + shift).max()),
+                      "windows": subs[n].windows_run(
+                          pairs.sum(), int(load.sum()) // subs[n].top_k)}
             if telemetry.enabled():
                 m = _trainer_metrics()
                 for e, pairs in enumerate(out[n]["pairs"], first):
                     m["expert_pairs"](n, e).set(pairs)
                 m["load_peak"](n).set(out[n]["load_peak"])
                 m["bias_max"](n).set(out[n]["bias_max"])
+                m["windows"](n).set(out[n]["windows"])
         return out
 
     def lower_step(self, batch):

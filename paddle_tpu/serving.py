@@ -335,7 +335,11 @@ class ArenaCounters:
     ``steps`` steps; ``expert_tokens`` is the one a model with routed
     experts gives, the (held,) (token, pick) pairs each held expert
     got, every row of the step counted (an idle slot's junk row too),
-    and None for any other model. ``prefills``: the prompts this arena
+    and None for any other model (the windows of held pairs a grouped
+    expert layer ran follow from it on the host, for a step's mean
+    layer: ``nn.DroplessMoE.windows_run(expert_tokens.sum() / (steps x
+    expert layers), rows)``; no step counts them). ``prefills``: the
+    prompts this arena
     prefilled itself (a handoff's import is none); ``prefill_resteps``:
     those whose first token came from a step of the last prompt token
     through the whole model, a second read of every weight, and not

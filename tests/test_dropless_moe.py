@@ -319,3 +319,187 @@ def test_layer_checks_its_arguments():
                dict(top_k=4, routing="sigmoid")):
         with pytest.raises(EnforceError):
             nn.DroplessMoE(16, 24, 12, **kw)
+
+
+# --------------------------------------------------------------------------
+# the grouped body's windows (PR 49): the pairs on held experts alone,
+# ``window_rows`` of them a pass of the three grouped products
+# --------------------------------------------------------------------------
+
+WE, WK, WHELD = 16, 2, (0, 4)       # 16 router outputs, 2 a token, 4 held
+WINDOW = 32                         # of 96 or 100 pairs, at a tile of 8
+
+
+def classed(rows, all_held=0, none_held=0, one_held=0, seed=6):
+    """Tokens and a router in which the first ``all_held`` tokens send
+    both picks to held experts, the next ``none_held`` both to absent
+    ones and the next ``one_held`` one each way (three indicator
+    features and router rows of 50 on them); the rest route by their
+    other, random features."""
+    rng = np.random.default_rng(seed)
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)
+    x, router = f(rows, D), f(D, WE)
+    x[:, :3], router[:3] = 0.0, 0.0
+    marks = np.repeat([0, 1, 2], [all_held, none_held, one_held])
+    x[np.arange(len(marks)), marks] = 1.0
+    router[0, :4], router[1, 4:], router[2, [0, 5]] = 50.0, 50.0, 50.0
+    return tuple(jnp.asarray(a) for a in (
+        x, router, 0.3 * f(4, D, F), 0.3 * f(4, D, F), 0.3 * f(4, F, D)))
+
+
+def expert_loop(x, router, wg, wu, wd):
+    """Held expert by held expert over every token, the gate 0.0 where
+    the token did not pick it: the terms the grouped body owes."""
+    gates, top_i = moe.route(x @ router, WK)
+    y = jnp.zeros_like(x)
+    for e in range(wg.shape[0]):
+        gate = jnp.sum(jnp.where(top_i == WHELD[0] + e, gates, 0.0), -1)
+        y += gate[:, None] * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e]))
+                              @ wd[e])
+    return y
+
+
+def windowed(x, router, wg, wu, wd):
+    return dropless_moe(x, router, wg, wu, wd, top_k=WK,
+                        experts_held=WHELD)
+
+
+def value_and_grads(fn, args, seed=7):
+    """(y, the gradients of ``sum(y * c)`` in the tokens and the three
+    expert tensors) for a fixed random ``c``."""
+    c = jnp.asarray(np.random.default_rng(seed).standard_normal(
+        args[0].shape).astype(np.float32))
+    take = lambda out: out[0] if isinstance(out, tuple) else out
+    y = take(fn(*args))
+    grads = jax.grad(lambda *a: jnp.sum(take(fn(*a)) * c),
+                     argnums=(0, 2, 3, 4))(*args)
+    return y, grads
+
+
+@pytest.fixture
+def small_windows(monkeypatch):
+    force(monkeypatch, "grouped")
+    monkeypatch.setattr(moe, "WINDOW_TILE", 8)
+
+
+def unwritten_past_the_groups(real):
+    """``lax.ragged_dot`` as the TPU runs it, made worse: the rows of
+    the left operand that belong to no group are NaN before the product
+    reads them, and the rows of the result that belong to no group are
+    NaN, going forward and in the transpose with respect to the left
+    operand (the chip leaves them unwritten; the CPU writes zeros, which
+    hides a missing mask)."""
+    def past(a, sizes):
+        return jnp.where((jnp.arange(a.shape[0]) < jnp.sum(sizes))[:, None],
+                         a, jnp.nan)
+
+    @jax.custom_vjp
+    def product(lhs, rhs, sizes):
+        return past(real(past(lhs, sizes), rhs, sizes,
+                         preferred_element_type=jnp.float32), sizes)
+
+    def fwd(lhs, rhs, sizes):
+        return product(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def bwd(kept, g):
+        lhs, rhs, sizes = kept
+        _, pull = jax.vjp(lambda a, b: real(
+            a, b, sizes, preferred_element_type=jnp.float32),
+            past(lhs, sizes), rhs)
+        d_lhs, d_rhs = pull(g)
+        return past(d_lhs, sizes), d_rhs, None
+
+    product.defvjp(fwd, bwd)
+    return lambda lhs, rhs, sizes, preferred_element_type=None: product(
+        lhs, rhs, sizes)
+
+
+# (rows, all_held, none_held, one_held) -> the held pairs they make
+WINDOWED = {
+    "even-one-window": ((50, 0, 0, 0), None, 1),
+    "every-pair-held-all-windows": ((50, 50, 0, 0), 100, 4),
+    "every-pair-held-whole-windows": ((48, 48, 0, 0), 96, 3),
+    "no-pair-held-no-window": ((50, 0, 50, 0), 0, 0),
+    "held-pairs-end-on-a-windows-edge": ((50, 16, 34, 0), 32, 1),
+    "one-row-past-the-edge": ((50, 16, 33, 1), 33, 2),
+}
+
+
+@pytest.mark.parametrize("poisoned", [False, True],
+                         ids=["plain", "rows-no-group-owns-are-nan"])
+@pytest.mark.parametrize("case", list(WINDOWED))
+def test_the_windows_give_the_held_pairs_terms_and_gradients(
+        case, poisoned, small_windows, monkeypatch):
+    """The windowed body against the loop over held experts, value and
+    gradients in the tokens and all three expert tensors, whatever share
+    of the pairs is held: one window under even routing; every pair held
+    (all ``W`` windows run, the last part full where ``S k`` is no
+    multiple of the window); none held (no window: result and gradients
+    exactly 0); the held pairs ending on a window's edge and one row
+    past it. ``poisoned``: the same with every row that no group owns
+    NaN wherever a grouped product reads or writes one, as the chip
+    leaves them unwritten: none may reach the result or a gradient."""
+    (rows, *classes), pairs, windows = WINDOWED[case]
+    args = classed(rows, *classes)
+    assert moe.window_rows(rows, WK, WE, 4) == WINDOW
+    want_y, want_g = value_and_grads(expert_loop, args)
+    if poisoned:
+        monkeypatch.setattr(jax.lax, "ragged_dot",
+                            unwritten_past_the_groups(jax.lax.ragged_dot))
+    (got_y, tokens), got_g = windowed(*args), value_and_grads(
+        windowed, args)[1]
+    held_pairs = int(tokens.sum())
+    assert pairs in (None, held_pairs) and 0 < (held_pairs or 1) <= rows * WK
+    assert moe.windows_run(held_pairs, rows, WK, WE, 4) == windows
+    np.testing.assert_allclose(got_y, want_y, **TOL)
+    for got, want in zip(got_g, want_g):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    if not held_pairs:
+        assert not np.asarray(got_y).any()
+        assert not any(np.asarray(g).any() for g in got_g)
+
+
+def test_the_windows_are_one_loop_body_whatever_their_count(monkeypatch):
+    """The form PR 48 was refused for: the expert layer's program must
+    not grow with the windows. Differentiated, the layer holds the same
+    grouped products at 2 windows as at 8 (three forward; going
+    backward the three again, over the rows gathered anew, the third
+    of them traced and never read, and two a product for the
+    transposes), and its lowered text is the same size to 5%."""
+    force(monkeypatch, "grouped")
+    args = classed(64)
+    fn = jax.grad(lambda *a: jnp.sum(windowed(*a)[0]), argnums=(0, 2, 3, 4))
+    dots, text = {}, {}
+    for room, tile in ((1.25, 64), (0.5, 16)):
+        monkeypatch.setattr(moe, "WINDOW_ROOM", room)
+        monkeypatch.setattr(moe, "WINDOW_TILE", tile)
+        w = -(-64 * WK // moe.window_rows(64, WK, WE, 4))
+        dots[w] = primitives(jax.make_jaxpr(fn)(*args)).count(
+            "ragged_dot_general")
+        text[w] = len(jax.jit(fn).lower(*args).as_text())
+    assert sorted(dots) == [2, 8]
+    assert dots[2] == dots[8] == 3 + 3 + 6
+    assert abs(text[2] - text[8]) < 0.05 * text[2]
+
+
+def test_one_window_is_straight_line():
+    """Where all the pairs fit one window (a decode step's rows, a
+    prefill's lone last row, every expert held) there is no loop."""
+    args = classed(6)
+    assert moe.window_rows(6, WK, WE, 4) == 6 * WK
+    used = primitives(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(windowed(*a)[0]), argnums=(0, 2)))(*args))
+    assert "while" not in used and "cond" not in used
+    assert used.count("ragged_dot_general") == 12
+
+
+@pytest.mark.parametrize("counts,rows", [
+    ((16384, 6, 128, 16), 15360),       # the trained cell: 12288 x 1.25
+    ((14336, 4, 64, 8), 9216),          # Xing4.0's longest prefill
+    ((4096, 8, 256, 16), 2560),         # a part of a GLM-5 prefill
+    ((16, 8, 256, 16), 128),            # its decode step: every pair
+    ((1, 10, 72, 36), 10),              # a prefill's lone last row
+    ((24, 10, 72, 72), 240)])           # every expert held
+def test_a_window_is_a_quarter_over_the_even_share(counts, rows):
+    assert moe.window_rows(*counts) == rows
+    assert rows == counts[0] * counts[1] or rows % moe.WINDOW_TILE == 0

@@ -302,6 +302,34 @@ def test_the_bias_rule_follows_the_reference_for_three_steps():
     assert len(np.unique(np.round(shift / GAMMA))) > 1
 
 
+@pytest.mark.parametrize("opt", ["adam", "sgd"])
+def test_the_second_step_is_not_traced_again(opt):
+    """A trainer's first step and every later one are ONE program: the
+    leaves the optimizer makes itself (its step counter) are placed on
+    the mesh like the moments, so the
+    state the first step is given has the type of the state it hands
+    back. Left on the default device the second step was traced,
+    lowered and compiled (or loaded) again: half a training cell's
+    set-up."""
+    traced = []
+    model = build(config(layers=2))
+
+    def loss_builder(params, buffers, rng, ids):
+        traced.append(1)
+        loss, new_buffers = model.functional_call(
+            params, ids, buffers=buffers, rng=rng, training=True,
+            method="forward_loss")
+        return loss, ({}, new_buffers)
+
+    made = {"adam": optimizer.Adam, "sgd": optimizer.SGD}
+    tr = parallel.Trainer(
+        model, made[opt](1e-2), loss_builder,
+        mesh=pt.build_mesh(dp=1, devices=jax.devices()[:1]))
+    for step in range(3):
+        tr.train_step(batch(seed=step))
+    assert len(traced) == 1
+
+
 def test_the_trainers_telemetry_reads_the_routers_counters():
     """``Trainer.router_telemetry``: the held experts' pairs, the peak
     load over the mean over all outputs and the bias's largest
@@ -334,6 +362,45 @@ def test_the_trainers_telemetry_reads_the_routers_counters():
     finally:
         telemetry.disable()
     assert trainer_of(build(config(gamma=0.0))).router_telemetry() == {}
+
+
+@pytest.mark.parametrize("favoured,windows", [
+    (None, 1), (range(4, 8), 4), (range(8, 12), 0)],
+    ids=["even-one-window", "every-pair-held-all-windows",
+         "no-pair-held-no-window"])
+def test_the_trainers_telemetry_counts_the_windows(monkeypatch, favoured,
+                                                   windows):
+    """``router_telemetry()["windows"]``: the windows of held pairs the
+    grouped body ran a layer in the last step, host arithmetic on the
+    pairs the step left in its buffers (``nn.DroplessMoE.windows_run``).
+    64 tokens x 4 picks over 16 outputs of which 4 are held, windows of
+    80 pairs: 1 under the seeded routing; all 4 where a selection bias
+    of 10 sends every pick to the held experts; 0 where it sends every
+    pick elsewhere. In the trainer's gauge too."""
+    from paddle_tpu import telemetry
+
+    force(monkeypatch, False)
+    monkeypatch.setattr(moe, "WINDOW_TILE", 8)
+    assert moe.window_rows(64, 4, 16, 4) == 80
+    model = build(config(held=(4, 4)))
+    if favoured is not None:
+        model.set_parameters({
+            f"blocks.{i}.moe.score_bias": jnp.zeros((16,)).at[
+                jnp.asarray(favoured)].set(10.0) for i in (1, 2)})
+    tr = trainer_of(model)
+    tr.train_step(batch(rows=4))
+    telemetry.enable()
+    try:
+        got = tr.router_telemetry()
+        for name in ("blocks.1.moe", "blocks.2.moe"):
+            held_pairs = int(got[name]["pairs"].sum())
+            assert held_pairs == {None: held_pairs, 4: 256, 0: 0}[
+                None if favoured is None else windows]
+            assert got[name]["windows"] == windows == -(-held_pairs // 80)
+            assert telemetry.registry().get("pt_trainer_expert_windows", {
+                "layer": name}).value == windows
+    finally:
+        telemetry.disable()
 
 
 def test_gamma_zero_is_todays_layer():

@@ -125,8 +125,8 @@ def test_one_pass_prefill_is_the_chunk_and_step_it_replaced(name, plen):
 def products(jaxpr) -> int:
     """Matrix products of a jaxpr, those of every nested one too (the
     routed experts' are three ``dot_general``s in the dense body and
-    three grouped ``ragged_dot``s and the ``dot_general`` that sums a
-    token's picks in the other: ``nn.moe.streams_densely``)."""
+    three grouped ``ragged_dot``s in the other:
+    ``nn.moe.streams_densely``)."""
     n = 0
     for eqn in jaxpr.eqns:
         n += eqn.primitive.name in ("dot_general", "ragged_dot",
@@ -155,13 +155,13 @@ def test_the_prefill_program_holds_one_pass_of_products(name):
     got = products_of(dec._prefill_fn(LB), *args)
     # every product of the chunk and the head's one: nothing else. The
     # hybrid's last block routes the one row the head reads, not the
-    # chunk's ``LB``: its experts take the grouped body there, whose sum
-    # over a token's picks is a product of its own (the dense body,
-    # which ``LB`` rows take, sums inside its down product)
-    regrouped = name == "hybrid"
-    if regrouped:
+    # chunk's ``LB``: its experts take the grouped body there, whose
+    # three grouped products stand where the dense body's three stood
+    # (it adds a token's picks up by a scatter-add, no product of its
+    # own since PR 49)
+    if name == "hybrid":
         assert model.expert_layers(LB, 1) == (6, 5)
-    assert got == products_of(chunk_only, *args) + 1 + regrouped
+    assert got == products_of(chunk_only, *args) + 1
     # the counter sees a second pass: the old program held the step's
     # products too (a step has the head and one product a Linear)
     linears = sum(type(m).__name__ == "Linear"

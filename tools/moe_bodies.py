@@ -22,8 +22,18 @@ itself has no switch.
     chiprun -- python tools/moe_bodies.py --widths 3584,1024,64,8,4 \
         --rows 16,128,512,1024,2048,4096,8192,14336
 
+    chiprun -- python tools/moe_bodies.py --widths 2048,768,128,16,6 \
+        --rows 16384 --layers 5 --grad
+
 (the second: the Xing4.0 cell's share, 8 held of 64 experts of 3584 x
-1024, 4 a token).
+1024, 4 a token; the third: the trained kanana-2 cell's, 16 held of 128
+experts of 2048 x 768, 6 a token, a step's 16384 rows). ``--grad`` times
+value and gradient (in the rows and the three expert tensors) of the
+stack's sum, each layer under ``jax.checkpoint`` as a training step's
+blocks are, where the default times the forward bodies alone: a trained
+cell's cost is two thirds backward. ``roofline_pct`` then counts three
+passes of the products. ``windows`` is what the grouped body ran a
+layer at the tool's own routing (``nn.moe.windows_run``).
 """
 
 from __future__ import annotations
@@ -44,6 +54,8 @@ def main(argv=None) -> int:
                     help="D,F,experts,held,top_k")
     ap.add_argument("--layers", type=int, default=10)
     ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--grad", action="store_true",
+                    help="time value and gradient, layers checkpointed")
     args = ap.parse_args(argv)
 
     import jax
@@ -67,14 +79,27 @@ def main(argv=None) -> int:
 
     def stack_fn():
         # a function of its own a pair: jit's cache goes by the function
+        def layer(x, router, wg, wu, wd):
+            y, tokens = moe.dropless_moe(x, router, wg, wu, wd, top_k=k,
+                                         experts_held=(0, held))
+            return x + y, tokens
+
         def stack(x, router, wg, wu, wd):
             for _ in range(args.layers):
-                y, _ = moe.dropless_moe(x, router, wg, wu, wd, top_k=k,
-                                        experts_held=(0, held))
-                x = x + y
-            return x
+                x, tokens = (jax.checkpoint(layer) if args.grad else layer)(
+                    x, router, wg, wu, wd)
+            return x, tokens
 
-        return jax.jit(stack)
+        def total(*a):
+            x, tokens = stack(*a)
+            return jnp.sum(x.astype(jnp.float32)), tokens
+
+        def grads(*a):
+            (_, tokens), got = jax.value_and_grad(
+                total, argnums=(0, 2, 3, 4), has_aux=True)(*a)
+            return got, tokens
+
+        return jax.jit(grads if args.grad else stack)
 
     keep = moe.streams_densely
     try:
@@ -89,7 +114,8 @@ def main(argv=None) -> int:
                 fn = stack_fn()
                 try:
                     for _ in range(3):
-                        fn(x, router, wg, wu, wd).block_until_ready()
+                        out, tokens = jax.block_until_ready(
+                            fn(x, router, wg, wu, wd))
                 except Exception as err:  # noqa: BLE001 — a body that
                     # does not fit at these rows is a finding, not an end
                     print(json.dumps({"rows": rows, "body": body,
@@ -99,15 +125,20 @@ def main(argv=None) -> int:
                 t0 = time.perf_counter()
                 for _ in range(args.calls):
                     out = fn(x, router, wg, wu, wd)
-                out.block_until_ready()
+                jax.block_until_ready(out)
                 ms = ((time.perf_counter() - t0) * 1e3
                       / (args.calls * args.layers))
-                least = (max(weight_bytes / peak,
-                             6 * d * f * pairs[body] / flops_peak)
+                passes = 3 if args.grad else 1
+                least = (max(weight_bytes / peak, passes * 6 * d * f
+                             * pairs[body] / flops_peak)
                          if peak and flops_peak else None)
                 print(json.dumps({
                     "device": dev.device_kind, "platform": dev.platform,
                     "rows": rows, "body": body, "rule": rule,
+                    "grad": args.grad, "held_pairs": int(tokens.sum()),
+                    "windows": (moe.windows_run(int(tokens.sum()), rows, k, e,
+                                                held)
+                                if body == "grouped" else None),
                     "ms_layer": round(ms, 4),
                     "roofline_pct": (round(100 * least / (ms * 1e-3), 2)
                                      if least else None)}), flush=True)
