@@ -20,8 +20,9 @@ for every other platform and shape (what the CPU tests hold the
 kernels to), chosen by static shapes and the platform alone
 (:func:`read_kernel_ok`, :func:`prefill_kernel_ok`): the read is
 ``ops/pallas/mla_decode.py`` (per-row cursors, live blocks only), the
-prefill the flash kernel over heads padded to 256, which never holds
-an ``S x S`` score. ``tools/mla_bodies.py`` times the read's bodies on
+prefill the flash kernel at a score width and a value width, each
+padded to whole lanes and no further, which never holds an ``S x S``
+score. ``tools/mla_bodies.py`` times the read's bodies on
 the chip; PERF.md section 3 has the numbers.
 
 **Under a learned selection** (the "DSA" lightning indexer of
@@ -112,30 +113,49 @@ def latent_read(qa, qr, c, r, t_rows, scale: float, keep=None):
     return _read_jnp(qa, qr, c, r, t_rows, scale, keep)
 
 
-# the Pallas flash kernel's blocks for a prefill's padded heads
+# the widest head a prefill hands the Pallas flash kernel, and the
+# kernel's blocks where the table measured on the chip
+# (``ops/pallas/tuned_blocks.json``) has no entry for the call
 FLASH_WIDTH, FLASH_BLOCK_Q, FLASH_BLOCK_K = 256, 1024, 512
 
 
 def prefill_kernel_ok(s: int, dq: int, dv: int) -> bool:
-    """Whether :func:`causal_attention` pads its heads to
-    :data:`FLASH_WIDTH` and takes the Pallas flash kernel: on the TPU
-    (or under ``ops.attention.force_flash()``, interpreted), a length
-    the kernel's query block divides, heads no wider than the padded
-    width. Static shapes and the platform alone."""
+    """Whether :func:`causal_attention` takes the Pallas flash kernel:
+    on the TPU (or under ``ops.attention.force_flash()``, interpreted),
+    a length the kernel's query block divides, heads no wider than
+    :data:`FLASH_WIDTH`. Static shapes and the platform alone."""
     return (_kernels_run() and s % FLASH_BLOCK_Q == 0
             and max(dq, dv) <= FLASH_WIDTH)
 
 
-def _flash_padded(q, k, v, scale):
-    """Heads of dq / dv as heads of 256 through the flash kernel: zeros
-    add nothing to a score, and the padded values' columns are cut."""
-    from .pallas.flash_attention import flash_attention
+def _pad_last(a, to: int):
+    """The last axis zero-padded to a multiple of ``to``."""
+    extra = -a.shape[-1] % to
+    return a if not extra else jnp.pad(
+        a, ((0, 0),) * (a.ndim - 1) + ((0, extra),))
 
-    pad = lambda a: jnp.pad(a, ((0, 0),) * 3 + (
-        (0, FLASH_WIDTH - a.shape[-1]),))
-    return flash_attention(pad(q), pad(k), pad(v), causal=True,
-                           scale=scale, block_q=FLASH_BLOCK_Q,
-                           block_k=FLASH_BLOCK_K)[..., :v.shape[-1]]
+
+def _flash_padded(q, k, v, scale):
+    """Heads of dq / dv through the flash kernel, which keeps a score
+    width and a value width apart: each operand is padded to ITS OWN
+    next multiple of 128 lanes and no further (192 / 128: q and k to
+    256, v not at all). Zeros add nothing to a score; a value width of
+    whole lanes comes back as it is, another's padded columns are cut.
+    The blocks are the tuned table's for the call (length bucket, the
+    two widths as padded, the type the operands reach the kernel in),
+    :data:`FLASH_BLOCK_Q` x :data:`FLASH_BLOCK_K` where it has none."""
+    from .attention import flash_operand_dtype
+    from .pallas.flash_attention import flash_attention, resolve_block_sizes
+
+    dv = v.shape[-1]
+    q, k, v = (_pad_last(a, 128) for a in (q, k, v))
+    s = q.shape[1]
+    bq, bk, bq_bwd, bk_bwd = resolve_block_sizes(
+        s, s, q.shape[-1], True, dtype=flash_operand_dtype(q.dtype),
+        e=v.shape[-1], default_q=FLASH_BLOCK_Q, default_k=FLASH_BLOCK_K)
+    return flash_attention(
+        q, k, v, causal=True, scale=scale, block_q=bq, block_k=bk,
+        block_q_bwd=bq_bwd, block_k_bwd=bk_bwd)[..., :dv]
 
 
 def causal_attention(q, k, v, scale: float):
@@ -143,9 +163,10 @@ def causal_attention(q, k, v, scale: float):
     whose score and value widths differ: ``q``, ``k`` (B, S, H, dq),
     ``v`` (B, S, H, dv) -> (B, S, H, dv). On the TPU, at a length the
     kernel's query block divides (:func:`prefill_kernel_ok`), the
-    Pallas flash kernel over heads padded to 256 (it is written for
-    equal widths of 64, 128 or 256; the zeros cost 1.6 times the
-    operations, ``PERF.md`` section 3); elsewhere the whole masked
+    Pallas flash kernel at the two widths, each padded to whole lanes
+    (192 / 128 runs as 256 / 128: on a 128-deep MXU a score product of
+    192 is two passes as one of 256 is, and the values are multiplied
+    at their own width, ``PERF.md`` section 3); elsewhere the whole masked
     score in ``jax.numpy``, (B, H, S, S) float32: the CPU's body and an
     odd length's, not a long prefill's."""
     if prefill_kernel_ok(q.shape[1], q.shape[-1], v.shape[-1]):
@@ -167,13 +188,6 @@ def causal_attention(q, k, v, scale: float):
 # the queries one span of a sparse prefill holds: its index scores are
 # (QUERY_SPAN, keys) float32, 235 MB at 28672 keys
 QUERY_SPAN = 2048
-
-
-def _pad_last(a, to: int):
-    """The last axis zero-padded to a multiple of ``to``."""
-    extra = -a.shape[-1] % to
-    return a if not extra else jnp.pad(
-        a, ((0, 0),) * (a.ndim - 1) + ((0, extra),))
 
 
 def step_index_scores(qi, wi, ki):

@@ -12,6 +12,13 @@ Layout: (batch, seq, heads, head_dim) at the API; internally (batch*heads,
 seq, head_dim). Sequence lengths must be divisible by the block sizes (the
 framework-level caller pads — ragged semantics are handled one level up, see
 ops/sequence.py).
+
+Two widths: q and k share the SCORE width ``d`` (their last dim), v has
+the VALUE width ``e`` (its last dim), and every kernel keeps them apart:
+q / k / dq / dk blocks and accumulators are ``d`` wide, v / o / do / dv
+blocks and accumulators ``e`` wide. Masks, segments, dropout, windows
+and GQA act on the (block_q, block_k) score block and see neither. Where
+the two are equal the specs, scratch and grid are what one width gave.
 """
 
 from __future__ import annotations
@@ -240,7 +247,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
         # vector work being most of it. Accumulators are f32 either way
         q = q_ref[0]                      # (bq, d)
         k = k_ref[0]                      # (bk, d)
-        v = v_ref[0]                      # (bk, d)
+        v = v_ref[0]                      # (bk, e)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (bq, bk) f32
@@ -376,7 +383,7 @@ def _block_mask(m, n_j, block_k):
 def _fwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, causal,
               window, scale, dropout_p, block_q, block_k, interpret):
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, e = k.shape[1], v.shape[2]
     offset = tk - tq
     n_j = tk // block_k
     n_band = (_band_width_j(block_q=block_q, block_k=block_k,
@@ -392,7 +399,7 @@ def _fwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, causal,
     # lse carried as (bh, tq, 1): the trailing unit dim keeps the block's
     # last-two-dims (block_q, 1) legal for the Mosaic (8, 128) tiling rule
     out_shape = (
-        jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+        jax.ShapeDtypeStruct((bh, tq, e), q.dtype),
         jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
     )
     j_lo = functools.partial(_band_j_lo, block_q=block_q,
@@ -401,18 +408,21 @@ def _fwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, causal,
     if banded:
         # k/v specs walk only the band: jj -> clamp(j_lo(i) + jj); the
         # pipeline then never streams out-of-band K/V blocks from HBM
-        kv_spec = _vmem_spec((1, block_k, d), _banded_imap(
-            j_lo, n_j, lambda b: _kv_row_fold(b, nheads, kv_heads)))
+        kv_imap = _banded_imap(
+            j_lo, n_j, lambda b: _kv_row_fold(b, nheads, kv_heads))
+        k_spec, v_spec = (_vmem_spec((1, block_k, w), kv_imap)
+                          for w in (d, e))
     else:
-        kv_spec = _causal_kv_spec(block_q, block_k, d, nheads, kv_heads,
-                                  offset, n_j, causal)
+        k_spec, v_spec = (
+            _causal_kv_spec(block_q, block_k, w, nheads, kv_heads, offset,
+                            n_j, causal) for w in (d, e))
     mask_spec = _mask_block_spec(
         nheads, block_k, j_pos=2,
         banded_lo=j_lo if banded else None, n_j=n_j)
     in_specs = [
         _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        kv_spec,
-        kv_spec,
+        k_spec,
+        v_spec,
     ]
     inputs = (q, k, v)
     if kvm is not None:
@@ -431,12 +441,12 @@ def _fwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, causal,
         grid=grid,
         in_specs=in_specs,
         out_specs=(
-            _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, block_q, e), lambda b, i, j: (b, i, 0)),
             _vmem_spec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ),
         out_shape=out_shape,
         scratch_shapes=[
-            _scratch((block_q, d), jnp.float32),
+            _scratch((block_q, e), jnp.float32),
             _scratch((block_q, 128), jnp.float32),
             _scratch((block_q, 128), jnp.float32),
         ],
@@ -588,7 +598,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             p_v = jnp.where(keep, p * (1.0 / (1.0 - dropout_p)), 0.0)
         dv_acc[:] += jax.lax.dot_general(
             p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bk, d)
+            preferred_element_type=jnp.float32)            # (bk, e)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # (bq, bk)
@@ -609,7 +619,7 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
               do, causal, window, scale, dropout_p, block_q, block_k,
               interpret, delta=None):
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, e = k.shape[1], v.shape[2]
     offset = tk - tq
     if delta is None:  # ring callers pass the hop-invariant value once
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -636,15 +646,16 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
         j_lo, n_j, lambda b: _kv_row_fold(b, nheads, kv_heads))
     q_imap_banded = _banded_imap(i_lo, n_i)
 
-    dq_kv_spec = (_vmem_spec((1, block_k, d), kv_imap_banded)
-                  if banded_j else _causal_kv_spec(
-                      block_q, block_k, d, nheads, kv_heads, offset, n_j,
-                      causal))
+    dq_k_spec, dq_v_spec = (
+        _vmem_spec((1, block_k, w), kv_imap_banded)
+        if banded_j else _causal_kv_spec(
+            block_q, block_k, w, nheads, kv_heads, offset, n_j, causal)
+        for w in (d, e))
     dq_in_specs = [
         _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        dq_kv_spec,
-        dq_kv_spec,
-        _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        dq_k_spec,
+        dq_v_spec,
+        _vmem_spec((1, block_q, e), lambda b, i, j: (b, i, 0)),
         _vmem_spec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         _vmem_spec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
     ]
@@ -693,15 +704,14 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
         q_imap = lambda b, j, i: (b, jnp.maximum(i, i_first(j)), 0)
     else:
         q_imap = lambda b, j, i: (b, i, 0)
-    dkv_q_spec = _vmem_spec(
-        (1, block_q, d), q_imap_banded if banded_i else q_imap)
-    dkv_q1_spec = _vmem_spec(
-        (1, block_q, 1), q_imap_banded if banded_i else q_imap)
+    dkv_q_spec, dkv_do_spec, dkv_q1_spec = (
+        _vmem_spec((1, block_q, w), q_imap_banded if banded_i else q_imap)
+        for w in (d, e, 1))
     dkv_in_specs = [
         dkv_q_spec,
         _kv_spec(block_k, d, nheads, kv_heads, kv_arg_pos=1),
-        _kv_spec(block_k, d, nheads, kv_heads, kv_arg_pos=1),
-        dkv_q_spec,
+        _kv_spec(block_k, e, nheads, kv_heads, kv_arg_pos=1),
+        dkv_do_spec,
         dkv_q1_spec,
         dkv_q1_spec,
     ]
@@ -738,15 +748,15 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
         in_specs=dkv_in_specs,
         out_specs=(
             _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            _vmem_spec((1, block_k, e), lambda b, j, i: (b, j, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, tk, e), v.dtype),
         ),
         scratch_shapes=[
             _scratch((block_k, d), jnp.float32),
-            _scratch((block_k, d), jnp.float32),
+            _scratch((block_k, e), jnp.float32),
         ],
         interpret=interpret,
     )(*dkv_inputs)
@@ -757,8 +767,8 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
         b = bh // nheads
         dk = dk.reshape(b, kv_heads, group, tk, d).sum(2).reshape(
             b * kv_heads, tk, d)
-        dv = dv.reshape(b, kv_heads, group, tk, d).sum(2).reshape(
-            b * kv_heads, tk, d)
+        dv = dv.reshape(b, kv_heads, group, tk, e).sum(2).reshape(
+            b * kv_heads, tk, e)
     return dq, dk, dv
 
 
@@ -807,15 +817,26 @@ def _unpack_opt(args, has_mask, has_segs, has_seed):
     return args[0], args[1], args[2], kvm, seg, seed
 
 
+def _rows(x):
+    """(B, T, H, W) -> the kernel layout (B*H, T, W), each operand at
+    its own width."""
+    b, t, h, w = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, w)
+
+
+def _unrows(x, b):
+    """The kernel layout (B*H, T, W) -> (B, T, H, W)."""
+    bh, t, w = x.shape
+    return x.reshape(b, bh // b, t, w).transpose(0, 2, 1, 3)
+
+
 def _fwd4(q, k, v, kvm, seg, seed, *, causal, window, scale,
           dropout_p, block_q, block_k, interpret):
     """Forward on (B, T, H, D) arrays (global or per-shard): flatten to
-    the kernel layout, run, unflatten. Returns (o BTHD, lse (B, H, Tq))."""
-    b, tq, h, d = q.shape
+    the kernel layout, run, unflatten. Returns (o (B, Tq, H, E), lse
+    (B, H, Tq)): o is as wide as v."""
+    b, tq, h, _ = q.shape
     tk, hkv = k.shape[1], k.shape[2]
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, tk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, tk, d)
     kvm3 = None if kvm is None else kvm.astype(jnp.float32).reshape(b, 1, tk)
     # q side reads (block_q, 1) lse-layout blocks, kv side full-row
     # slices — two views of the ONE (B, T) ids array that crossed the
@@ -823,53 +844,53 @@ def _fwd4(q, k, v, kvm, seg, seed, *, causal, window, scale,
     qseg3 = None if seg is None else seg.astype(jnp.int32).reshape(b, tq, 1)
     kseg3 = None if seg is None else seg.astype(jnp.int32).reshape(b, 1, tk)
     seed2 = None if seed is None else seed.reshape(1, b * h)
-    o, lse = _fwd_call(qf, kf, vf, kvm3, qseg3, kseg3, seed2, h, hkv,
-                       causal, window, scale, dropout_p, block_q, block_k,
-                       interpret)
-    return (o.reshape(b, h, tq, d).transpose(0, 2, 1, 3),
-            lse.reshape(b, h, tq))
+    o, lse = _fwd_call(_rows(q), _rows(k), _rows(v), kvm3, qseg3, kseg3,
+                       seed2, h, hkv, causal, window, scale, dropout_p,
+                       block_q, block_k, interpret)
+    return _unrows(o, b), lse.reshape(b, h, tq)
 
 
 def _bwd4(q, k, v, kvm, seg, seed, o, lse, do, *, causal, window,
           scale, dropout_p, block_q_bwd, block_k_bwd, interpret):
-    """Backward on (B, T, H, D) arrays; returns (dq, dk, dv) in BTHD
-    (dk/dv carry the K/V head count — already group-summed under GQA)."""
-    b, tq, h, d = q.shape
+    """Backward on (B, T, H, D) arrays; returns (dq, dk, dv) in BTHD,
+    each as wide as its primal (dk/dv carry the K/V head count — already
+    group-summed under GQA)."""
+    b, tq, h, _ = q.shape
     tk, hkv = k.shape[1], k.shape[2]
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, tk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, tk, d)
-    of = o.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    dof = do.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     lsef = lse.reshape(b * h, tq, 1)
     kvm3 = None if kvm is None else kvm.astype(jnp.float32).reshape(b, 1, tk)
     qseg3 = None if seg is None else seg.astype(jnp.int32).reshape(b, tq, 1)
     kseg3 = None if seg is None else seg.astype(jnp.int32).reshape(b, 1, tk)
     seed2 = None if seed is None else seed.reshape(1, b * h)
-    dq, dk, dv = _bwd_call(qf, kf, vf, kvm3, qseg3, kseg3, seed2, h, hkv,
-                           of, lsef, dof, causal, window, scale, dropout_p,
-                           block_q_bwd, block_k_bwd, interpret)
-    return (dq.reshape(b, h, tq, d).transpose(0, 2, 1, 3),
-            dk.reshape(b, hkv, tk, d).transpose(0, 2, 1, 3),
-            dv.reshape(b, hkv, tk, d).transpose(0, 2, 1, 3))
+    dq, dk, dv = _bwd_call(_rows(q), _rows(k), _rows(v), kvm3, qseg3,
+                           kseg3, seed2, h, hkv, _rows(o), lsef, _rows(do),
+                           causal, window, scale, dropout_p, block_q_bwd,
+                           block_k_bwd, interpret)
+    return _unrows(dq, b), _unrows(dk, b), _unrows(dv, b)
 
 
 def resolve_block_sizes(tq, tk, d, causal, block_q=None, block_k=None,
                         block_q_bwd=None, block_k_bwd=None,
-                        dtype=jnp.float32):
+                        dtype=jnp.float32, e=None,
+                        default_q=DEFAULT_BLOCK_Q,
+                        default_k=DEFAULT_BLOCK_K):
     """Resolve the four kernel block sizes from the autotuned table
     (ops/pallas/tuning.py), falling back pow2-wise to sizes that divide
-    the sequence lengths. Shared by flash_attention and the
-    ring-attention per-step calls (parallel/context_parallel.py), which
-    see t/sp-sized blocks and must resolve against THOSE shapes.
-    ``dtype`` is the type q/k/v reach the kernel in: the table is keyed
-    by it, so an entry measured at bf16 never sizes an f32 call."""
+    the sequence lengths. Shared by flash_attention, the ring-attention
+    per-step calls (parallel/context_parallel.py), which see t/sp-sized
+    blocks and must resolve against THOSE shapes, and latent
+    attention's prefill (ops/latent_attention.py), whose static
+    defaults are its own (``default_q`` / ``default_k``).
+    ``dtype`` is the type q/k/v reach the kernel in and ``e`` the value
+    width where it is not ``d``: the table is keyed by both, so an
+    entry measured at bf16 never sizes an f32 call, nor one measured at
+    equal widths a call whose value block is narrower."""
     tuned = {}
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
         from .tuning import attention_key, get_tuned
 
         tuned = get_tuned(attention_key(tq, tk, d, causal,
-                                        dtype=dtype)) or {}
+                                        dtype=dtype, e=e)) or {}
 
     def _resolve(given, key, seq, default):
         # pow2 buckets can hold shapes the tuned block doesn't divide
@@ -886,8 +907,8 @@ def resolve_block_sizes(tq, tk, d, causal, block_q=None, block_k=None,
                 return min(cand, seq)
         return min(default, seq)
 
-    block_q = _resolve(block_q, "block_q", tq, DEFAULT_BLOCK_Q)
-    block_k = _resolve(block_k, "block_k", tk, DEFAULT_BLOCK_K)
+    block_q = _resolve(block_q, "block_q", tq, default_q)
+    block_k = _resolve(block_k, "block_k", tk, default_k)
     # the backward kernels (dq + dkv) have their own arithmetic-intensity
     # sweet spot; tuned independently, defaulting to the forward blocks
     block_q_bwd = _resolve(block_q_bwd, "block_q_bwd", tq, block_q)
@@ -912,7 +933,7 @@ def resolve_block_sizes(tq, tk, d, causal, block_q=None, block_k=None,
 def ring_fwd_block(q, k, v, kvm, qseg, kseg, *, causal, scale, block_q,
                    block_k, interpret):
     """One ring hop's flash forward: local q (B, Tq, H, D) against one
-    rotating K/V block (B, Tk, Hkv, D; Hkv | H — GQA blocks rotate with
+    rotating K/V block (B, Tk, Hkv, D / E; Hkv | H — GQA blocks rotate with
     their FEWER heads, the kernel's index map shares them across each
     group). Returns (o, lse): o is the block-normalized output and
     lse = m + log(l) its per-row logsumexp ((B, H, Tq)) — exactly the
@@ -920,19 +941,15 @@ def ring_fwd_block(q, k, v, kvm, qseg, kseg, *, causal, scale, block_q,
     block is the diagonal one (same global offsets); strictly-past
     blocks are called with causal=False and strictly-future ones are
     skipped by the caller."""
-    b, tq, h, d = q.shape
+    b, tq, h, _ = q.shape
     tk, hkv = k.shape[1], k.shape[2]
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, tk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, tk, d)
     kvm3 = None if kvm is None else kvm.astype(jnp.float32).reshape(b, 1, tk)
     qseg3 = None if qseg is None else qseg.astype(jnp.int32).reshape(b, tq, 1)
     kseg3 = None if kseg is None else kseg.astype(jnp.int32).reshape(b, 1, tk)
-    o, lse = _fwd_call(qf, kf, vf, kvm3, qseg3, kseg3, None, h, hkv,
-                       causal, None, scale, 0.0, block_q, block_k,
-                       interpret)
-    return (o.reshape(b, h, tq, d).transpose(0, 2, 1, 3),
-            lse.reshape(b, h, tq))
+    o, lse = _fwd_call(_rows(q), _rows(k), _rows(v), kvm3, qseg3, kseg3,
+                       None, h, hkv, causal, None, scale, 0.0, block_q,
+                       block_k, interpret)
+    return _unrows(o, b), lse.reshape(b, h, tq)
 
 
 def ring_bwd_block(q, k, v, kvm, qseg, kseg, o, lse, do, *, causal,
@@ -947,43 +964,41 @@ def ring_bwd_block(q, k, v, kvm, qseg, kseg, o, lse, do, *, causal,
     precomputed rowsum(do*o) ((B, Tq, H) — hop-invariant, so the ring
     loop computes it once instead of n times). Under GQA (k/v carry
     Hkv < H heads) dk/dv come back group-summed onto the Hkv heads."""
-    b, tq, h, d = q.shape
+    b, tq, h, _ = q.shape
     tk, hkv = k.shape[1], k.shape[2]
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, tk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, tk, d)
-    of = o.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    dof = do.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     lsef = lse.reshape(b * h, tq, 1)
     deltaf = (None if delta is None
               else delta.transpose(0, 2, 1).reshape(b * h, tq, 1))
     kvm3 = None if kvm is None else kvm.astype(jnp.float32).reshape(b, 1, tk)
     qseg3 = None if qseg is None else qseg.astype(jnp.int32).reshape(b, tq, 1)
     kseg3 = None if kseg is None else kseg.astype(jnp.int32).reshape(b, 1, tk)
-    dq, dk, dv = _bwd_call(qf, kf, vf, kvm3, qseg3, kseg3, None, h, hkv,
-                           of, lsef, dof, causal, None, scale, 0.0,
-                           block_q, block_k, interpret, delta=deltaf)
-    return (dq.reshape(b, h, tq, d).transpose(0, 2, 1, 3),
-            dk.reshape(b, hkv, tk, d).transpose(0, 2, 1, 3),
-            dv.reshape(b, hkv, tk, d).transpose(0, 2, 1, 3))
+    dq, dk, dv = _bwd_call(_rows(q), _rows(k), _rows(v), kvm3, qseg3,
+                           kseg3, None, h, hkv, _rows(o), lsef, _rows(do),
+                           causal, None, scale, 0.0, block_q, block_k,
+                           interpret, delta=deltaf)
+    return _unrows(dq, b), _unrows(dk, b), _unrows(dv, b)
 
 
 def _attn_rule(has_mask, has_segs, has_seed, gqa, bwd):
     """Einsum-style Shardy sharding rule + need-replication factors for
     the fwd/bwd custom calls. b (batch) and the head factor are
-    passthrough (shardable); tq/tk/d must be replicated (the kernel
-    computes full attention rows locally). Under GQA the q tensor
-    crosses the boundary as 5-D (b, tq, kv_heads, group, d) so the
-    KV-HEAD factor g is SHARED with k/v and shards consistently — a
-    head shard then owns whole kv groups (group itself is pinned
-    replicated: splitting a group would orphan its shared K/V)."""
+    passthrough (shardable); tq/tk and both widths, d (scores: q, k and
+    their cotangents) and e (values: v, o and theirs), must be
+    replicated (the kernel computes full attention rows locally). Under
+    GQA the q tensor crosses the boundary as 5-D (b, tq, kv_heads,
+    group, d) so the KV-HEAD factor g is SHARED with k/v and shards
+    consistently — a head shard then owns whole kv groups (group itself
+    is pinned replicated: splitting a group would orphan its shared
+    K/V)."""
     if gqa:
         qm, km = "b tq g grp d", "b tk g d"
+        om, vm = "b tq g grp e", "b tk g e"
         lse, seed = "b g grp tq", "b g grp"
     else:
         qm, km = "b tq h d", "b tk h d"
+        om, vm = "b tq h e", "b tk h e"
         lse, seed = "b h tq", "b h"
-    ins = [qm, km, km]
+    ins = [qm, km, vm]
     if has_mask:
         ins.append("b tk")
     if has_segs:
@@ -991,14 +1006,15 @@ def _attn_rule(has_mask, has_segs, has_seed, gqa, bwd):
     if has_seed:
         ins.append(seed)
     if bwd:
-        ins += [qm, lse, qm]               # o, lse, do
-        outs = [qm, km, km]                # dq, dk, dv
+        ins += [om, lse, om]               # o, lse, do
+        outs = [qm, km, vm]                # dq, dk, dv
     else:
-        outs = [qm, lse]                   # o, lse
+        outs = [om, lse]                   # o, lse
     # need_replication must be sorted by factor first-appearance index:
-    # non-GQA b=0, tq=1, h=2, d=3, tk=4; GQA b=0, tq=1, g=2, grp=3,
-    # d=4, tk=5
-    need = ("tq", "grp", "d", "tk") if gqa else ("tq", "d", "tk")
+    # non-GQA b=0, tq=1, h=2, d=3, tk=4, e=5; GQA b=0, tq=1, g=2,
+    # grp=3, d=4, tk=5, e=6
+    need = (("tq", "grp", "d", "tk", "e") if gqa
+            else ("tq", "d", "tk", "e"))
     rule = ", ".join(ins) + " -> " + ", ".join(outs)
     return rule, need
 
@@ -1105,8 +1121,8 @@ def _partitioned(bwd, has_mask, has_segs, has_seed, gqa, causal, window,
             if gqa:  # 5-D boundary (see _attn_rule) -> kernel 4-D forms
                 b, tq, kv, grp, d = q.shape
                 q = q.reshape(b, tq, kv * grp, d)
-                o = o.reshape(b, tq, kv * grp, d)
-                do = do.reshape(b, tq, kv * grp, d)
+                o = o.reshape(b, tq, kv * grp, -1)
+                do = do.reshape(b, tq, kv * grp, -1)
                 lse = lse.reshape(b, kv * grp, tq)
                 seed = (None if seed is None
                         else seed.reshape(seed.shape[0], kv * grp))
@@ -1131,7 +1147,7 @@ def _partitioned(bwd, has_mask, has_segs, has_seed, gqa, causal, window,
                            block_q=blk_a, block_k=blk_b,
                            interpret=interpret)
             if gqa:
-                o = o.reshape(b, tq, kv, grp, d)
+                o = o.reshape(b, tq, kv, grp, -1)
                 lse = lse.reshape(b, kv, grp, tq)
             return o, lse
 
@@ -1223,10 +1239,10 @@ def _flash_fwd(q, k, v, kvm, seg, seed, causal, window, scale, dropout_p,
                        seed is not None, gqa, causal, window, scale,
                        dropout_p, block_q, block_k, interpret)
     if gqa:
-        b, tq, h, d = q.shape
+        b, tq, h, _ = q.shape
         q5, seed3 = _gqa_pack(q, seed, k.shape[2])
         o5, lse = fwd(*_opt_args(q5, k, v, kvm, seg, seed3))
-        o = o5.reshape(b, tq, h, d)
+        o = o5.reshape(b, tq, h, -1)
     else:
         o, lse = fwd(*_opt_args(q, k, v, kvm, seg, seed))
     # lse is stored in the call's boundary layout ((b, kv, grp, tq)
@@ -1246,8 +1262,8 @@ def _flash_bwd(causal, window, scale, dropout_p, block_q, block_k,
         hkv = k.shape[2]
         grp = h // hkv
         q5, seed3 = _gqa_pack(q, seed, hkv)
-        o5 = o.reshape(b, tq, hkv, grp, d)
-        do5 = do.reshape(b, tq, hkv, grp, d)
+        o5 = o.reshape(b, tq, hkv, grp, -1)
+        do5 = do.reshape(b, tq, hkv, grp, -1)
         dq5, dk, dv = bwd(*(_opt_args(q5, k, v, kvm, seg, seed3)
                             + (o5, lse, do5)))
         dq = dq5.reshape(b, tq, h, d)
@@ -1273,7 +1289,11 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q_bwd: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
                     interpret: Optional[bool] = None):
-    """Blockwise attention over (batch, seq, heads, head_dim) inputs.
+    """Blockwise attention: q (batch, tq, heads, d), k (batch, tk,
+    kv_heads, d), v (batch, tk, kv_heads, e) -> (batch, tq, heads, e).
+    The score width d and the value width e are read from the operands
+    and may differ (latent attention: 192 / 128); ``scale`` defaults to
+    ``d ** -0.5``.
 
     Sequence lengths must divide the block sizes (shrunk automatically for
     short sequences). Differentiable (custom VJP, recompute backward).
@@ -1288,8 +1308,8 @@ def flash_attention(q, k, v, causal: bool = False,
 
     Block sizes come from the table measured on the chip
     (ops/pallas/tuned_blocks.json, written by tools/pallas_tune.py,
-    keyed by device kind, shape bucket and operand type) and fall back
-    to 128x128 where it has no entry.
+    keyed by device kind, shape bucket, the two widths and operand
+    type) and fall back to 128x128 where it has no entry.
 
     ``kv_mask``: optional (batch, tk) keep-mask (True/nonzero = attend) —
     the key-padding form every ragged-batch model needs (the LoD
@@ -1314,6 +1334,10 @@ def flash_attention(q, k, v, causal: bool = False,
     h_kv = k.shape[2]
     from ..attention import flash_operand_dtype
 
+    if k.shape[-1] != d:
+        raise ValueError(
+            f"q and k must share the score width, got {d} and "
+            f"{k.shape[-1]}")
     out_dtype = q.dtype
     q, k, v = (x.astype(flash_operand_dtype(x.dtype)) for x in (q, k, v))
     if h_kv != h:
@@ -1327,7 +1351,7 @@ def flash_attention(q, k, v, causal: bool = False,
         scale = d ** -0.5
     block_q, block_k, block_q_bwd, block_k_bwd = resolve_block_sizes(
         tq, tk, d, causal, block_q, block_k, block_q_bwd, block_k_bwd,
-        dtype=q.dtype)
+        dtype=q.dtype, e=v.shape[-1])
     if tq % block_q or tk % block_k or tq % block_q_bwd or tk % block_k_bwd:
         raise ValueError(
             f"seq lens ({tq},{tk}) must be divisible by blocks "

@@ -78,14 +78,20 @@ def dtype_tag(dtype) -> str:
 
 
 def attention_key(tq: int, tk: int, d: int, causal: bool,
-                  kind: Optional[str] = None, dtype="float32") -> str:
+                  kind: Optional[str] = None, dtype="float32",
+                  e: Optional[int] = None) -> str:
     """Flash-attention bucket: pow2 sequence lengths x head_dim x mask
     x OPERAND TYPE (as :func:`decode_key` keys by ``pool_dtype``). The
     type of q/k/v decides the MXU rate and how much VMEM a score block
     takes, so blocks measured at bf16 never size an f32 call: a
-    1024 x 1024 f32 score block is 4 MB a buffer."""
+    1024 x 1024 f32 score block is 4 MB a buffer. ``e`` is the value
+    width where it is not the score width ``d`` (``d256e128``): the
+    value block, the output block and the accumulator are that wide, so
+    such a call has room, and winners, of its own; equal widths keep
+    the key they had."""
+    width = f"d{d}" if e in (None, d) else f"d{d}e{e}"
     return (f"flash_attention|{kind or _device_kind()}|"
-            f"tq{_pow2_bucket(tq)}|tk{_pow2_bucket(tk)}|d{d}|"
+            f"tq{_pow2_bucket(tq)}|tk{_pow2_bucket(tk)}|{width}|"
             f"{'causal' if causal else 'full'}|{dtype_tag(dtype)}")
 
 

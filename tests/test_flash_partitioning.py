@@ -109,6 +109,36 @@ class TestFlashUnderPjit:
                                        rtol=2e-5, atol=2e-5,
                                        err_msg=f"d{name}")
 
+    @pytest.mark.parametrize("h_kv", [4, 2])
+    def test_two_widths_partition_without_gather(self, partitioner, h_kv):
+        """A value width of its own is a second replicated factor of the
+        sharding rule: batch and (kv-)heads still shard, forward and
+        backward, with and without GQA, and nothing is gathered."""
+        mesh = pt.build_mesh(dp=2, tp=2, pp=2)
+        q, k, _ = _qkv(d=128, seed=6)
+        _, _, v = _qkv(d=64, seed=7)
+        k, v = k[:, :, :h_kv], v[:, :, :h_kv]
+        ct = jnp.asarray(RNG.normal(size=v.shape[:2] + (4, 64))
+                         .astype(np.float32))
+
+        def loss(q, k, v):
+            return (flash_attention(q, k, v, causal=True,
+                                    interpret=True) * ct).sum()
+
+        want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        qs, ks, vs = _put(mesh, P("dp", None, "tp", None), q, k, v)
+        fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        assert "all-gather" not in fn.lower(qs, ks, vs).compile().as_text()
+        got = fn(qs, ks, vs)
+        # the scalar is summed shard by shard: another rounding order
+        np.testing.assert_allclose(got[0], want[0], rtol=5e-4)
+        for g, r, name in zip(got[1], want[1], "qkv"):
+            assert g.shape == r.shape, name
+            assert _spec4(g.sharding) == ("dp", None, "tp", None), name
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=2e-5, atol=2e-5,
+                                       err_msg=f"d{name}")
+
     def test_mask_and_segments_shard_with_batch(self, partitioner):
         mesh = pt.build_mesh(dp=2, tp=2, pp=2)
         b, t = 4, 256

@@ -224,9 +224,10 @@ def test_the_plain_body_is_whole_attention(length):
 
 
 def test_the_padded_flash_body_is_the_jnp_body():
-    """Heads of 24 / 16 padded to 256 through the Pallas flash kernel
-    (interpreted) against the plain ``jax.numpy`` body; the rule takes the
-    kernel only where its query block divides the length."""
+    """Heads of 24 / 16, each width padded to whole lanes, through the
+    Pallas flash kernel (interpreted) against the plain ``jax.numpy``
+    body; the rule takes the kernel only where its query block divides
+    the length."""
     rng = np.random.default_rng(8)
     draw = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
     q, k, v = draw(1, 1024, 2, 24), draw(1, 1024, 2, 24), draw(
@@ -241,6 +242,85 @@ def test_the_padded_flash_body_is_the_jnp_body():
     assert got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=0, atol=2e-6)
+
+
+@pytest.mark.parametrize("widths", [(192, 128), (24, 16)])
+def test_the_flash_body_at_two_widths_is_the_jnp_body(widths):
+    """Scores of dq and values of dv through the Pallas flash kernel
+    (interpreted), each padded to its own whole lanes (192 / 128 as
+    256 / 128, 24 / 16 as 128 / 128), against the plain ``jax.numpy``
+    body: forward and the three gradients."""
+    dq, dv = widths
+    rng = np.random.default_rng(9)
+    draw = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    q, k, v = draw(1, 1024, 2, dq), draw(1, 1024, 2, dq), draw(
+        1, 1024, 2, dv)
+    ct = draw(1, 1024, 2, dv)
+
+    def body(q, k, v):
+        o = LA.causal_attention(q, k, v, dq ** -0.5)
+        return jnp.sum(o * ct), o
+
+    def run():  # a new function a call: jit's cache goes by the function
+        return jax.jit(jax.value_and_grad(
+            lambda *a: body(*a), argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+    (_, want), want_grads = run()
+    with A.force_flash():
+        assert LA.prefill_kernel_ok(1024, dq, dv)
+        (_, got), got_grads = run()
+    assert got.shape == want.shape == (1, 1024, 2, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=2e-6)
+    for g, w, name in zip(got_grads, want_grads, "qkv"):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=0, atol=2e-5, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("dq,dv,dtype,tuned", [
+    (192, 128, jnp.bfloat16, True),     # kanana-2, Xing4.0: 256 / 128
+    (24, 16, jnp.bfloat16, False),      # 128 / 128: another key
+    (128, 256, jnp.bfloat16, False),    # 128 / 256: another key
+    (192, 256, jnp.bfloat16, False),    # 256 / 256: the equal-width key
+    (192, 128, jnp.float32, False),     # four-byte operands: another key
+])
+def test_the_prefills_blocks_are_the_tuned_tables(monkeypatch, dq, dv,
+                                                  dtype, tuned):
+    """One owner picks the flash kernels' blocks: the table measured on
+    the chip, keyed by the length's bucket, the two widths as padded and
+    the type the operands reach the kernels in; where it has no entry
+    for the call, the prefill's static 1024 x 512."""
+    import importlib
+
+    from paddle_tpu.ops.pallas import tuning
+
+    FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    entry = {"block_q": 512, "block_k": 1024, "block_q_bwd": 256,
+             "block_k_bwd": 512}
+    seen = {}
+
+    def fake_flash(q, k, v, **kw):
+        seen.update(kw, widths=(q.shape[-1], k.shape[-1], v.shape[-1]))
+        return jnp.zeros(q.shape[:-1] + v.shape[-1:], q.dtype)
+
+    monkeypatch.setattr(FA, "flash_attention", fake_flash)
+    tuning.reset_cache()
+    try:
+        tuning.set_tuned(tuning.attention_key(
+            2048, 2048, 256, True, dtype=jnp.bfloat16, e=128), entry,
+            persist=False)
+        q, k, v = (jnp.zeros((1, 2048, 2, w), dtype) for w in (dq, dq, dv))
+        out = LA._flash_padded(q, k, v, 0.1)
+    finally:
+        tuning.reset_cache()
+    lanes = lambda w: w + -w % 128
+    assert out.shape == (1, 2048, 2, dv)
+    assert seen["widths"] == (lanes(dq), lanes(dq), lanes(dv))
+    blocks = {name: seen[name] for name in entry}
+    assert blocks == (entry if tuned else {
+        "block_q": LA.FLASH_BLOCK_Q, "block_k": LA.FLASH_BLOCK_K,
+        "block_q_bwd": LA.FLASH_BLOCK_Q, "block_k_bwd": LA.FLASH_BLOCK_K})
 
 
 def test_yarn_frequencies_blend_between_the_two_turn_counts():

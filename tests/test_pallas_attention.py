@@ -579,3 +579,69 @@ class TestFlashWindowBandedGrid:
             band = FA._band_width_j(block_q=128, block_k=128, window=64,
                                     causal=causal, n_j=8)
             assert band < 8, (causal, band)
+
+
+class TestFlashTwoWidths:
+    """q and k share a score width, v has a value width of its own
+    (latent attention: 192 / 128 runs as 256 / 128). The oracle is the
+    XLA composite on operands zero-padded to ONE width, cut back: zeros
+    add nothing to a score and the padded value columns are exact
+    zeros, so forward and all three gradients must agree."""
+
+    # feature -> flash_attention's keywords; "gqa" halves the K/V heads
+    FEATURES = {
+        "plain": {},
+        "gqa": {},
+        "kv_mask": {"kv_mask": jnp.asarray(
+            np.arange(256)[None, :] < np.array([200, 131])[:, None])},
+        "segment_ids": {"segment_ids": jnp.asarray(
+            (np.arange(256)[None, :] >= np.array([96, 160])[:, None])
+            .astype(np.int32))},
+        "window": {"window": 96},
+    }
+    CASES = ([("plain", c) for c in (False, True)]
+             + [("gqa", True), ("kv_mask", False), ("segment_ids", True),
+                ("window", False)])
+
+    @pytest.mark.parametrize("feature,causal", CASES)
+    @pytest.mark.parametrize("widths", [(256, 128), (128, 256)])
+    def test_forward_and_grads_match_padded_oracle(self, widths, feature,
+                                                   causal):
+        d, e = widths
+        b, t, h = 2, 256, 2
+        h_kv = 1 if feature == "gqa" else h
+        rng = np.random.default_rng(61)
+        draw = lambda *s: jnp.asarray(rng.normal(size=s).astype(np.float32))
+        q, k, v = draw(b, t, h, d), draw(b, t, h_kv, d), draw(b, t, h_kv, e)
+        ct = draw(b, t, h, e)
+        kw = dict(self.FEATURES[feature])
+        ref_kw = dict(kw)
+        if "kv_mask" in ref_kw:
+            ref_kw["mask"] = ref_kw.pop("kv_mask")[:, None, None, :]
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=causal, block_q=128,
+                                   block_k=128, block_q_bwd=64,
+                                   block_k_bwd=128, interpret=True, **kw)
+
+        def oracle(q, k, v):
+            pad = lambda a: jnp.pad(a, ((0, 0),) * 3 + (
+                (0, max(d, e) - a.shape[-1]),))
+            return xla_attention(pad(q), pad(k), pad(v), causal=causal,
+                                 scale=d ** -0.5, **ref_kw)[..., :e]
+
+        out, pull = jax.vjp(flash, q, k, v)
+        ref, ref_pull = jax.vjp(oracle, q, k, v)
+        assert out.shape == (b, t, h, e)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+        for got, want, name in zip(pull(ct), ref_pull(ct), "qkv"):
+            assert got.shape == want.shape, name
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-4, atol=2e-4,
+                                       err_msg=f"d{name}")
+
+    def test_q_and_k_must_share_the_score_width(self):
+        q, k, v = _rand_qkv(t=128, d=64)
+        with pytest.raises(ValueError, match="score width"):
+            flash_attention(q, k[..., :32], v, interpret=True)

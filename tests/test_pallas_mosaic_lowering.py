@@ -199,6 +199,51 @@ def test_flash_gqa_lowers_to_mosaic():
     _export_tpu(bwd, q, k, k)
 
 
+# (b, t, h, h_kv, score width, value width): the trained latent cell's
+# real call (kanana-2, 2 x 8192, 32 heads of 192 / 128 with the scores
+# padded to whole lanes), the same with the scores as published (a block
+# whose last dim is the array's own), and unequal widths under GQA
+TWO_WIDTH_CASES = {
+    "latent_train_cell": (2, 8192, 32, 32, 256, 128),
+    "latent_unpadded_scores": (2, 8192, 32, 32, 192, 128),
+    "gqa": (2, 2048, 8, 2, 256, 128),
+}
+
+
+@pytest.mark.parametrize("blocks", ["static", "v5e_table"])
+@pytest.mark.parametrize("case", sorted(TWO_WIDTH_CASES))
+def test_flash_two_widths_lower_to_mosaic(monkeypatch, case, blocks):
+    """A score width and a value width of its own, at the latent
+    prefill's blocks (its static 1024 x 512, and what the committed
+    table gives the call on the v5e): forward and ``jax.grad`` (v, o,
+    do and dv blocks at the value width, q, k, dq and dk at the score
+    width)."""
+    from paddle_tpu.ops.latent_attention import (FLASH_BLOCK_K,
+                                                 FLASH_BLOCK_Q)
+    from paddle_tpu.ops.pallas import tuning
+    from paddle_tpu.ops.pallas.flash_attention import resolve_block_sizes
+
+    b, t, h, h_kv, d, e = TWO_WIDTH_CASES[case]
+    bq, bk, bq_bwd, bk_bwd = (FLASH_BLOCK_Q, FLASH_BLOCK_K) * 2
+    if blocks == "v5e_table":
+        monkeypatch.setattr(tuning, "_device_kind", lambda: "tpu_v5_lite")
+        bq, bk, bq_bwd, bk_bwd = resolve_block_sizes(
+            t, t, d, True, dtype=jnp.bfloat16, e=e,
+            default_q=FLASH_BLOCK_Q, default_k=FLASH_BLOCK_K)
+    q = jnp.zeros((b, t, h, d), jnp.bfloat16)
+    k = jnp.zeros((b, t, h_kv, d), jnp.bfloat16)
+    v = jnp.zeros((b, t, h_kv, e), jnp.bfloat16)
+    attend = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=bq, block_k=bk, block_q_bwd=bq_bwd,
+        block_k_bwd=bk_bwd, interpret=False)
+    fwd = _export_tpu(jax.jit(attend), q, k, v)
+    assert fwd.out_avals[0].shape == (b, t, h, e)
+    bwd = _export_tpu(jax.jit(jax.grad(
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))), q, k, v)
+    assert [a.shape for a in bwd.out_avals] == [q.shape, k.shape, v.shape]
+
+
 # --- flash-DECODE kernels (serving hot loop) --------------------------------
 # The NMT lesson applied forward: interpret-mode correctness never
 # exercises Mosaic tiling/scalar-prefetch legality, so the decode

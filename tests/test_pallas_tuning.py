@@ -246,6 +246,44 @@ def test_tune_attention_sweeps_fwd_and_bwd_independently(monkeypatch):
     assert entry["xla_ms"] == pytest.approx(10000.0)
 
 
+def test_tune_attention_at_a_value_width_of_its_own(monkeypatch):
+    """``--value-width`` / ``--blocks``: v, the result and the cotangent
+    are ``e`` wide, the sweep is over the given candidates alone, the
+    entry says at what widths it was measured, and an XLA fallback that
+    cannot run (a long shape's whole score) loses to the kernel instead
+    of stopping the tuner."""
+    import importlib
+
+    pt_mod = _load_pallas_tune()
+    from paddle_tpu.ops import attention as A
+
+    FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    pairs = []
+
+    def fake_flash(q, k, v, causal=False, block_q=None, block_k=None,
+                   block_q_bwd=None, block_k_bwd=None, interpret=None):
+        assert (q.shape[-1], k.shape[-1], v.shape[-1]) == (256, 256, 128)
+        pairs.append((block_q, block_k, block_q_bwd, block_k_bwd))
+        return q[..., :128] * 1.0
+
+    def no_xla(*a, **kw):
+        raise MemoryError("a (B, H, T, T) score")
+
+    monkeypatch.setattr(FA, "flash_attention", fake_flash)
+    monkeypatch.setattr(A, "xla_attention", no_xla)
+    monkeypatch.setattr(pt_mod, "_time", lambda fn, *a, **kw: (
+        fn(*a), 1.0 / sum(x or 1 for x in pairs[-1]))[1])
+
+    entry = pt_mod.tune_attention(1, 1024, 2, 256, causal=True,
+                                  dry_run=True, e=128, blocks=[512, 1024])
+    assert entry["shape"] == [1, 1024, 2, 2, 256, 128]
+    assert set(entry["sweep_fwd_ms"]) == set(entry["sweep_grad_ms"]) == {
+        "512x512", "512x1024", "1024x512", "1024x1024"}
+    assert [entry[n] for n in ("block_q", "block_k", "block_q_bwd",
+                               "block_k_bwd")] == [1024] * 4
+    assert entry["use_flash"] is True and "xla_ms" not in entry
+
+
 # ---------------------------------------------------------------------------
 # the committed table (paddle_tpu/ops/pallas/tuned_blocks.json) and the
 # operand type in its keys
@@ -272,6 +310,56 @@ def test_attention_key_carries_operand_type():
     assert k16 == "flash_attention|tpu_v5_lite|tq2048|tk2048|d128|causal|bf16"
     assert tuning.attention_key(2048, 2048, 128, True, kind=V5E,
                                 dtype="bfloat16") == k16
+
+
+def test_attention_key_carries_the_value_width_where_it_differs():
+    key = tuning.attention_key(8192, 8192, 256, True, kind=V5E,
+                               dtype=jnp.bfloat16, e=128)
+    assert key == ("flash_attention|tpu_v5_lite|tq8192|tk8192|d256e128|"
+                   "causal|bf16")
+    # equal widths keep the key they had: the committed entries stand
+    assert tuning.attention_key(
+        2048, 2048, 128, True, kind=V5E, e=128) == tuning.attention_key(
+        2048, 2048, 128, True, kind=V5E)
+
+
+@pytest.mark.parametrize("length", [2048, 4096, 6144, 8192, 14336])
+def test_committed_entries_size_the_latent_prefills(committed, monkeypatch,
+                                                    length):
+    """A latent prefill's call on a v5e (heads of 192 / 128 as 256 / 128,
+    bf16: the trained kanana-2 cell at 8192, the served Xing4.0 cell's
+    prompt buckets of 2048 to 14336) reaches the flash kernels with the
+    blocks of the committed entry of its length's bucket, which records
+    the widths it was measured at and the sweep it won."""
+    import importlib
+
+    from paddle_tpu.ops import latent_attention as LA
+
+    FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    key = tuning.attention_key(length, length, 256, True,
+                               dtype=jnp.bfloat16, e=128)
+    entry = committed[key]
+    assert "|d256e128|causal|bf16" in key and key.split("|")[1] == V5E
+    assert entry["shape"][2:] == [32, 32, 256, 128]
+    seen = {}
+
+    def fake_flash(q, k, v, **kw):
+        seen.update(kw)
+        return jnp.zeros(q.shape[:-1] + v.shape[-1:], q.dtype)
+
+    monkeypatch.setattr(FA, "flash_attention", fake_flash)
+    q, k, v = (jnp.zeros((1, length, 1, w), jnp.bfloat16)
+               for w in (192, 192, 128))
+    LA._flash_padded(q, k, v, 192 ** -0.5)
+    got = tuple(seen[n] for n in ("block_q", "block_k", "block_q_bwd",
+                                  "block_k_bwd"))
+    assert got == (entry["block_q"], entry["block_k"],
+                   entry["block_q_bwd"], entry["block_k_bwd"])
+    fwd, grad = entry["sweep_fwd_ms"], entry["sweep_grad_ms"]
+    assert min(fwd, key=fwd.get) == f"{got[0]}x{got[1]}"
+    assert min(grad, key=grad.get) == f"{got[2]}x{got[3]}"
+    # whole blocks at every length ``prefill_kernel_ok`` admits
+    assert all(LA.FLASH_BLOCK_Q % blk == 0 for blk in got)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

@@ -167,6 +167,47 @@ def test_flash_backward_compiles(one_chip, table_of_described_chip, case):
         assert name in text, name
 
 
+def test_flash_two_widths_compile_at_the_trained_latent_cells_call(
+        one_chip, table_of_described_chip):
+    """kanana-2's attention as ``causal_attention`` hands it to the
+    kernels: 2 x 8192 positions, 32 heads, scores padded 192 -> 256,
+    values at their own 128, bf16, at the blocks the committed table
+    gives the call on the v5e; forward and backward through the TPU
+    compiler, which is where a block too large for VMEM is refused."""
+    from paddle_tpu.ops.latent_attention import (FLASH_BLOCK_K,
+                                                 FLASH_BLOCK_Q)
+    from paddle_tpu.ops.pallas import tuning
+    from paddle_tpu.ops.pallas.flash_attention import resolve_block_sizes
+
+    key = tuning.attention_key(8192, 8192, 256, True, dtype=jnp.bfloat16,
+                               e=128)
+    assert tuning.get_tuned(key), key
+    bq, bk, bq_bwd, bk_bwd = resolve_block_sizes(
+        8192, 8192, 256, True, dtype=jnp.bfloat16, e=128,
+        default_q=FLASH_BLOCK_Q, default_k=FLASH_BLOCK_K)
+    qk = jax.ShapeDtypeStruct((2, 8192, 32, 256), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, scale=192 ** -0.5, block_q=bq,
+            block_k=bk, block_q_bwd=bq_bwd, block_k_bwd=bk_bwd,
+            interpret=False).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    # what each kernel writes: o and dv as wide as the values, dq and dk
+    # as wide as the scores
+    wrote = {name: re.search(rf"%{name}[.\d]* = (.*?) custom-call\(",
+                             text).group(1)
+             for name in ("pt_flash_fwd", "pt_flash_dq", "pt_flash_dkdv")}
+    assert wrote["pt_flash_fwd"].count("bf16[64,8192,128]") == 1
+    assert wrote["pt_flash_dq"].count("bf16[64,8192,256]") == 1
+    assert (wrote["pt_flash_dkdv"].index("bf16[64,8192,256]")
+            < wrote["pt_flash_dkdv"].index("bf16[64,8192,128]"))
+
+
 def test_flash_key_padding_mask_compiles(one_chip):
     q, kv = _attn_shapes(one_chip)
     mask = jax.ShapeDtypeStruct((B, T), jnp.bool_, sharding=one_chip)

@@ -9,6 +9,8 @@ Usage (on real TPU; refuses to record from CPU/interpret timings):
   python tools/pallas_tune.py --attention 32,128,12,64 --causal
   python tools/pallas_tune.py --attention 8,2048,16,8,128 --causal \
       --dtype bf16 --dtype f32                     # GQA 16 q / 8 kv heads
+  python tools/pallas_tune.py --attention 2,8192,32,256 --value-width 128 \
+      --causal --blocks 512,1024    # latent attention's 192 / 128 as padded
   python tools/pallas_tune.py --matmul 1024,1024,1024
   python tools/pallas_tune.py --dry-run            # print, don't persist
 
@@ -80,12 +82,15 @@ def _time_reps(fn, *args, reps=5):
 
 
 def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
-                   dtype="bf16"):
+                   dtype="bf16", e=None, blocks=None):
     """Sweep the flash blocks for one shape at one OPERAND TYPE (the
     table is keyed by it: bf16 is what the kernels see under mixed_bf16
     and bfloat16 policies, f32 under the float32 policy). ``kv_heads``
     < ``h`` runs the GQA form: the dk/dv kernel then runs per q head
-    and the groups are summed after it, as in training."""
+    and the groups are summed after it, as in training. ``e`` is the
+    value width where it is not ``d`` (the table is keyed by it too);
+    ``blocks`` the candidates, where not all of ``ATTN_BLOCKS`` (a long
+    shape's whole sweep is tens of chip-minutes)."""
     import jax
     import jax.numpy as jnp
 
@@ -94,16 +99,17 @@ def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
     kv_heads = kv_heads or h
+    e = e or d
     jdtype = jnp.dtype(ATTN_DTYPES[dtype])
     rng = np.random.default_rng(0)
-    mk = lambda heads=h: jnp.asarray(rng.normal(size=(b, t, heads, d))
-                                     .astype(np.float32)).astype(jdtype)
-    q, k, v = mk(), mk(kv_heads), mk(kv_heads)
+    mk = lambda heads=h, w=d: jnp.asarray(
+        rng.normal(size=(b, t, heads, w)).astype(np.float32)).astype(jdtype)
+    q, k, v = mk(), mk(kv_heads), mk(kv_heads, e)
     # a RANDOM cotangent keeps the comparison honest: grad of a plain
     # .sum() hands XLA a constant all-ones dO it can fold through its
     # transparent backward, while the opaque Pallas kernel sees a real
     # tensor either way
-    ct = mk().astype(jnp.float32)
+    ct = mk(w=e).astype(jnp.float32)
 
     def grad_of(fn):
         g = jax.jit(jax.grad(lambda q, k, v: (fn(q, k, v).astype(
@@ -114,7 +120,7 @@ def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
     # (e.g. t=64 vs ATTN_BLOCKS starting at 128) fall back to block=t so
     # short-sequence shapes still get a real flash measurement instead of
     # an empty sweep that would persist use_flash=False unmeasured
-    cand = [blk for blk in ATTN_BLOCKS if blk <= t] or [t]
+    cand = [blk for blk in blocks or ATTN_BLOCKS if blk <= t] or [t]
 
     def sweep(what, build):
         results = []
@@ -149,19 +155,27 @@ def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
                 block_q_bwd=bq, block_k_bwd=bk, interpret=False)))
     best_bwd = min(bwd_results) if bwd_results else None
 
-    xf = jax.jit(lambda q, k, v: xla_attention(q, k, v, causal=causal))
-    x_fwd, _ = _time_reps(xf, q, k, v)
-    x_bwd, _ = _time_reps(grad_of(lambda q, k, v: xla_attention(
-        q, k, v, causal=causal)), q, k, v)
-    x_total = x_fwd + x_bwd
-    print(f"  xla fallback: fwd {x_fwd*1e3:.3f}ms grad {x_bwd*1e3:.3f}ms")
-
-    key = tuning.attention_key(t, t, d, causal, dtype=jdtype)
     ms = lambda x: round(x * 1e3, 4)
+    measured = {"shape": [b, t, h, kv_heads, d] + [e] * (e != d)}
+    try:
+        xf = jax.jit(lambda q, k, v: xla_attention(q, k, v, causal=causal))
+        x_fwd, _ = _time_reps(xf, q, k, v)
+        x_bwd, _ = _time_reps(grad_of(lambda q, k, v: xla_attention(
+            q, k, v, causal=causal)), q, k, v)
+        x_total = x_fwd + x_bwd
+        print(f"  xla fallback: fwd {x_fwd*1e3:.3f}ms "
+              f"grad {x_bwd*1e3:.3f}ms")
+        measured.update(xla_ms=ms(x_total), xla_fwd_ms=ms(x_fwd),
+                        xla_grad_ms=ms(x_bwd))
+    except Exception as err:  # noqa: BLE001 — the (B, H, T, T) score
+        # of a long shape does not fit the device: nothing to lose to
+        x_total = float("inf")
+        print(f"  xla fallback: FAILED ({type(err).__name__}: "
+              f"{str(err)[:120]})")
+        measured.update(xla_note="the XLA fallback did not run")
+
+    key = tuning.attention_key(t, t, d, causal, dtype=jdtype, e=e)
     table = lambda rs: {f"{bq}x{bk}": ms(dt) for dt, bq, bk, _ in rs}
-    measured = {"shape": [b, t, h, kv_heads, d],
-                "xla_ms": ms(x_total), "xla_fwd_ms": ms(x_fwd),
-                "xla_grad_ms": ms(x_bwd)}
     if best_fwd is None:
         entry = {"use_flash": False, "note": "no flash config compiled"}
     elif best_bwd is None:
@@ -384,6 +398,12 @@ def main():
     ap.add_argument("--attention", action="append", default=None,
                     metavar="B,T,H[,KV],D",
                     help="attention shape to tune (KV: GQA kv heads)")
+    ap.add_argument("--value-width", type=int, default=None, metavar="E",
+                    help="the value width of every --attention shape, "
+                    "where it is not D (v, o: E wide; q, k: D)")
+    ap.add_argument("--blocks", default=None, metavar="N,N",
+                    help="the attention block candidates (default: "
+                    + ",".join(map(str, ATTN_BLOCKS)) + ")")
     ap.add_argument("--dtype", action="append", default=None,
                     choices=sorted(ATTN_DTYPES),
                     help="attention operand type(s) to sweep; the table "
@@ -432,9 +452,12 @@ def main():
         for causal, dtype in itertools.product(causal_set,
                                                args.dtype or ["bf16"]):
             print(f"tuning attention b={b} t={t} h={h} kv={kv} d={d} "
-                  f"causal={causal} {dtype} on {backend}")
-            tune_attention(b, t, h, d, causal, dry_run=args.dry_run,
-                           kv_heads=kv, dtype=dtype)
+                  f"e={args.value_width or d} causal={causal} {dtype} "
+                  f"on {backend}", flush=True)
+            tune_attention(
+                b, t, h, d, causal, dry_run=args.dry_run, kv_heads=kv,
+                dtype=dtype, e=args.value_width,
+                blocks=args.blocks and list(map(int, args.blocks.split(","))))
     for (m, n, k) in gemm:
         print(f"tuning int8 gemm m={m} n={n} k={k} on {backend}")
         tune_matmul(m, n, k, dry_run=args.dry_run)
