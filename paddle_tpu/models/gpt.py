@@ -172,21 +172,11 @@ class GPTForCausalLM(Layer):
         """Mean next-token CE. ``labels`` default to ids shifted left
         (standard causal-LM training); pass explicit labels with
         ``ignore_index`` holes for masked/padded positions."""
-        from ..ops.fused_loss import mean_linear_cross_entropy
-
         x = self._trunk(ids, kv_mask=kv_mask)
         with scope("head"):     # the head itself is ``linear_ce``
             h = self.norm_f(x)
-        if labels is None:
-            labels = jnp.concatenate(
-                [ids[:, 1:],
-                 jnp.full((ids.shape[0], 1), ignore_index, ids.dtype)],
-                axis=1)
-        b, t, d = h.shape
-        w = self._head_weight()
-        return mean_linear_cross_entropy(
-            h.reshape(b * t, d), w, None, labels.reshape(-1),
-            chunk=vocab_chunk, ignore_index=ignore_index)
+        return next_token_loss(h, self._head_weight(), ids, labels,
+                               vocab_chunk, ignore_index)
 
     def init_cache(self, batch: int, capacity: int, dtype=None):
         """The decode cache ``serving.BatchedDecoder`` holds as its
@@ -388,6 +378,26 @@ class GPTForCausalLM(Layer):
         """KV-cached greedy continuation — generate(temperature=0)."""
         return self.generate(prompt_ids, max_len, temperature=0.0,
                              capacity=capacity)
+
+
+def next_token_loss(h, head_weight, ids, labels=None,
+                    vocab_chunk: int = 1024, ignore_index: int = -100):
+    """The training tail of both causal-LM shells: mean cross-entropy of
+    the final hidden states ``h`` (B, T, D) under ``head_weight`` (D, V)
+    against ``labels`` (default: ``ids`` shifted left, the last position
+    ignored), by the fused chunked head (``ops/fused_loss.py``, scope
+    ``linear_ce``): the (B, T, V) logits never exist."""
+    from ..ops.fused_loss import mean_linear_cross_entropy
+
+    if labels is None:
+        labels = jnp.concatenate(
+            [ids[:, 1:],
+             jnp.full((ids.shape[0], 1), ignore_index, ids.dtype)],
+            axis=1)
+    b, t, d = h.shape
+    return mean_linear_cross_entropy(
+        h.reshape(b * t, d), head_weight, None, labels.reshape(-1),
+        chunk=vocab_chunk, ignore_index=ignore_index)
 
 
 def loss_fn(logits, labels, ignore_index: int = -100):

@@ -1,21 +1,43 @@
 """A causal-LM shell whose blocks are built from kinds: a token mixer
 and a channel mix a block, chosen by the configuration.
 
-``layer_types[i]`` names block ``i``'s MIXER:
+``layer_types[i]`` names block ``i``'s MIXER, a key of :data:`MIXERS`:
 
-- ``"mamba"``: a Mamba-2 (SSD) state-space layer (:class:`SSDMixer`);
-- ``"attention"``: GQA softmax attention without positional encoding
-  (``nn.MultiHeadAttention``);
-- ``"retention"``: power retention of degree 2, linear attention whose
-  score is the square of a dot product (:class:`RetentionMixer`), with
-  a per-head RMSNorm and a rotary embedding on queries and keys and
-  one learned gate a key-value head;
-- ``"latent"``: multi-head latent attention (``nn.LatentAttention``):
-  low-rank queries, one compressed record a position for all heads, a
-  (YaRN) rotary part, decompressed in a prefill and read absorbed by a
-  decode step; with ``index_topk`` a learned indexer in front of it
-  picks the positions each query attends, and its key is a third array
-  of the record.
+- ``"mamba"``: :class:`SSDMixer`, a Mamba-2 (SSD) state-space layer;
+- ``"attention"``: :class:`SoftmaxMixer`, GQA softmax attention without
+  positional encoding (an ``nn.MultiHeadAttention``);
+- ``"retention"``: :class:`RetentionMixer`, power retention of degree
+  2, linear attention whose score is the square of a dot product;
+- ``"latent"``: ``nn.LatentAttention``, multi-head latent attention:
+  one compressed record a position for all heads, decompressed in a
+  prefill and read absorbed by a decode step; with ``index_topk`` a
+  learned indexer picks the positions each query attends.
+
+**A mixer is one convention**, and the shell knows no kind by name. A
+mixer is built from the configuration (``MIXERS[kind](cfg)``, reading
+its own fields) and answers, whether or not it reads an argument:
+
+- ``init_cache(batch, capacity, dtype) -> cache``: ONE pytree, every
+  leaf with the sequence (slot) axis first, handed to the entries below
+  whole and handed back whole;
+- ``mixer(h) -> a``: causal, from empty state (training, ``forward``);
+- ``forward_chunk(h, cache, t0, valid_len, decode_kernel) -> (a,
+  cache)``: S positions at cache indices [t0, t0 + S), of which a
+  recurrence advances over the first ``valid_len`` only;
+- ``forward_step(h, cache, t, decode_kernel)`` and
+  ``forward_step_rows(h, cache, t_rows, decode_kernel) -> (a, cache)``:
+  one position a row at one cursor, or at per-row cursors (B,);
+- class attributes: ``state_kind`` (``"kv"``, addressed by position, or
+  ``"recurrent"``, a state of fixed size), ``cache_record`` (what a
+  ``"kv"`` cache holds: ``"heads"``, keys and values by head, or
+  ``"latent"``; None for a state), ``cached_scope`` / ``empty_scope``
+  (the scope its whole sublayer runs under in a cached call and from
+  empty state, or None: the mixer then enters scopes of its own around
+  itself alone, and the norms and adds beside it are under none);
+- ``counted``: the names and int32 values its latest cached call
+  counted ({} for a mixer that counts nothing). The residual path
+  reports the same way, and the shell sums by name
+  (:meth:`HybridForCausalLM.step_counters`).
 
 ``channel_mix`` names what follows the mixer, in every block (one
 name) or block by block (one name a block, as ``layer_types``):
@@ -54,25 +76,14 @@ default); the head is the embedding transposed or, with
 (the plain path; with hyper-connections ``u, held = read(X)``, ``X =
 write(X, F(RMSNorm(u)), held)`` around each sublayer).
 
-What decoding keeps a sequence differs by mixer: attention keeps keys
-and values by position, (K, V) of shape (slots, capacity, kv_heads,
-head_dim); a state-space block keeps a state of fixed size,
-(convolution tail (slots, conv - 1, channels), S (slots, heads,
-head_dim, state) float32); a retention block keeps (S (slots,
-kv_heads, D, head_dim), z (slots, kv_heads, D)) float32 with ``D`` =
-``ops.retention.phi_dim(head_dim)``, 34 MB a slot at head dimension
-128 whatever the context; a latent block keeps its records by
-position, (c (slots, capacity, kv_rank), r (slots, capacity, rope))
-and, with an indexer, k^I (slots, capacity, index_head_dim): a ``"kv"``
-mixer's cache is whatever tuple its ``init_cache`` gives.
-:meth:`HybridForCausalLM.init_cache` gives
-the list, one entry a block, ``cache_kinds`` says which is which
-(``"kv"``, addressed by position, or ``"recurrent"``) and
-``cache_records`` what a ``"kv"`` entry holds (``"heads"``: keys and
-values by head, or ``"latent"``);
-``serving.BatchedDecoder`` holds it as its arena. A recurrent mixer
-that needs positions (retention's rotary embedding) says so
-(``takes_positions``) and is handed the cursors attention is.
+What decoding keeps a sequence is the mixer's own, and its
+``init_cache`` says what: keys and values by position, a state of fixed
+size (retention's is 34 MB a slot at head dimension 128 whatever the
+context), or one compressed record a position.
+:meth:`HybridForCausalLM.init_cache` gives the list, one entry a block,
+``cache_kinds`` and ``cache_records`` are the blocks' ``state_kind``
+and ``cache_record``, and ``serving.BatchedDecoder`` holds the list as
+its arena.
 
 TRAINING goes through :meth:`HybridForCausalLM.forward_loss` under the
 one ``parallel.Trainer``, as ``models/gpt.py``'s does: the blocks from
@@ -100,8 +111,8 @@ from ..nn.layer import Layer
 from ..ops import retention, ssm
 from ..ops.attention import rotary_embedding
 from ..telemetry.scopes import scope
+from .gpt import next_token_loss
 
-MIXERS = ("mamba", "attention", "retention", "latent")
 CHANNEL_MIXES = ("experts", "mlp")
 
 
@@ -251,9 +262,12 @@ class SSDMixer(Layer):
     causal convolution (kernel ``conv``, with bias) and SiLU on ``xBC``;
     the selective state-space recurrence (``ops/ssm.py``) with one group
     of B and C; RMSNorm of ``y * silu(z)`` over the whole inner width;
-    an output projection. Decays and the state are float32."""
+    an output projection. Decays and the state are float32. A state
+    has no cursor: ``t0``, ``t`` and ``decode_kernel`` are not read."""
 
-    state_kind = "recurrent"
+    state_kind, cache_record = "recurrent", None
+    cached_scope = empty_scope = None
+    counted = {}
 
     def __init__(self, cfg: HybridConfig):
         super().__init__()
@@ -310,7 +324,8 @@ class SSDMixer(Layer):
         y = y.reshape(*z.shape).astype(z.dtype) * jax.nn.silu(z)
         return self.out_proj(self.norm(y))
 
-    def forward_chunk(self, x, cache, valid_len=None):
+    def forward_chunk(self, x, cache, t0=0, valid_len=None,
+                      decode_kernel: bool = False):
         """``x`` (B, S, D) continuing ``cache``; only the first
         ``valid_len`` positions (default all) advance it. Returns
         (out (B, S, D), new cache)."""
@@ -326,7 +341,7 @@ class SSDMixer(Layer):
                 self.D, self.chunk, state, valid_len)
             return self._finish(y, z), (tail, state)
 
-    def forward_step(self, x, cache):
+    def forward_step(self, x, cache, t=None, decode_kernel: bool = False):
         """One position a row: ``x`` (B, 1, D) -> (out (B, 1, D), new
         cache)."""
         tail, state = cache
@@ -341,6 +356,8 @@ class SSDMixer(Layer):
                 self.D, state)
             return self._finish(y, z)[:, None], (tail, state)
 
+    forward_step_rows = forward_step
+
     def forward(self, x):
         return self.forward_chunk(x, self.init_cache(x.shape[0], 0,
                                                      x.dtype))[0]
@@ -352,10 +369,14 @@ class RetentionMixer(Layer):
     and keys, then the rotary embedding; ``log g = logsigmoid(gate)``,
     one a key-value head, float32; the state's update and read
     (``retention_step``) or the chunked form over a sequence; an output
-    projection. The state and the denominators are float32."""
+    projection. The state and the denominators are float32. A step
+    counts ``retention_small_norm``: how many of its (row, head)
+    denominators fell under ``10 eps`` (an idle slot's junk row counted
+    too); a chunk counts none."""
 
-    state_kind = "recurrent"
-    takes_positions = True
+    state_kind, cache_record = "recurrent", None
+    cached_scope = empty_scope = None
+    counted = {}
 
     def __init__(self, cfg: HybridConfig):
         super().__init__()
@@ -374,11 +395,12 @@ class RetentionMixer(Layer):
         self.q_norm = nn.RMSNorm(hd, epsilon=cfg.rms_norm_eps)
         self.k_norm = nn.RMSNorm(hd, epsilon=cfg.rms_norm_eps)
         self.out_proj = nn.Linear(self.heads * hd, h, bias_attr=False)
-        self.small_norm = None
 
     def init_cache(self, batch: int, capacity: int, dtype=None):
-        """(S, z) of a sequence that has seen no token, float32
-        whatever ``dtype``; ``capacity`` does not size it."""
+        """(S (B, kv_heads, D, head_dim), z (B, kv_heads, D)), ``D`` =
+        ``ops.retention.phi_dim(head_dim)``, of a sequence that has seen
+        no token: float32 whatever ``dtype``, and not sized by
+        ``capacity``."""
         return retention.zero_state(batch, self.kv_heads, self.head_dim)
 
     def _project(self, x, positions):
@@ -397,7 +419,8 @@ class RetentionMixer(Layer):
     def _finish(self, y, x):
         return self.out_proj(y.astype(x.dtype).reshape(*x.shape[:2], -1))
 
-    def forward_chunk(self, x, cache, t0, valid_len=None):
+    def forward_chunk(self, x, cache, t0=0, valid_len=None,
+                      decode_kernel: bool = False):
         """``x`` (B, S, D) at positions [t0, t0 + S) continuing
         ``cache``; only the first ``valid_len`` positions (default all)
         advance it. Returns (out (B, S, D), new cache)."""
@@ -406,46 +429,112 @@ class RetentionMixer(Layer):
                 x, t0 + jnp.arange(x.shape[1]))
             y, cache = retention.retention_chunked(
                 q, k, v, log_g, self.chunk, cache, valid_len, self.eps)
-            self.small_norm = jnp.int32(0)      # a chunk counts none
+            self.counted = {"retention_small_norm": jnp.int32(0)}
             return self._finish(y, x), cache
 
-    def forward_step(self, x, cache, t_rows):
+    def forward_step(self, x, cache, t, decode_kernel: bool = False):
+        """:meth:`forward_step_rows` with every row at the cursor ``t``."""
+        return self.forward_step_rows(
+            x, cache, jnp.broadcast_to(t, x.shape[:1]))
+
+    def forward_step_rows(self, x, cache, t_rows,
+                          decode_kernel: bool = False):
         """One position a row at per-row positions ``t_rows`` (B,):
-        ``x`` (B, 1, D) -> (out (B, 1, D), new cache). ``small_norm``
-        then holds how many of the step's (row, head) denominators fell
-        under ``10 eps`` (an idle slot's junk row counted too)."""
+        ``x`` (B, 1, D) -> (out (B, 1, D), new cache)."""
         with scope("retention_step"):
             q, k, v, log_g = self._project(x, t_rows[:, None])
             num, den, cache = retention.retention_step_parts(
                 q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], cache)
-            self.small_norm = jnp.sum(den < 10 * self.eps, dtype=jnp.int32)
+            self.counted = {"retention_small_norm": jnp.sum(
+                den < 10 * self.eps, dtype=jnp.int32)}
             y = num / (den[..., None] + self.eps)
             return self._finish(y[:, None], x), cache
 
     def forward(self, x):
-        return self.forward_chunk(
-            x, self.init_cache(x.shape[0], 0), 0)[0]
+        return self.forward_chunk(x, self.init_cache(x.shape[0], 0))[0]
+
+
+class SoftmaxMixer(nn.MultiHeadAttention):
+    """GQA softmax attention without positional encoding or biases, the
+    scores times ``cfg.attention_multiplier``: ``nn.MultiHeadAttention``
+    answering the mixers' convention, its cache the pair (K, V). The
+    whole sublayer (norm1, the mixer, the residual add) runs under
+    ``attn``, cached or not."""
+
+    state_kind, cache_record = "kv", "heads"
+    cached_scope = empty_scope = "attn"
+    counted = {}
+
+    def __init__(self, cfg: HybridConfig):
+        super().__init__(
+            cfg.hidden_size, cfg.num_heads, bias=False,
+            use_flash=cfg.use_flash,
+            num_kv_heads=cfg.num_kv_heads or cfg.num_heads,
+            rotary=False, scale=cfg.attention_multiplier)
+
+    def forward(self, x):
+        return super().forward(x, causal=True)
+
+    def forward_chunk(self, x, cache, t0=0, valid_len=None,
+                      decode_kernel: bool = False):
+        a, ck, cv = super().forward_chunk(x, *cache, t0,
+                                          decode_kernel=decode_kernel)
+        return a, (ck, cv)
+
+    def forward_step(self, x, cache, t, decode_kernel: bool = False):
+        return self.forward_chunk(x, cache, t, None, decode_kernel)
+
+    def forward_step_rows(self, x, cache, t_rows,
+                          decode_kernel: bool = False):
+        a, ck, cv = super().forward_step_rows(x, *cache, t_rows,
+                                              decode_kernel=decode_kernel)
+        return a, (ck, cv)
+
+
+def _latent(cfg: HybridConfig):
+    return LatentAttention(
+        cfg.hidden_size, cfg.num_heads, cfg.q_lora_rank or 0,
+        cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+        cfg.v_head_dim, cfg.rope_theta, cfg.rope_yarn,
+        cfg.rope_mscale_all_dim, cfg.rms_norm_eps, cfg.index_n_heads,
+        cfg.index_head_dim, cfg.index_topk)
+
+
+# a kind's name -> what builds its mixer from the configuration; a new
+# kind is a class that answers the convention and one line here
+MIXERS = {"mamba": SSDMixer, "attention": SoftmaxMixer,
+          "retention": RetentionMixer, "latent": _latent}
+
+
+def _under(name):
+    return scope(name) if name else contextlib.nullcontext()
+
+
+def tally(totals: dict, counted: dict):
+    """Add what a part of a block counted to the call's, by name and in
+    the names' order (not the order a part built its dictionary in)."""
+    for name in sorted(counted):
+        totals[name] = totals.get(name, 0) + counted[name]
 
 
 class HybridBlock(Layer):
     """Two sublayers over one residual path (``res1``, ``res2``:
     ``u, held = read(X)``, ``X = write(X, F(norm(u)), held)``): the
-    mixer ``kind`` names (``mamba``, ``attention``, ``retention``,
-    ``latent``), then the channel mix ``mix`` names, routed experts plus
-    a shared MLP (``moe``, ``shared``) or one gated MLP (``mlp``). The
-    path is the plain ``X + m F(norm(X))`` or, with ``cfg.hc_mult`` > 1,
-    hyper-connections over that many streams, each sublayer with maps
-    of its own."""
+    mixer ``MIXERS[kind]`` builds, then the channel mix ``mix`` names,
+    routed experts plus a shared MLP (``moe``, ``shared``) or one gated
+    MLP (``mlp``). The path is the plain ``X + m F(norm(X))`` or, with
+    ``cfg.hc_mult`` > 1, hyper-connections over that many streams, each
+    sublayer with maps of its own."""
 
     def __init__(self, cfg: HybridConfig, kind: str,
                  mix: Optional[str] = None):
         super().__init__()
         mix = cfg.channel_mix if mix is None else mix
         enforce(kind in MIXERS, "layer type %r is none of %s", kind,
-                MIXERS)
+                tuple(MIXERS))
         enforce(mix in CHANNEL_MIXES, "channel mix %r is none of %s",
                 mix, CHANNEL_MIXES)
-        self.kind, self.m = kind, float(cfg.residual_multiplier)
+        self.m = float(cfg.residual_multiplier)
         if cfg.hc_mult > 1:
             enforce(self.m == 1.0, "hyper-connections carry no residual "
                     "multiplier, got %s", self.m)
@@ -457,23 +546,7 @@ class HybridBlock(Layer):
             self.res1 = self.res2 = PlainResidual(self.m,
                                                   cfg.settle_residual)
         self.norm1 = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
-        if kind == "mamba":
-            self.mixer = SSDMixer(cfg)
-        elif kind == "retention":
-            self.mixer = RetentionMixer(cfg)
-        elif kind == "latent":
-            self.mixer = LatentAttention(
-                cfg.hidden_size, cfg.num_heads, cfg.q_lora_rank or 0,
-                cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-                cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.rope_theta,
-                cfg.rope_yarn, cfg.rope_mscale_all_dim, cfg.rms_norm_eps,
-                cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk)
-        else:
-            self.mixer = nn.MultiHeadAttention(
-                cfg.hidden_size, cfg.num_heads, bias=False,
-                use_flash=cfg.use_flash,
-                num_kv_heads=cfg.num_kv_heads or cfg.num_heads,
-                rotary=False, scale=cfg.attention_multiplier)
+        self.mixer = MIXERS[kind](cfg)
         self.norm2 = nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
         self.moe = None
         if mix == "mlp":
@@ -486,54 +559,39 @@ class HybridBlock(Layer):
             bias_update_rate=cfg.router_bias_update_rate)
         self.shared = GatedMLP(cfg.hidden_size, cfg.shared_width)
 
-    @property
-    def state_kind(self) -> str:
-        return getattr(self.mixer, "state_kind", "kv")
-
     def channel_mix(self, x, with_load: bool = False):
-        """(the state after the channel mix's sublayer, the (held,)
-        tokens each held expert got, or None where there are no
-        experts) and, ``with_load``, a third: the (num_experts,) pairs
-        each router output got, or None."""
+        """(the state after the channel mix's sublayer, what it counted):
+        with experts ``expert_tokens``, the (held,) tokens each held
+        expert got, and, ``with_load``, ``expert_load``, the
+        (num_experts,) pairs each router output got; else nothing."""
         u, held = self.res2.read(x)
         if self.moe is None:
             with scope("mlp"):
                 y = self.mlp(self.norm2(u))
-            return (self.res2.write(x, y, held), None) + (None,) * with_load
+            return self.res2.write(x, y, held), {}
         u = self.norm2(u)
         routed, *counts = self.moe.forward_counted(u, with_load)
         with scope("moe_shared"):
             shared = self.shared(u)
-        return (self.res2.write(x, routed + shared, held), *counts)
-
-    def mixer_scope(self):
-        """``attn`` around the whole sublayer of a softmax-attention
-        block (norm1, the mixer, the residual add); the other mixers
-        enter scopes of their own around the mixer alone, and the norms
-        and adds beside them are under none."""
-        return (scope("attn") if self.kind == "attention"
-                else contextlib.nullcontext())
+        return (self.res2.write(x, routed + shared, held),
+                dict(zip(("expert_tokens", "expert_load"), counts)))
 
     def forward_counted(self, x):
         """The block from empty state, a pure function of ``x`` (what
         ``jax.checkpoint`` wraps): (the state after both sublayers, the
         (held,) tokens each held expert got or None, the (num_experts,)
         load of every router output or None where the block has no bias
-        rule). A softmax-attention OR latent block's mixer sublayer runs
-        under ``attn`` here (norm1, the mixer with ``mla_prefill``
-        inside, the residual add): a training step's table reads it as
-        the dense decoder's."""
-        with (scope("attn") if self.kind in ("attention", "latent")
-              else contextlib.nullcontext()):
+        rule). The mixer's sublayer (norm1, the mixer, the residual add)
+        runs under the mixer's ``empty_scope`` (``attn`` for both
+        attentions: a training step's table reads it as the dense
+        decoder's); what the mixer and the residual path count stays
+        inside."""
+        with _under(self.mixer.empty_scope):
             u, held = self.res1.read(x)
-            h = self.norm1(u)
-            a = (self.mixer(h, causal=True)
-                 if self.kind in ("attention", "latent")
-                 else self.mixer(h))
-            x = self.res1.write(x, a, held)
+            x = self.res1.write(x, self.mixer(self.norm1(u)), held)
         with_load = self.moe is not None and bool(self.moe.bias_update_rate)
-        out = self.channel_mix(x, with_load)
-        return out if with_load else (*out, None)
+        x, counted = self.channel_mix(x, with_load)
+        return x, counted.get("expert_tokens"), counted.get("expert_load")
 
     def forward(self, x):
         return self.forward_counted(x)[0]
@@ -560,46 +618,35 @@ class HybridForCausalLM(Layer):
             self.create_parameter(
                 "lm_head", (cfg.hidden_size, cfg.vocab_size), None,
                 I.XavierUniform())
-        self.cache_kinds = [blk.state_kind for blk in self.blocks]
-        self.cache_records = [
-            getattr(blk.mixer, "cache_record", "heads")
-            if kind == "kv" else None
-            for blk, kind in zip(self.blocks, self.cache_kinds)]
+        self.cache_kinds = [blk.mixer.state_kind for blk in self.blocks]
+        self.cache_records = [blk.mixer.cache_record for blk in self.blocks]
         self._counted = {}
 
     def init_cache(self, batch: int, capacity: int, dtype=None):
-        """One pytree a block, every leaf with the sequence (slot) axis
-        first: (K, V) for attention, (tail, S) for a state-space block,
-        (S, z) for a retention block, (c, r) or (c, r, k^I) for a latent
-        block."""
+        """One pytree a block, its mixer's own, every leaf with the
+        sequence (slot) axis first."""
         return [blk.mixer.init_cache(batch, capacity, dtype)
                 for blk in self.blocks]
 
     def step_counters(self):
         """What the latest cached call, or the latest call from empty
         state (:meth:`forward`, :meth:`forward_loss`: a training step),
-        counted, for the program that made the call to return. With
-        routed experts: ``expert_tokens``
-        (held,) int32, the (token, pick) pairs each held expert got,
-        summed over blocks; ``expert_dense_layers`` int32, the expert
-        layers of the call whose rows took the dense body of
-        ``nn.moe.dropless_moe`` (the trace fixes it:
-        :meth:`expert_layers`); a call from empty state fills these two
-        and, where the routers have the bias rule
+        counted, for the program that made the call to return: int32
+        sums over the blocks, by the name each part reports under (its
+        own docstring says what it counts). A cached call: each mixer's
+        ``counted`` (``RetentionMixer``: ``retention_small_norm``;
+        ``nn.LatentAttention`` with an indexer: ``dsa_positions_live`` /
+        ``dsa_positions_read``), the residual path's
+        (``nn.latent.HyperConnection``: ``mhc_unbalanced``), the channel
+        mix's (``expert_tokens`` (held,)) and, with routed experts, the
+        shell's own ``expert_dense_layers``, the expert layers of the
+        call whose rows took the dense body of ``nn.moe.dropless_moe``
+        (the trace fixes it: :meth:`expert_layers`). A call from empty
+        state: the last two and, where the routers have the bias rule
         (``router_bias_update_rate``), ``expert_load`` (expert layers,
-        num_experts) int32, the pairs each router output got a layer,
-        and NONE of the names below (its mixers' own counts are left
-        inside ``jax.checkpoint``). With retention
-        blocks: ``retention_small_norm`` int32, the (row, head, block)
-        denominators of a step that fell under ``10 retention_eps``
-        (idle rows' included; a chunk counts none). With
-        hyper-connections: ``mhc_unbalanced`` int32, the (position,
-        sublayer) maps whose ``H_res`` has a row or column sum off 1 by
-        more than 1e-3 after the Sinkhorn rounds. With an indexer:
-        ``dsa_positions_live`` / ``dsa_positions_read`` int32, the
-        records a step's rows held and those their attention was given,
-        summed over rows (idle rows' too) and latent blocks (a chunk
-        counts none). Valid only inside the trace of that call."""
+        num_experts); what its mixers and residual paths count is left
+        inside ``jax.checkpoint``. Valid only inside the trace of that
+        call."""
         return dict(self._counted)
 
     @scope("embed")
@@ -650,10 +697,10 @@ class HybridForCausalLM(Layer):
                     with scope("moe_bias_update"):
                         blk.moe.bias_update(load)
         self._counted = {}
-        if "experts" in self.cfg.channel_mixes():
-            self._counted.update(
-                expert_tokens=tokens, expert_dense_layers=jnp.int32(
-                    self.expert_layers(ids.shape[0] * ids.shape[1])[1]))
+        layers, dense = self.expert_layers(ids.shape[0] * ids.shape[1])
+        if layers:
+            self._counted.update(expert_tokens=tokens,
+                                 expert_dense_layers=jnp.int32(dense))
         if loads:
             self._counted["expert_load"] = jnp.stack(loads)
         return x
@@ -667,28 +714,17 @@ class HybridForCausalLM(Layer):
         (``parallel.Trainer`` through ``functional_call(...,
         method="forward_loss")``, as ``GPTForCausalLM.forward_loss``):
         the blocks from empty state (:meth:`_trunk`: remat, the routers'
-        bias rule), the final norm under ``head``, then the fused
-        chunked linear cross-entropy (``ops/fused_loss.py``, scope
-        ``linear_ce``): the (B, T, V) logits never exist. ``labels``
-        default to ``ids`` shifted left; equal to ``models.gpt.loss_fn``
-        over :meth:`forward`'s logits (``tests/test_hybrid_train.py``).
-        The routers' new state comes back as the call's buffers."""
-        from ..ops.fused_loss import mean_linear_cross_entropy
-
+        bias rule), the final norm under ``head``, then the tail both
+        shells share (``models.gpt.next_token_loss``); equal to
+        ``models.gpt.loss_fn`` over :meth:`forward`'s logits
+        (``tests/test_hybrid_train.py``). The routers' new state comes
+        back as the call's buffers."""
         x = self._trunk(ids)
         with scope("head"):     # the head itself is ``linear_ce``
             h = self._final_hidden(x)
             h = h / jnp.asarray(self.cfg.logits_scaling, h.dtype)
-        if labels is None:
-            labels = jnp.concatenate(
-                [ids[:, 1:],
-                 jnp.full((ids.shape[0], 1), ignore_index, ids.dtype)],
-                axis=1)
-        b, t, d = h.shape
-        return mean_linear_cross_entropy(
-            h.reshape(b * t, d), self._head_weight(), None,
-            labels.reshape(-1), chunk=vocab_chunk,
-            ignore_index=ignore_index)
+        return next_token_loss(h, self._head_weight(), ids, labels,
+                               vocab_chunk, ignore_index)
 
     def expert_layers(self, rows: int, last_rows=None):
         """(the expert layers of a call over ``rows`` positions, cached
@@ -697,10 +733,9 @@ class HybridForCausalLM(Layer):
         ``nn.moe.dropless_moe``): static counts, so a caller that knows
         a program's shape knows them without running it; the second is
         the ``expert_dense_layers`` both kinds of call count.
-        ``last_rows``:
-        the rows of the last block's channel mix where the call is cut
-        to the head's position before it (``head_at``: a prefill of one
-        prompt leaves it 1 row)."""
+        ``last_rows``: the rows of the last block's channel mix where
+        the call is cut to the head's position before it (``head_at``: a
+        prefill of one prompt leaves it 1 row)."""
         n = dense = 0
         for i, blk in enumerate(self.blocks):
             if blk.moe is None:
@@ -710,63 +745,38 @@ class HybridForCausalLM(Layer):
             dense += blk.moe.streams_densely(last_rows if cut else rows)
         return n, dense
 
-    def _cached_blocks(self, x, caches, attn_step, rec_step, rec_at,
-                       head: bool = True, head_at=None):
-        """The cached block composition, written once over the mixed
-        block list: ``attn_step(mixer, h, *cache) -> (a, *cache)`` (keys
-        and values, or a latent mixer's two or three record arrays),
-        ``rec_step(mixer, h, cache) -> (a, cache)`` for a recurrent
-        mixer and ``rec_at(mixer, h, cache) -> (a, cache)`` for one that
-        takes positions are all that vary between the chunk, single-step
-        and per-row entries. It ends in the head over every position
-        (``head=True``), in none (``head=False``), or in the head at the
-        one position ``head_at`` (may be traced): ``x`` is cut to that
-        row as soon as only that row is wanted, after the last block's
-        mixer (whose cache every position writes), so the last block's
-        channel mix and the head have one row, and the logits are
-        (B, V)."""
-        new_caches, tokens, small, unbalanced = [], 0, 0, 0
-        live = read = 0
+    def _cached_blocks(self, x, caches, call, head: bool = True,
+                       head_at=None):
+        """The cached block composition, written once: ``call(mixer, h,
+        cache) -> (a, cache)``, the entry of the mixers' convention a
+        caller wants with its cursor bound, is all that varies between
+        the chunk, single-step and per-row entries; each mixer's
+        sublayer runs under its ``cached_scope``. It ends in the head
+        over every position (``head=True``), in none (``head=False``),
+        or in the head at the one position ``head_at`` (may be traced):
+        ``x`` is cut to that row as soon as only that row is wanted,
+        after the last block's mixer (whose cache every position
+        writes), so the last block's channel mix and the head have one
+        row, and the logits are (B, V)."""
+        new_caches, totals = [], {}
         rows, last = x.shape[0] * x.shape[1], len(self.blocks) - 1
-        last_rows = None if head_at is None else x.shape[0]
         for i, (blk, cache) in enumerate(zip(self.blocks, caches)):
-            with blk.mixer_scope():
+            with _under(blk.mixer.cached_scope):
                 u, held = blk.res1.read(x)
-                h = blk.norm1(u)
-                if blk.kind in ("attention", "latent"):
-                    a, *cache = attn_step(blk.mixer, h, *cache)
-                    cache = tuple(cache)
-                    if getattr(blk.mixer, "positions_live", None) is not None:
-                        live = live + blk.mixer.positions_live
-                        read = read + blk.mixer.positions_read
-                elif getattr(blk.mixer, "takes_positions", False):
-                    a, cache = rec_at(blk.mixer, h, cache)
-                    small = small + blk.mixer.small_norm
-                else:
-                    a, cache = rec_step(blk.mixer, h, cache)
+                a, cache = call(blk.mixer, blk.norm1(u), cache)
+                tally(totals, blk.mixer.counted)
                 x = blk.res1.write(x, a, held)
             if head_at is not None and i == last:
                 x = lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
-            x, got = blk.channel_mix(x)
-            if got is not None:
-                tokens = tokens + got
-            if self.cfg.hc_mult > 1:
-                unbalanced = (unbalanced + blk.res1.unbalanced
-                              + blk.res2.unbalanced)
+            x, counted = blk.channel_mix(x)
+            for part in (counted, blk.res1.counted, blk.res2.counted):
+                tally(totals, part)
             new_caches.append(cache)
-        self._counted = {}
-        if "experts" in self.cfg.channel_mixes():
-            self._counted.update(
-                expert_tokens=tokens, expert_dense_layers=jnp.int32(
-                    self.expert_layers(rows, last_rows)[1]))
-        if "retention" in self.cfg.layer_types:
-            self._counted["retention_small_norm"] = small
-        if self.cfg.hc_mult > 1:
-            self._counted["mhc_unbalanced"] = unbalanced
-        if self.cfg.index_topk and "latent" in self.cfg.layer_types:
-            self._counted.update(
-                dsa_positions_live=jnp.asarray(live, jnp.int32),
-                dsa_positions_read=jnp.asarray(read, jnp.int32))
+        layers, dense = self.expert_layers(
+            rows, None if head_at is None else x.shape[0])
+        if layers:
+            totals["expert_dense_layers"] = jnp.int32(dense)
+        self._counted = totals
         if head_at is not None:
             return self._head(x)[:, 0], new_caches
         return (self._head(x) if head else None), new_caches
@@ -782,22 +792,15 @@ class HybridForCausalLM(Layer):
         logits, (B, V), from the same pass that leaves the state after
         the whole prompt)."""
         return self._cached_blocks(
-            self._embed(toks), caches,
-            lambda sa, h, *cache: sa.forward_chunk(
-                h, *cache, t0, decode_kernel=decode_kernel),
-            lambda mx, h, c: mx.forward_chunk(h, c, valid_len),
-            lambda mx, h, c: mx.forward_chunk(h, c, t0, valid_len),
+            self._embed(toks), caches, lambda mx, h, c: mx.forward_chunk(
+                h, c, t0, valid_len, decode_kernel),
             head=head, head_at=head_at)
 
     def _step_logits(self, tok, caches, t, decode_kernel: bool = False):
         """One cached position: ``tok`` (B,) -> ((B, V), caches)."""
         logits, caches = self._cached_blocks(
             self._embed(tok[:, None]), caches,
-            lambda sa, h, *cache: sa.forward_step(
-                h, *cache, t, decode_kernel=decode_kernel),
-            lambda mx, h, c: mx.forward_step(h, c),
-            lambda mx, h, c: mx.forward_step(
-                h, c, jnp.broadcast_to(t, tok.shape)))
+            lambda mx, h, c: mx.forward_step(h, c, t, decode_kernel))
         return logits[:, 0], caches
 
     def _step_logits_rows(self, tok, caches, t_rows,
@@ -807,8 +810,6 @@ class HybridForCausalLM(Layer):
         attention and a mixer with a rotary embedding read ``t_rows``."""
         logits, caches = self._cached_blocks(
             self._embed(tok[:, None]), caches,
-            lambda sa, h, *cache: sa.forward_step_rows(
-                h, *cache, t_rows, decode_kernel=decode_kernel),
-            lambda mx, h, c: mx.forward_step(h, c),
-            lambda mx, h, c: mx.forward_step(h, c, t_rows))
+            lambda mx, h, c: mx.forward_step_rows(h, c, t_rows,
+                                                  decode_kernel))
         return logits[:, 0], caches

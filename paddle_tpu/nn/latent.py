@@ -48,11 +48,12 @@ class LatentAttention(Layer):
     (``ops.latent_attention.causal_attention``); a step runs the
     **absorbed** form over the records (``latent_read``): ``q'_j =
     W^K_j q^N_j`` against ``c``, the sum of ``p c`` times ``W^V_j``
-    afterwards, equal term by term. The three cached entries have
-    ``MultiHeadAttention``'s signatures with ``(c, r)`` in the place of
-    ``(K, V)``; ``decode_kernel`` is the shell's argument to every
-    attention mixer and is not read here: the bodies are chosen by
-    static shapes and the platform alone.
+    afterwards, equal term by term. The three cached entries are the
+    mixers' convention (``models/hybrid.py``): the cache goes in and
+    comes back whole, as :meth:`init_cache` gave it; ``valid_len`` and
+    ``decode_kernel`` are not read here (records past a prompt's end sit
+    above the cursor, and the bodies are chosen by static shapes and the
+    platform alone). From empty state the sublayer runs under ``attn``.
 
     **With an indexer** (``index_heads``, ``index_dim``, ``index_topk``
     all given; DeepSeek-V3.2-Exp's sparse attention) a query attends
@@ -69,16 +70,16 @@ class LatentAttention(Layer):
     with the rotary embedding on the first ``rope_dim`` numbers of
     ``q^I`` and ``k^I`` (unscaled frequencies), bfloat16 inputs and
     float32 sums. The cache then holds a third array a position, the
-    index key: :meth:`init_cache` gives ``(c, r, k^I)``, and the three
-    cached entries take and return all three, ``(x, c, r, k^I, t)``.
-    The indexer runs under the scope ``dsa_index``, beside the
-    mixer's own scopes and not inside them. After a step
-    ``positions_live`` / ``positions_read`` hold the records the
-    step's rows held and those their attention was given (int32, idle
-    rows' counted too; valid inside the trace of the call)."""
+    index key: :meth:`init_cache` gives ``(c, r, k^I)``. The indexer
+    runs under the scope ``dsa_index``, beside the mixer's own scopes
+    and not inside them. A step then counts ``dsa_positions_live`` /
+    ``dsa_positions_read`` (``counted``): the records the step's rows
+    held and those their attention was given (int32, idle rows' counted
+    too; a chunk counts none; valid inside the trace of the call)."""
 
-    state_kind = "kv"
-    cache_record = "latent"
+    state_kind, cache_record = "kv", "latent"
+    cached_scope, empty_scope = None, "attn"
+    counted = {}
 
     def __init__(self, hidden: int, num_heads: int, q_rank: int,
                  kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
@@ -114,7 +115,6 @@ class LatentAttention(Layer):
                 "dim %s topk %s", index_heads, index_dim, index_topk)
         self.index_heads, self.index_dim = index_heads, index_dim
         self.index_topk = index_topk
-        self.positions_live = self.positions_read = None
         if index_topk:
             enforce(rope_dim <= index_dim, "the indexer's rotary part "
                     "(%s) is wider than its heads (%s)", rope_dim, index_dim)
@@ -152,16 +152,13 @@ class LatentAttention(Layer):
         return q[..., :self.nope], rotary_embedding(
             q[..., self.nope:], positions, self.theta, self.yarn)
 
-    def _cache_and_cursor(self, rest):
-        """``rest`` of a cached entry's arguments after ``(x, c, r)``:
-        ``(t,)`` or, with an indexer, ``(cache_ki, t)`` -> (cache_ki or
-        None, t)."""
-        *cache_ki, t = rest
-        enforce(len(cache_ki) == bool(self.index_topk), "a latent mixer "
-                "%s an indexer takes %s cache arrays, got %s",
-                "with" if self.index_topk else "without",
-                2 + bool(self.index_topk), 2 + len(cache_ki))
-        return (cache_ki[0] if cache_ki else None), t
+    def _arrays(self, cache):
+        """``cache`` as (c, r, k^I or None)."""
+        n = 2 + bool(self.index_topk)
+        enforce(len(cache) == n, "a latent mixer with%s an indexer takes "
+                "%s cache arrays, got %s", "" if n == 3 else "out", n,
+                len(cache))
+        return (*cache, None)[:3]
 
     def _index_rope(self, a, positions):
         """The rotary embedding on the first ``rope`` numbers of (B, S,
@@ -276,17 +273,16 @@ class LatentAttention(Layer):
                        w[..., self.nope:], preferred_element_type=f32)
         return self.out_proj(o.astype(x.dtype).reshape(x.shape[0], 1, -1))
 
-    def forward_chunk(self, x, cache_c, cache_r, *rest,
+    def forward_chunk(self, x, cache, t0=0, valid_len=None,
                       decode_kernel: bool = False):
         """A prefill: ``x`` (B, S, D) at positions [0, S) writes their
         records there and attends each position over records ``<=`` its
         own (with an indexer: over its pick of them), decompressed.
-        ``rest`` is ``(t0,)`` or, with an indexer, ``(cache_ki, t0)``.
-        Returns (out (B, S, D), cache_c, cache_r[, cache_ki]). ``t0`` is
-        the static 0: a chunk that continues a cache would have to read
-        it, which no serving path does for a latent record
-        (``serving.BatchedDecoder`` refuses each by name)."""
-        cache_ki, t0 = self._cache_and_cursor(rest)
+        Returns (out (B, S, D), the cache). ``t0`` is the static 0: a
+        chunk that continues a cache would have to read it, which no
+        serving path does for a latent record (``serving.BatchedDecoder``
+        refuses each by name)."""
+        cache_c, cache_r, cache_ki = self._arrays(cache)
         enforce(isinstance(t0, int) and t0 == 0, "a latent chunk starts "
                 "at the static offset 0, got %r: a chunk that continues "
                 "a cache is not written", t0)
@@ -302,31 +298,29 @@ class LatentAttention(Layer):
             c, r = c.astype(cache_c.dtype), r.astype(cache_r.dtype)
             cache_c, cache_r = put(cache_c, c), put(cache_r, r)
             if not self.index_topk:
-                return self._decompressed(x, qn, qr, c, r), cache_c, cache_r
+                out = self._decompressed(x, qn, qr, c, r)
+                return out, (cache_c, cache_r)
         with scope("dsa_index"):
             qi, wi, ki = self._index(x, cq, pos)
             ki = ki.astype(cache_ki.dtype)
             cache_ki = put(cache_ki, ki)
-            self.positions_live = self.positions_read = jnp.int32(0)
-        return (self._sparse(x, cq, c, r, qi, wi, ki), cache_c, cache_r,
-                cache_ki)
+            self.counted = dict.fromkeys(
+                ("dsa_positions_live", "dsa_positions_read"), jnp.int32(0))
+        return (self._sparse(x, cq, c, r, qi, wi, ki),
+                (cache_c, cache_r, cache_ki))
 
-    def forward_step(self, x, cache_c, cache_r, *rest,
-                     decode_kernel: bool = False):
-        """One decode step at the shared cursor ``t`` (the last of
-        ``rest``): ``x`` (B, 1, D)."""
+    def forward_step(self, x, cache, t, decode_kernel: bool = False):
+        """One decode step at the shared cursor ``t``: ``x`` (B, 1, D)."""
         return self.forward_step_rows(
-            x, cache_c, cache_r, *rest[:-1],
-            jnp.broadcast_to(rest[-1], x.shape[:1]))
+            x, cache, jnp.broadcast_to(t, x.shape[:1]))
 
-    def forward_step_rows(self, x, cache_c, cache_r, *rest,
+    def forward_step_rows(self, x, cache, t_rows,
                           decode_kernel: bool = False):
         """One position PER ROW at per-row cursors ``t_rows`` (B,), the
         continuous-batching step: each row's record is written at its
         own cursor and its query reads the row's records ``<= t`` (with
-        an indexer: its pick of them), absorbed. ``x``: (B, 1, D);
-        ``rest`` is ``(t_rows,)`` or ``(cache_ki, t_rows)``."""
-        cache_ki, t_rows = self._cache_and_cursor(rest)
+        an indexer: its pick of them), absorbed. ``x``: (B, 1, D)."""
+        cache_c, cache_r, cache_ki = self._arrays(cache)
         write = jax.vmap(lambda a, u, s: lax.dynamic_update_slice_in_dim(
             a, u, s, axis=0))
         with scope("mla_decode"):
@@ -338,19 +332,20 @@ class LatentAttention(Layer):
             cache_r = write(cache_r, r.astype(cache_r.dtype), pos[:, 0])
             if not self.index_topk:
                 out = self._absorbed(x, qn, qr, cache_c, cache_r, pos[:, 0])
-                return out, cache_c, cache_r
+                return out, (cache_c, cache_r)
         with scope("dsa_index"):
             qi, wi, ki = self._index(x, cq, pos)
             cache_ki = write(cache_ki, ki.astype(cache_ki.dtype), pos[:, 0])
             keep, n = LA.step_pick(
                 LA.step_index_scores(qi[:, 0], wi[:, 0], cache_ki),
                 pos[:, 0], self.index_topk)
-            self.positions_read = jnp.sum(n, dtype=jnp.int32)
-            self.positions_live = jnp.sum(pos + 1, dtype=jnp.int32)
+            self.counted = {
+                "dsa_positions_read": jnp.sum(n, dtype=jnp.int32),
+                "dsa_positions_live": jnp.sum(pos + 1, dtype=jnp.int32)}
         with scope("mla_decode"):
             out = self._absorbed(x, qn, qr, cache_c, cache_r, pos[:, 0],
                                  keep)
-        return out, cache_c, cache_r, cache_ki
+        return out, (cache_c, cache_r, cache_ki)
 
     def forward(self, x, causal: bool = True):
         """Causal self-attention of (B, T, D) from no cache."""
@@ -391,6 +386,8 @@ class PlainResidual:
     output so far and adds them up again at each use: at 28672 positions
     of 6144 numbers that is 0.67 GB a sublayer held to the end."""
 
+    counted = {}
+
     def __init__(self, multiplier: float = 1.0, settle: bool = False):
         self.m, self.settle = float(multiplier), bool(settle)
 
@@ -425,10 +422,10 @@ class HyperConnection(Layer):
     rewrites all ``n`` streams, and a state rounded to bfloat16 each
     time would add 2^-9 of the whole state, several times a sublayer's
     own output, to what it carries (``PERF.md`` section 6, PR 41).
-    ``unbalanced`` then
-    holds how many of the call's positions have a row or column sum of
-    ``H_res`` off 1 by more than 1e-3 (int32; valid inside the trace of
-    the call)."""
+    ``counted`` then holds ``mhc_unbalanced``: how many of the call's
+    positions have a row or column sum of ``H_res`` off 1 by more than
+    1e-3 after the Sinkhorn rounds (int32; valid inside the trace of the
+    call)."""
 
     state_dtype = jnp.float32
 
@@ -448,7 +445,7 @@ class HyperConnection(Layer):
         # a_pre, a_post, a_res: the maps start all but static
         self.create_parameter("gain", (3,), None,
                               lambda k, s, d: jnp.full(s, 0.01, d))
-        self.unbalanced = None
+        self.counted = {}
 
     def maps(self, x):
         """(H_pre (..., n), H_post (..., n), H_res (..., n, n)) float32
@@ -468,7 +465,8 @@ class HyperConnection(Layer):
         off = jnp.maximum(
             jnp.max(jnp.abs(jnp.sum(res, axis=-1) - 1.0), axis=-1),
             jnp.max(jnp.abs(jnp.sum(res, axis=-2) - 1.0), axis=-1))
-        self.unbalanced = jnp.sum(off > 1e-3, dtype=jnp.int32)
+        self.counted = {"mhc_unbalanced": jnp.sum(off > 1e-3,
+                                                  dtype=jnp.int32)}
         return pre, post, res
 
     def read(self, x):

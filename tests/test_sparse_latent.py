@@ -200,9 +200,9 @@ def test_an_indexer_is_three_sizes_and_a_cache_of_three_arrays():
     mixer = model.blocks[0].mixer
     x = jnp.zeros((2, 4, 64))
     with pytest.raises(EnforceError, match="takes 3 cache arrays, got 2"):
-        mixer.forward_chunk(x, *caches[0][:2], 0)
+        mixer.forward_chunk(x, caches[0][:2], 0)
     with pytest.raises(EnforceError, match="static offset 0"):
-        mixer.forward_chunk(x, *caches[0], 4)
+        mixer.forward_chunk(x, caches[0], 4)
 
 
 # --------------------------------------------------------------------------
