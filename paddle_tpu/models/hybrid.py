@@ -11,7 +11,13 @@ and a channel mix a block, chosen by the configuration.
 - ``"latent"``: ``nn.LatentAttention``, multi-head latent attention:
   one compressed record a position for all heads, decompressed in a
   prefill and read absorbed by a decode step; with ``index_topk`` a
-  learned indexer picks the positions each query attends.
+  learned indexer picks the positions each query attends;
+- ``"full_attention"`` / ``"sliding_attention"``: ``nn.GatedAttention``,
+  GQA softmax attention with a head width of its own (``attn_head_dim``),
+  query heads and a rotary embedding by kind (``attn_heads``,
+  ``attn_rope``), one sigmoid gate a head (``attn_gate``); the sliding
+  kind attends the last ``sliding_window`` positions, and its cache is
+  a ring that long whatever the capacity.
 
 **A mixer is one convention**, and the shell knows no kind by name. A
 mixer is built from the configuration (``MIXERS[kind](cfg)``, reading
@@ -29,8 +35,10 @@ its own fields) and answers, whether or not it reads an argument:
   one position a row at one cursor, or at per-row cursors (B,);
 - class attributes: ``state_kind`` (``"kv"``, addressed by position, or
   ``"recurrent"``, a state of fixed size), ``cache_record`` (what a
-  ``"kv"`` cache holds: ``"heads"``, keys and values by head, or
-  ``"latent"``; None for a state), ``cached_scope`` / ``empty_scope``
+  ``"kv"`` cache holds: ``"heads"``, keys and values by head for
+  ``capacity`` positions, ``"ring"``, the same for a window's positions
+  alone, written round and round, or ``"latent"``; None for a state),
+  ``cached_scope`` / ``empty_scope``
   (the scope its whole sublayer runs under in a cached call and from
   empty state, or None: the mixer then enters scopes of its own around
   itself alone, and the norms and adds beside it are under none);
@@ -96,6 +104,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Optional, Tuple, Union
 
 import jax
@@ -106,6 +115,7 @@ from .. import initializer as I
 from .. import nn
 from ..core.dtypes import default_dtype
 from ..core.enforce import enforce
+from ..nn.gated_attention import GatedAttention
 from ..nn.latent import HyperConnection, LatentAttention, PlainResidual
 from ..nn.layer import Layer
 from ..ops import retention, ssm
@@ -156,6 +166,16 @@ class HybridConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # the "full_attention" / "sliding_attention" mixers: the head width
+    # (0: hidden / heads), query heads by kind (None: num_heads), the
+    # sliding kind's window, the rotary embedding by kind (the keyword
+    # arguments of nn.GatedAttention after ``window``: rope_theta,
+    # rotary_dim, yarn, attention_factor) and the gate a head
+    attn_head_dim: int = 0
+    attn_heads: Optional[dict] = None
+    sliding_window: int = 0
+    attn_rope: Optional[dict] = None
+    attn_gate: bool = False
     # hyper-connections: streams of the residual state (1: the plain
     # path), Sinkhorn rounds and epsilon, the clamp on H_res's logits
     hc_mult: int = 1
@@ -216,6 +236,35 @@ class HybridConfig:
             cls.tiny_latent(layers, dense), rope_yarn=None, hc_mult=1,
             v_head_dim=24, index_n_heads=4, index_head_dim=16,
             index_topk=topk)
+
+    @classmethod
+    def tiny_window(cls, periods: int = 2, held=(0, 4)):
+        """For tests: ``periods`` x (full, sliding, sliding, sliding)
+        attention, hidden 64, heads of 16 (6 query heads on a full
+        layer, 8 on a sliding one, 2 key-value heads), a window of 8, a
+        gate a head; the full layers turn the first 8 numbers of a head
+        at YaRN's frequencies (by 4 over 16 positions, cosines times
+        1.2), the sliding ones all 16 plainly; a gated MLP of 96 in the
+        first block, then 16 sigmoid-routed experts of width 24, 4 a
+        token, scaled by 2.5, of which ``held`` are here, plus a shared
+        MLP of 24; an untied head."""
+        kinds = ("full_attention",) + ("sliding_attention",) * 3
+        return cls(vocab_size=256, hidden_size=64,
+                   layer_types=kinds * periods, num_kv_heads=2,
+                   channel_mix=("mlp",) + ("experts",) * (4 * periods - 1),
+                   mlp_width=96, expert_width=24, shared_width=24,
+                   num_experts=16, experts_per_token=4, experts_held=held,
+                   routing="sigmoid_noaux_tc", routed_scaling_factor=2.5,
+                   attn_head_dim=16, sliding_window=8, attn_gate=True,
+                   attn_heads={"full_attention": 6, "sliding_attention": 8},
+                   attn_rope={
+                       "full_attention": dict(
+                           rope_theta=500000.0, rotary_dim=8,
+                           attention_factor=1.2,
+                           yarn=dict(factor=4.0, original_max_position=16,
+                                     beta_fast=8.0, beta_slow=1.0)),
+                       "sliding_attention": dict(rope_theta=10000.0)},
+                   tie_embeddings=False, rms_norm_eps=1e-6)
 
     @classmethod
     def tiny(cls, periods: int = 1):
@@ -500,10 +549,26 @@ def _latent(cfg: HybridConfig):
         cfg.index_head_dim, cfg.index_topk)
 
 
+def _gated(kind: str, cfg: HybridConfig):
+    sliding = kind == "sliding_attention"
+    enforce(not sliding or cfg.sliding_window >= 1, "a sliding layer "
+            "needs a sliding_window, got %s", cfg.sliding_window)
+    heads = (cfg.attn_heads or {}).get(kind, cfg.num_heads)
+    return GatedAttention(
+        cfg.hidden_size, heads, cfg.num_kv_heads or heads,
+        cfg.attn_head_dim or cfg.hidden_size // heads,
+        cfg.sliding_window if sliding else None,
+        gate=cfg.attn_gate, use_flash=cfg.use_flash,
+        **(cfg.attn_rope or {}).get(kind, {}))
+
+
 # a kind's name -> what builds its mixer from the configuration; a new
 # kind is a class that answers the convention and one line here
 MIXERS = {"mamba": SSDMixer, "attention": SoftmaxMixer,
-          "retention": RetentionMixer, "latent": _latent}
+          "retention": RetentionMixer, "latent": _latent,
+          "full_attention": functools.partial(_gated, "full_attention"),
+          "sliding_attention": functools.partial(_gated,
+                                                 "sliding_attention")}
 
 
 def _under(name):

@@ -7,6 +7,7 @@ from .layers import (GELU, RNN, BatchNorm, BilinearTensorProduct, Conv2D,
                      GRUCell, LayerNorm, Linear, LSTMCell, MultiHeadAttention,
                      Pool2D, PRelu, ReLU, RMSNorm, Sigmoid, Softmax,
                      SpectralNorm, Tanh)
+from .gated_attention import GatedAttention
 from .latent import HyperConnection, LatentAttention
 from .lora import (LoRALinear, apply_lora, lora_parameters,
                    merge_lora)
@@ -26,7 +27,7 @@ __all__ = [
     "Pool2D", "PRelu", "ReLU", "RMSNorm", "Sigmoid", "Softmax",
     "SpectralNorm", "Tanh",
     "GRU", "LSTM", "NCE", "HSigmoid", "SwitchFFN", "DroplessMoE",
-    "LatentAttention", "HyperConnection",
+    "LatentAttention", "HyperConnection", "GatedAttention",
     "LoRALinear", "apply_lora", "lora_parameters", "merge_lora",
     "FeedForward", "LearnedPositionalEmbedding", "PositionalEncoding",
     "TransformerDecoder", "TransformerDecoderLayer", "TransformerEncoder",

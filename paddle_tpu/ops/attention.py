@@ -200,7 +200,9 @@ def yarn_frequencies(half: int, theta: float, factor: float,
     return plain / factor * ramp + plain * (1.0 - ramp)
 
 
-def rotary_embedding(x, positions, theta: float = 10000.0, yarn=None):
+def rotary_embedding(x, positions, theta: float = 10000.0, yarn=None,
+                     rotary_dim: Optional[int] = None,
+                     attention_factor: float = 1.0):
     """Rotary position embedding (RoPE) over (B, T, H, D) with even D.
 
     ``positions``: (T,) or (B, T) integer absolute positions — decode
@@ -211,13 +213,25 @@ def rotary_embedding(x, positions, theta: float = 10000.0, yarn=None):
     ``yarn``: None, or the keyword arguments of
     :func:`yarn_frequencies` (``factor``, ``original_max_position``,
     ``beta_fast``, ``beta_slow``) for a context extended that way.
+    ``rotary_dim``: the LEADING part of each head that turns (even, at
+    most D; None: all of it): its pairs are (x[..., i], x[..., i +
+    rotary_dim/2]) at the frequencies of a head that wide, and the rest
+    of the head passes as it is (a partial rotary factor).
+    ``attention_factor``: cosines and sines are multiplied by it (YaRN's
+    temperature where a model applies it there and not to the scores):
+    the rotated part comes out that much longer, the rest does not.
 
     Green-field (the reference era predates RoPE; its positional story
     is learned position tables, reference:
     python/paddle/fluid/layers/nn.py position_encoding role).
     """
-    d = x.shape[-1]
-    enforce(d % 2 == 0, "rotary needs an even head_dim, got %s", d)
+    d = x.shape[-1] if rotary_dim is None else int(rotary_dim)
+    enforce(d % 2 == 0 and 0 < d <= x.shape[-1], "rotary needs an even "
+            "width of at most the head's %s, got %s", x.shape[-1], d)
+    if d < x.shape[-1]:
+        turned = rotary_embedding(x[..., :d], positions, theta, yarn,
+                                  attention_factor=attention_factor)
+        return jnp.concatenate([turned, x[..., d:]], axis=-1)
     half = d // 2
     if yarn is None:
         freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -228,6 +242,8 @@ def rotary_embedding(x, positions, theta: float = 10000.0, yarn=None):
     # broadcast over batch AND heads, (B, T, half) over heads only
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)

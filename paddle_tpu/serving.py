@@ -330,7 +330,9 @@ class ArenaCounters:
     outlive it (``last_counters`` is the newest arena's: a benchmark
     frees the decoder before it reads). ``state_bytes``: device bytes of
     the arena by kind of state, ``kv`` (addressed by position) and
-    ``recurrent`` (a fixed size a slot). ``sums``: the running sum of
+    ``recurrent`` (a fixed size a slot), and, for a model with window
+    layers, ``ring`` apart from ``kv``: the rings of a window's
+    positions, whatever the capacity. ``sums``: the running sum of
     whatever the model's decode step counts (``step_counters``), over
     ``steps`` steps; ``expert_tokens`` is the one a model with routed
     experts gives, the (held,) (token, pick) pairs each held expert
@@ -736,7 +738,13 @@ class BatchedDecoder:
     compressed record a position, ``nn.LatentAttention``) is served
     from it, while the modes that assume keys and values by head
     (pages, prefix reuse, a quantised pool, handoff, speculative
-    verify, chunked prefill) are refused for it by name. With
+    verify, chunked prefill) are refused for it by name. So is a model
+    with a ``"ring"`` entry (a window layer's keys and values for the
+    last ``window`` positions alone, written round and round,
+    ``nn.GatedAttention``): its leaf leads with (slots, window) beside
+    the full layers' (slots, capacity) in the one arena, and the same
+    modes, which assume ``capacity`` positions a block, are refused for
+    it by name. With
     recurrent state a prefill starts the slot's state from zeros and
     advances it over exactly the prompt, and every mode that addresses
     the cache by position (pages, prefix reuse, handoff, speculative
@@ -807,6 +815,7 @@ class BatchedDecoder:
         self._recurrent = bool(kinds) and "recurrent" in kinds
         records = getattr(model, "cache_records", None)
         self._latent = bool(records) and "latent" in records
+        self._ring = bool(records) and "ring" in records
         # a recurrent state is one value a slot, not a value a position:
         # nothing below can page it, share a prefix of it, hand it over
         # as pages, roll it back after a rejected draft, or resume it
@@ -815,14 +824,24 @@ class BatchedDecoder:
         # below that pages, quantises, shares, hands over or verifies
         # against a cache goes through ops/paged_kv.attend, which is; a
         # chunk that continues a cache is not written for a record
-        # (LatentAttention.forward_chunk takes the offset 0 alone)
+        # (LatentAttention.forward_chunk takes the offset 0 alone). A
+        # ring holds a window's positions and not the capacity's: a page
+        # table, a shared prefix, a handoff's pages and a verify chunk's
+        # roll-back all address positions the ring has overwritten, and
+        # a chunk that continues a ring is not written either
+        # (GatedAttention.forward_chunk)
         refused = (
             "recurrent state: the state is not addressable by position, "
             "and snapshots of it are not kept" if self._recurrent else
             "a latent record: the cache holds one compressed record a "
             "position, not keys and values by head, and the paged pool, "
             "its quantised form, the handoff and the verify chunk assume "
-            "those" if self._latent else None)
+            "those" if self._latent else
+            "a ring: a window layer's cache holds its last window of "
+            "positions and not the capacity's, and the paged pool, its "
+            "quantised form, the prefix registry, the handoff, the verify "
+            "chunk and a chunk that continues a cache address positions "
+            "a ring has overwritten" if self._ring else None)
         for what, on in (("pages=", pages is not None),
                          ("prefix_cache", prefix_cache),
                          ("kv_dtype", kv_dtype is not None),
@@ -963,6 +982,8 @@ class BatchedDecoder:
         self._check_arena_donation()
         self._kinds = (list(kinds) if kinds and not self.paged
                        else ["kv"] * len(model.blocks))
+        self._records = (list(records) if records and not self.paged
+                         else [None] * len(self._kinds))
         self._counted = (hasattr(model, "step_counters")
                          and not self.paged)
         global last_counters
@@ -1393,7 +1414,9 @@ class BatchedDecoder:
         past capacity so the junk writes DROP (write_rows' OOB
         semantics); contiguous junk lands at positions a later prefill
         fully overwrites and no attention ever reads (nothing is
-        active, and prefill rewrites [0, bucket) wholesale); a
+        active, and prefill rewrites [0, bucket) wholesale; in a ring
+        the junk lands at ``t mod ring``, an entry the ring's own mask
+        hides until the sequence writes it); a
         recurrent state advanced by junk is zeroed by the slot's next
         prefill. Like every dispatch it consumes the arena and rebinds
         the decoder to the one the programs return. On a contiguous
@@ -1455,6 +1478,10 @@ class BatchedDecoder:
                 "with a latent record: a KVHandoff carries pages of keys "
                 "and values by head, and the record is one compressed "
                 "vector a position")
+        enforce(not self._ring, "prefill_export is refused for a model "
+                "with a ring: a KVHandoff carries pages of keys and values "
+                "for every position, and a window layer keeps its last "
+                "window alone")
         enforce(self.paged, "prefill_export requires paged mode "
                 "(pages=N) — the handoff payload is KV pages")
         # deadline check BEFORE the prefill compute: an expired request
@@ -1527,6 +1554,10 @@ class BatchedDecoder:
                 "model with a latent record: a KVHandoff carries pages of "
                 "keys and values by head, and the record is one "
                 "compressed vector a position")
+        enforce(not self._ring, "inject_prefilled is refused for a model "
+                "with a ring: a KVHandoff carries pages of keys and values "
+                "for every position, and a window layer keeps its last "
+                "window alone")
         enforce(self.paged, "inject_prefilled requires paged mode "
                 "(pages=N) on the decode replica")
         enforce(isinstance(handoff, KVHandoff),
@@ -1619,12 +1650,15 @@ class BatchedDecoder:
     # ----- internals -------------------------------------------------------
 
     def _state_bytes(self) -> Dict[str, int]:
-        """Device bytes of the arena by kind of state."""
+        """Device bytes of the arena by kind of state; a window
+        layer's ring is counted apart from ``kv``, under ``ring``."""
         out = {"kv": 0, "recurrent": 0}
         arena = self.pools if self.paged else self.caches
-        for kind, block in zip(self._kinds, arena):
-            out[kind] += sum(int(leaf.nbytes) for leaf in
-                             jax.tree_util.tree_leaves(block))
+        for kind, record, block in zip(self._kinds, self._records, arena):
+            name = "ring" if record == "ring" else kind
+            out[name] = out.get(name, 0) + sum(
+                int(leaf.nbytes) for leaf in
+                jax.tree_util.tree_leaves(block))
         return out
 
     def _fresh_row(self, row):
@@ -1632,7 +1666,9 @@ class BatchedDecoder:
         whatever the slot's last request (or an idle slot's junk steps)
         left there, a new sequence starts from nothing, and the
         prefill's one chunk advances it over the whole prompt. Keys and
-        values stay: the cursor masks them."""
+        values stay: the cursor masks them, and a ring's own mask (its
+        entries up to the cursor while the cursor is inside it) masks a
+        longer request's leftovers the same way."""
         return [jax.tree_util.tree_map(jnp.zeros_like, r)
                 if kind == "recurrent" else r
                 for kind, r in zip(self._kinds, row)]
@@ -1660,8 +1696,10 @@ class BatchedDecoder:
             # first plen positions are the prompt (``valid_len``): keys
             # and values are written for the whole bucket (positions
             # >= plen land above the cursor, masked + overwritten
-            # later), a recurrence advances over those plen tokens and
-            # no further, from zeros. The first token's logits are the
+            # later; a ring takes the last window of the plen valid
+            # positions and nothing of the padding), a recurrence
+            # advances over those plen tokens and no further, from
+            # zeros. The first token's logits are the
             # head applied to the chunk's own row plen - 1 (causal: it
             # has seen positions <= plen - 1 and no padding): an
             # (lb, vocab) head would be the dominant prefill FLOP, and

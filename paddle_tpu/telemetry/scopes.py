@@ -53,6 +53,17 @@ SCOPES = {
                  "mla_prefill and never inside them: its three "
                  "projections, the index key's norm, rotary and write, "
                  "the index scores and the pick",
+    "gqa_full_step": "a full-attention gated GQA mixer at one position "
+                     "a row: projections, rotary, the gate, the cache's "
+                     "write, the read, the output projection",
+    "gqa_window_step": "the same mixer with a window: its write goes "
+                       "round a ring and its read is the whole ring",
+    "gqa_full_prefill": "a full-attention gated GQA mixer over a chunk: "
+                        "projections, rotary, causal attention, the "
+                        "gate, the chunk's write, the output projection",
+    "gqa_window_prefill": "the same mixer with a window: banded "
+                          "attention, and the chunk's last window "
+                          "written into the ring",
     "mhc_mix": "a hyper-connection's maps: both halves around a "
                "sublayer, never the sublayer itself",
     "moe_route": "an expert layer's router: logits, top-k, counts and "

@@ -71,6 +71,8 @@ CASES = {      # a kind -> the tiny configuration it is built from
     "retention": (HybridConfig.tiny_retention, "retention"),
     "latent": (HybridConfig.tiny_latent, "latent"),
     "latent+indexer": (HybridConfig.tiny_sparse_latent, "latent"),
+    "full_attention": (HybridConfig.tiny_window, "full_attention"),
+    "sliding_attention": (HybridConfig.tiny_window, "sliding_attention"),
     "toy": (HybridConfig.tiny, "running_mean"),
 }
 
@@ -119,10 +121,12 @@ def test_a_kind_answers_the_convention(case, toy_kind):
         assert any(name in vars(c) for c in type(mixer).__mro__), name
     assert mixer.state_kind in ("kv", "recurrent")
     assert mixer.cache_record in (
-        ("heads", "latent") if mixer.state_kind == "kv" else (None,))
+        ("heads", "ring", "latent") if mixer.state_kind == "kv"
+        else (None,))
     assert {mixer.cached_scope, mixer.empty_scope} <= {None, *SCOPES}
 
     s = 11                       # 12 positions: past the indexer's 8
+    #                              and the sliding kind's window of 8
     x = jnp.asarray(np.random.default_rng(6).standard_normal(
         (2, s + 1, cfg.hidden_size)), jnp.float32)
     want = mixer(x)              # from empty state, causal

@@ -255,7 +255,10 @@ from paddle_tpu.ops.pallas.flash_decode import (  # noqa: E402
     flash_decode, flash_decode_paged)
 
 # (cap, d, h, kv): GQA serving shape + the small NMT decode cache
-DECODE_SHAPES = [(2048, 64, 12, 4), (256, 64, 8, 8), (512, 128, 16, 8)]
+# + the window cell's two kinds of layer (Laguna-XS.2): a full cache
+# read by 48 query heads and a ring of 512 read by 64, 8 key-value heads
+DECODE_SHAPES = [(2048, 64, 12, 4), (256, 64, 8, 8), (512, 128, 16, 8),
+                 (16384, 128, 48, 8), (512, 128, 64, 8)]
 
 
 @pytest.mark.parametrize("shape", DECODE_SHAPES)

@@ -155,7 +155,7 @@ def test_a_train_step_with_remat_shows_its_blocks_in_three_passes(program):
 
 
 def test_weight_cast_is_on_the_list_with_its_line():
-    assert LISTED[-1] == "weight_cast" and len(LISTED) == 19
+    assert LISTED[-1] == "weight_cast" and len(LISTED) == 23
     assert "functional_call" in scopes.SCOPES["weight_cast"]
 
 

@@ -235,6 +235,26 @@ def test_flash_decode_compiles(one_chip, dtype):
     assert "%pt_flash_decode" in text
 
 
+@pytest.mark.parametrize("capacity,heads", [(16384, 48), (512, 64)],
+                         ids=["full_6_a_kv_head", "ring_8_a_kv_head"])
+def test_flash_decode_compiles_at_the_window_cells_two_head_counts(
+        one_chip, capacity, heads):
+    """Laguna-XS.2's two kinds of layer: a full cache of 16384 read by
+    48 query heads and a ring of 512 read by 64, both over 8 key-value
+    heads of 128: 6 and 8 query heads a key-value head, where the served
+    dense cell has 4 (a group of 6 rows is no multiple of a sublane
+    tile)."""
+    q = jax.ShapeDtypeStruct((16, 1, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((16, capacity, 8, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    t = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    text = _compiled_text(
+        lambda q, k, v, t: flash_decode(q, k, v, t, interpret=False),
+        q, kv, kv, t)
+    assert "%pt_flash_decode" in text
+
+
 def test_retention_step_compiles_and_writes_the_state_in_place(one_chip):
     """The power-retention step kernel at the Brumby cell's widths (40 q
     / 8 kv heads of 128, a state of 8320 x 128 a head; 4 slots here):
