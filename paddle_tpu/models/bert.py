@@ -32,7 +32,7 @@ class BertConfig:
     # None | 'ring' | 'ulysses' — shard attention over the 'sp' mesh axis
     seq_parallel: Optional[str] = None
     remat: bool = False        # jax.checkpoint per block (HBM for FLOPs)
-    remat_policy: Optional[str] = None  # None (save nothing) | "dots"
+    remat_policy: Optional[str] = None  # None (flash o, lse only) | "dots"
     # sliding-window/local attention width (None = full; the flash
     # kernel skips out-of-band blocks — O(T*window) long-context mode)
     attn_window: Optional[int] = None

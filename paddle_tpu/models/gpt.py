@@ -44,7 +44,7 @@ class GPTConfig:
     rope_theta: float = 10000.0
     dropout: float = 0.0                 # residual/FFN dropout
     use_flash: bool = True
-    remat: bool = False                  # jax.checkpoint per block
+    remat: bool = False  # keep a block's input + flash o, lse; recompute rest
     # None | 'ring' | 'ulysses' — shard attention over the 'sp' axis
     # (ring supports GQA; see parallel.context_parallel)
     seq_parallel: Optional[str] = None
@@ -158,8 +158,8 @@ class GPTForCausalLM(Layer):
         x = self._embed(ids)
         for blk in self.blocks:
             if self.cfg.remat:
-                x = jax.checkpoint(
-                    lambda h, b=blk: b(h, kv_mask=kv_mask))(x)
+                x = jax.checkpoint(lambda h, b=blk: b(h, kv_mask=kv_mask),
+                                   policy=nn.remat_policy())(x)
             else:
                 x = blk(x, kv_mask=kv_mask)
         return x

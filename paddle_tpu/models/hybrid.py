@@ -95,9 +95,9 @@ its arena.
 
 TRAINING goes through :meth:`HybridForCausalLM.forward_loss` under the
 one ``parallel.Trainer``, as ``models/gpt.py``'s does: the blocks from
-empty state (``remat``: each under ``jax.checkpoint``), the fused
-linear cross-entropy head, and the routers' new state handed on as
-buffers.
+empty state (``remat``: each under ``jax.checkpoint``, which keeps a
+block's input and its flash kernel's ``o`` and ``lse`` and recomputes the
+rest), the fused linear-CE head, the routers' new state as buffers.
 """
 
 from __future__ import annotations
@@ -193,7 +193,7 @@ class HybridConfig:
     logits_scaling: float = 1.0
     rms_norm_eps: float = 1e-5
     use_flash: bool = True
-    remat: bool = False                  # jax.checkpoint a block (training)
+    remat: bool = False  # keep a block's input + flash o, lse; recompute rest
 
     def channel_mixes(self) -> Tuple[str, ...]:
         """The channel mix of each block."""
@@ -742,17 +742,17 @@ class HybridForCausalLM(Layer):
 
     def _trunk(self, ids):
         """The blocks over ``ids`` from empty state, each under
-        ``jax.checkpoint`` with ``cfg.remat``. In training mode a block
-        whose router has the bias rule then moves its bias by the load
-        the call's batch gave it (``nn.DroplessMoE.bias_update``, scope
-        ``moe_bias_update``): a buffer update made HERE, outside the
-        checkpoint, that ``functional_call`` hands on as ``new_buffers``;
-        the call itself routes by the bias it was given."""
+        ``jax.checkpoint`` with ``cfg.remat`` (kept: ``nn.remat_policy``).
+        In training mode a block whose router has the bias rule then moves
+        its bias by the load of its batch (``nn.DroplessMoE.bias_update``,
+        scope ``moe_bias_update``): a buffer update made HERE, outside the
+        checkpoint, for ``new_buffers``; the call routes by the bias given."""
         x = self._embed(ids)
         tokens, loads = 0, []
         for blk in self.blocks:
-            run = (jax.checkpoint(blk.forward_counted) if self.cfg.remat
-                   else blk.forward_counted)
+            run = (jax.checkpoint(blk.forward_counted,
+                                  policy=nn.remat_policy())
+                   if self.cfg.remat else blk.forward_counted)
             x, got, load = run(x)
             if got is not None:
                 tokens = tokens + got

@@ -1,7 +1,8 @@
 """Layer API — dygraph-equivalent modules (reference: fluid/dygraph/nn.py),
 functional under the hood (functional_call over param/buffer pytrees)."""
 
-from .layer import Layer, LayerList, Parameter, Sequential
+from .layer import (Layer, LayerList, Parameter, Sequential,
+                    remat_policy)
 from .layers import (GELU, RNN, BatchNorm, BilinearTensorProduct, Conv2D,
                      Conv2DTranspose, Dropout, Embedding, Flatten, GroupNorm,
                      GRUCell, LayerNorm, Linear, LSTMCell, MultiHeadAttention,
@@ -20,7 +21,7 @@ from .transformer import (FeedForward, LearnedPositionalEmbedding,
                           TransformerEncoderLayer)
 
 __all__ = [
-    "Layer", "LayerList", "Parameter", "Sequential",
+    "Layer", "LayerList", "Parameter", "Sequential", "remat_policy",
     "GELU", "RNN", "BatchNorm", "BilinearTensorProduct", "Conv2D",
     "Conv2DTranspose", "Dropout", "Embedding", "Flatten", "GroupNorm",
     "GRUCell", "LayerNorm", "Linear", "LSTMCell", "MultiHeadAttention",

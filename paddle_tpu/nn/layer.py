@@ -472,3 +472,27 @@ class LayerList(Layer):
 
     def __getitem__(self, i: int) -> Layer:
         return self._sublayers[str(i)]
+
+
+# below every class on purpose, its import too: a serving program's
+# Pallas kernels carry the line numbers of their innermost call sites,
+# ``Layer.__call__`` among them, and the compile cache keys on them
+import functools  # noqa: E402
+
+
+@functools.lru_cache(maxsize=None)
+def remat_policy():
+    """What a block under ``jax.checkpoint`` keeps besides its inputs:
+    the flash kernel's output ``o`` and log-sum-exp ``lse``, the two
+    residuals its backward kernels take from the forward
+    (``ops.pallas.flash_attention.REMAT_NAMES``; one (batch, seq, heads
+    x value width) activation and one float32 (batch, heads, seq) row a
+    call), so that the backward pass recomputes everything of the block
+    but the kernel. The one meaning of ``remat=True`` in the model
+    shells: ``jax.checkpoint(block, policy=remat_policy())``. A block
+    with no flash kernel holds no such name and keeps what it kept, its
+    inputs. One object for the process: ``jax.checkpoint`` caches its
+    traces by the policy's identity."""
+    from ..ops.pallas.flash_attention import REMAT_NAMES
+
+    return jax.checkpoint_policies.save_only_these_names(*REMAT_NAMES)

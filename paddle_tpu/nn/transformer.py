@@ -12,6 +12,7 @@ TPU notes: pre-norm by default (stable in bf16), GELU FFN, static shapes
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -19,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.enforce import enforce
-from .layer import Layer, LayerList
+from .layer import Layer, LayerList, remat_policy
 from .layers import Dropout, Embedding, LayerNorm, Linear, MultiHeadAttention
 
 
@@ -132,11 +133,27 @@ class TransformerDecoderLayer(Layer):
         return x
 
 
+@functools.lru_cache(maxsize=None)
+def _dots_policy():
+    """``remat_policy="dots"``: the matmul outputs beside what
+    ``remat_policy()`` keeps; one object, as ``jax.checkpoint`` caches
+    its traces by the policy's identity."""
+    import jax
+
+    return jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        remat_policy())
+
+
 class TransformerEncoder(Layer):
     """``remat=True`` wraps each block in ``jax.checkpoint`` so backward
     recomputes block activations instead of storing every layer's — the
     HBM-for-FLOPs trade that makes long-sequence training fit (TPU
-    guidance: rematerialize at block boundaries). Applies on every call
+    guidance: rematerialize at block boundaries). What a block keeps is
+    its input and, under either ``remat_policy``, its flash kernel's
+    ``o`` and ``lse`` (``nn.remat_policy``, the rule the model shells
+    share: the backward pass does not run the forward kernel again);
+    ``"dots"`` keeps the matmul outputs besides. Applies on every call
     when enabled; meant for the jitted training path (eager callers
     should leave the default False)."""
 
@@ -157,11 +174,12 @@ class TransformerEncoder(Layer):
             for _ in range(num_layers)])
         self.final_norm = LayerNorm(d_model) if normalize_before else None
         self.remat = remat
-        # None = save nothing (recompute everything); "dots" = save
-        # matmul outputs and recompute only the elementwise tail — less
-        # recompute FLOPs for a bit more HBM (the standard policy sweep
-        # for MFU at long sequence). Validated HERE so a policy on a
-        # non-remat encoder fails loudly instead of silently not running
+        # None = keep the flash kernel's o and lse alone (recompute
+        # everything else); "dots" = save matmul outputs too and
+        # recompute only the elementwise tail — less recompute FLOPs for
+        # a bit more HBM (the standard policy sweep for MFU at long
+        # sequence). Validated HERE so a policy on a non-remat encoder
+        # fails loudly instead of silently not running
         enforce(remat_policy in (None, "dots"),
                 "remat_policy must be None or 'dots', got %r", remat_policy)
         enforce(remat_policy is None or remat,
@@ -178,11 +196,7 @@ class TransformerEncoder(Layer):
         self.scan_layers = scan_layers
 
     def _ckpt_policy(self):
-        import jax
-
-        if self.remat_policy is None:
-            return None
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        return remat_policy() if self.remat_policy is None else _dots_policy()
 
     def forward(self, x, mask=None, segment_ids=None):
         import jax

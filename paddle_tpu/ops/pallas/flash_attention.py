@@ -1274,7 +1274,7 @@ def _flash_bwd(causal, window, scale, dropout_p, block_q, block_k,
     return dq, dk, dv, None, None, None
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+# ``_flash.defvjp`` is at the end of the file, beside its forward rule
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -1290,13 +1290,13 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_k_bwd: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Blockwise attention: q (batch, tq, heads, d), k (batch, tk,
-    kv_heads, d), v (batch, tk, kv_heads, e) -> (batch, tq, heads, e).
-    The score width d and the value width e are read from the operands
-    and may differ (latent attention: 192 / 128); ``scale`` defaults to
-    ``d ** -0.5``.
+    kv_heads, d), v (batch, tk, kv_heads, e) -> (batch, tq, heads, e);
+    the score width d and the value width e are read from the operands
+    and may differ (latent: 192 / 128); ``scale`` defaults to ``d ** -0.5``.
+    Sequence lengths must divide the block sizes (shrunk for short ones).
 
-    Sequence lengths must divide the block sizes (shrunk automatically for
-    short sequences). Differentiable (custom VJP, recompute backward).
+    Differentiable: the backward recomputes the scores from o and lse,
+    which the forward rule NAMES so that remat keeps them (end of file).
 
     Operand type: q, k and v are narrowed HERE, before the custom VJP,
     to the active policy's compute type where that is narrower than
@@ -1396,3 +1396,47 @@ def flash_attention(q, k, v, causal: bool = False,
                   None if window is None else int(window), float(scale),
                   float(dropout_p), block_q, block_k, block_q_bwd,
                   block_k_bwd, interpret).astype(out_dtype)
+
+
+# ---------------------------------------------------------------------------
+# what remat keeps of a call
+# ---------------------------------------------------------------------------
+# The backward kernels take two things from the forward kernel, its
+# output ``o`` and the float32 log-sum-exp of every score row ``lse``,
+# and recompute the scores from them. A block under ``jax.checkpoint``
+# with no policy keeps neither, so its backward pass ran the forward
+# KERNEL a second time only to have them again. ``_flash``'s forward
+# rule names the two as they are stored in the residual tuple, and a
+# policy that saves these names (``nn.remat_policy``, what ``remat=True``
+# passes in ``models/gpt.py``, ``models/hybrid.py`` and
+# ``nn.TransformerEncoder``) keeps them: one (batch, seq, heads, e)
+# activation and one float32 (batch, heads, seq) row a call, for a
+# forward kernel a layer a step. Under no such policy a name is the
+# identity and lowers to nothing.
+#
+# This block is the file's LAST, its import is here, and a call that is
+# not differentiated (``_flash`` itself: a serving prefill) runs
+# ``_flash_fwd`` bare, all on purpose: a program that holds one of these
+# kernels carries the line numbers of the kernel's body and of its ten
+# innermost call sites (this file's and the line of ``flash_attention``'s
+# caller), and the compile cache keys on them and on the private
+# functions' numbering, which a name shifted by one in one serving
+# prefill. A line added above, or a name in the bare call, moves every
+# serving program's key.
+
+from jax.ad_checkpoint import checkpoint_name as _checkpoint_name  # noqa: E402
+
+REMAT_O = "pt_flash_o"
+REMAT_LSE = "pt_flash_lse"
+REMAT_NAMES = (REMAT_O, REMAT_LSE)
+
+
+def _flash_fwd_named(*args):
+    """``_flash``'s forward rule: ``_flash_fwd`` with ``o`` and ``lse``
+    named where they enter the residual tuple (its last two)."""
+    o, res = _flash_fwd(*args)
+    o = _checkpoint_name(o, REMAT_O)
+    return o, res[:-2] + (o, _checkpoint_name(res[-1], REMAT_LSE))
+
+
+_flash.defvjp(_flash_fwd_named, _flash_bwd)

@@ -649,6 +649,10 @@ def test_no_product_of_the_train_step_reads_a_float32_linear_weight(
                                                           ("dp",))):
         text = jax.jit(tr._step, donate_argnums=(0, 1, 2)).lower(
             *args).compile().as_text()
+    # remat keeps a block's flash o and lse (``nn.remat_policy``), and the
+    # compiler leaves it so: each kernel once a layer in the COMPILED step
+    for kernel in ("pt_flash_fwd", "pt_flash_dq", "pt_flash_dkdv"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 2, kernel
     assert text.count(" convolution(") >= 2 * 7 * 4    # forward, second
     assert _wide_weights_of_products(text, names) == set()  # forward, two back
     control = """
