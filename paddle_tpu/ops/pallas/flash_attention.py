@@ -460,28 +460,53 @@ def _fwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, causal,
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-               scale, causal, window, has_mask, has_segs, dropout_p,
-               offset, block_q, block_k, num_k_blocks, banded=False,
-               n_j=None):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                scale, causal, window, has_mask, has_segs, dropout_p,
+                offset, block_q, block_k, grads, num_steps, banded=False,
+                n_inner=None):
+    """The backward's one body: p and ds of a score block, recomputed,
+    and the sums ``grads`` asks of them.
+
+    ``"dq"``: grid (b, i, j), dq of query block i summed over the key
+    blocks (``pt_flash_dq``). ``"dkdv"``: grid (b, j, i), dk and dv of
+    key block j summed over the query blocks (the pair's
+    ``pt_flash_dkdv``). ``"all"``: that grid and those two, and dq from
+    the same ds: ``ds k`` adds into the query block's rows of a float32
+    accumulator that spans the (batch, head)'s WHOLE query length and
+    stays in VMEM while the key blocks go by. For a fixed query block
+    they arrive in ascending order, as under ``"dq"``: the same sum,
+    term by term. ``banded``: the inner axis walks a window's band,
+    ``num_steps`` of its ``n_inner`` blocks."""
     refs = list(refs)
     kvm_ref = refs.pop(0) if has_mask else None
     qseg_ref = refs.pop(0) if has_segs else None
     kseg_ref = refs.pop(0) if has_segs else None
     seed_ref = refs.pop(0) if dropout_p > 0.0 else None
-    dq_ref, dq_acc = refs
-    bh, i, jj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    by_key = grads != "dq"  # kv block outer, q inner
+    if grads != "dkdv":
+        dq_ref, dq_acc = refs.pop(0), refs.pop()
+    if by_key:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
+    bh, outer, step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    inner, in_range = step, True
     if banded:
-        j_raw = _band_j_lo(i, block_q=block_q, block_k=block_k,
-                           offset=offset, window=window) + jj
-        j = jnp.clip(j_raw, 0, n_j - 1)
-        in_range = (j_raw >= 0) & (j_raw < n_j)
-    else:
-        j, in_range = jj, True
+        band = dict(block_q=block_q, block_k=block_k, offset=offset,
+                    window=window)
+        raw = (_band_i_lo(outer, causal=causal, **band) if by_key
+               else _band_j_lo(outer, **band)) + step
+        inner = jnp.clip(raw, 0, n_inner - 1)
+        in_range = (raw >= 0) & (raw < n_inner)
+    i, j = (inner, outer) if by_key else (outer, inner)
 
-    @pl.when(jj == 0)
+    @pl.when(step == 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        for acc in ((dk_acc, dv_acc) if by_key else (dq_acc,)):
+            acc[:] = jnp.zeros_like(acc)
+
+    if grads == "all":
+        @pl.when((outer == 0) & (step == 0))
+        def _init_dq():
+            dq_acc[:] = jnp.zeros_like(dq_acc)
 
     should_run = in_range & _block_should_run(
         i, j, causal=causal, window=window, offset=offset,
@@ -511,108 +536,82 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             qseg = qseg_ref[0]
             kseg = kseg_ref[0, 0]
             s = jnp.where(qseg == kseg, s, _NEG_INF)
-        p = jnp.exp(s - lse)
+        p = jnp.exp(s - lse)                               # (bq, bk) f32
         if causal or window is not None or has_mask or has_segs:
             # fully-masked rows carry lse == _NEG_INF (see fwd _finish)
             p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
+        # same counter-based mask as fwd: out = (m ⊙ y / keep) @ v, so
+        # dL/dy = (do @ v^T) ⊙ m / keep and ds = y ⊙ (dL/dy − δ)
+        keep_mask = lambda: _dropout_keep(
+            seed_ref[0, bh], i * block_q + offset, j * block_k, block_q,
+            block_k, dropout_p)
+        if by_key:
+            p_v = p  # dv uses the DROPPED probabilities (out = p_drop @ v)
+            if dropout_p > 0.0:
+                keep = keep_mask()
+                p_v = jnp.where(keep, p * (1.0 / (1.0 - dropout_p)), 0.0)
+            dv_acc[:] += jax.lax.dot_general(
+                p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # (bk, e)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32)                # (bq, bk)
         if dropout_p > 0.0:
-            # same counter-based mask as fwd: out = (m ⊙ y / keep) @ v,
-            # so dL/dy = (do @ v^T) ⊙ m / keep and ds = y ⊙ (dL/dy − δ)
-            keep = _dropout_keep(seed_ref[0, bh],
-                                 i * block_q + offset, j * block_k,
-                                 block_q, block_k, dropout_p)
+            keep = keep if by_key else keep_mask()
             dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_p)), 0.0)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        dq_acc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta) * scale).astype((q if by_key else k).dtype)
+        if by_key:
+            dk_acc[:] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # (bk, d)
+        if grads != "dkdv":
+            rows = (pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+                    if by_key else slice(None))
+            dq_acc[rows] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # (bq, d)
 
-    @pl.when(jj == num_k_blocks - 1)
+    @pl.when(step == num_steps - 1)
     def _finish():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        if by_key:
+            dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        else:
+            dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+    if grads == "all":
+        @pl.when((outer == pl.num_programs(1) - 1) & (step == num_steps - 1))
+        def _finish_dq():
+            dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                scale, causal, window, has_mask, has_segs, dropout_p,
-                offset, block_q, block_k, num_q_blocks, banded=False,
-                n_i=None):
-    refs = list(refs)
-    kvm_ref = refs.pop(0) if has_mask else None
-    qseg_ref = refs.pop(0) if has_segs else None
-    kseg_ref = refs.pop(0) if has_segs else None
-    seed_ref = refs.pop(0) if dropout_p > 0.0 else None
-    dk_ref, dv_ref, dk_acc, dv_acc = refs
-    bh = pl.program_id(0)
-    j, ii = pl.program_id(1), pl.program_id(2)  # kv block outer, q inner
-    if banded:
-        i_raw = _band_i_lo(j, block_q=block_q, block_k=block_k,
-                           offset=offset, window=window,
-                           causal=causal) + ii
-        i = jnp.clip(i_raw, 0, n_i - 1)
-        in_range = (i_raw >= 0) & (i_raw < n_i)
-    else:
-        i, in_range = ii, True
+# The one-kernel backward's VMEM ceiling: what that call asks Mosaic for
+# (the chip has 128 MiB; the pair and the forward live under the default
+# scoped limit), and the rule that says BY SHAPE ALONE whether dq's
+# accumulator fits it. Read off the described v5e's compile (PR 54): at
+# 256 / 128, 1024 x 1024, bf16 it accepts tq 40960 and refuses 49152; the
+# estimate below is over Mosaic's own count everywhere tried (kanana's
+# call: 40 MiB for the 26 the compile needs) and stops at tq 36864.
+FUSED_BWD_VMEM_LIMIT = 96 * 1024 * 1024
 
-    @pl.when(ii == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    should_run = in_range & _block_should_run(
-        i, j, causal=causal, window=window, offset=offset,
-        block_q=block_q, block_k=block_k)
+def fused_bwd_vmem_bytes(tq, d, e, block_q, block_k, dtype):
+    """VMEM the one-kernel backward holds: the pipeline's two buffers of
+    every operand and output block (dq's spans ``tq``; an lse or delta
+    row pads to 128 lanes), the three float32 accumulators (dq's spans
+    ``tq`` too) and four float32 score-sized blocks live in the body."""
+    w = jnp.dtype(dtype).itemsize
+    blocks = 2 * w * ((block_q + 2 * block_k) * (d + e) + tq * d)
+    rows = 2 * 2 * 4 * block_q * 128
+    accs = 4 * (tq * d + block_k * (d + e))
+    return blocks + rows + accs + 4 * 4 * block_q * block_k
 
-    @pl.when(should_run)
-    def _body():
-        # native-dtype matmul inputs (see _fwd_kernel note)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]      # (bq, 1)
-        delta = delta_ref[0]  # (bq, 1)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _apply_causal_band(s, i, j, causal=causal, window=window,
-                               offset=offset, block_q=block_q,
-                               block_k=block_k)
-        if has_mask:
-            kvm = kvm_ref[0, 0]  # j-th block via the index map
-            s = jnp.where(kvm > 0, s, _NEG_INF)
-        if has_segs:
-            qseg = qseg_ref[0]
-            kseg = kseg_ref[0, 0]
-            s = jnp.where(qseg == kseg, s, _NEG_INF)
-        p = jnp.exp(s - lse)                               # (bq, bk) f32
-        if causal or window is not None or has_mask or has_segs:
-            p = jnp.where(s <= _NEG_INF * 0.5, 0.0, p)
-        p_v = p  # dv uses the DROPPED probabilities (out = p_drop @ v)
-        if dropout_p > 0.0:
-            keep = _dropout_keep(seed_ref[0, bh],
-                                 i * block_q + offset, j * block_k,
-                                 block_q, block_k, dropout_p)
-            p_v = jnp.where(keep, p * (1.0 / (1.0 - dropout_p)), 0.0)
-        dv_acc[:] += jax.lax.dot_general(
-            p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bk, e)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bq, bk)
-        if dropout_p > 0.0:
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_p)), 0.0)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bk, d)
 
-    @pl.when(ii == num_q_blocks - 1)
-    def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+def bwd_is_fused(tq, d, e, block_q, block_k, dtype):
+    """Whether ``_bwd_call`` runs ONE kernel (dq beside dk and dv) or the
+    pair ``pt_flash_dq`` + ``pt_flash_dkdv``: the same sums either way."""
+    return (fused_bwd_vmem_bytes(tq, d, e, block_q, block_k, dtype)
+            <= FUSED_BWD_VMEM_LIMIT)
 
 
 def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
@@ -636,64 +635,63 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
               if window is not None else n_i)
     banded_i = window is not None and band_i < n_i
 
-    j_lo = functools.partial(_band_j_lo, block_q=block_q,
-                             block_k=block_k, offset=offset,
-                             window=window)
-    i_lo = functools.partial(_band_i_lo, block_q=block_q,
-                             block_k=block_k, offset=offset,
-                             window=window, causal=causal)
+    band = dict(block_q=block_q, block_k=block_k, offset=offset,
+                window=window)
+    j_lo = functools.partial(_band_j_lo, **band)
+    i_lo = functools.partial(_band_i_lo, causal=causal, **band)
     kv_imap_banded = _banded_imap(
         j_lo, n_j, lambda b: _kv_row_fold(b, nheads, kv_heads))
     q_imap_banded = _banded_imap(i_lo, n_i)
 
-    dq_k_spec, dq_v_spec = (
-        _vmem_spec((1, block_k, w), kv_imap_banded)
-        if banded_j else _causal_kv_spec(
-            block_q, block_k, w, nheads, kv_heads, offset, n_j, causal)
-        for w in (d, e))
-    dq_in_specs = [
-        _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        dq_k_spec,
-        dq_v_spec,
-        _vmem_spec((1, block_q, e), lambda b, i, j: (b, i, 0)),
-        _vmem_spec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        _vmem_spec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-    ]
     # blocked kv-side mask layout (see _mask_block_spec): the grid's
     # k-block index picks the chunk, shared by dq (j = args[2], banded
     # clamp when windowed) and dkv (j = args[1], never banded over j)
     kvm_b = _block_mask(kvm, n_j, block_k)
     kseg_b = _block_mask(kseg, n_j, block_k)
-    dq_mask_spec = _mask_block_spec(
-        nheads, block_k, j_pos=2,
-        banded_lo=j_lo if banded_j else None, n_j=n_j)
-    dq_inputs = (q, k, v, do, lse, delta)
-    if has_mask:
-        dq_in_specs.append(dq_mask_spec)
-        dq_inputs += (kvm_b,)
-    if has_segs:
-        dq_in_specs.append(_qseg_spec(nheads, block_q))
-        dq_in_specs.append(dq_mask_spec)
-        dq_inputs += (qseg, kseg_b)
-    if dropout_p > 0.0:
-        dq_in_specs.append(_seed_spec(q.shape[0]))
-        dq_inputs += (seed,)
-    dq = _named_call(
-        "pt_flash_dq",
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal, window=window,
-            has_mask=has_mask, has_segs=has_segs, dropout_p=dropout_p,
-            offset=offset, block_q=block_q, block_k=block_k,
-            num_k_blocks=band_j if banded_j else n_j, banded=banded_j,
-            n_j=n_j),
-        grid=(bh, n_i, band_j if banded_j else n_j),
-        in_specs=dq_in_specs,
-        out_specs=_vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[_scratch((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(*dq_inputs)
-
+    fused = bwd_is_fused(tq, d, e, block_q, block_k, q.dtype)
+    kernel = functools.partial(
+        _bwd_kernel, scale=scale, causal=causal, window=window,
+        has_mask=has_mask, has_segs=has_segs, dropout_p=dropout_p,
+        offset=offset, block_q=block_q, block_k=block_k)
+    if not fused:  # dq of a query block, summed over the key blocks
+        dq_k_spec, dq_v_spec = (
+            _vmem_spec((1, block_k, w), kv_imap_banded)
+            if banded_j else _causal_kv_spec(
+                block_q, block_k, w, nheads, kv_heads, offset, n_j, causal)
+            for w in (d, e))
+        dq_in_specs = [
+            _vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            dq_k_spec,
+            dq_v_spec,
+            _vmem_spec((1, block_q, e), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            _vmem_spec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+        ]
+        dq_mask_spec = _mask_block_spec(
+            nheads, block_k, j_pos=2,
+            banded_lo=j_lo if banded_j else None, n_j=n_j)
+        dq_inputs = (q, k, v, do, lse, delta)
+        if has_mask:
+            dq_in_specs.append(dq_mask_spec)
+            dq_inputs += (kvm_b,)
+        if has_segs:
+            dq_in_specs.append(_qseg_spec(nheads, block_q))
+            dq_in_specs.append(dq_mask_spec)
+            dq_inputs += (qseg, kseg_b)
+        if dropout_p > 0.0:
+            dq_in_specs.append(_seed_spec(q.shape[0]))
+            dq_inputs += (seed,)
+        dq = _named_call(
+            "pt_flash_dq",
+            functools.partial(
+                kernel, grads="dq", banded=banded_j, n_inner=n_j,
+                num_steps=band_j if banded_j else n_j),
+            grid=(bh, n_i, band_j if banded_j else n_j),
+            in_specs=dq_in_specs,
+            out_specs=_vmem_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            scratch_shapes=[_scratch((block_q, d), jnp.float32)],
+            interpret=interpret)(*dq_inputs)
     if causal:
         # dkv's skipped steps come FIRST (q-blocks above the diagonal):
         # clamped up to the first block that runs, they prefetch it
@@ -736,30 +734,32 @@ def _bwd_call(q, k, v, kvm, qseg, kseg, seed, nheads, kv_heads, o, lse,
     if dropout_p > 0.0:
         dkv_in_specs.append(_seed_spec(q.shape[0]))
         dkv_inputs += (seed,)
-    dk, dv = _named_call(
+    out_specs = [_vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+                 _vmem_spec((1, block_k, e), lambda b, j, i: (b, j, 0))]
+    out_shape = [jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
+                 jax.ShapeDtypeStruct((bh, tk, e), v.dtype)]
+    scratch = [_scratch((block_k, d), jnp.float32),
+               _scratch((block_k, e), jnp.float32)]
+    limit = {}
+    if fused:
+        # dq leads the outputs: one (tq, d) block a (batch, head) whose
+        # index ignores both block axes, so it stays in VMEM beside its
+        # float32 accumulator (the last scratch) and is written back once
+        out_specs.insert(0, _vmem_spec((1, tq, d), lambda b, j, i: (b, 0, 0)))
+        out_shape.insert(0, jax.ShapeDtypeStruct((bh, tq, d), q.dtype))
+        scratch.append(_scratch((tq, d), jnp.float32))
+        limit = dict(compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_BWD_VMEM_LIMIT))
+    *dq_fused, dk, dv = _named_call(
         "pt_flash_dkdv",
         functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, window=window,
-            has_mask=has_mask, has_segs=has_segs, dropout_p=dropout_p,
-            offset=offset, block_q=block_q, block_k=block_k,
-            num_q_blocks=band_i if banded_i else n_i, banded=banded_i,
-            n_i=n_i),
-        grid=(bh, n_j, band_i if banded_i else n_i),
-        in_specs=dkv_in_specs,
-        out_specs=(
-            _vmem_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _vmem_spec((1, block_k, e), lambda b, j, i: (b, j, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, e), v.dtype),
-        ),
-        scratch_shapes=[
-            _scratch((block_k, d), jnp.float32),
-            _scratch((block_k, e), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*dkv_inputs)
+            kernel, grads="all" if fused else "dkdv", banded=banded_i,
+            n_inner=n_i, num_steps=band_i if banded_i else n_i),
+        grid=(bh, n_j, band_i if banded_i else n_i), in_specs=dkv_in_specs,
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+        interpret=interpret, **limit)(*dkv_inputs)
+    if fused:
+        dq, = dq_fused
     if kv_heads != nheads:
         # dk/dv came back per Q-head; sum each group onto its shared
         # K/V head (h is kv-major: head = kv_head * group + g)

@@ -279,6 +279,16 @@ def test_tune_attention_at_a_value_width_of_its_own(monkeypatch):
     assert entry["shape"] == [1, 1024, 2, 2, 256, 128]
     assert set(entry["sweep_fwd_ms"]) == set(entry["sweep_grad_ms"]) == {
         "512x512", "512x1024", "1024x512", "1024x1024"}
+    # which backward each gradient timing ran, by ``_bwd_call``'s own rule
+    assert entry["sweep_grad_backward"] == {
+        pair: "fused" if FA.bwd_is_fused(
+            1024, 256, 128, *map(int, pair.split("x")), jnp.bfloat16)
+        else "pair" for pair in entry["sweep_grad_ms"]}
+    assert set(entry["sweep_grad_backward"].values()) == {"fused"}
+    monkeypatch.setattr(FA, "FUSED_BWD_VMEM_LIMIT", 0)
+    assert set(pt_mod.tune_attention(
+        1, 1024, 2, 256, causal=True, dry_run=True, e=128,
+        blocks=[512])["sweep_grad_backward"].values()) == {"pair"}
     assert [entry[n] for n in ("block_q", "block_k", "block_q_bwd",
                                "block_k_bwd")] == [1024] * 4
     assert entry["use_flash"] is True and "xla_ms" not in entry

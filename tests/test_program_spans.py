@@ -298,9 +298,19 @@ def _kernel_names(jitted, *args):
     return set(re.findall(r'kernel_name = "([^"]+)"', text))
 
 
-def test_flash_kernels_carry_their_names():
+@pytest.mark.parametrize("fused", [True, False], ids=["one_kernel", "pair"])
+def test_flash_kernels_carry_their_names(fused, monkeypatch):
+    """The backward is one kernel, dq beside dk and dv under dk / dv's
+    name; past the rule ``bwd_is_fused`` (here: a ceiling of nothing) it
+    is the pair, each kernel under its own name."""
+    import importlib
+
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
+    if not fused:
+        monkeypatch.setattr(importlib.import_module(
+            "paddle_tpu.ops.pallas.flash_attention"),
+            "FUSED_BWD_VMEM_LIMIT", 0)
     q = jnp.zeros((2, 256, 4, 64), jnp.bfloat16)
     fwd = jax.jit(lambda q, k, v: flash_attention(
         q, k, v, causal=True, interpret=False))
@@ -309,7 +319,8 @@ def test_flash_kernels_carry_their_names():
         q, k, v, causal=True, interpret=False).astype(jnp.float32).sum(),
         argnums=(0, 1, 2)))
     assert _kernel_names(bwd, q, q, q) == {
-        "pt_flash_fwd", "pt_flash_dq", "pt_flash_dkdv"}
+        "pt_flash_fwd", "pt_flash_dkdv"} | (set() if fused
+                                            else {"pt_flash_dq"})
 
 
 def test_decode_kernels_carry_their_names():
@@ -332,15 +343,24 @@ def test_decode_kernels_carry_their_names():
         "pt_flash_decode_paged"}
 
 
-@pytest.mark.parametrize("kernel", ["pt_flash_fwd", "pt_flash_dq",
-                                    "pt_flash_dkdv"])
-def test_a_scope_of_the_kernels_name_is_the_innermost_around_it(kernel):
+@pytest.mark.parametrize("kernel, fused", [
+    ("pt_flash_fwd", True), ("pt_flash_dkdv", True),
+    ("pt_flash_dq", False), ("pt_flash_dkdv", False)])
+def test_a_scope_of_the_kernels_name_is_the_innermost_around_it(
+        kernel, fused, monkeypatch):
     """The compiled ``custom-call`` instruction, and with it the
     profiler's ``XLA Ops`` event, is named after the innermost scope of
     its ``op_name``: that has to be the kernel's own name, not the JAX
-    transform around it (``checkpoint``, ``jvp``)."""
+    transform around it (``checkpoint``, ``jvp``). The one-kernel
+    backward and the pair (forced by a ceiling of nothing) alike."""
+    import importlib
+
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
+    if not fused:
+        monkeypatch.setattr(importlib.import_module(
+            "paddle_tpu.ops.pallas.flash_attention"),
+            "FUSED_BWD_VMEM_LIMIT", 0)
     q = jnp.zeros((1, 128, 2, 64), jnp.float32)
 
     def loss(q, k, v):
@@ -351,6 +371,7 @@ def test_a_scope_of_the_kernels_name_is_the_innermost_around_it(kernel):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, q, q).as_text(debug_info=True)
     assert re.search(rf'/{kernel}/pallas_call"', text), kernel
+    assert ("/pt_flash_dq/" in text) == (not fused)
 
 
 def test_step_programs_carry_their_names():

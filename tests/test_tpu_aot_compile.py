@@ -159,35 +159,46 @@ def test_flash_backward_compiles(one_chip, table_of_described_chip, case):
                                interpret=False).astype(jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    # forward (recomputed) + the dq and dk/dv backward kernels
-    assert text.count("tpu_custom_call") >= 3
+    # the forward + the ONE backward kernel (dq beside dk and dv, under
+    # the limit of its own that ``_bwd_call`` asks Mosaic for)
+    assert text.count("tpu_custom_call") >= 2
     # each custom-call instruction carries its kernel's own name: what a
     # profile's XLA Ops line calls the event
-    for name in ("%pt_flash_fwd", "%pt_flash_dq", "%pt_flash_dkdv"):
+    for name in ("%pt_flash_fwd", "%pt_flash_dkdv"):
         assert name in text, name
+    assert "%pt_flash_dq" not in text
 
 
+@pytest.mark.parametrize("rows, seq", [(2, 8192), (1, 14336)],
+                         ids=["kanana_2x8192", "longest_1x14336"])
 def test_flash_two_widths_compile_at_the_trained_latent_cells_call(
-        one_chip, table_of_described_chip):
+        one_chip, table_of_described_chip, rows, seq):
     """kanana-2's attention as ``causal_attention`` hands it to the
     kernels: 2 x 8192 positions, 32 heads, scores padded 192 -> 256,
     values at their own 128, bf16, at the blocks the committed table
     gives the call on the v5e; forward and backward through the TPU
-    compiler, which is where a block too large for VMEM is refused."""
+    compiler, which is where a block too large for VMEM is refused.
+    This is the one-kernel backward's VMEM gate: dq's float32
+    accumulator spans the whole query length (8 MiB here beside its
+    4 MiB output block, twice), so the call compiles only under the
+    limit ``_bwd_call`` asks for; the table's longest entry at these
+    widths (1 x 14336) is where the accumulator is largest."""
     from paddle_tpu.ops.latent_attention import (FLASH_BLOCK_K,
                                                  FLASH_BLOCK_Q)
     from paddle_tpu.ops.pallas import tuning
-    from paddle_tpu.ops.pallas.flash_attention import resolve_block_sizes
+    from paddle_tpu.ops.pallas.flash_attention import (bwd_is_fused,
+                                                       resolve_block_sizes)
 
-    key = tuning.attention_key(8192, 8192, 256, True, dtype=jnp.bfloat16,
+    key = tuning.attention_key(seq, seq, 256, True, dtype=jnp.bfloat16,
                                e=128)
     assert tuning.get_tuned(key), key
     bq, bk, bq_bwd, bk_bwd = resolve_block_sizes(
-        8192, 8192, 256, True, dtype=jnp.bfloat16, e=128,
+        seq, seq, 256, True, dtype=jnp.bfloat16, e=128,
         default_q=FLASH_BLOCK_Q, default_k=FLASH_BLOCK_K)
-    qk = jax.ShapeDtypeStruct((2, 8192, 32, 256), jnp.bfloat16,
+    assert bwd_is_fused(seq, 256, 128, bq_bwd, bk_bwd, jnp.bfloat16)
+    qk = jax.ShapeDtypeStruct((rows, seq, 32, 256), jnp.bfloat16,
                               sharding=one_chip)
-    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+    v = jax.ShapeDtypeStruct((rows, seq, 32, 128), jnp.bfloat16,
                              sharding=one_chip)
 
     def loss(q, k, v):
@@ -198,14 +209,17 @@ def test_flash_two_widths_compile_at_the_trained_latent_cells_call(
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
     # what each kernel writes: o and dv as wide as the values, dq and dk
-    # as wide as the scores
+    # as wide as the scores; the one backward kernel writes all three
+    assert "%pt_flash_dq" not in text
     wrote = {name: re.search(rf"%{name}[.\d]* = (.*?) custom-call\(",
                              text).group(1)
-             for name in ("pt_flash_fwd", "pt_flash_dq", "pt_flash_dkdv")}
-    assert wrote["pt_flash_fwd"].count("bf16[64,8192,128]") == 1
-    assert wrote["pt_flash_dq"].count("bf16[64,8192,256]") == 1
-    assert (wrote["pt_flash_dkdv"].index("bf16[64,8192,256]")
-            < wrote["pt_flash_dkdv"].index("bf16[64,8192,128]"))
+             for name in ("pt_flash_fwd", "pt_flash_dkdv")}
+    scores, values = (f"bf16[{rows * 32},{seq},{w}]" for w in (256, 128))
+    assert wrote["pt_flash_fwd"].count(values) == 1
+    assert wrote["pt_flash_dkdv"].count(scores) == 2       # dq, dk
+    assert wrote["pt_flash_dkdv"].count(values) == 1       # dv
+    assert (wrote["pt_flash_dkdv"].rindex(scores)
+            < wrote["pt_flash_dkdv"].index(values))
 
 
 def test_flash_key_padding_mask_compiles(one_chip):
@@ -446,7 +460,9 @@ def test_flash_dp4_compiles_via_shard_map(chips, monkeypatch):
 
     with mesh_scope(mesh):
         text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count("tpu_custom_call") >= 3
+    # the forward and the one backward kernel, each on its quarter
+    assert text.count("tpu_custom_call") >= 2
+    assert "%pt_flash_fwd" in text and "%pt_flash_dkdv" in text
     assert "all-gather" not in text
     # each chip works on its quarter of the batch
     assert f"bf16[{B // 4},{T},{H_KV}," in text
@@ -651,8 +667,10 @@ def test_no_product_of_the_train_step_reads_a_float32_linear_weight(
             *args).compile().as_text()
     # remat keeps a block's flash o and lse (``nn.remat_policy``), and the
     # compiler leaves it so: each kernel once a layer in the COMPILED step
-    for kernel in ("pt_flash_fwd", "pt_flash_dq", "pt_flash_dkdv"):
+    # (two layers: the forward kernel and the one backward kernel twice)
+    for kernel in ("pt_flash_fwd", "pt_flash_dkdv"):
         assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 2, kernel
+    assert "%pt_flash_dq" not in text
     assert text.count(" convolution(") >= 2 * 7 * 4    # forward, second
     assert _wide_weights_of_products(text, names) == set()  # forward, two back
     control = """

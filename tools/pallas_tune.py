@@ -96,7 +96,8 @@ def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
 
     from paddle_tpu.ops.attention import xla_attention
     from paddle_tpu.ops.pallas import tuning
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.ops.pallas.flash_attention import (bwd_is_fused,
+                                                       flash_attention)
 
     kv_heads = kv_heads or h
     e = e or d
@@ -112,9 +113,11 @@ def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
     ct = mk(w=e).astype(jnp.float32)
 
     def grad_of(fn):
-        g = jax.jit(jax.grad(lambda q, k, v: (fn(q, k, v).astype(
+        # ct is an ARGUMENT: closed over, it is a constant of the program
+        # (268 MB at the kanana call: a 330 MB executable, 20 s a compile)
+        g = jax.jit(jax.grad(lambda q, k, v, ct: (fn(q, k, v).astype(
             jnp.float32) * ct).sum(), argnums=(0, 1, 2)))
-        return lambda *a: g(*a)
+        return lambda *a: g(*a, ct)
 
     # candidates never exceed t; when t is below every table entry
     # (e.g. t=64 vs ATTN_BLOCKS starting at 128) fall back to block=t so
@@ -122,21 +125,29 @@ def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
     # an empty sweep that would persist use_flash=False unmeasured
     cand = [blk for blk in blocks or ATTN_BLOCKS if blk <= t] or [t]
 
-    def sweep(what, build):
+    def backward(bq, bk):
+        """Which backward value-and-gradient runs at these blocks: the
+        rule is ``_bwd_call``'s own, read here and nowhere restated."""
+        return "fused" if bwd_is_fused(t, d, e, bq, bk, jdtype) else "pair"
+
+    def sweep(what, build, note=lambda bq, bk: ""):
         results = []
         for bq, bk in itertools.product(cand, cand):
             try:
                 ms, spread = _time_reps(build(bq, bk), q, k, v)
                 results.append((ms, bq, bk, spread))
-                print(f"  flash {what} bq={bq} bk={bk}: {ms*1e3:.3f}ms "
-                      f"(spread {spread*100:.1f}%)", flush=True)
+                print(f"  flash {what}{note(bq, bk)} bq={bq} bk={bk}: "
+                      f"{ms*1e3:.3f}ms (spread {spread*100:.1f}%)",
+                      flush=True)
             except Exception as e:
-                print(f"  flash {what} bq={bq} bk={bk}: FAILED "
-                      f"({type(e).__name__}: {str(e)[:120]})", flush=True)
+                print(f"  flash {what}{note(bq, bk)} bq={bq} bk={bk}: "
+                      f"FAILED ({type(e).__name__}: {str(e)[:120]})",
+                      flush=True)
         return results
 
-    # forward and backward are tuned INDEPENDENTLY: the dq/dkv kernels
-    # have a different arithmetic-intensity sweet spot than the fwd
+    # forward and backward are tuned INDEPENDENTLY: the backward (one
+    # kernel where dq's accumulator fits VMEM, else the dq / dkv pair)
+    # has a different arithmetic-intensity sweet spot than the fwd
     # kernel, and coupling them to one (bq, bk) pair leaves bwd time on
     # the table (observed on-chip: best fwd pair != best bwd pair)
     fwd_results = sweep("fwd", lambda bq, bk: jax.jit(
@@ -152,7 +163,8 @@ def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
         bwd_results = sweep("bwd", lambda bq, bk: grad_of(
             lambda q, k, v: flash_attention(
                 q, k, v, causal=causal, block_q=fq, block_k=fk,
-                block_q_bwd=bq, block_k_bwd=bk, interpret=False)))
+                block_q_bwd=bq, block_k_bwd=bk, interpret=False)),
+            note=lambda bq, bk: f" ({backward(bq, bk)} backward)")
     best_bwd = min(bwd_results) if bwd_results else None
 
     ms = lambda x: round(x * 1e3, 4)
@@ -199,8 +211,12 @@ def tune_attention(b, t, h, d, causal, dry_run=False, kv_heads=None,
                  "fwd_spread_pct": round(best_fwd[3] * 100, 2),
                  "grad_spread_pct": round(best_bwd[3] * 100, 2)}
     # what the winner was chosen from: every pair that compiled, in ms
+    # ... and which backward each gradient timing ran ("fused": dq beside
+    # dk and dv in one kernel; "pair": pt_flash_dq + pt_flash_dkdv)
     entry.update(measured, sweep_fwd_ms=table(fwd_results),
-                 sweep_grad_ms=table(bwd_results))
+                 sweep_grad_ms=table(bwd_results),
+                 sweep_grad_backward={f"{bq}x{bk}": backward(bq, bk)
+                                      for _, bq, bk, _ in bwd_results})
     print(f"  -> {key}: {entry}")
     if not dry_run:
         tuning.set_tuned(key, entry)
